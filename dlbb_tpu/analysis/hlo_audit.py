@@ -614,7 +614,7 @@ def _tp_overlap_train_target(schedule: str, dp: int = 2,
         expectation=TargetExpectation(
             # all-to-all: GSPMD reshards the scanned backward's
             # broadcast-zero cotangent init with a (tiny, constant-operand)
-            # all-to-all on this jaxlib — covered by the byte ceiling, and
+            # all-to-all on some jaxlibs — covered by the byte ceiling, and
             # absent from the forward target where the strict set holds
             allowed=plan_expected_kinds(dp=dp, tp=tp, tp_overlap=schedule)
             | {"all-to-all"},
@@ -1337,13 +1337,24 @@ def default_targets() -> list[AuditTarget]:
     return targets
 
 
+# device_kind (as jax reports it) -> cost-model tier; a device that is not
+# here is an error, never a default priced at another chip's peaks
+TIER_BY_DEVICE_KIND = {"cpu": "cpu-sim", "TPU v5 lite": "tpu-v5lite"}
+
+
 def default_tier() -> str:
-    """The cost-model tier matching the current backend: the CPU-simulated
-    mesh prices at ``cpu-sim`` (the committed-baseline tier); a real TPU
-    at ``tpu-v5lite``."""
+    """The cost-model tier matching the current device: the CPU-simulated
+    mesh prices at ``cpu-sim`` (the committed-baseline tier), a v5e at
+    ``tpu-v5lite``."""
     import jax
 
-    return "cpu-sim" if jax.default_backend() == "cpu" else "tpu-v5lite"
+    kind = jax.devices()[0].device_kind
+    if kind not in TIER_BY_DEVICE_KIND:
+        raise KeyError(
+            f"no cost-model tier for device_kind {kind!r} (known: "
+            f"{sorted(TIER_BY_DEVICE_KIND)}); pass --tier explicitly"
+        )
+    return TIER_BY_DEVICE_KIND[kind]
 
 
 def run_hlo_audit(

@@ -36,10 +36,11 @@ from dlbb_tpu.models.transformer import (
     init_params_sharded,
     num_parameters,
 )
+from dlbb_tpu.ops import mosaic_call_count
 from dlbb_tpu.utils.config import load_config, save_json
 from dlbb_tpu.utils.metrics import Timer, summarize
 from dlbb_tpu.utils.profiling import annotate
-from dlbb_tpu.utils.sysinfo import collect_system_info
+from dlbb_tpu.utils.sysinfo import collect_system_info, device_spread
 from dlbb_tpu.utils.timing import (
     force_completion,
     resolve_timing_mode,
@@ -90,18 +91,29 @@ def run_e2e(
         for k, v in (execution.get("compiler_options") or {}).items()
     }
 
-    # The model maps [B,S,H] -> [B,S,H], so chained timing on remote-async
-    # backends feeds the output straight back as the next input.
+    # The model maps [B,S,H] -> [B,S,H], so chained timing feeds the output
+    # straight back as the next input.
     mode = resolve_timing_mode("auto")
 
     with annotate("compile+warmup"):
         with Timer() as t_compile:
+            mosaic_calls = mosaic_call_count(step, params, batch)
             if comp_opts and mode == "per_iter":
                 step = step.lower(params, batch).compile(
                     compiler_options=comp_opts
                 )
-            force_completion(step(params, batch))
+            out = step(params, batch)
+            force_completion(out)
         compile_time = t_compile.elapsed
+        # what came out, read once here — never inside the timed loop
+        out32 = out.astype(jnp.float32)
+        output_check = {
+            "shape": list(out.shape),
+            "finite": bool(jnp.isfinite(out32).all()),
+            "mean_abs": float(jnp.abs(out32).mean()),
+            "devices": device_spread(out),
+        }
+        del out, out32
 
     with annotate("measure"):
         if mode == "per_iter":
@@ -155,6 +167,8 @@ def run_e2e(
         "init_time_s": init_time,
         "compiler_options": comp_opts or None,
         "compile_time_s": compile_time,
+        "mosaic_calls": mosaic_calls,
+        "output_check": output_check,
         "forward_time": summarize(forward_times),
         **timing_meta,
         "per_host_means_s": host_means.tolist(),

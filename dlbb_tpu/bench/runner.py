@@ -65,6 +65,7 @@ from dlbb_tpu.resilience.validate import (
     validate_result_json,
     validate_timings,
 )
+from dlbb_tpu.utils.compile_cache import sweep_scope
 from dlbb_tpu.utils.config import save_json
 from dlbb_tpu.utils.sysinfo import collect_system_info
 from dlbb_tpu.utils.timing import resolve_timing_mode, time_collective
@@ -149,9 +150,8 @@ class Sweep1D:
     # schema/semantics; True forces the thread on
     pipeline: Optional[bool] = None
     prefetch: int = 2
-    # persistent XLA compilation cache: "auto" -> results/.xla_cache, an
-    # explicit directory, or None/"off" to disable (DLBB_XLA_CACHE env
-    # overrides either way)
+    # persistent XLA compilation cache for this sweep: "auto" = on, in the
+    # one directory utils/compile_cache.py resolves; None/"off" = off
     compile_cache: Optional[str] = "auto"
     # --- resilience knobs (docs/resilience.md) ---------------------------
     # fault-injection plan spec (dlbb_tpu.resilience.inject grammar);
@@ -261,21 +261,6 @@ def _check_variant_flags(variant: Variant) -> None:
             f"variant {variant.name!r} requires XLA_FLAGS to contain "
             f"{missing}; relaunch the process with them set (process-start "
             "option; cannot be applied after backend init)"
-        )
-    from dlbb_tpu.compat import supports_compiler_option
-
-    unsupported = [
-        k for k, v in variant.compiler_options
-        if not supports_compiler_option(k, v)
-    ]
-    if unsupported:
-        raise RuntimeError(
-            f"variant {variant.name!r} needs per-computation compiler "
-            f"option(s) {unsupported}, which this jaxlib's compile path "
-            "rejects (protobuf reflection cannot set repeated DebugOptions "
-            "fields); the variant cannot run — and cannot be labeled "
-            "honestly — on this jaxlib; upgrade jaxlib to one whose PJRT "
-            "compile path accepts these options"
         )
 
 
@@ -416,23 +401,19 @@ def run_sweep(
     # --span-trace wrapper, a test) already opened WINS and collects this
     # sweep's spans — the tracing() scope is then a pure pass-through
     span_path = sweep.span_trace or spans.default_span_path()
-    # everything from here — planning included — runs with the persistent
-    # compilation cache scoped to this sweep; the finally guarantees no
-    # later non-sweep compile ever sees it (see
-    # schedule.deactivate_compilation_cache)
-    cache_dir = schedule.configure_compilation_cache(sweep.compile_cache)
-    try:
-        with spans.tracing(span_path,
-                           meta={"kind": sweep.kind,
-                                 "implementation": impl,
-                                 "variant": variant.name}), \
-                inject.plan_scope(fault_spec), PreemptionGuard() as guard:
-            return _run_sweep_configured(
-                sweep, variant, impl, out_dir, written, sysinfo, n_avail,
-                devices, mode, cache_dir, t_sweep0, verbose, guard,
-            )
-    finally:
-        schedule.deactivate_compilation_cache()
+    # everything from here — planning included — runs inside the sweep's
+    # compilation-cache scope (on the simulated mesh the cache is on only
+    # here; utils/compile_cache.py says why)
+    with sweep_scope(sweep.compile_cache) as cache_dir, \
+            spans.tracing(span_path,
+                          meta={"kind": sweep.kind,
+                                "implementation": impl,
+                                "variant": variant.name}), \
+            inject.plan_scope(fault_spec), PreemptionGuard() as guard:
+        return _run_sweep_configured(
+            sweep, variant, impl, out_dir, written, sysinfo, n_avail,
+            devices, mode, cache_dir, t_sweep0, verbose, guard,
+        )
 
 
 def _collective_stop(requested: bool) -> bool:
@@ -518,15 +499,13 @@ def _run_sweep_configured(
         # tell the same story — docs/observability.md
         sink=spans.journal_sink,
     )
-    # topology fingerprint (ROADMAP item 5 standing chore): which fabric
-    # this sweep actually measured, journaled + manifested — a degraded
-    # CPU fallback is a durable record, never just a log line
+    # topology fingerprint: which fabric this sweep measured, journaled +
+    # manifested (raises on a CPU backend nobody asked for — the no-chip
+    # rule, utils/simulate.require_accelerator)
     from dlbb_tpu.utils.simulate import topology_record
 
     topology = topology_record()
     journal.event("topology", **topology)
-    if topology["degraded"] and verbose:
-        print(f"[topology] DEGRADED backend: {topology.get('degraded_reason')}")
     # ---- planning pass -------------------------------------------------
     plan: list[_Planned] = []
     units: "dict[tuple, schedule.WorkUnit]" = {}
@@ -534,15 +513,6 @@ def _run_sweep_configured(
     # counters below are registry-backed, so the manifest's `configs`
     # section and the metrics.prom textfile export come from one source
     metrics = MetricsRegistry()
-    # a degraded-probe fallback is a FIRST-CLASS event (ROADMAP standing
-    # chore): its own journal record + Prometheus counter, so `obs
-    # trace` timelines and scrapes both see it — not just a field
-    # buried in the topology record
-    metrics.inc("sweep_degraded", 1 if topology["degraded"] else 0,
-                help="sweeps measured on a degraded (fallback) backend")
-    if topology["degraded"]:
-        journal.event("degraded",
-                      reason=topology.get("degraded_reason"))
     # every counter counts CONFIGS (a skipped rank count skips one whole
     # grid of them), so planned+skipped+resumed+failed adds up
     # (resume_invalid configs re-run, so they also land in

@@ -22,10 +22,10 @@ semantics.  Three mechanisms, all orthogonal to *how* a config is timed:
   sweep's ``prefetch``; ``pipeline=False`` degrades to inline
   compile-on-demand through the *same* code path (the ``--no-pipeline``
   debug mode).
-- **Persistent compilation cache** — :func:`configure_compilation_cache`
-  wires ``jax_compilation_cache_dir`` (default ``results/.xla_cache``,
-  ``DLBB_XLA_CACHE`` env / ``--compile-cache`` CLI override, ``off`` to
-  disable), so publisher re-runs and ``resume`` sweeps deserialise
+- **Persistent compilation cache** — configured in one place,
+  ``dlbb_tpu.utils.compile_cache`` (``JAX_COMPILATION_CACHE_DIR`` or
+  ``<checkout>/.jax_cache``; ``--compile-cache off`` disables it for one
+  sweep), so publisher re-runs and ``resume`` sweeps deserialise
   executables instead of recompiling.  Hits/misses are observed through
   ``jax.monitoring`` events and recorded per work unit — each result
   artifact carries honest ``compile_seconds`` / ``compile_cache_hit``
@@ -61,125 +61,8 @@ from dlbb_tpu.comm.ops import CollectiveOp, payload_aval
 from dlbb_tpu.obs import spans
 from dlbb_tpu.resilience import inject
 from dlbb_tpu.resilience.errors import DeadlineExceeded, InjectedFault
+from dlbb_tpu.utils.compile_cache import CACHE_EVENTS
 from dlbb_tpu.utils.timing import build_chained_loop, chained_chunk_size
-
-# ---------------------------------------------------------------------------
-# persistent compilation cache
-# ---------------------------------------------------------------------------
-
-# Default under results/: the cache is a results-adjacent artifact of the
-# publisher corpus (gitignored), salted by jaxlib version inside JAX's own
-# cache key, so upgrading jaxlib invalidates it automatically.
-DEFAULT_CACHE_DIR = os.path.join("results", ".xla_cache")
-
-_CACHE_OFF_VALUES = {"", "off", "none", "0", "disabled"}
-
-# last directory this process configured (sentinel: never configured).
-# jax 0.4.x latches cache-enablement state at the FIRST compile of the
-# process (compilation_cache._cache_checked): a compile that ran before
-# any cache dir was set pins the cache "unused" forever unless the state
-# is reset — so every directory CHANGE resets it.
-_configured_dir: Any = object()
-
-# the caller's jax cache config (dir, min-compile-time, min-entry-size)
-# captured before the first mutation, so deactivation RESTORES a
-# pre-existing user configuration (e.g. JAX_COMPILATION_CACHE_DIR set in
-# an embedding process) instead of clobbering it to disabled
-_saved_cache_state: Optional[tuple] = None
-
-
-def _snapshot_cache_state() -> None:
-    global _saved_cache_state
-    if _saved_cache_state is None:
-        _saved_cache_state = (
-            jax.config.jax_compilation_cache_dir,
-            jax.config.jax_persistent_cache_min_compile_time_secs,
-            jax.config.jax_persistent_cache_min_entry_size_bytes,
-        )
-
-
-def _reset_jax_cache_state() -> None:
-    from jax.experimental.compilation_cache import compilation_cache as cc
-
-    cc.reset_cache()
-
-
-def configure_compilation_cache(
-    setting: Optional[str] = "auto",
-) -> Optional[str]:
-    """Point JAX's persistent compilation cache at a directory (or disable).
-
-    ``setting``: ``"auto"`` → :data:`DEFAULT_CACHE_DIR`; an explicit path →
-    that path; ``None``/``"off"``/``"0"`` → disabled.  The ``DLBB_XLA_CACHE``
-    environment variable overrides whatever the caller passes (the launcher
-    analogue of the CLI flag).  Returns the configured directory, or None
-    when disabled.
-
-    The min-compile-time/min-entry-size thresholds are zeroed: the
-    simulated-mesh micro-programs compile in milliseconds and would
-    otherwise never be cached, which is exactly the regime where re-run
-    compile time dominates sweep wall time.
-    """
-    global _configured_dir
-    env = os.environ.get("DLBB_XLA_CACHE")
-    if env is not None:
-        setting = env
-    if setting is None or str(setting).lower() in _CACHE_OFF_VALUES:
-        _snapshot_cache_state()
-        jax.config.update("jax_compilation_cache_dir", None)
-        if _configured_dir is not None:
-            _reset_jax_cache_state()
-            _configured_dir = None
-        return None
-    _snapshot_cache_state()
-    cache_dir = DEFAULT_CACHE_DIR if setting == "auto" else str(setting)
-    Path(cache_dir).mkdir(parents=True, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-    if _configured_dir != cache_dir:
-        # also clears the "cache unused" latch a pre-configuration compile
-        # may have pinned (see _configured_dir comment)
-        _reset_jax_cache_state()
-        _configured_dir = cache_dir
-    return cache_dir
-
-
-def deactivate_compilation_cache() -> None:
-    """Disable the persistent cache and clear JAX's latched cache state.
-
-    The cache is SCOPED TO SWEEPS: ``run_sweep`` activates it for its own
-    compiles and calls this on exit, so no other compile in the process
-    ever goes through executable (de)serialization.  That scoping is a
-    correctness requirement on this jaxlib, not hygiene: with the cache
-    left enabled process-wide, XLA:CPU hard-aborts (fatal ``Aborted``, not
-    an exception) serialising some non-sweep programs — observed
-    deterministically on the checkpoint-restore train step
-    (``tests/test_checkpoint.py::test_resume_continues_trajectory``) the
-    moment a prior sweep left the cache on.  Sweep programs (shard_map
-    collectives and the chained timing loop) round-trip fine.
-
-    A configuration the CALLER had in place before the sweep (e.g.
-    ``JAX_COMPILATION_CACHE_DIR`` in an embedding process) is restored,
-    thresholds included, not clobbered to disabled — the sweep scope
-    must be invisible to the surrounding process.  Unlike
-    :func:`configure_compilation_cache` this ignores ``DLBB_XLA_CACHE``
-    — the env var picks the cache *location*, it must not be able to
-    veto the restore."""
-    global _configured_dir, _saved_cache_state
-    if _saved_cache_state is not None:
-        prev_dir, prev_mct, prev_mes = _saved_cache_state
-        jax.config.update("jax_compilation_cache_dir", prev_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs", prev_mct)
-        jax.config.update(
-            "jax_persistent_cache_min_entry_size_bytes", prev_mes)
-        _saved_cache_state = None
-    else:
-        jax.config.update("jax_compilation_cache_dir", None)
-    if _configured_dir is not None:
-        _reset_jax_cache_state()
-        _configured_dir = None
 
 
 def default_pipeline() -> bool:
@@ -204,45 +87,6 @@ def default_pipeline() -> bool:
         return True
     return (os.cpu_count() or 1) >= 4
 
-
-class _CacheEventCounter:
-    """Counts JAX persistent-compilation-cache hit/miss monitoring events.
-
-    ``jax.monitoring`` listeners are global and cannot be unregistered, so
-    one process-wide counter is registered lazily and compile sites sample
-    it before/after each compile (under :data:`_COMPILE_LOCK`, which
-    serialises compiles so the delta attributes to exactly one of them).
-    """
-
-    HIT = "/jax/compilation_cache/cache_hits"
-    MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self) -> None:
-        self.hits = 0
-        self.misses = 0
-        self._registered = False
-        self._lock = threading.Lock()
-
-    def ensure_registered(self) -> None:
-        with self._lock:
-            if self._registered:
-                return
-            from jax import monitoring
-
-            def _listener(event: str, **kwargs: Any) -> None:
-                if event == self.HIT:
-                    self.hits += 1
-                elif event == self.MISS:
-                    self.misses += 1
-
-            monitoring.register_event_listener(_listener)
-            self._registered = True
-
-    def snapshot(self) -> tuple[int, int]:
-        return self.hits, self.misses
-
-
-CACHE_EVENTS = _CacheEventCounter()
 
 # Serialises trace+lower+compile so persistent-cache hit events attribute
 # to the unit being compiled.  XLA compilation would release the GIL, but

@@ -10,12 +10,21 @@ become the ``--ranks`` flag; the CCL_* env tuning matrix becomes
 ``--simulate N`` stands up the N-device CPU-simulated mesh (the dev path,
 analogue of running N ranks on localhost) — it must act before the JAX
 backend initialises, which is why it is handled first in ``main``.
+Without it a device command needs an accelerator: on a CPU backend it
+exits non-zero before it measures or writes anything
+(``utils/simulate.require_accelerator``).
 """
 
 from __future__ import annotations
 
 import argparse
+import os
 import sys
+
+# subcommands that measure on the device: they get the persistent compile
+# cache and the no-chip rule; the rest are file processing, or (analyze,
+# obs, chaos) reach a backend only through code that checks for itself
+DEVICE_COMMANDS = ("bench1d", "bench3d", "e2e", "train", "serve", "plan")
 
 
 def _add_common(p: argparse.ArgumentParser) -> None:
@@ -41,11 +50,11 @@ def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--prefetch", type=int, default=2, metavar="K",
                    help="configs compiled ahead of the one measuring "
                         "(pipelined mode; default 2)")
-    p.add_argument("--compile-cache", default="auto", metavar="DIR|off",
-                   help="persistent XLA compilation cache directory "
-                        "('auto' = results/.xla_cache relative to the CWD, "
-                        "like every other default path here; 'off' "
-                        "disables; DLBB_XLA_CACHE env overrides)")
+    p.add_argument("--compile-cache", default="auto", choices=("auto", "off"),
+                   help="persistent XLA compilation cache for this sweep "
+                        "('auto' = on, in JAX_COMPILATION_CACHE_DIR or "
+                        "<checkout>/.jax_cache; 'off' = real compiles, "
+                        "the chaos gate's setting)")
     p.add_argument("--fault-plan", default=None, metavar="PLAN",
                    help="deterministic fault-injection plan (chaos "
                         "harness, e.g. 'exec-transient:2,seed=7'; "
@@ -571,8 +580,6 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv: list[str] | None = None) -> int:
-    import os
-
     args = build_parser().parse_args(argv)
     if getattr(args, "simulate", 0):
         from dlbb_tpu.utils.simulate import force_cpu_simulation
@@ -580,7 +587,7 @@ def main(argv: list[str] | None = None) -> int:
         force_cpu_simulation(args.simulate)
     elif (
         os.environ.get("DLBB_DISTRIBUTED") == "auto"
-        and args.cmd in ("bench1d", "bench3d", "e2e", "train", "serve")
+        and args.cmd in DEVICE_COMMANDS
     ):
         # pod launcher path (launch/launch_tpu_pod.sh): stand up
         # jax.distributed across hosts before any backend use; stats
@@ -592,6 +599,20 @@ def main(argv: list[str] | None = None) -> int:
             f"[distributed] process {ctx.process_id}/{ctx.num_processes}, "
             f"{ctx.num_devices} devices"
         )
+
+    if args.cmd in DEVICE_COMMANDS:
+        from dlbb_tpu.utils.compile_cache import configure_compile_cache
+        from dlbb_tpu.utils.simulate import (
+            NoAcceleratorError,
+            require_accelerator,
+        )
+
+        configure_compile_cache()
+        try:
+            require_accelerator()
+        except NoAcceleratorError as e:
+            print(f"error: {e}", file=sys.stderr)
+            return 2
 
     if getattr(args, "variant", None) is not None:
         from dlbb_tpu.comm.variants import get_variant
@@ -993,6 +1014,17 @@ def _dispatch(args) -> int:
             f"{req['completed']} completed / {req['rejected']} rejected "
             f"request(s)"
         )
+        from dlbb_tpu.resilience import inject
+
+        if req.get("failed") and not (
+                args.fault_plan or os.environ.get(inject.ENV_VAR)):
+            # the engine contains a failed dispatch and serves on (the
+            # resilience contract); with no fault plan asking for one, a
+            # failed request is a real failure and the run says so
+            print(f"error: {req['failed']} request(s) failed with no "
+                  "fault plan active (see resilience.failed in the "
+                  "report)", file=sys.stderr)
+            return 1
         return 0
 
     if args.cmd == "plan":

@@ -1,104 +1,11 @@
-"""JAX version-compatibility shims.
-
-This image pins JAX 0.4.37, where ``shard_map`` still lives at
-``jax.experimental.shard_map.shard_map`` with the older keyword surface
-(``check_rep``, ``auto``).  Newer JAX promotes it to ``jax.shard_map`` and
-renames ``check_rep`` -> ``check_vma`` and ``auto`` -> its complement
-``axis_names`` (the axes that ARE manual).  Every shard_map call site in the
-repo imports from here and writes against the *new* surface; this module
-translates when only the experimental API exists.
-"""
+"""The JAX names every shard_map call site in the repo imports from one
+place (jax 0.9: ``jax.shard_map`` with ``check_vma`` / ``axis_names``,
+``jax.lax.pcast``, ``jax.lax.axis_size``)."""
 
 from __future__ import annotations
 
 import jax
 
-# True when shard_map supports genuinely-auto (non-manual) mesh axes of
-# size > 1.  On jaxlib 0.4.37 the SPMD partitioner hard-aborts the process
-# (`Check failed: sharding.IsManualSubgroup()`) on the collective-permutes
-# such programs lower to — verified with a ppermute over a manual axis on a
-# (2,2,2) mesh with two auto axes — so pipeline+dp/tp/ep composition is
-# unavailable and the shim below raises at trace time instead.  Tests for
-# that composition skip on this flag.
-PARTIAL_AUTO_SHARD_MAP = hasattr(jax, "shard_map")
-
-if PARTIAL_AUTO_SHARD_MAP:  # JAX >= 0.6: first-class API
-    shard_map = jax.shard_map
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None,
-                  axis_names=None, **kwargs):
-        """New-style ``jax.shard_map`` surface on the experimental API.
-
-        ``axis_names`` (new: the manual axes) becomes ``auto`` (old: the
-        axes left automatic — the complement over the mesh); ``check_vma``
-        becomes ``check_rep``.
-        """
-        if axis_names is not None:
-            # axes of size 1 are semantically identical manual or auto (the
-            # local shard IS the global array and the body never names
-            # them), so fold them into the manual set — that keeps e.g. a
-            # (dp=1, pp=2, tp=1) pipeline mesh on the working full-manual
-            # path below
-            auto = frozenset(
-                a for a in mesh.axis_names
-                if a not in axis_names and mesh.shape[a] > 1
-            )
-            if auto:
-                # Genuinely partial-auto shard_map on this jaxlib aborts
-                # XLA with `Check failed: sharding.IsManualSubgroup()`
-                # (fatal, kills the process) — fail at trace time instead.
-                raise NotImplementedError(
-                    "shard_map with auto (non-manual) mesh axes "
-                    f"{sorted(auto)} is not supported on JAX "
-                    f"{jax.__version__}: the SPMD partitioner aborts on "
-                    "manual-subgroup shardings. Use a mesh whose non-"
-                    "manual axes have size 1, or a newer JAX."
-                )
-        if check_vma is not None:
-            kwargs["check_rep"] = check_vma
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, **kwargs)
-
-
-if hasattr(jax.lax, "pcast"):
-    pcast = jax.lax.pcast
-else:
-    def pcast(x, axis_name, to="varying"):
-        """``jax.lax.pcast`` for JAX < 0.7: under the old ``check_rep``
-        replication tracking there is no explicit varying-axes type, so
-        replicated -> varying casts are implicit and this is the identity."""
-        del axis_name, to
-        return x
-
-
-_COMPILER_OPTION_SUPPORT: dict[str, bool] = {}
-
-
-def supports_compiler_option(name: str, value: str = "") -> bool:
-    """Whether this jaxlib's PJRT compile path accepts a per-computation
-    DebugOptions override for ``name``.  jaxlib 0.4.x sets options through
-    protobuf reflection's ``SetString``, which raises on repeated fields
-    (e.g. ``xla_disable_hlo_passes``) — such options then exist only as
-    process-start ``XLA_FLAGS``.  Probes with a trivial jit and caches."""
-    if name not in _COMPILER_OPTION_SUPPORT:
-        import jax.numpy as jnp
-
-        try:
-            jax.jit(lambda x: x + 1).lower(jnp.zeros(())).compile(
-                compiler_options={name: value}
-            )
-            _COMPILER_OPTION_SUPPORT[name] = True
-        except Exception:
-            _COMPILER_OPTION_SUPPORT[name] = False
-    return _COMPILER_OPTION_SUPPORT[name]
-
-
-if hasattr(jax.lax, "axis_size"):
-    axis_size = jax.lax.axis_size
-else:
-    def axis_size(axis_name):
-        """``jax.lax.axis_size`` for JAX < 0.5: ``psum(1, axis)`` is
-        special-cased to the static axis size."""
-        return jax.lax.psum(1, axis_name)
+shard_map = jax.shard_map
+pcast = jax.lax.pcast
+axis_size = jax.lax.axis_size

@@ -443,19 +443,6 @@ def journal_to_trace(journal_dir: "str | Path",
                 "args": _jsonable(args),
             })
             continue
-        if name == "degraded":
-            # a degraded-probe fallback (PR 11) changes how EVERY later
-            # number in the run must be read — render it as a labelled,
-            # process-scoped instant (full-height marker in Perfetto)
-            # instead of a thread-local tick lost among the lifecycle
-            # events
-            reason = rec.get("reason") or "unknown"
-            events.append({
-                "name": f"degraded[{reason}]", "cat": "degraded",
-                "ph": "i", "s": "p", "ts": ts_us, "pid": pid, "tid": 1,
-                "args": _jsonable(args),
-            })
-            continue
         if name in ("prefix-attach", "prefix-cow"):
             # the prefix-cache pair: an attach instant labelled with its
             # donor/reuse (the TTFT story of that admission) and its CoW

@@ -6,6 +6,6 @@ VMEM blocking beats XLA's default lowering — above all attention, whose
 materialised ``[S, S]`` score matrix is the canonical HBM-bandwidth trap.
 """
 
-from dlbb_tpu.ops.flash_attention import flash_attention
+from dlbb_tpu.ops.flash_attention import flash_attention, mosaic_call_count
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "mosaic_call_count"]

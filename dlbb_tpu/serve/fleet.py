@@ -1192,10 +1192,6 @@ def run_fleet(
             )
             if jrn is not None:
                 jrn.event("topology", **topology)
-            sup.registry.inc(
-                "serve_degraded", 1 if topology["degraded"] else 0,
-                help="runs on a degraded (fallback) backend",
-            )
             report = sup.serve(trace)
     finally:
         if jrn is not None:

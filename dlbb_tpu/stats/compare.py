@@ -19,9 +19,8 @@ Honesty caveats (carried into the report header):
 - chunked-timing rows (``timing_granularity`` column) aggregate chunk
   means; mean comparisons remain valid, tail comparisons do not.
 - the reference publishes no E2E number (BASELINE.md); the E2E section
-  compares against the re-measured reference-stack torch-CPU baseline
-  (``bench_baseline_cpu.json``) and reports the TPU-chip numbers from
-  ``BENCH_r*.json``.
+  compares the committed ``results/e2e`` corpus against the re-measured
+  reference-stack torch-CPU baseline (``bench_baseline_cpu.json``).
 """
 
 from __future__ import annotations
@@ -221,9 +220,8 @@ def compare_3d(
 
 def _e2e_rows(repo_root: Path) -> list[dict[str, Any]]:
     """E2E tokens/s vs the reference-stack CPU baseline, from the committed
-    bench artifacts (TPU-chip numbers, not the simulated mesh), plus the
-    per-config real-chip e2e corpus under ``results/e2e`` (attention-mode
-    ladder, long-context ladder, infeasibility boundaries)."""
+    per-config e2e corpus under ``results/e2e`` (attention-mode ladder,
+    long-context ladder, infeasibility boundaries)."""
     rows = []
     cpu = repo_root / "bench_baseline_cpu.json"
     base_tps = (json.loads(cpu.read_text())["tokens_per_second"]
@@ -288,34 +286,6 @@ def _e2e_rows(repo_root: Path) -> list[dict[str, Any]]:
                          "chip number)" if simulated
                     else "(no reference number)"
                 ),
-            })
-    if base_tps is None:
-        return rows
-    for bench_file in sorted(repo_root.glob("BENCH_r*.json")):
-        try:
-            b = json.loads(bench_file.read_text())
-        except Exception:  # noqa: BLE001
-            continue
-        # driver BENCH records nest the bench.py line under "parsed"
-        b = b.get("parsed", b)
-        if "tokens/s" not in b.get("unit", ""):
-            continue
-        rows.append({
-            "config": f"1B/simplified ({bench_file.name})",
-            "device": "v5e chip",
-            "reference_cpu_stack_tokens_per_s": round(base_tps, 1),
-            "xla_tpu_tokens_per_s": b["value"],
-            "speedup": round(b["value"] / base_tps, 2),
-            "verdict": _raw_verdict(b["value"] / base_tps),
-        })
-        for name, extra in b.get("extras", {}).items():
-            rows.append({
-                "config": f"{name} ({bench_file.name})",
-                "device": "v5e chip",
-                "reference_cpu_stack_tokens_per_s": None,
-                "xla_tpu_tokens_per_s": extra["tokens_per_second"],
-                "speedup": None,
-                "verdict": "(no reference number)",
             })
     return rows
 
@@ -465,8 +435,7 @@ def write_comparison(
         "",
     ]
     if e2e:
-        md += ["## E2E forward throughput "
-               "(per-row device column; BENCH rows are the v5e chip)", ""]
+        md += ["## E2E forward throughput (per-row device column)", ""]
         md += _md_table(
             e2e,
             ["config", "device", "reference_cpu_stack_tokens_per_s",

@@ -85,22 +85,15 @@ CSV_COLUMNS = [
 def calculate_statistics(timings_2d: list[list[float]]) -> dict[str, Any]:
     """Aggregate stats (µs) + load imbalance over per-rank means
     (reference ``collectives/1d/stats.py:26-75``)."""
-    from dlbb_tpu.native import load_imbalance_native, row_means_native
-
     arr = np.asarray(timings_2d, dtype=np.float64)
-    rm = row_means_native(arr)
-    per_rank_means = rm if rm is not None else arr.mean(axis=1)
+    per_rank_means = arr.mean(axis=1)
     flat = arr.ravel()
-    li = load_imbalance_native(per_rank_means)
-    if li is not None:
-        load_imbalance = li
-    else:
-        mean_of_means = per_rank_means.mean()
-        load_imbalance = (
-            (per_rank_means.max() - mean_of_means) / mean_of_means * 100.0
-            if mean_of_means > 0
-            else 0.0
-        )
+    mean_of_means = per_rank_means.mean()
+    load_imbalance = (
+        (per_rank_means.max() - mean_of_means) / mean_of_means * 100.0
+        if mean_of_means > 0
+        else 0.0
+    )
     return {
         "mean_time_us": float(flat.mean() * 1e6),
         "median_time_us": float(np.median(flat) * 1e6),

@@ -46,10 +46,7 @@ _SORT_KEY = lambda r: (  # noqa: E731
 def calculate_statistics_3d(timings_2d: list[list[float]]) -> dict[str, float]:
     """ms-scale aggregate stats (reference ``collectives/3d/stats.py:32-49``).
 
-    Hot loop of the 3D pipeline (hundreds of files per corpus pass) —
-    delegates to ``utils.metrics.summarize``, the ONE
-    native-C++-with-numpy-fallback summary dispatch (numerics asserted
-    identical in ``tests/test_native.py``), and maps its seconds-scale
+    Delegates to ``utils.metrics.summarize`` and maps its seconds-scale
     fields to the reference's ms keys."""
     from dlbb_tpu.utils.metrics import summarize
 

@@ -53,10 +53,11 @@ from dlbb_tpu.models.transformer import (
     init_params_sharded,
 )
 from dlbb_tpu.obs import spans
+from dlbb_tpu.ops import mosaic_call_count
 from dlbb_tpu.utils.config import load_config, save_json
 from dlbb_tpu.utils.metrics import Timer, summarize
 from dlbb_tpu.utils.profiling import annotate, step_annotation
-from dlbb_tpu.utils.sysinfo import collect_system_info
+from dlbb_tpu.utils.sysinfo import collect_system_info, device_spread
 from dlbb_tpu.utils.timing import resolve_timing_mode, time_fn_chained
 
 
@@ -639,6 +640,7 @@ def run_train(
     with spans.span("compile+warmup", cat="train"), \
             annotate("compile+warmup"):
         t0 = time.perf_counter()
+        mosaic_calls = mosaic_call_count(jit_step, state, batch, tgt)
         if comp_opts and mode == "per_iter":
             # AOT-compile with the options; in chained mode the options are
             # instead applied to the outer timing loop (an AOT executable
@@ -810,6 +812,9 @@ def run_train(
         "tp_overlap": model_cfg.tp_overlap,
         "compiler_options": comp_opts or None,
         "compile_time_s": compile_time,
+        "mosaic_calls": mosaic_calls,
+        # devices the parameters ended up spread over
+        "param_devices": device_spread(state.params),
         "step_time": summarize(step_times),
         "num_params": n_params,
         "tokens_per_second": tokens / mean_step,

@@ -25,19 +25,16 @@ import numpy as np
 
 
 # the full summary schema, empty series included: every caller can rely
-# on these keys existing (serving-path metrics — ROADMAP item 1 — key on
-# p99.9 tail latency, hence p999).  ONE source of truth with the native
-# bindings — the native path zips values against this order, so a field
-# added to only one copy would silently mislabel numbers.
-from dlbb_tpu.native import SUMMARY_FIELDS as SUMMARY_KEYS
+# on these keys existing (serving-path metrics key on p99.9 tail latency,
+# hence p999)
+SUMMARY_KEYS = ("mean", "std", "min", "max", "median", "p95", "p99",
+                "p999", "count")
 
 
 def summarize(values: list[float]) -> dict[str, float]:
     """Summary statistics over a timing series (seconds), matching the
     reference's metric names (``utils.py:43-66``) plus ``p999`` (the
-    p99.9 tail the serving-path metrics need).  Uses the native C++
-    stats core when available (``dlbb_tpu/native``), numpy otherwise —
-    numerics asserted identical in ``tests/test_native.py``.
+    p99.9 tail the serving-path metrics need).
 
     An EMPTY series (every sample quarantined, a preempted run) returns
     explicit NaN-valued keys with ``count == 0`` — never a bare ``{}``
@@ -48,11 +45,6 @@ def summarize(values: list[float]) -> dict[str, float]:
         out = {k: float("nan") for k in SUMMARY_KEYS}
         out["count"] = 0
         return out
-    from dlbb_tpu.native import summarize_native
-
-    native = summarize_native(arr)
-    if native is not None:
-        return native
     return {
         "mean": float(arr.mean()),
         "std": float(arr.std()),

@@ -1,6 +1,8 @@
 """Shared parent-loop for the real-chip publisher scripts.
 
-One subprocess per config (fresh HBM arena per measurement), one
+The parent never imports JAX (``dlbb_tpu.utils.config`` does not), so it
+never holds the chip its workers need.  One subprocess per config (fresh
+HBM arena per measurement), one
 boundary-handling contract: a config whose failure is expected AND whose
 stderr matches a memory/compile signature gets a deterministic
 ``*_infeasible.json`` boundary artifact (and its stale measured artifact
@@ -16,8 +18,28 @@ import sys
 from pathlib import Path
 from typing import Any, Callable, Iterable
 
-# error signatures that qualify a failure as the memory boundary
-BOUNDARY_SIGNATURES = ("RESOURCE_EXHAUSTED", "remote_compile", "Allocat")
+# error signatures that qualify a failure as the memory boundary.  A
+# compile that cannot fit says, on libtpu 0.0.34: "RESOURCE_EXHAUSTED:
+# XLA:TPU compile permanent error. Ran out of memory in memory space hbm.
+# Used 16.00G of 15.75G hbm."
+BOUNDARY_SIGNATURES = ("RESOURCE_EXHAUSTED",
+                       "Ran out of memory in memory space", "Allocat")
+
+
+def require_tpu() -> None:
+    """A publisher worker measures the chip or nothing."""
+    import jax
+
+    from dlbb_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+    devices = jax.devices()
+    print(f"devices: {devices}", flush=True)
+    if devices[0].platform != "tpu":
+        raise SystemExit(
+            f"not a TPU backend (platform={devices[0].platform!r}): the "
+            "publisher scripts write chip artifacts only"
+        )
 
 
 def run_worker_matrix(

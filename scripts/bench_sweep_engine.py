@@ -9,6 +9,11 @@ perf claim the artifact pins: warm-cache sweeps (either mode) finish in
 measurably less wall time than the cold serial sweep, while the measured
 medians stay statistically equivalent across modes.
 
+Each run is a child process started with ``JAX_COMPILATION_CACHE_DIR``
+pointing at that run's cache directory — the one way the program takes a
+cache directory (``dlbb_tpu/utils/compile_cache.py``); the child times the
+sweep itself, so interpreter and JAX start-up are outside every wall.
+
 Usage: python scripts/bench_sweep_engine.py [--iters N]
 """
 
@@ -16,7 +21,9 @@ from __future__ import annotations
 
 import argparse
 import json
+import os
 import shutil
+import subprocess
 import sys
 import tempfile
 import time
@@ -52,6 +59,17 @@ GRID = dict(
 
 def _one_run(name: str, work: Path, cache: Path, pipeline: bool,
              iters: int) -> dict:
+    """One setting, in a child whose environment names its cache."""
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(cache))
+    proc = subprocess.run(
+        [sys.executable, __file__, "--child", name, str(work),
+         str(int(pipeline)), str(iters)],
+        env=env, capture_output=True, text=True, check=True,
+    )
+    return json.loads(proc.stdout.strip().splitlines()[-1])
+
+
+def _child(name: str, work: Path, pipeline: bool, iters: int) -> dict:
     out = work / name
     sweep = Sweep1D(
         implementation="bench_sweep",
@@ -59,10 +77,13 @@ def _one_run(name: str, work: Path, cache: Path, pipeline: bool,
         warmup_iterations=2,
         measurement_iterations=iters,
         output_dir=str(out),
-        compile_cache=str(cache),
         pipeline=pipeline,
         **GRID,
     )
+    # absorb process-level one-time costs (first dispatch) outside the wall
+    import jax.numpy as jnp
+
+    jnp.zeros(8).block_until_ready()
     t0 = time.perf_counter()
     files = run_sweep(sweep, verbose=False)
     wall = time.perf_counter() - t0
@@ -120,6 +141,11 @@ def _aggregate(reps: list[dict]) -> dict:
 
 
 def main() -> int:
+    if sys.argv[1:2] == ["--child"]:
+        name, work, pipeline, iters = sys.argv[2:6]
+        print(json.dumps(_child(name, Path(work), bool(int(pipeline)),
+                                int(iters))))
+        return 0
     ap = argparse.ArgumentParser()
     ap.add_argument("--iters", type=int, default=30,
                     help="measured iterations per config (default 30)")
@@ -137,9 +163,7 @@ def main() -> int:
         "serial_warm": [], "pipelined_warm": [],
     }
     try:
-        # warms the shared cache for the *_warm settings AND absorbs
-        # process-level one-time costs (imports, first dispatch) so they
-        # don't bias the first measured setting
+        # warms the shared cache for the *_warm settings
         _one_run("warmup", work, warm_cache, True, 3)
 
         # interleave settings within each repetition so host drift

@@ -208,9 +208,9 @@ def _run_one(suffix: str, iters: int, output: str) -> None:
         )
     training, model_over, input_over = match[0]
 
-    import jax
+    from _publish_common import require_tpu
 
-    print(f"devices: {jax.devices()}", flush=True)
+    require_tpu()
 
     from dlbb_tpu.train.loop import run_train
     config = {
@@ -236,8 +236,8 @@ def main() -> int:
     ap.add_argument("--missing", action="store_true",
                     help="matrix mode, but only configs with neither a "
                          "measured nor a boundary artifact — resume a "
-                         "matrix interrupted by a tunnel outage without "
-                         "re-measuring the landed rungs")
+                         "interrupted matrix without re-measuring the "
+                         "landed rungs")
     args = ap.parse_args()
 
     if args.only:

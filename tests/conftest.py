@@ -29,6 +29,13 @@ import pytest  # noqa: E402
 
 from dlbb_tpu.comm import MeshSpec, build_mesh  # noqa: E402
 
+if RUN_TPU_TESTS:
+    # the chip tests share the program's one persistent compile cache
+    # (with chip_smoke.py, when both run in one chip-tool call)
+    from dlbb_tpu.utils.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+
 
 def pytest_configure(config):
     config.addinivalue_line(
@@ -201,6 +208,24 @@ def dense_attention_ref(q, k, v, causal=True):
     p = np.exp(logits)
     p /= p.sum(-1, keepdims=True)
     return np.einsum("bnqk,bnkd->bnqd", p, v)
+
+
+@pytest.fixture
+def compile_cache_dir(tmp_path, monkeypatch):
+    """A private persistent-cache directory, set the only way the program
+    accepts one: as if the process had been started with
+    ``JAX_COMPILATION_CACHE_DIR`` pointing at it (JAX reads that variable
+    into its config at import, so the fixture does both halves)."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    path = str(tmp_path / "jax_cache")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", path)
+    prior = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", path)
+    cc.reset_cache()
+    yield path
+    jax.config.update("jax_compilation_cache_dir", prior)
+    cc.reset_cache()
 
 
 @pytest.fixture(scope="session")

@@ -7,7 +7,6 @@ import optax
 import pytest
 
 from dlbb_tpu.comm.mesh import MeshSpec, build_mesh
-from dlbb_tpu.compat import PARTIAL_AUTO_SHARD_MAP
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.models.transformer import init_params
 from dlbb_tpu.train.checkpoint import (
@@ -50,11 +49,6 @@ def test_save_restore_roundtrip(devices, tmp_path, zero1):
         assert a.sharding == b.sharding, (a.sharding, b.sharding)
 
 
-@pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason="pp x ep mesh needs partial-auto shard_map, unsupported on "
-           "this jaxlib (dlbb_tpu.compat.PARTIAL_AUTO_SHARD_MAP)",
-)
 def test_save_restore_pp_ep_mesh(devices, tmp_path):
     """Checkpointing preserves shardings on a pp x ep mesh too (MoE model
     with the layer stack sharded across pipeline stages and experts

@@ -181,3 +181,20 @@ def test_allreduce_on_4rank_mesh(mesh4):
     fn = op.build(mesh4, AXES)
     out = np.asarray(fn(x))
     np.testing.assert_allclose(out[0], host.sum(axis=0), rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("root", [0, 3])
+def test_oracle_agrees_on_every_reference_op(mesh8, root):
+    """``comm/oracle.py`` states the same semantics the cases above pin
+    inline — it is what ``chip_smoke.py`` checks a real multi-chip ring
+    against — and its comparison is not vacuous."""
+    from dlbb_tpu.bench.runner import OPERATIONS_1D
+    from dlbb_tpu.comm.oracle import check_op, expected_output
+
+    for name in OPERATIONS_1D + ("reducescatter",):
+        seen = check_op(name, mesh8, AXES, N, root=root)
+        assert seen["devices"] == 8, name
+    host = np.arange(8.0 * N).reshape(8, N)
+    assert not np.array_equal(expected_output("sendrecv", host), host)
+    assert not np.array_equal(expected_output("reduce", host, root),
+                              expected_output("allreduce", host))

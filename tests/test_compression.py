@@ -505,8 +505,7 @@ def test_compressed_train_tracks_uncompressed(devices):
 def test_compression_mini_sweep_and_topology(tmp_path, devices):
     """allreduce_q variant mini-sweep through the real engine: artifacts
     carry the compression field, and the sweep manifest + journal carry
-    the topology record (platform, rank count, degraded flag — the
-    ROADMAP item 5 standing chore, first slice)."""
+    the topology record (platform, device count, simulated flag)."""
     from dlbb_tpu.bench.runner import Sweep1D, run_sweep
     from dlbb_tpu.resilience.journal import read_journal
 
@@ -532,8 +531,6 @@ def test_compression_mini_sweep_and_topology(tmp_path, devices):
         assert topo["platform"] == "cpu"
         assert topo["num_devices"] >= 8
         assert topo["simulated"] is True
-        # the test harness REQUESTED the simulation: not a degraded fallback
-        assert topo["degraded"] is False
 
         events, torn = read_journal(out)
         assert torn == 0
@@ -541,16 +538,15 @@ def test_compression_mini_sweep_and_topology(tmp_path, devices):
         assert topo_events and topo_events[0]["platform"] == "cpu"
 
 
-def test_topology_record_degraded_classification(monkeypatch):
-    """An explicit degraded reason (the bench.py probe fallback) flips
-    the record to degraded; a test-requested simulation stays clean."""
+def test_topology_record_refuses_unrequested_cpu(monkeypatch):
+    """The no-chip rule at the library door: a requested simulation gets
+    its record; the same CPU backend with nobody having asked for it is
+    an error that names the way out, not a labelled run."""
     from dlbb_tpu.utils import simulate
 
-    rec = simulate.topology_record()
-    assert rec["degraded"] is False  # conftest forced the simulation
-    assert rec["simulation_forced"] is True
-    monkeypatch.setattr(simulate, "_DEGRADED_REASON",
-                        "accelerator backend unreachable (probe timeout)")
-    rec = simulate.topology_record()
-    assert rec["degraded"] is True
-    assert "unreachable" in rec["degraded_reason"]
+    rec = simulate.topology_record()  # conftest forced the simulation
+    assert rec["platform"] == "cpu" and rec["simulated"] is True
+    assert "degraded" not in rec
+    monkeypatch.setattr(simulate, "_SIMULATION_FORCED", False)
+    with pytest.raises(simulate.NoAcceleratorError, match="--simulate N"):
+        simulate.topology_record()

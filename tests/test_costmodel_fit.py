@@ -373,7 +373,6 @@ def test_journal_to_trace_merges_sweep_and_serving_streams(tmp_path):
         {"ts": 5.0, "event": "request-arrived", "config": "request-0"},
         {"ts": 6.0, "event": "request-completed", "config": "request-0",
          "output_tokens": 3},
-        {"ts": 6.5, "event": "degraded", "reason": "probe"},
     ]
     with open(tmp_path / "sweep_journal.jsonl", "w") as f:
         for r in recs:
@@ -389,12 +388,6 @@ def test_journal_to_trace_merges_sweep_and_serving_streams(tmp_path):
     assert names == {(1, "sweep"), (2, "serving")}
     spans = {(e["pid"], e["name"]): e for e in events if e["ph"] == "X"}
     assert (1, "cfg_a.json") in spans and (2, "request-0") in spans
-    # the serve-session degraded event lands on the serving track, as a
-    # labelled process-scoped instant (the reason IS the name)
-    degraded = [e for e in events if e.get("cat") == "degraded"]
-    assert degraded and degraded[0]["pid"] == 2
-    assert degraded[0]["name"] == "degraded[probe]"
-    assert degraded[0]["s"] == "p"
     assert trace["otherData"]["streams"] == {"1": "sweep", "2": "serving"}
 
 

@@ -491,7 +491,7 @@ def test_fit_host_filter_exempts_device_samples():
 
 
 # ---------------------------------------------------------------------------
-# serving rows + degraded journal instants
+# serving rows + journal instants
 # ---------------------------------------------------------------------------
 
 
@@ -519,17 +519,18 @@ def test_serving_capture_phase_rows(tmp_path):
     assert row["buckets_us"]["fusion"] == 40.0
 
 
-def test_journal_degraded_event_renders_labelled_instant(tmp_path):
-    """PR-11 ``degraded`` journal events render as labelled,
-    process-scoped instants in the reconstructed timeline — and the
-    config pairing around them still works."""
+def test_journal_topology_event_renders_plain_instant(tmp_path):
+    """Journal events with no renderer of their own (``topology``, which
+    every sweep and serving run journals first) become thread-scoped
+    ``journal`` instants carrying their fields — and the config pairing
+    around them still works."""
     from dlbb_tpu.obs.spans import journal_to_trace
 
     journal = tmp_path / "sweep_journal.jsonl"
     records = [
         {"ts": 1.0, "event": "sweep-start"},
-        {"ts": 1.5, "event": "degraded",
-         "reason": "tpu probe failed: tunnel down"},
+        {"ts": 1.5, "event": "topology", "platform": "tpu",
+         "num_devices": 4, "simulated": False},
         {"ts": 2.0, "event": "started", "config": "cfg_a.json"},
         {"ts": 3.0, "event": "completed", "config": "cfg_a.json"},
     ]
@@ -537,12 +538,11 @@ def test_journal_degraded_event_renders_labelled_instant(tmp_path):
     out, _n, torn = journal_to_trace(tmp_path, tmp_path / "trace.json")
     assert torn == 0
     events = json.loads(out.read_text())["traceEvents"]
-    degraded = [e for e in events if e.get("cat") == "degraded"]
-    assert len(degraded) == 1
-    assert degraded[0]["name"] == \
-        "degraded[tpu probe failed: tunnel down]"
-    assert degraded[0]["ph"] == "i"
-    assert degraded[0]["s"] == "p"
+    topo = [e for e in events if e["name"] == "topology"]
+    assert len(topo) == 1
+    assert topo[0]["cat"] == "journal"
+    assert topo[0]["ph"] == "i" and topo[0]["s"] == "t"
+    assert topo[0]["args"]["num_devices"] == 4
     # the started -> completed pairing still yields the config X span
     spans = [e for e in events if e.get("ph") == "X"]
     assert any(e["name"] == "cfg_a.json" for e in spans)

@@ -8,20 +8,10 @@ import numpy as np
 import pytest
 
 from dlbb_tpu.comm.mesh import MeshSpec, build_mesh
-from dlbb_tpu.compat import PARTIAL_AUTO_SHARD_MAP
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.models.transformer import forward, init_params, shard_params
 from dlbb_tpu.parallel.pipeline import validate_pipeline
 from dlbb_tpu.train.loop import run_train
-
-# pp composed with another >1 mesh axis needs partial-auto shard_map
-# (pp manual, dp/tp/ep auto), which this jaxlib's SPMD partitioner cannot
-# lower (see dlbb_tpu/compat.py) — pure-pp meshes are unaffected.
-needs_partial_auto = pytest.mark.skipif(
-    not PARTIAL_AUTO_SHARD_MAP,
-    reason="partial-auto shard_map (pp + other >1 axes) unsupported on "
-           "this jaxlib (dlbb_tpu.compat.PARTIAL_AUTO_SHARD_MAP)",
-)
 
 TINY = ModelConfig(hidden_size=32, num_layers=4, num_heads=4,
                    ffn_intermediate=64, attention="full", dtype="float32")
@@ -47,7 +37,6 @@ def test_pipeline_matches_single_device(devices):
                                rtol=1e-5, atol=1e-5)
 
 
-@needs_partial_auto
 def test_pipeline_with_dp_tp(devices):
     """pp composes with dp and tp on a (dp=2, pp=2, tp=2) mesh."""
     params = init_params(TINY, jax.random.key(0))
@@ -93,7 +82,6 @@ def _train_config(pp=1):
     return cfg
 
 
-@needs_partial_auto
 def test_pipeline_train_matches_plain(devices):
     """The pipelined train step must follow the same optimisation
     trajectory as the unpipelined one (same global math)."""
@@ -105,7 +93,6 @@ def test_pipeline_train_matches_plain(devices):
     )
 
 
-@needs_partial_auto
 def test_pipeline_train_zero3(devices):
     """pp composes with ZeRO-3/FSDP: same trajectory as plain DDP."""
     r_plain = run_train(_train_config(pp=1), verbose=False)
@@ -117,7 +104,6 @@ def test_pipeline_train_zero3(devices):
     )
 
 
-@needs_partial_auto
 def test_moe_pipeline_forward(devices):
     """MoE FFN inside the pipelined layer scan stays exact (pp x ep)."""
     moe = TINY.with_(num_experts=4, moe_top_k=2)
@@ -132,7 +118,6 @@ def test_moe_pipeline_forward(devices):
                                rtol=1e-5, atol=1e-5)
 
 
-@needs_partial_auto
 def test_moe_pipeline_with_aux(devices):
     """with_aux under pp: the pipelined aux (per-stage masked accumulation
     + psum, averaged over layers x microbatches) equals the mean of the
@@ -161,7 +146,6 @@ def test_moe_pipeline_with_aux(devices):
                                rtol=1e-5, atol=1e-5)
 
 
-@needs_partial_auto
 def test_moe_pipeline_train_with_aux_weight(devices):
     """MoE + pipeline + load-balancing loss trains end-to-end (the
     combination previously raised)."""
@@ -236,7 +220,6 @@ def test_1f1b_grads_match_unpipelined(devices):
         )
 
 
-@needs_partial_auto
 def test_1f1b_train_matches_gpipe(devices):
     """training.pipeline_schedule='1f1b' follows the same optimisation
     trajectory as GPipe autodiff and the unpipelined step."""
@@ -250,7 +233,6 @@ def test_1f1b_train_matches_gpipe(devices):
     )
 
 
-@needs_partial_auto
 def test_1f1b_moe_aux_matches_gpipe(devices):
     """MoE + aux loss under 1F1B == the GPipe with_aux path (same
     per-microbatch aux averaging)."""

@@ -206,7 +206,7 @@ def test_train_adam_fp32m_failure_is_real(monkeypatch, tmp_path):
 
 def test_train_missing_mode_runs_only_absent_configs(monkeypatch,
                                                      tmp_path):
-    """--missing resumes a matrix interrupted by a tunnel outage: configs
+    """--missing resumes an interrupted matrix: configs
     with a measured OR boundary artifact are excluded; only absent ones
     re-run."""
     mod, calls = _load_train(monkeypatch, tmp_path, {})

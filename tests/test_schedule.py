@@ -17,11 +17,13 @@ from dlbb_tpu.bench.schedule import (
     CompileAheadScheduler,
     PayloadCache,
     WorkUnit,
-    configure_compilation_cache,
     work_unit_key,
 )
 from dlbb_tpu.comm.mesh import MeshSpec, get_mesh
 from dlbb_tpu.comm.ops import OPERATIONS, CollectiveOp, get_op, payload_aval
+
+# every sweep here caches into a private directory, not the checkout's
+pytestmark = pytest.mark.usefixtures("compile_cache_dir")
 
 
 def _key(variant="default", op="allreduce", n=256, mode="per_iter",
@@ -64,7 +66,6 @@ def _tiny(tmp_path, **kw):
         warmup_iterations=1,
         measurement_iterations=3,
         output_dir=str(tmp_path / "results"),
-        compile_cache=str(tmp_path / "xla_cache"),
         # exercise the compile-ahead thread regardless of the host-auto
         # default (schedule.default_pipeline is core-count dependent)
         pipeline=True,
@@ -73,7 +74,8 @@ def _tiny(tmp_path, **kw):
     return Sweep1D(**defaults)
 
 
-def test_serial_and_pipelined_results_equivalent(tmp_path, devices):
+def test_serial_and_pipelined_results_equivalent(tmp_path, devices,
+                                                 compile_cache_dir):
     """--no-pipeline and the pipelined engine must emit the same artifact
     set with the same schema and identical non-timing fields."""
     fp = run_sweep(_tiny(tmp_path, output_dir=str(tmp_path / "pipe")),
@@ -165,19 +167,25 @@ def test_chained_mode_through_engine(tmp_path, devices):
         assert "compile_seconds" in d and "compile_cache_hit" in d
 
 
-def test_warm_persistent_cache_hits(tmp_path, devices):
+def test_warm_persistent_cache_hits(tmp_path, devices, compile_cache_dir):
     """A second sweep over the same grid (fresh jit objects, same
     programs) deserialises from the persistent cache: every artifact
-    reports a compile-cache hit."""
-    kw = dict(compile_cache=str(tmp_path / "shared_cache"))
-    run_sweep(_tiny(tmp_path, output_dir=str(tmp_path / "cold"), **kw),
-              verbose=False)
-    warm = run_sweep(_tiny(tmp_path, output_dir=str(tmp_path / "warm"), **kw),
+    reports a compile-cache hit, and every entry landed in the directory
+    JAX_COMPILATION_CACHE_DIR named."""
+    import os
+
+    cold = run_sweep(_tiny(tmp_path, output_dir=str(tmp_path / "cold")),
+                     verbose=False)
+    for f in cold:
+        assert json.loads(f.read_text())["compile_cache_hit"] is False
+    assert os.listdir(compile_cache_dir)
+    warm = run_sweep(_tiny(tmp_path, output_dir=str(tmp_path / "warm")),
                      verbose=False)
     assert warm
     for f in warm:
         assert json.loads(f.read_text())["compile_cache_hit"] is True
     man = json.loads((tmp_path / "warm" / "sweep_manifest.json").read_text())
+    assert man["compile_cache"]["dir"] == compile_cache_dir
     assert man["compile_cache"]["persistent_hits"] == 2
     assert man["compile_cache"]["persistent_misses"] == 0
 
@@ -199,36 +207,89 @@ def test_default_pipeline_env_overrides(monkeypatch):
     assert default_pipeline() is ((os.cpu_count() or 1) >= 4)
 
 
-def test_cache_scope_restores_prior_config(tmp_path):
-    """A cache dir the CALLER configured before the sweep survives the
-    sweep's cache scoping — deactivation restores it instead of
-    clobbering it to disabled."""
+def _spy_config_updates(monkeypatch):
     import jax
 
-    from dlbb_tpu.bench import schedule
+    seen = []
+    real = jax.config.update
 
-    prior = str(tmp_path / "user_cache")
-    jax.config.update("jax_compilation_cache_dir", prior)
+    def update(name, value):
+        seen.append(name)
+        return real(name, value)
+
+    monkeypatch.setattr(jax.config, "update", update)
+    return seen
+
+
+def test_compile_cache_env_dir_is_left_alone(compile_cache_dir,
+                                             monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets no directory of its
+    own — no ``jax_compilation_cache_dir`` update is made by the one
+    configuring function or by a sweep's scope, not even to restore it."""
+    import jax
+
+    from dlbb_tpu.utils import compile_cache
+
+    seen = _spy_config_updates(monkeypatch)
+    assert compile_cache.configure_compile_cache() == compile_cache_dir
+    with compile_cache.sweep_scope("auto") as d:
+        assert d == compile_cache_dir
+        assert jax.config.jax_enable_compilation_cache is True
+    with compile_cache.sweep_scope("off") as d:
+        assert d is None
+    assert "jax_compilation_cache_dir" not in seen
+    assert jax.config.jax_compilation_cache_dir == compile_cache_dir
+
+
+def test_compile_cache_default_dir_ignores_cwd(tmp_path, monkeypatch):
+    """Variable unset: ``<checkout>/.jax_cache``, resolved from the
+    package's own location — the same path from any working directory
+    (the path is part of the cache key; one that moves never hits)."""
+    from pathlib import Path
+
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    from dlbb_tpu.utils import compile_cache
+
+    repo = Path(__file__).resolve().parents[1]
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    prior = jax.config.jax_compilation_cache_dir
     try:
-        schedule.configure_compilation_cache(str(tmp_path / "sweep_cache"))
-        assert jax.config.jax_compilation_cache_dir == str(
-            tmp_path / "sweep_cache")
-        schedule.deactivate_compilation_cache()
-        assert jax.config.jax_compilation_cache_dir == prior
+        for cwd in (tmp_path, repo / "tests"):
+            monkeypatch.chdir(cwd)
+            jax.config.update("jax_compilation_cache_dir", None)
+            assert compile_cache.configure_compile_cache() \
+                == str(repo / ".jax_cache")
+        assert compile_cache.DEFAULT_CACHE_DIR == str(repo / ".jax_cache")
+        assert not (tmp_path / ".jax_cache").exists()
     finally:
-        jax.config.update("jax_compilation_cache_dir", None)
-        schedule.deactivate_compilation_cache()
+        jax.config.update("jax_compilation_cache_dir", prior)
+        cc.reset_cache()
 
 
-def test_configure_compilation_cache_off(monkeypatch, tmp_path):
-    for value in ("off", "0", "none", ""):
-        monkeypatch.setenv("DLBB_XLA_CACHE", value)
-        assert configure_compilation_cache("auto") is None
-    monkeypatch.delenv("DLBB_XLA_CACHE")
-    d = tmp_path / "explicit"
-    assert configure_compilation_cache(str(d)) == str(d)
-    assert d.is_dir()
-    assert configure_compilation_cache(None) is None
+def test_sweep_scope_is_the_only_cache_window_on_the_simulated_mesh(
+        compile_cache_dir):
+    """force_cpu_simulation leaves the cache off (XLA:CPU aborts on some
+    deserialised non-sweep programs, utils/compile_cache.py); a sweep's
+    scope turns it on and off again; 'off' and a bare directory — the
+    form that is gone — never turn it on."""
+    import jax
+
+    from dlbb_tpu.utils.compile_cache import sweep_scope
+
+    assert jax.config.jax_enable_compilation_cache is False
+    with sweep_scope("auto"):
+        assert jax.config.jax_enable_compilation_cache is True
+    assert jax.config.jax_enable_compilation_cache is False
+    for off in ("off", None):
+        with sweep_scope(off) as d:
+            assert d is None
+            assert jax.config.jax_enable_compilation_cache is False
+    with pytest.raises(ValueError, match="JAX_COMPILATION_CACHE_DIR"):
+        with sweep_scope("/some/dir"):
+            pass
+    assert jax.config.jax_enable_compilation_cache is False
 
 
 def test_scheduler_dedup_and_drain():

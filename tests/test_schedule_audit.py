@@ -110,12 +110,15 @@ def test_while_body_wire_counted_in_hlo_audit_total(mesh8):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from dlbb_tpu.analysis.hlo_audit import AuditTarget, audit_target
-    from dlbb_tpu.compat import shard_map
+    from dlbb_tpu.compat import pcast, shard_map
 
     def build():
         def body(x):
             def step(c, _):
-                return lax.psum(c, "ranks") * 0.125, None
+                # psum's result is replicated over "ranks"; the carry came
+                # in varying, and check_vma wants the two types to agree
+                return pcast(lax.psum(c, "ranks") * 0.125, "ranks",
+                             to="varying"), None
 
             y, _ = lax.scan(step, x, None, length=3)
             return y

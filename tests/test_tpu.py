@@ -6,8 +6,9 @@ wrong headline BENCH number.  This ``tpu``-marked subset compiles the
 kernels natively on the one real chip and asserts numerics against the
 dense oracle, so a broken compiled path is a red test, not a bad artifact.
 
-Run: ``DLBB_TPU_TESTS=1 python -m pytest tests/ -m tpu``
-(committed log: ``results/tpu_tests/pytest_tpu_log.txt``).
+Run: ``DLBB_TPU_TESTS=1 python -m pytest tests/ -m tpu`` — on the chip, so
+through the chip tool, in the same call as ``chip_smoke.py`` (they share
+the compile cache).
 
 Tolerances: TPU matmuls run on the MXU at DEFAULT internal precision even
 for fp32 inputs (bf16 multiply passes, fp32 accumulate), and the kernel's
@@ -28,8 +29,10 @@ pytestmark = pytest.mark.tpu
 
 @pytest.fixture(scope="module", autouse=True)
 def _require_tpu():
-    if jax.default_backend() != "tpu":
-        pytest.skip("no TPU backend available")
+    # these tests only run under DLBB_TPU_TESTS=1 (tests/conftest.py), which
+    # is a claim that there is a chip: no chip is then a failure, not a skip
+    assert jax.default_backend() == "tpu", (
+        f"DLBB_TPU_TESTS=1 but the backend is {jax.default_backend()!r}")
 
 
 def _qkv(seed, b, n, s, d, dtype, kvh=None):
@@ -121,6 +124,37 @@ def test_flash_compiled_gqa_bwd():
         )
 
 
+@pytest.mark.parametrize("seq", [512, 1024])
+def test_flash_compiled_7b_head_geometry(seq):
+    """The 7B head geometry the smoke runs (32 heads x 128, bf16), forward
+    and backward, at the default blocks: S=512 fits one 512 x 512 block,
+    S=1024 one 1024 x 1024 block — the largest VMEM working set the
+    kernels are asked for (s, p, dp, ds in fp32 plus two iotas)."""
+    from dlbb_tpu.models.attention import dense_attention
+    from dlbb_tpu.ops import flash_attention, mosaic_call_count
+
+    q, k, v = _qkv(5, 1, 32, seq, 128, jnp.bfloat16)
+
+    def loss(attn):
+        return lambda q, k, v: jnp.sum(
+            attn(q, k, v).astype(jnp.float32) ** 2)
+
+    flash = lambda q, k, v: flash_attention(q, k, v, interpret=False)  # noqa: E731
+    grad_flash = jax.jit(jax.value_and_grad(loss(flash), argnums=(0, 1, 2)))
+    assert mosaic_call_count(grad_flash, q, k, v) == 3  # fwd, dq, dkv
+    l_flash, g_flash = grad_flash(q, k, v)
+    l_dense, g_dense = jax.jit(jax.value_and_grad(
+        loss(dense_attention), argnums=(0, 1, 2)))(q, k, v)
+    np.testing.assert_allclose(float(l_flash), float(l_dense), rtol=2e-2)
+    for gf, gd, name in zip(g_flash, g_dense, "qkv"):
+        gf, gd = np.asarray(gf, np.float32), np.asarray(gd, np.float32)
+        assert np.isfinite(gf).all(), f"d{name} not finite"
+        # bf16 gradients of an O(S) sum: compare at the scale of the tensor
+        scale = np.abs(gd).max()
+        np.testing.assert_allclose(gf / scale, gd / scale, atol=3e-2,
+                                   err_msg=f"d{name} mismatch")
+
+
 def test_full_attention_routes_to_flash_on_tpu():
     """attention='full' at S >= FLASH_ROUTE_MIN_SEQ must produce the same
     numbers as the pinned 'dense' kernel — the routing is a kernel swap,
@@ -176,3 +210,50 @@ def test_e2e_smoke_on_chip():
     assert result["tokens_per_second"] > 0
     assert result["forward_time"]["mean"] > 0
     assert np.isfinite(result["achieved_tflops_per_second"])
+
+
+def test_train_step_smoke_on_chip():
+    """One real train run on the chip at small widths: flash forward and
+    both backward kernels compile inside the remat'd, donated Adam step,
+    and a fixed batch's loss falls."""
+    from dlbb_tpu.train.loop import run_train
+
+    result = run_train({
+        "experiment": {"name": "tpu_train_smoke"},
+        "model": {"hidden_size": 512, "num_layers": 2, "num_heads": 4,
+                  "ffn_intermediate": 1024, "attention": "full",
+                  "remat": True, "remat_policy": "dots"},
+        "parallelism": {"world_size": 1, "data_parallel": 1},
+        "input": {"batch_size": 2, "sequence_length": 512, "seed": 42},
+        "execution": {"warmup_iterations": 1, "benchmark_iterations": 4},
+        "training": {"learning_rate": 1e-3, "optimizer": "adam",
+                     "moments_dtype": "bfloat16"},
+    }, zero_stage=0, verbose=False)
+    assert result["system_info"]["backend"] == "tpu"
+    assert result["mosaic_calls"] >= 3
+    assert np.isfinite(result["losses"]).all()
+    assert result["losses"][-1] < result["losses"][0]
+
+
+def test_serve_smoke_on_chip(tmp_path):
+    """One real serving run on the chip at small widths, bf16, through the
+    fused-scan / chunked-prefill fast path: every request completes with
+    no retry, failure or carry reset, and the device reports its peak."""
+    from dlbb_tpu.serve.bench import run_serve_from_config
+
+    report = run_serve_from_config(
+        None, trace="poisson", num_requests=12, rate=50.0, seed=7,
+        output_dir=str(tmp_path), verbose=False,
+        overrides={"max_batch": 8, "max_seq": 256, "decode_horizon": 8,
+                   "inflight_window": 2, "prefill_chunk": 64},
+    )
+    req, res = report["requests"], report["resilience"]
+    assert set(req["outcomes"].values()) == {"completed"}
+    assert len(req["outcomes"]) == 12 and req["rejected"] == 0
+    assert (res["retries"], res["failed_requests"],
+            res["hung_dispatches"]) == (0, 0, 0)
+    assert report["fast_path"]["fused_scans"] > 0
+    assert report["fast_path"]["prefill_chunks"] > 0
+    info = report["system_info"]
+    assert info["backend"] == "tpu"
+    assert info["devices"][0]["memory_stats"]["peak_bytes_in_use"] > 0
