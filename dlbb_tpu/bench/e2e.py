@@ -34,6 +34,7 @@ from dlbb_tpu.models.transformer import (
     forward,
     forward_flops,
     init_params_sharded,
+    named,
     num_parameters,
 )
 from dlbb_tpu.ops import mosaic_call_count
@@ -47,6 +48,19 @@ from dlbb_tpu.utils.timing import (
     time_fn_chained,
     time_fn_per_iter,
 )
+
+
+def build_forward_step(model_cfg: ModelConfig, mesh,
+                       num_microbatches: Optional[int] = None):
+    """The jitted forward pass ``step(params, x) -> y``, batch-sharded
+    output; its program name in a device trace is ``forward``."""
+    @named("forward")
+    def fwd(p, x):
+        return forward(p, x, model_cfg, mesh=mesh,
+                       num_microbatches=num_microbatches)
+
+    return jax.jit(fwd,
+                   out_shardings=NamedSharding(mesh, batch_spec(mesh)))
 
 
 def run_e2e(
@@ -75,12 +89,7 @@ def run_e2e(
         batch = dataset.get_batch()
     init_time = t_init.elapsed
 
-    out_sharding = NamedSharding(mesh, batch_spec(mesh))
-    step = jax.jit(
-        lambda p, x: forward(p, x, model_cfg, mesh=mesh,
-                             num_microbatches=num_microbatches),
-        out_shardings=out_sharding,
-    )
+    step = build_forward_step(model_cfg, mesh, num_microbatches)
 
     execution = config.get("execution", {})
     warmup = execution.get("warmup_iterations", 5)

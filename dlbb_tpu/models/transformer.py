@@ -32,6 +32,30 @@ from dlbb_tpu.models.sharding import PP_AXIS, specs_for_mesh
 
 Params = dict[str, Any]
 
+# The phase names every transformer block carries as ``jax.named_scope``s,
+# in the order a block runs them.  A scope is HLO metadata (``op_name``):
+# it costs nothing at run time and survives a recompile, so a device
+# trace can be grouped by phase where fusion numbers cannot
+# (docs/observability.md, "Names").  ``_block`` here and the serving
+# twin ``serve/engine.py::_serve_block`` both unpack THIS tuple.
+BLOCK_PHASES = ("ln1", "attn_qkv", "attn_core", "attn_out",
+                "ln2", "mlp_up", "mlp_act", "mlp_down")
+(LN1, ATTN_QKV, ATTN_CORE, ATTN_OUT,
+ LN2, MLP_UP, MLP_ACT, MLP_DOWN) = BLOCK_PHASES
+# the serving programs' cache phases (inside ``attn_core``) and the
+# train step's phases outside the blocks
+SERVE_PHASES = ("kv_update", "kv_attend")
+TRAIN_PHASES = ("loss", "grad_reduce", "optimizer")
+
+
+def named(name: str):
+    """Decorator: give a function about to be jitted the program name
+    the profile's "XLA Modules" line prints (``jit_<name>``)."""
+    def rename(fn):
+        fn.__name__ = fn.__qualname__ = name
+        return fn
+    return rename
+
 
 def _dtype_of(name: str):
     return {"bfloat16": jnp.bfloat16, "float32": jnp.float32,
@@ -396,21 +420,30 @@ def _block(x, layer: Params, config: ModelConfig, mesh=None,
             return y @ kernel + bias
 
     residual = x
-    y = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
-    qkv = col(y, layer["qkv"]["kernel"], layer["qkv"]["bias"])
-    attn = _attention(qkv, config, mesh, sp_axis)
-    x = row(attn, layer["out"]["kernel"], layer["out"]["bias"]) + residual
+    with jax.named_scope(LN1):
+        y = _layernorm(x, layer["ln1"]["scale"], layer["ln1"]["bias"])
+    with jax.named_scope(ATTN_QKV):
+        qkv = col(y, layer["qkv"]["kernel"], layer["qkv"]["bias"])
+    with jax.named_scope(ATTN_CORE):
+        attn = _attention(qkv, config, mesh, sp_axis)
+    with jax.named_scope(ATTN_OUT):
+        x = row(attn, layer["out"]["kernel"],
+                layer["out"]["bias"]) + residual
 
     residual = x
-    y = _layernorm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
+    with jax.named_scope(LN2):
+        y = _layernorm(x, layer["ln2"]["scale"], layer["ln2"]["bias"])
     if config.is_moe:
         ffn_out, aux = _moe_ffn(y, layer, config)
         x = ffn_out + residual
     else:
-        y = col(y, layer["ffn_up"]["kernel"], layer["ffn_up"]["bias"])
-        y = jax.nn.gelu(y)
-        x = row(y, layer["ffn_down"]["kernel"],
-                layer["ffn_down"]["bias"]) + residual
+        with jax.named_scope(MLP_UP):
+            y = col(y, layer["ffn_up"]["kernel"], layer["ffn_up"]["bias"])
+        with jax.named_scope(MLP_ACT):
+            y = jax.nn.gelu(y)
+        with jax.named_scope(MLP_DOWN):
+            x = row(y, layer["ffn_down"]["kernel"],
+                    layer["ffn_down"]["bias"]) + residual
         aux = jnp.zeros((), jnp.float32)
     return x, aux
 

@@ -24,6 +24,15 @@ monotonic clock — wall-clock timestamps live in the ``otherData``
 metadata block, outside every event), in microseconds as the trace-event
 spec requires.  Thread ids are real ``threading.get_ident`` values, so
 the compile-ahead worker renders as its own track.
+
+One clock with the device: an active tracer's :func:`span` also opens
+the profiler annotation of the same name and arguments
+(``utils/profiling.annotate``), so in ANY device capture taken while
+spans are on — the benchmark's, ``--device-trace``, an operator's own
+``jax.profiler`` session — the program's spans sit on the profile's
+host line, on the profile's clock, beside the device ops; no alignment
+step, and no site opens the two by hand.  Outside a profiler session
+the annotation is one flag test.
 """
 
 from __future__ import annotations
@@ -35,6 +44,8 @@ import threading
 import time
 from pathlib import Path
 from typing import Any, Iterator, Optional
+
+from dlbb_tpu.utils import profiling
 
 SPAN_SCHEMA = "dlbb_span_trace_v1"
 
@@ -99,9 +110,11 @@ class SpanTracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "harness",
              **args: Any) -> Iterator[None]:
-        self.begin(name, cat, args=_jsonable(args))
+        args = _jsonable(args)
+        self.begin(name, cat, args=args)
         try:
-            yield
+            with profiling.annotate(name, **args):
+                yield
         finally:
             self.end(name, cat)
 

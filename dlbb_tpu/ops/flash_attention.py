@@ -64,6 +64,13 @@ NEG_INF = -1e30
 
 _LANES = 128  # TPU vector lane count — row-stat arrays carry this axis
 
+# The kernels' names in a device trace: each ``pl.pallas_call`` gets the
+# name as ``name=`` and runs under a ``jax.named_scope`` of the same
+# name, so whichever of the two the profile keeps tells forward, dq and
+# dkv apart (they used to show as three ``closed_call.N``).
+KERNEL_NAMES = ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+FLASH_FWD, FLASH_BWD_DQ, FLASH_BWD_DKV = KERNEL_NAMES
+
 
 def mosaic_call_count(jitted, *args) -> int:
     """How many Mosaic kernels (compiled Pallas, custom-call target
@@ -181,8 +188,9 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
         _fwd_kernel, sm_scale=sm_scale, block_q=block_q, block_k=block_k,
         causal=causal, offset=offset,
     )
-    return pl.pallas_call(
+    call = pl.pallas_call(
         kernel,
+        name=FLASH_FWD,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -203,7 +211,9 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
             pltpu.VMEM((block_q, _LANES), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v)
+    )
+    with jax.named_scope(FLASH_FWD):
+        return call(q, k, v)
 
 
 def _p_from_lse(s, lse_row):
@@ -330,16 +340,19 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     k_spec = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b // g, j, 0))
     row_spec = pl.BlockSpec((1, block_q, _LANES), lambda b, i, j: (b, i, 0))
 
-    dq = pl.pallas_call(
+    dq_call = pl.pallas_call(
         functools.partial(_dq_kernel, sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, causal=causal, offset=offset),
+        name=FLASH_BWD_DQ,
         grid=(bn, s // block_q, sk // block_k),
         in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope(FLASH_BWD_DQ):
+        dq = dq_call(q, k, v, do, lse, delta)
 
     # dkv: swap loop order — K blocks outer; the inner dim flattens
     # (query-head group, Q block) so each of the bkv K/V rows accumulates
@@ -351,10 +364,11 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
     row_spec_t = pl.BlockSpec(
         (1, block_q, _LANES), lambda b, j, i: (b * g + i // nq, i % nq, 0)
     )
-    dk, dv = pl.pallas_call(
+    dkv_call = pl.pallas_call(
         functools.partial(_dkv_kernel, sm_scale=sm_scale, block_q=block_q,
                           block_k=block_k, causal=causal, offset=offset,
                           q_blocks=nq),
+        name=FLASH_BWD_DKV,
         grid=(bkv, sk // block_k, g * nq),
         in_specs=[q_spec_t, k_spec_t, k_spec_t, q_spec_t, row_spec_t,
                   row_spec_t],
@@ -368,7 +382,9 @@ def _bwd(sm_scale, causal, block_q, block_k, interpret, res, do):
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    )
+    with jax.named_scope(FLASH_BWD_DKV):
+        dk, dv = dkv_call(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

@@ -111,9 +111,19 @@ from dlbb_tpu.data.synthetic import (
 from dlbb_tpu.models.configs import ModelConfig, validate_serving
 from dlbb_tpu.models.attention import dense_attention
 from dlbb_tpu.models.transformer import (
+    ATTN_CORE,
+    ATTN_OUT,
+    ATTN_QKV,
+    LN1,
+    LN2,
+    MLP_ACT,
+    MLP_DOWN,
+    MLP_UP,
+    SERVE_PHASES,
     _dtype_of,
     _layernorm,
     init_params_sharded,
+    named,
 )
 from dlbb_tpu.obs import spans
 from dlbb_tpu.obs.export import MetricsRegistry
@@ -739,18 +749,29 @@ def _serve_block(h, layer, config: ModelConfig, attention_step,
     (cached append + length-masked read), and chunked prefill (prefix
     carry + offset block write); ``cache_state`` is an opaque per-layer
     tuple (the scanned cache leaves, plus the prefix K/V for chunks)."""
-    y = _layernorm(h, layer["ln1"]["scale"], layer["ln1"]["bias"])
-    qkv = y @ layer["qkv"]["kernel"] + layer["qkv"]["bias"]
-    q, k, v = _split_qkv(qkv, config)
-    attn, cache_state = attention_step(q, k, v, cache_state)
-    h = attn @ layer["out"]["kernel"] + layer["out"]["bias"] + h
+    with jax.named_scope(LN1):
+        y = _layernorm(h, layer["ln1"]["scale"], layer["ln1"]["bias"])
+    with jax.named_scope(ATTN_QKV):
+        qkv = y @ layer["qkv"]["kernel"] + layer["qkv"]["bias"]
+        q, k, v = _split_qkv(qkv, config)
+    with jax.named_scope(ATTN_CORE):
+        attn, cache_state = attention_step(q, k, v, cache_state)
+    with jax.named_scope(ATTN_OUT):
+        h = attn @ layer["out"]["kernel"] + layer["out"]["bias"] + h
     residual = h
-    y2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-    y2 = y2 @ layer["ffn_up"]["kernel"] + layer["ffn_up"]["bias"]
-    y2 = jax.nn.gelu(y2)
-    h = (y2 @ layer["ffn_down"]["kernel"]
-         + layer["ffn_down"]["bias"] + residual)
+    with jax.named_scope(LN2):
+        y2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
+    with jax.named_scope(MLP_UP):
+        y2 = y2 @ layer["ffn_up"]["kernel"] + layer["ffn_up"]["bias"]
+    with jax.named_scope(MLP_ACT):
+        y2 = jax.nn.gelu(y2)
+    with jax.named_scope(MLP_DOWN):
+        h = (y2 @ layer["ffn_down"]["kernel"]
+             + layer["ffn_down"]["bias"] + residual)
     return h, cache_state
+
+
+KV_UPDATE, KV_ATTEND = SERVE_PHASES
 
 
 def _heads(t: jax.Array, nh: int, d: int) -> jax.Array:
@@ -759,6 +780,7 @@ def _heads(t: jax.Array, nh: int, d: int) -> jax.Array:
     return t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
 
 
+@jax.named_scope(KV_ATTEND)
 def _cached_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
                       valid: jax.Array) -> jax.Array:
     """Length-masked decode attention over the flattened cache.
@@ -789,6 +811,7 @@ def _cached_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
     return out.astype(k_flat.dtype)
 
 
+@jax.named_scope(KV_UPDATE)
 def _write_prompt_blocks(cache_layer: jax.Array, update: jax.Array,
                          slot: jax.Array, start_blk: int = 0) -> jax.Array:
     """Masked-select write of a prefill bucket (or chunk) into one slot's
@@ -809,6 +832,7 @@ def _write_prompt_blocks(cache_layer: jax.Array, update: jax.Array,
     return jnp.where(slot_mask & blk_mask, padded[None], cache_layer)
 
 
+@jax.named_scope(KV_UPDATE)
 def _write_scale_blocks(scale_layer: jax.Array, update: jax.Array,
                         slot: jax.Array, start_blk: int = 0) -> jax.Array:
     """``_write_prompt_blocks`` for the int8 side-channel scale plane:
@@ -826,11 +850,13 @@ def _write_scale_blocks(scale_layer: jax.Array, update: jax.Array,
 
 
 def build_prefill(config: ModelConfig, mesh: Mesh,
-                  quantized: bool = False):
+                  quantized: bool = False, name: str = "serve_prefill"):
     """Jitted ``prefill(cache, params, x, slot, length) -> (cache,
     y_last)`` — retraces once per prompt bucket (x's static shape).  The
     cache is donated (argnum 0), so the carried protocol matches the
     train-step convention the audit and calibration understand.
+    ``name`` is the program's name in a device trace: the engine builds
+    one jit per bucket, ``serve_prefill_b<bucket>``.
 
     ``quantized`` writes the int8 layout (``QuantKVCache``): each
     freshly-computed K/V block is quantised per (block, kv-head) and
@@ -840,6 +866,7 @@ def build_prefill(config: ModelConfig, mesh: Mesh,
     only the write."""
     n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
 
+    @named(name)
     def prefill(cache, params, x, slot, length):
         bs = cache.block_size
         s_bucket = x.shape[1]
@@ -920,6 +947,7 @@ def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple[jax.Array,
     return (jax.device_put(zeros, sh), jax.device_put(zeros, sh))
 
 
+@jax.named_scope(KV_ATTEND)
 def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
                      start: int) -> jax.Array:
     """Offset-causal fp32 attention for one prefill chunk.
@@ -979,6 +1007,7 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
     touches only the cache write, exactly as in monolithic prefill."""
     n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
 
+    @named(f"serve_prefill_chunk_o{start}")
     def prefill_chunk(cache, prefix, params, x, slot, length):
         bs = cache.block_size
         wb = chunk_len // bs
@@ -1085,6 +1114,7 @@ def build_prefix_attach(config: ModelConfig, mesh: Mesh,
             (1, 1, -1) + (1,) * (plane.ndim - 3))
         return jnp.where(slot_mask & blk_mask, donor[:, None], plane)
 
+    @named("serve_prefix_attach")
     def attach(cache, src, dst):
         nl = cache.k.shape[0]
         k_q = jnp.take(cache.k, src, axis=1)[:, :nb_m]
@@ -1124,6 +1154,7 @@ def build_compact_gather(mesh: Mesh):
     results are scattered back into it at scan exit."""
     from dlbb_tpu.serve.kvcache import gather_cache_slots
 
+    @named("serve_compact_gather")
     def gather(carry, idx):
         cache, x = carry
         return (gather_cache_slots(cache, idx), x[idx])
@@ -1142,6 +1173,7 @@ def build_compact_scatter(mesh: Mesh):
     with distinct free slots, so the scatter is unambiguous)."""
     from dlbb_tpu.serve.kvcache import scatter_cache_slots
 
+    @named("serve_compact_scatter")
     def scatter(carry, small_carry, idx):
         cache, x = carry
         s_cache, s_x = small_carry
@@ -1205,26 +1237,28 @@ def _decode_step_math(carry, params, active, config: ModelConfig,
         v_new = v[:, 0].reshape(b_dim, kvh, d).astype(v_fp.dtype)
         # append at each active slot's own length (masked select —
         # elementwise, shard-local; see serve/kvcache.py)
-        k_flat = k_fp.reshape(b_dim, s_max, kvh, d)
-        v_flat = v_fp.reshape(b_dim, s_max, kvh, d)
-        k_flat = jnp.where(write_mask[..., None, None],
-                           k_new[:, None], k_flat)
-        v_flat = jnp.where(write_mask[..., None, None],
-                           v_new[:, None], v_flat)
+        with jax.named_scope(KV_UPDATE):
+            k_flat = k_fp.reshape(b_dim, s_max, kvh, d)
+            v_flat = v_fp.reshape(b_dim, s_max, kvh, d)
+            k_flat = jnp.where(write_mask[..., None, None],
+                               k_new[:, None], k_flat)
+            v_flat = jnp.where(write_mask[..., None, None],
+                               v_new[:, None], v_flat)
         attn = _cached_attention(qh, k_flat.astype(x.dtype),
                                  v_flat.astype(x.dtype), valid)
-        if quantized:
-            kq, ks = quantize_kv_blocks(
-                k_flat.reshape(b_dim, nb, bs, kvh, d))
-            vq, vs = quantize_kv_blocks(
-                v_flat.reshape(b_dim, nb, bs, kvh, d))
-            state = (jnp.where(sel5, kq, k_l),
-                     jnp.where(sel5, vq, v_l),
-                     jnp.where(sel3, ks, ks_l),
-                     jnp.where(sel3, vs, vs_l))
-        else:
-            state = (k_flat.reshape(b_dim, nb, bs, kvh, d),
-                     v_flat.reshape(b_dim, nb, bs, kvh, d))
+        with jax.named_scope(KV_UPDATE):
+            if quantized:
+                kq, ks = quantize_kv_blocks(
+                    k_flat.reshape(b_dim, nb, bs, kvh, d))
+                vq, vs = quantize_kv_blocks(
+                    v_flat.reshape(b_dim, nb, bs, kvh, d))
+                state = (jnp.where(sel5, kq, k_l),
+                         jnp.where(sel5, vq, v_l),
+                         jnp.where(sel3, ks, ks_l),
+                         jnp.where(sel3, vs, vs_l))
+            else:
+                state = (k_flat.reshape(b_dim, nb, bs, kvh, d),
+                         v_flat.reshape(b_dim, nb, bs, kvh, d))
         return (attn.transpose(0, 2, 1, 3).reshape(b_dim, 1, n * d),
                 state)
 
@@ -1253,6 +1287,7 @@ def build_decode_step(config: ModelConfig, mesh: Mesh,
     the engine (and the calibration harness's carry protocol) feeds
     ``out[0]`` straight back in."""
 
+    @named("serve_decode_step")
     def decode_step(carry, params, active):
         return _decode_step_math(carry, params, active, config,
                                  quantized=quantized)
@@ -1284,6 +1319,7 @@ def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int,
     token each then-active slot generated at trip t)."""
     cache_cls = QuantKVCache if quantized else KVCache
 
+    @named(f"serve_decode_k{k}")
     def decode_fused(carry, params, active, remaining):
         # the slot-lengths vector deliberately stays OUT of the scan
         # carry: its trajectory is fully determined by the replicated
@@ -1328,6 +1364,7 @@ def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int,
     )
 
 
+@named("serve_inject")
 def _inject_token(carry, slot, vec):
     """Place a freshly-prefilled request's first token into the decode
     input buffer: ``x[slot, 0] = vec``."""
@@ -1341,6 +1378,7 @@ def _inject_token(carry, slot, vec):
 # ---------------------------------------------------------------------------
 
 
+@named("serve_inject_greedy")
 def _inject_token_greedy(carry, slot, vec, table):
     """Token-mode admission inject: quantise the prefill's last output
     through the greedy token table (``tok = argmax(vec)``, ``x[slot, 0]
@@ -1356,6 +1394,7 @@ def _inject_token_greedy(carry, slot, vec, table):
             tok)
 
 
+@named("serve_inject_sampled")
 def _inject_token_sampled(carry, slot, tok, table):
     """Sampled-mode admission inject: the HOST already sampled the
     first token from the prefill's softmax (``temperature > 0``), so
@@ -1369,6 +1408,7 @@ def _inject_token_sampled(carry, slot, tok, table):
                       emb[None, None, :].astype(x.dtype), x))
 
 
+@jax.named_scope(KV_ATTEND)
 def _verify_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
                       valid: jax.Array) -> jax.Array:
     """Offset-causal length-masked attention for one verify step.
@@ -1408,6 +1448,7 @@ def build_decode_token_step(config: ModelConfig, mesh: Mesh):
     output (device argmax, never a host float transfer).  This is the
     speculative modes' pinned per-step oracle."""
 
+    @named("serve_decode_token_step")
     def decode_token_step(carry, params, table, active):
         (cache, y), _ = _decode_step_math(carry, params, active, config)
         tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
@@ -1431,6 +1472,7 @@ def build_decode_fused_token(config: ModelConfig, mesh: Mesh, k: int):
     the greedy token quantisation between trips.  Returns ``(carry,
     toks [k, B])``."""
 
+    @named(f"serve_decode_token_k{k}")
     def decode_fused_token(carry, params, table, active, remaining):
         cache0, x0 = carry
         lengths0 = cache0.lengths
@@ -1496,6 +1538,7 @@ def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
     n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
     g1 = gamma + 1
 
+    @named(f"serve_spec_verify_g{gamma}")
     def verify_step(carry, params, table, draft_ids, active, remaining):
         cache, x = carry
         b_dim, s_max = cache.max_batch, cache.max_seq
@@ -1518,11 +1561,12 @@ def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
             # the decode-step masked write, unrolled over the verify
             # positions (static γ, so this stays collective-free
             # elementwise selects)
-            for i in range(g1):
-                m = ((pos == lengths[:, None] + i)
-                     & active[:, None])[..., None, None]
-                k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
-                v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
+            with jax.named_scope(KV_UPDATE):
+                for i in range(g1):
+                    m = ((pos == lengths[:, None] + i)
+                         & active[:, None])[..., None, None]
+                    k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
+                    v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
             attn = _verify_attention(qh, k_flat, v_flat, valid)
             return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
                     (k_flat.reshape(b_dim, nb, bs, kvh, d),
@@ -1579,6 +1623,7 @@ def build_verify_probs(config: ModelConfig, mesh: Mesh, gamma: int):
     cold-drafter fallback unit (one sampled token per trip)."""
     g1 = gamma + 1
 
+    @named(f"serve_spec_probs_g{gamma}")
     def verify_probs(carry, params, table, draft_ids, active):
         cache, x = carry
         b_dim, s_max = cache.max_batch, cache.max_seq
@@ -1598,11 +1643,12 @@ def build_verify_probs(config: ModelConfig, mesh: Mesh, gamma: int):
             v_new = v.reshape(b_dim, g1, kvh, d)
             k_flat = k_l.reshape(b_dim, s_max, kvh, d)
             v_flat = v_l.reshape(b_dim, s_max, kvh, d)
-            for i in range(g1):
-                m = ((pos == lengths[:, None] + i)
-                     & active[:, None])[..., None, None]
-                k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
-                v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
+            with jax.named_scope(KV_UPDATE):
+                for i in range(g1):
+                    m = ((pos == lengths[:, None] + i)
+                         & active[:, None])[..., None, None]
+                    k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
+                    v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
             attn = _verify_attention(qh, k_flat, v_flat, valid)
             return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
                     (k_flat.reshape(b_dim, nb, bs, kvh, d),
@@ -1639,6 +1685,7 @@ def build_spec_commit(config: ModelConfig, mesh: Mesh):
     law.  The rejected suffix needs no cleanup — same dead-by-
     construction argument as the greedy verify."""
 
+    @named("serve_spec_commit")
     def spec_commit(carry, table, next_ids, commits, active):
         cache, x = carry
         lengths_f = (cache.lengths + commits).astype(jnp.int32)
@@ -1671,6 +1718,7 @@ def build_draft_scan(config: ModelConfig, mesh: Mesh, gamma: int):
     into the verify step — no host round-trip in the draft-verify
     chain."""
 
+    @named(f"serve_spec_draft_g{gamma}")
     def draft_scan(cache, params, table, x, lengths, active):
         act_i32 = active.astype(jnp.int32)
 
@@ -1950,8 +1998,7 @@ class ServingEngine:
         self.params = (params if params is not None
                        else init_params_sharded(config, jax.random.key(seed),
                                                 mesh))
-        self._prefill = build_prefill(config, mesh,
-                                      quantized=self._quantized)
+        self._prefill_jits: dict[int, Any] = {}
         self._decode = build_decode_step(config, mesh,
                                          quantized=self._quantized)
         self._fused_ks = serving.fused_horizons
@@ -2048,7 +2095,8 @@ class ServingEngine:
             # identically; sharded by the same ParallelismPlan
             self._draft_params = init_params_sharded(
                 self._draft_config, jax.random.key(seed + 1), mesh)
-            self._draft_prefill = build_prefill(self._draft_config, mesh)
+            self._draft_prefill = build_prefill(
+                self._draft_config, mesh, name="serve_spec_draft_prefill")
             self._draft_scan = {
                 g: build_draft_scan(self._draft_config, mesh, g)
                 for g in self._spec_gammas
@@ -2112,8 +2160,8 @@ class ServingEngine:
             return (carry[0], x)
 
         def prefill_fn(t):
-            return self._prefill(t[0], self.params, t[1], np.int32(0),
-                                 np.int32(bucket))
+            return self._prefill_jit(bucket)(
+                t[0], self.params, t[1], np.int32(0), np.int32(bucket))
 
         metas = [obs_capture.capture_device_trace(
             prefill_fn, prefill_payload, trace_root,
@@ -2203,6 +2251,19 @@ class ServingEngine:
             if reason is not None:
                 raise ValueError(f"request {r.rid}: {reason}")
 
+    def _prefill_jit(self, bucket: int):
+        """The monolithic-prefill jit of one prompt bucket (one program
+        per bucket either way; a jit of its own gives each its name,
+        ``serve_prefill_b<bucket>``; built lazily, warmed by
+        ``_compile``)."""
+        jit = self._prefill_jits.get(bucket)
+        if jit is None:
+            jit = build_prefill(self.config, self.mesh,
+                                quantized=self._quantized,
+                                name=f"serve_prefill_b{bucket}")
+            self._prefill_jits[bucket] = jit
+        return jit
+
     def _chunk_jit(self, chunk_index: int):
         """The chunked-prefill jit for static chunk offset
         ``chunk_index * prefill_chunk`` (one retrace per offset — the
@@ -2243,7 +2304,7 @@ class ServingEngine:
         for b in buckets:
             dummy = request_embeddings(0, b, self.config.hidden_size,
                                        dtype=self._dtype, pad_to=b)
-            cache, y_last = self._prefill(
+            cache, y_last = self._prefill_jit(b)(
                 carry[0], self.params, dummy, np.int32(0), np.int32(b))
             carry = (cache, carry[1])
         if max_chunks:
@@ -2346,6 +2407,10 @@ class ServingEngine:
         jax.block_until_ready(carry[1])
 
     def _event(self, event: str, rid: int, **extra: Any) -> None:
+        # the request's identifier in the span file: every lifecycle
+        # event as an instant carrying the ``rid`` the admission spans
+        # carry (a global load when tracing is off)
+        spans.instant(event, cat="request", rid=rid)
         if self.journal is not None:
             self.journal.event(event, config=f"request-{rid}", **extra)
         ctl = self._control
@@ -2673,10 +2738,13 @@ class ServingEngine:
         def sync_one() -> None:
             unit = inflight.popleft()
             try:
-                _with_deadline(
-                    lambda: jax.block_until_ready(unit["ys"]),
-                    unit_deadline(unit["k_exec"]),
-                    f"decode[k={unit['k_exec']}]", "serve-sync")
+                # ``k`` is the unit WAITED FOR — under a deeper window
+                # an older one than the unit just dispatched
+                with spans.span("serve-decode-sync", k=unit["k_exec"]):
+                    _with_deadline(
+                        lambda: jax.block_until_ready(unit["ys"]),
+                        unit_deadline(unit["k_exec"]),
+                        f"decode[k={unit['k_exec']}]", "serve-sync")
             except DeadlineExceeded as e:
                 abandon_window(unit, e)
                 return
@@ -2740,7 +2808,11 @@ class ServingEngine:
             # the span therefore spans the real step wall (as PR-9's
             # did); under a deeper window the synced device time
             # belongs to an older unit and per-unit device attribution
-            # lives in decode_step_s/per_token_s instead
+            # lives in decode_step_s/per_token_s instead.  Its children
+            # tell the two apart: ``serve-decode-dispatch`` (the jit
+            # call of THIS unit) and ``serve-decode-sync`` (the wait,
+            # with the ``k`` of the unit waited for); what is left is
+            # the host bookkeeping at scan exit
             span_args = dict(active=len(slots), steps=k)
             if compact:
                 span_args["compacted"] = True
@@ -2761,9 +2833,10 @@ class ServingEngine:
                             # engine's scheduler thread
                             time.sleep(inject.param("hang_seconds"))
                         return fn()
-                    return _with_deadline(run, deadline,
-                                          f"decode[k={k}]",
-                                          "serve-dispatch")
+                    with spans.span("serve-decode-dispatch", k=k):
+                        return _with_deadline(run, deadline,
+                                              f"decode[k={k}]",
+                                              "serve-dispatch")
 
                 if k == 1:
                     if token_mode:
@@ -3275,47 +3348,48 @@ class ServingEngine:
                 if dispatch_spec():
                     return
                 refresh_active()
-            rem = {s: slots[s].req.output_len - slots[s].tokens_done
-                   for s in sorted(slots)}
-            # next event: the earliest completion while anything is (or
-            # may soon be) waiting for a slot; a quiescent batch fuses
-            # through its full drain
-            horizon = (min(rem.values()) if (queue or pending)
-                       else max(rem.values()))
-            horizon = min(cfg.decode_horizon, horizon)
-            if control is not None and control.horizon_cap is not None:
-                # degradation ladder (serve/fleet.py): a shrunk horizon
-                # trades fused-scan throughput for scheduling latency
-                # under overload — never silently (each transition is
-                # journaled ``degrade-transition``)
-                horizon = min(horizon, max(1, control.horizon_cap))
-            if pending:
-                # a known arrival is a scheduling event too: bound the
-                # scan so admission happens near the arrival instead of
-                # up to decode_horizon steps late (steps estimated from
-                # the observed per-step interval; before the first
-                # sample exists, stay per-step — one unit bootstraps
-                # the EMA)
-                if step_ema[0] > 0.0:
-                    gap = pending[0].arrival_s - self._now()
-                    steps_to_arrival = (max(1, int(gap / step_ema[0]))
-                                        if gap > 0 else 1)
-                    horizon = min(horizon, steps_to_arrival)
-                else:
-                    horizon = 1
-            if max_k is not None:
-                horizon = min(horizon, max_k)
-            k = 1
-            for cand in self._fused_ks:
-                if cand <= horizon:
-                    k = cand
-            steps = {s: min(k, r) for s, r in rem.items()}
-            compact = (
-                self._compact_gather_fn is not None and k > 1
-                and len(slots) <= cfg.compact_threshold * cfg.max_batch
-                and len(slots) <= cfg.max_batch // 2
-            )
-            snap = take_snapshot()
+            with spans.span("serve-decode-plan"):
+                rem = {s: slots[s].req.output_len - slots[s].tokens_done
+                       for s in sorted(slots)}
+                # next event: the earliest completion while anything is (or
+                # may soon be) waiting for a slot; a quiescent batch fuses
+                # through its full drain
+                horizon = (min(rem.values()) if (queue or pending)
+                           else max(rem.values()))
+                horizon = min(cfg.decode_horizon, horizon)
+                if control is not None and control.horizon_cap is not None:
+                    # degradation ladder (serve/fleet.py): a shrunk horizon
+                    # trades fused-scan throughput for scheduling latency
+                    # under overload — never silently (each transition is
+                    # journaled ``degrade-transition``)
+                    horizon = min(horizon, max(1, control.horizon_cap))
+                if pending:
+                    # a known arrival is a scheduling event too: bound the
+                    # scan so admission happens near the arrival instead of
+                    # up to decode_horizon steps late (steps estimated from
+                    # the observed per-step interval; before the first
+                    # sample exists, stay per-step — one unit bootstraps
+                    # the EMA)
+                    if step_ema[0] > 0.0:
+                        gap = pending[0].arrival_s - self._now()
+                        steps_to_arrival = (max(1, int(gap / step_ema[0]))
+                                            if gap > 0 else 1)
+                        horizon = min(horizon, steps_to_arrival)
+                    else:
+                        horizon = 1
+                if max_k is not None:
+                    horizon = min(horizon, max_k)
+                k = 1
+                for cand in self._fused_ks:
+                    if cand <= horizon:
+                        k = cand
+                steps = {s: min(k, r) for s, r in rem.items()}
+                compact = (
+                    self._compact_gather_fn is not None and k > 1
+                    and len(slots) <= cfg.compact_threshold * cfg.max_batch
+                    and len(slots) <= cfg.max_batch // 2
+                )
+                snap = take_snapshot()
             attempt = 0
             while True:
                 try:
@@ -3434,13 +3508,15 @@ class ServingEngine:
                     plan["attached_tokens"] = 0
                     if carry_resets[0] == plan["resets"]:
                         m_chunks = plan["attach_tokens"] // chunk
-                x_prompt = request_embeddings(
-                    req.seed, req.prompt_len,
-                    self.config.hidden_size,
-                    dtype=self._dtype, pad_to=bucket,
-                    prefix_len=req.prefix_len,
-                    prefix_seed=req.prefix_seed,
-                )
+                with spans.span("serve-admit-embed", rid=req.rid,
+                                slot=slot):
+                    x_prompt = request_embeddings(
+                        req.seed, req.prompt_len,
+                        self.config.hidden_size,
+                        dtype=self._dtype, pad_to=bucket,
+                        prefix_len=req.prefix_len,
+                        prefix_seed=req.prefix_seed,
+                    )
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot,
                                 chunks=n_chunks - m_chunks):
@@ -3505,15 +3581,17 @@ class ServingEngine:
                     dt = time.perf_counter() - t0 - decode_spent
             else:
                 bucket = cfg.bucket_for(req.prompt_len)
-                x_prompt = request_embeddings(
-                    req.seed, req.prompt_len,
-                    self.config.hidden_size,
-                    dtype=self._dtype, pad_to=bucket,
-                )
+                with spans.span("serve-admit-embed", rid=req.rid,
+                                slot=slot):
+                    x_prompt = request_embeddings(
+                        req.seed, req.prompt_len,
+                        self.config.hidden_size,
+                        dtype=self._dtype, pad_to=bucket,
+                    )
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot):
                     t0 = time.perf_counter()
-                    cache, y_last = self._prefill(
+                    cache, y_last = self._prefill_jit(bucket)(
                         carry[0], self.params, x_prompt,
                         np.int32(slot), np.int32(req.prompt_len))
                     if self._draft_prefill is not None:
@@ -3685,130 +3763,148 @@ class ServingEngine:
                 # scan boundary: settle in-flight decode before the
                 # prefill blocks, so its sync cost lands in decode
                 # timing and TTFT stays honest
-                drain()
+                with spans.span("serve-drain", inflight=len(inflight)):
+                    drain()
+                # one child span per step of an admission, each with
+                # the request's ``rid`` and its ``slot``: plan, embed
+                # and prefill (inside ``prefill_once``), inject, book —
+                # what is left of ``serve-admission`` is this loop
                 with spans.span("serve-admission", queue=len(queue),
                                 free_slots=len(free_slots)):
                     while queue and free_slots:
-                        # prefix admission: blocks the trie already
-                        # holds are counted ONCE fleet-wide, so a
-                        # request whose private suffix fits is
-                        # admittable even when its full footprint
-                        # would not be — the int8/prefix capacity win
-                        plan = (attach_plan(queue[0])
-                                if cfg.prefix_caching else None)
-                        attach_blocks = (plan["attach_blocks"]
-                                         if plan else 0)
-                        if not ledger.can_reserve(
-                                queue[0].total_tokens,
-                                shared_blocks=attach_blocks):
+                        req, slot = queue[0], free_slots[0]
+                        with spans.span("serve-admit-plan", rid=req.rid,
+                                        slot=slot):
+                            # prefix admission: blocks the trie already
+                            # holds are counted ONCE fleet-wide, so a
+                            # request whose private suffix fits is
+                            # admittable even when its full footprint
+                            # would not be — the int8/prefix capacity
+                            # win
+                            plan = (attach_plan(req)
+                                    if cfg.prefix_caching else None)
+                            attach_blocks = (plan["attach_blocks"]
+                                             if plan else 0)
+                            fits = ledger.can_reserve(
+                                req.total_tokens,
+                                shared_blocks=attach_blocks)
+                            if fits:
+                                queue.popleft()
+                                free_slots.pop(0)
+                                ledger.reserve(
+                                    slot, req.total_tokens,
+                                    chain=(plan["chain"] if plan
+                                           else None),
+                                    attach_blocks=attach_blocks)
+                                if draft_ledger is not None:
+                                    draft_ledger.reserve(
+                                        slot, req.total_tokens)
+                        if not fits:
                             break
-                        req = queue.popleft()
-                        slot = free_slots.pop(0)
-                        ledger.reserve(
-                            slot, req.total_tokens,
-                            chain=(plan["chain"] if plan else None),
-                            attach_blocks=attach_blocks)
-                        if draft_ledger is not None:
-                            draft_ledger.reserve(slot, req.total_tokens)
                         try:
                             bucket, y_last, dt = prefill_dispatch(
                                 req, slot, plan)
                         except Exception as e:  # noqa: BLE001 — closed
                             fail_admission(req, slot, e)
                             continue
-                        first_id = -1
-                        if token_mode and self._sampled:
-                            # sampled inject: position 0 obeys the same
-                            # temperature law as every later token —
-                            # the prefill's last logits come to host
-                            # (one [H] vector per admission), the first
-                            # token is drawn from their softmax, and
-                            # the device only embeds the committed id
-                            # (once per ADMISSION, not per token)
-                            # comm-lint: disable=host-transfer-in-loop
-                            p0 = softmax_np(np.asarray(y_last),
-                                            cfg.temperature)
-                            first_id = int(sample_rng.choice(
-                                p0.shape[-1], p=p0))
-                            carry = self._inject_sampled(
-                                carry, np.int32(slot),
-                                np.int32(first_id), self._table)
-                        elif token_mode:
-                            # greedy token inject: argmax on device, a
-                            # 4-byte id to host — the history seed AND
-                            # the equivalence capture in one transfer
-                            carry, first_tok = self._inject_greedy(
-                                carry, np.int32(slot), y_last,
-                                self._table)
-                            first_id = int(first_tok)
-                        else:
-                            carry = self._inject(carry, np.int32(slot),
-                                                 y_last)
-                        ledger.append(slot, req.prompt_len)
-                        if draft_ledger is not None:
-                            draft_ledger.append(slot, req.prompt_len)
-                        if cfg.prefix_caching and plan is not None:
-                            reused = plan["attached_tokens"]
-                            if reused:
-                                stats.prefix_hits += 1
-                                stats.prefix_tokens_reused += reused
-                                self.registry.inc("serve_prefix_hits")
-                                self.registry.inc(
-                                    "serve_prefix_tokens_reused", reused)
-                                self._event(
-                                    "prefix-attach", req.rid, slot=slot,
-                                    donor=plan["donor"], tokens=reused,
-                                    blocks=reused // cfg.block_size)
-                                if plan["cow_blocks"]:
-                                    # matched deeper than the attach cap:
-                                    # the tail blocks were recomputed
-                                    # privately — the copy-on-write edge
-                                    ledger.note_cow(plan["cow_blocks"])
-                                    stats.prefix_cow_blocks += (
-                                        plan["cow_blocks"])
+                        with spans.span("serve-admit-inject", rid=req.rid,
+                                        slot=slot):
+                            first_id = -1
+                            if token_mode and self._sampled:
+                                # sampled inject: position 0 obeys the same
+                                # temperature law as every later token —
+                                # the prefill's last logits come to host
+                                # (one [H] vector per admission), the first
+                                # token is drawn from their softmax, and
+                                # the device only embeds the committed id
+                                # (once per ADMISSION, not per token)
+                                # comm-lint: disable=host-transfer-in-loop
+                                p0 = softmax_np(np.asarray(y_last),
+                                                cfg.temperature)
+                                first_id = int(sample_rng.choice(
+                                    p0.shape[-1], p=p0))
+                                carry = self._inject_sampled(
+                                    carry, np.int32(slot),
+                                    np.int32(first_id), self._table)
+                            elif token_mode:
+                                # greedy token inject: argmax on device, a
+                                # 4-byte id to host — the history seed AND
+                                # the equivalence capture in one transfer
+                                carry, first_tok = self._inject_greedy(
+                                    carry, np.int32(slot), y_last,
+                                    self._table)
+                                first_id = int(first_tok)
+                            else:
+                                carry = self._inject(carry, np.int32(slot),
+                                                     y_last)
+                        with spans.span("serve-admit-book", rid=req.rid,
+                                        slot=slot):
+                            ledger.append(slot, req.prompt_len)
+                            if draft_ledger is not None:
+                                draft_ledger.append(slot, req.prompt_len)
+                            if cfg.prefix_caching and plan is not None:
+                                reused = plan["attached_tokens"]
+                                if reused:
+                                    stats.prefix_hits += 1
+                                    stats.prefix_tokens_reused += reused
+                                    self.registry.inc("serve_prefix_hits")
+                                    self.registry.inc(
+                                        "serve_prefix_tokens_reused", reused)
                                     self._event(
-                                        "prefix-cow", req.rid, slot=slot,
-                                        blocks=plan["cow_blocks"])
-                            # index this slot's full-block chain: the
-                            # prefill (attached or full) made the slot
-                            # a physical holder of every block it refs,
-                            # and dedup against already-shared blocks
-                            # refunds the private reservation
-                            ledger.register(slot, plan["chain"])
-                        t_first = self._now()
-                        st = _SlotState(req=req, tokens_done=1,
-                                        admitted_s=now,
-                                        first_token_s=t_first,
-                                        gamma_eff=cfg.spec_gamma)
-                        if cfg.speculation == "ngram":
-                            # prompt-lookup context: the prompt's own
-                            # token-id view (pure numpy, admission-time)
-                            # plus the prefill's first committed token
-                            hist[req.rid] = prompt_token_ids(
-                                req.seed, req.prompt_len,
-                                self.config.hidden_size,
-                                period=req.prompt_period,
-                                prefix_len=req.prefix_len,
-                                prefix_seed=req.prefix_seed) + [first_id]
-                        slots[slot] = st
-                        active_np[slot] = True
-                        active_dirty[0] = True
-                        stats.ttft_s.append(t_first - req.arrival_s)
-                        stats.prefill_s.append(dt)
-                        stats.generated_tokens += 1
-                        scheduled = True
-                        if self.capture_tokens:
-                            # device-side argmax: a 4-byte scalar comes
-                            # to host per admission, never the whole
-                            # hidden state (host-transfer-in-loop)
-                            tokens_by_rid.setdefault(req.rid, []).append(
-                                first_id if token_mode
-                                else int(jnp.argmax(y_last)))
-                        self._event("request-prefill", req.rid, slot=slot,
-                                    bucket=bucket,
-                                    ttft_s=round(t_first - req.arrival_s, 6))
-                        if st.tokens_done >= req.output_len:
-                            finish(release(slot), self._now())
+                                        "prefix-attach", req.rid, slot=slot,
+                                        donor=plan["donor"], tokens=reused,
+                                        blocks=reused // cfg.block_size)
+                                    if plan["cow_blocks"]:
+                                        # matched deeper than the attach cap:
+                                        # the tail blocks were recomputed
+                                        # privately — the copy-on-write edge
+                                        ledger.note_cow(plan["cow_blocks"])
+                                        stats.prefix_cow_blocks += (
+                                            plan["cow_blocks"])
+                                        self._event(
+                                            "prefix-cow", req.rid, slot=slot,
+                                            blocks=plan["cow_blocks"])
+                                # index this slot's full-block chain: the
+                                # prefill (attached or full) made the slot
+                                # a physical holder of every block it refs,
+                                # and dedup against already-shared blocks
+                                # refunds the private reservation
+                                ledger.register(slot, plan["chain"])
+                            t_first = self._now()
+                            st = _SlotState(req=req, tokens_done=1,
+                                            admitted_s=now,
+                                            first_token_s=t_first,
+                                            gamma_eff=cfg.spec_gamma)
+                            if cfg.speculation == "ngram":
+                                # prompt-lookup context: the prompt's own
+                                # token-id view (pure numpy, admission-time)
+                                # plus the prefill's first committed token
+                                hist[req.rid] = prompt_token_ids(
+                                    req.seed, req.prompt_len,
+                                    self.config.hidden_size,
+                                    period=req.prompt_period,
+                                    prefix_len=req.prefix_len,
+                                    prefix_seed=req.prefix_seed) + [first_id]
+                            slots[slot] = st
+                            active_np[slot] = True
+                            active_dirty[0] = True
+                            stats.ttft_s.append(t_first - req.arrival_s)
+                            stats.prefill_s.append(dt)
+                            stats.generated_tokens += 1
+                            scheduled = True
+                            if self.capture_tokens:
+                                # device-side argmax: a 4-byte scalar comes
+                                # to host per admission, never the whole
+                                # hidden state (host-transfer-in-loop)
+                                tokens_by_rid.setdefault(req.rid, []).append(
+                                    first_id if token_mode
+                                    else int(jnp.argmax(y_last)))
+                            self._event(
+                                "request-prefill", req.rid, slot=slot,
+                                bucket=bucket,
+                                ttft_s=round(t_first - req.arrival_s, 6))
+                            if st.tokens_done >= req.output_len:
+                                finish(release(slot), self._now())
                 if scheduled:
                     refresh_active()
             # 3. a decode unit over every resident request: one step, or
@@ -3832,10 +3928,6 @@ class ServingEngine:
                                     help="bounded admission queue depth")
             self.registry.set_gauge("serve_active_slots", len(slots),
                                     help="decode slots in use")
-            self.registry.set_gauge(
-                "serve_decode_batch_occupancy",
-                len(slots) / cfg.max_batch,
-                help="resident fraction of the decode batch")
             self.registry.set_gauge("serve_cache_blocks_in_use",
                                     ledger.blocks_in_use,
                                     help="cache blocks holding tokens")

@@ -48,17 +48,21 @@ from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.parallel.plan import ParallelismPlan
 from dlbb_tpu.models.sharding import batch_spec, param_specs, specs_for_mesh
 from dlbb_tpu.models.transformer import (
+    TRAIN_PHASES,
     forward,
     forward_flops,
     init_params_sharded,
+    named,
 )
 from dlbb_tpu.obs import spans
 from dlbb_tpu.ops import mosaic_call_count
 from dlbb_tpu.utils.config import load_config, save_json
 from dlbb_tpu.utils.metrics import Timer, summarize
-from dlbb_tpu.utils.profiling import annotate, step_annotation
 from dlbb_tpu.utils.sysinfo import collect_system_info, device_spread
 from dlbb_tpu.utils.timing import resolve_timing_mode, time_fn_chained
+
+
+LOSS, GRAD_REDUCE, OPTIMIZER = TRAIN_PHASES
 
 
 class TrainState(NamedTuple):
@@ -167,10 +171,11 @@ def mse_loss(params, batch, targets, config: ModelConfig,
         pred = forward(params, batch, config, mesh=mesh,
                        num_microbatches=num_microbatches)
         aux = 0.0
-    mse = jnp.mean(
-        (pred.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2
-    )
-    return mse + moe_aux_weight * aux
+    with jax.named_scope(LOSS):
+        mse = jnp.mean(
+            (pred.astype(jnp.float32) - targets.astype(jnp.float32)) ** 2
+        )
+        return mse + moe_aux_weight * aux
 
 
 def resolve_zero_stage(zero1: bool = False,
@@ -460,14 +465,17 @@ def make_train_step(
                 p, b, t, config, None, None, 0.0
             )
             flat_g, unravel = ravel_pytree(g)
-            c = flat_g.astype(jnp.float32) + res[0].astype(jnp.float32)
-            reduced = psum_compressed(
-                c, "dp", compression=grad_compression, accum_dtype=accum
-            ) / dp_size
-            # Seide-style error feedback: carry the LOCAL quantiser's
-            # error into the next step (docs/compression.md)
-            new_res = quantization_error(c, grad_compression)
-            loss = jax.lax.psum(loss, "dp") / dp_size
+            with jax.named_scope(GRAD_REDUCE):
+                c = (flat_g.astype(jnp.float32)
+                     + res[0].astype(jnp.float32))
+                reduced = psum_compressed(
+                    c, "dp", compression=grad_compression,
+                    accum_dtype=accum
+                ) / dp_size
+                # Seide-style error feedback: carry the LOCAL quantiser's
+                # error into the next step (docs/compression.md)
+                new_res = quantization_error(c, grad_compression)
+                loss = jax.lax.psum(loss, "dp") / dp_size
             return (loss, unravel(reduced.astype(flat_g.dtype)),
                     new_res.astype(res.dtype)[None])
 
@@ -481,6 +489,7 @@ def make_train_step(
             check_vma=False,
         )
 
+        @named("train_step")
         def step(state: TrainState, batch, targets):
             inner_state, comp = state.opt_state
             loss, grads, new_res = compressed_loss_and_grads(
@@ -492,15 +501,17 @@ def make_train_step(
                 # (replicated -> sharded is a local slice, no collective)
                 grads = jax.lax.with_sharding_constraint(
                     grads, grad_shardings)
-            updates, new_inner = optimizer.update(
-                grads, inner_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope(OPTIMIZER):
+                updates, new_inner = optimizer.update(
+                    grads, inner_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
             return TrainState(
                 new_params,
                 (new_inner, GradCompressionState(residual=new_res)),
                 state.step + 1,
             ), loss
     else:
+        @named("train_step")
         def step(state: TrainState, batch, targets):
             loss, grads = loss_and_grads(state.params, batch, targets)
             if stage >= 2:
@@ -509,9 +520,10 @@ def make_train_step(
                 # (ZeRO-2)
                 grads = jax.lax.with_sharding_constraint(
                     grads, grad_shardings)
-            updates, new_opt = optimizer.update(
-                grads, state.opt_state, state.params)
-            new_params = optax.apply_updates(state.params, updates)
+            with jax.named_scope(OPTIMIZER):
+                updates, new_opt = optimizer.update(
+                    grads, state.opt_state, state.params)
+                new_params = optax.apply_updates(state.params, updates)
             return TrainState(new_params, new_opt, state.step + 1), loss
 
     jit_step = jax.jit(
@@ -637,8 +649,7 @@ def run_train(
         str(k): str(v)
         for k, v in (execution.get("compiler_options") or {}).items()
     }
-    with spans.span("compile+warmup", cat="train"), \
-            annotate("compile+warmup"):
+    with spans.span("compile+warmup", cat="train"):
         t0 = time.perf_counter()
         mosaic_calls = mosaic_call_count(jit_step, state, batch, tgt)
         if comp_opts and mode == "per_iter":
@@ -673,11 +684,11 @@ def run_train(
                 if guard.requested:
                     preempted_at = int(jax.device_get(state.step))
                     break
-                # span + device annotation wrap the Timer from the
-                # OUTSIDE — nothing profiler-shaped inside the timed
-                # region (the profiler-in-timed-region lint contract)
-                with spans.span("train_step", cat="train", step=i), \
-                        step_annotation("train_step", i):
+                # the span (and the profiler annotation it opens)
+                # wraps the Timer from the OUTSIDE — nothing
+                # profiler-shaped inside the timed region (the
+                # profiler-in-timed-region lint contract)
+                with spans.span("train_step", cat="train", step=i):
                     with Timer() as t:
                         state, loss = jit_step(state, batch, tgt)
                         jax.block_until_ready(loss)
@@ -710,8 +721,7 @@ def run_train(
                     new_state, _ = jit_step(st, b, t)
                     return new_state
 
-                with spans.span("measure", cat="train"), \
-                        annotate("measure"):
+                with spans.span("measure", cat="train"):
                     # state is donated to the timing loop (halves resident
                     # TrainState HBM — decisive for Adam at 1B on the
                     # 16 GiB chip); the returned carry IS the post-timing

@@ -16,8 +16,9 @@ Surface, mirroring the reference's env-switched design:
   be traced without changing its invocation (the CCL_LOG_LEVEL analogue).
 - ``annotate(name)`` — host-side named region (``TraceAnnotation``) so
   warmup/measurement phases are distinguishable in the timeline
-  (``utils/timing.py`` wraps its warmup/measure loops in these, and
-  ``train/loop.py`` its phases).
+  (``utils/timing.py`` wraps its warmup/measure loops in these; every
+  ``obs/spans.py`` span of an active tracer opens one, which is how
+  ``train/loop.py`` and ``serve/engine.py`` get theirs).
 - ``step_annotation(name, step)`` — per-step annotation for training loops.
 
 This module is one of the two sanctioned profiler API homes (with
@@ -65,11 +66,14 @@ def maybe_trace(trace_dir: Optional[str] = None) -> Iterator[Optional[str]]:
         yield trace_dir
 
 
-def annotate(name: str):
-    """Named host-side region, visible in the trace timeline."""
+def annotate(name: str, **args):
+    """Named host-side region, visible in the trace timeline; ``args``
+    become the event's stats there.  ``obs/spans.py`` opens one for
+    every span of an active tracer — program code calls ``spans.span``,
+    not this."""
     import jax
 
-    return jax.profiler.TraceAnnotation(name)
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 def step_annotation(name: str, step: int):

@@ -272,3 +272,26 @@ def test_flash_rejects_sequence_parallel_mesh(devices):
     x = jax.random.normal(jax.random.key(1), (2, 64, 64))
     with pytest.raises(ValueError, match="ring"):
         forward(params, x, cfg, mesh=mesh)
+
+
+def test_flash_kernels_carry_their_names_forward_and_backward():
+    """Each ``pl.pallas_call`` has a ``name=`` and runs under a scope of
+    the same name, so a device trace tells forward, dq and dkv apart."""
+    import re
+
+    from dlbb_tpu.ops.flash_attention import KERNEL_NAMES
+
+    assert KERNEL_NAMES == ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")
+    q = k = v = jnp.ones((1, 2, 128, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True).sum()
+
+    jaxpr = str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v))
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, k, v).as_text(debug_info=True)
+    for name in KERNEL_NAMES:
+        assert f"name={name}" in jaxpr, name      # the pallas_call's own
+        # the scope (under jvp()/transpose() when differentiated),
+        # then the kernel's own name
+        assert re.search(rf"{name}\)+/{name}/pallas_call", text), name

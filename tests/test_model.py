@@ -280,3 +280,21 @@ def test_tp_forward_compiles_megatron_allreduce_pattern(devices):
         "scanned layer body"
     assert "all-reduce" not in hlo_single, \
         "single-device forward must need no collectives"
+
+
+def test_forward_program_is_named_and_carries_every_phase(mesh2x4):
+    """The e2e harness's jitted forward is ``forward`` in a device
+    trace, and its lowered text carries every phase scope of the block
+    — the names a trace reduction groups device time by."""
+    import re
+
+    from dlbb_tpu.bench.e2e import build_forward_step
+    from dlbb_tpu.models.transformer import BLOCK_PHASES
+
+    params = shard_params(init_params(TINY, jax.random.key(1)), mesh2x4)
+    step = build_forward_step(TINY, mesh2x4)
+    assert step.__wrapped__.__name__ == "forward"
+    text = step.lower(params, _batch(TINY)).as_text(debug_info=True)
+    assert "module @jit_forward" in text
+    for phase in BLOCK_PHASES:
+        assert re.search(rf'[/("]{phase}/', text), phase

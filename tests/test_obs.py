@@ -114,6 +114,56 @@ def test_disabled_span_is_shared_singleton():
     spans.instant("nothing-happens")  # must not raise, must not allocate
 
 
+def test_active_span_opens_the_profiler_annotation_of_its_name(
+        tmp_path, monkeypatch):
+    """One clock: with a tracer on, ``spans.span`` opens the profiler
+    annotation of the same name and arguments through the sanctioned
+    home (``utils/profiling.annotate``), so a device capture shows the
+    program's spans on its host line; with no tracer nothing is
+    opened."""
+    import contextlib
+
+    from dlbb_tpu.utils import profiling
+
+    opened = []
+
+    @contextlib.contextmanager
+    def fake_annotate(name, **args):
+        opened.append(("enter", name, args))
+        yield
+        opened.append(("exit", name, args))
+
+    monkeypatch.setattr(profiling, "annotate", fake_annotate)
+    with spans.span("serve-prefill", rid=3):
+        pass
+    assert opened == []
+    with spans.tracing(tmp_path / "t.json"):
+        with spans.span("serve-prefill", rid=3, path=tmp_path):
+            with spans.span("serve-prefill-chunk", chunk=0):
+                pass
+    assert [(kind, name) for kind, name, _a in opened] == [
+        ("enter", "serve-prefill"), ("enter", "serve-prefill-chunk"),
+        ("exit", "serve-prefill-chunk"), ("exit", "serve-prefill")]
+    # the arguments reach the annotation as the file gets them
+    assert opened[0][2] == {"rid": 3, "path": str(tmp_path)}
+
+
+def test_cell_paths_open_no_annotation_by_hand():
+    """One system: on the paths the benchmark's cells run, the only way
+    the program opens a host annotation is ``spans.span`` — no module
+    there imports the profiling wrappers or ``jax.profiler`` itself."""
+    import ast
+
+    for rel in ("serve/engine.py", "train/loop.py",
+                "models/transformer.py", "ops/flash_attention.py"):
+        tree = ast.parse((REPO / "dlbb_tpu" / rel).read_text())
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom):
+                assert "profiling" not in (node.module or ""), rel
+            if isinstance(node, ast.Attribute):
+                assert node.attr != "profiler", rel
+
+
 def test_timed_regions_carry_zero_obs_instructions():
     """The zero-overhead contract, statically (same pin shape as
     ``resilience/inject.py``): ``utils/timing.py`` — the only module

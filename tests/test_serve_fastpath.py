@@ -151,7 +151,10 @@ def test_fastpath_artifact_set_schema_valid(tmp_path):
     assert "dlbb_serve_decode_steps_total" in prom
     assert "dlbb_serve_fused_scan_steps_total" in prom
     assert "dlbb_serve_prefill_chunks_total" in prom
-    assert "dlbb_serve_decode_batch_occupancy" in prom
+    # batch occupancy over time is the report's ``timeseries.
+    # active_slots`` (a gauge written once after the drain read 0)
+    assert "dlbb_serve_decode_batch_occupancy" not in prom
+    assert "dlbb_serve_active_slots" in prom
 
 
 # ---------------------------------------------------------------------------
@@ -454,6 +457,188 @@ def test_fused_scan_emits_one_span_with_steps_attr(mesh2x4, tmp_path):
     req_spans = [e for e in rebuilt["traceEvents"] if e["ph"] == "X"]
     assert len(req_spans) == 4
     assert all(e["cat"] == "config-completed" for e in req_spans)
+
+
+# ---------------------------------------------------------------------------
+# names that survive a recompile: program names, phase scopes, the span
+# tree inside serve-admission and the request identifier (PR 25)
+# ---------------------------------------------------------------------------
+
+
+def _decode_args(mesh, sv):
+    from dlbb_tpu.models.transformer import init_params_sharded
+    from dlbb_tpu.serve.kvcache import create_kv_cache
+
+    params = init_params_sharded(MODEL, jax.random.key(0), mesh)
+    cache = create_kv_cache(MODEL, sv.max_batch, sv.num_blocks,
+                            sv.block_size, mesh=mesh)
+    x = jnp.zeros((sv.max_batch, 1, MODEL.hidden_size), jnp.float32)
+    active = jnp.zeros((sv.max_batch,), bool)
+    return params, cache, x, active
+
+
+def _lowered(jitted, *args):
+    return jitted.lower(*args).as_text(debug_info=True)
+
+
+def test_serve_blocks_share_the_one_phase_tuple():
+    """Both block definitions unpack ``models.transformer.BLOCK_PHASES``:
+    a phase renamed there is renamed in training and serving alike."""
+    from dlbb_tpu.models import transformer
+    from dlbb_tpu.serve import engine
+
+    names = ("LN1", "ATTN_QKV", "ATTN_CORE", "ATTN_OUT",
+             "LN2", "MLP_UP", "MLP_ACT", "MLP_DOWN")
+    assert tuple(getattr(transformer, n) for n in names) == \
+        transformer.BLOCK_PHASES
+    assert tuple(getattr(engine, n) for n in names) == \
+        transformer.BLOCK_PHASES
+    assert (engine.KV_UPDATE, engine.KV_ATTEND) == \
+        transformer.SERVE_PHASES == ("kv_update", "kv_attend")
+
+
+@pytest.mark.parametrize("program", ["serve_decode_k4",
+                                     "serve_prefill_chunk_o8"])
+def test_serving_program_lowers_with_its_name_and_every_phase(
+        mesh2x4, program):
+    """The lowered text of a decode scan and of a prefill chunk is
+    called what it is (the module name the profile prints) and carries
+    every phase scope of the block plus the two cache phases."""
+    from dlbb_tpu.models.transformer import BLOCK_PHASES, SERVE_PHASES
+    from dlbb_tpu.serve.engine import (
+        build_decode_fused,
+        build_prefill_chunk,
+        create_prefix,
+    )
+
+    sv = ServingConfig(**SERVE)
+    params, cache, x, active = _decode_args(mesh2x4, sv)
+    if program.startswith("serve_decode"):
+        text = _lowered(build_decode_fused(MODEL, mesh2x4, 4),
+                        (cache, x), params, active,
+                        jnp.zeros((sv.max_batch,), jnp.int32))
+    else:
+        text = _lowered(build_prefill_chunk(MODEL, mesh2x4, 8, 8),
+                        cache, create_prefix(MODEL, mesh2x4), params,
+                        jnp.zeros((1, 8, MODEL.hidden_size), jnp.float32),
+                        np.int32(0), np.int32(12))
+    assert f"module @jit_{program}" in text
+    for phase in BLOCK_PHASES + SERVE_PHASES:
+        assert f"{phase}/" in text, phase
+
+
+def test_every_serving_program_has_a_stable_name(mesh2x4):
+    """Program names say what the program is and which static shape it
+    was built for; they are the jitted function's own name."""
+    from dlbb_tpu.serve import engine as E
+
+    def name(jitted):
+        return jitted.__wrapped__.__name__
+
+    assert name(E.build_decode_step(MODEL, mesh2x4)) == "serve_decode_step"
+    assert name(E.build_decode_fused(MODEL, mesh2x4, 16)) == \
+        "serve_decode_k16"
+    assert name(E.build_prefill_chunk(MODEL, mesh2x4, 8, 24)) == \
+        "serve_prefill_chunk_o24"
+    assert name(E.build_prefill(MODEL, mesh2x4)) == "serve_prefill"
+    assert name(E.build_prefix_attach(MODEL, mesh2x4, 8, 8)) == \
+        "serve_prefix_attach"
+    assert name(E.build_compact_gather(mesh2x4)) == "serve_compact_gather"
+    assert name(E.build_compact_scatter(mesh2x4)) == \
+        "serve_compact_scatter"
+    assert name(E.build_decode_token_step(MODEL, mesh2x4)) == \
+        "serve_decode_token_step"
+    assert name(E.build_decode_fused_token(MODEL, mesh2x4, 4)) == \
+        "serve_decode_token_k4"
+    assert name(E.build_verify_step(MODEL, mesh2x4, 2)) == \
+        "serve_spec_verify_g2"
+    assert name(E.build_verify_probs(MODEL, mesh2x4, 2)) == \
+        "serve_spec_probs_g2"
+    assert name(E.build_spec_commit(MODEL, mesh2x4)) == "serve_spec_commit"
+    assert name(E.build_draft_scan(MODEL, mesh2x4, 2)) == \
+        "serve_spec_draft_g2"
+    # the engine's own: one prefill jit per bucket, and the inject
+    eng = ServingEngine(MODEL, ServingConfig(**SERVE), mesh2x4,
+                        verbose=False)
+    assert name(eng._prefill_jit(16)) == "serve_prefill_b16"
+    assert eng._prefill_jit(16) is eng._prefill_jit(16)
+    assert name(eng._inject) == "serve_inject"
+
+
+def test_traced_chunked_run_gives_admission_a_span_tree_and_requests_a_rid(
+        fast_engine, tmp_path):
+    """A traced serving run with chunked prefill: the file validates,
+    every ``serve-admit-*`` span lies inside a ``serve-admission`` and
+    carries ``rid`` and ``slot``, the decode unit's dispatch and sync
+    are children of ``serve-decode``, no program span uses a prefix the
+    benchmark keeps for itself, and every completed request has its
+    four lifecycle instants under ONE ``rid``."""
+    from dlbb_tpu.obs import spans
+
+    trace = _trace([
+        Request(rid=i, arrival_s=0.0, prompt_len=10 + 7 * i,
+                output_len=5 + i, seed=70 + i)
+        for i in range(5)
+    ])
+    span_path = tmp_path / "spans.json"
+    with spans.tracing(span_path):
+        report = fast_engine.run_trace(trace)
+    assert report["requests"]["completed"] == 5
+    events = spans.load_trace(span_path)["traceEvents"]
+    assert spans.validate_trace_events(events) == []
+
+    stack, parents = [], {}      # one scheduler thread: a plain stack
+    for ev in events:
+        if ev["ph"] == "B":
+            parents.setdefault(ev["name"], []).append(
+                [name for name, _a in stack])
+            stack.append((ev["name"], ev.get("args", {})))
+        elif ev["ph"] == "E":
+            stack.pop()
+    steps = ("serve-admit-plan", "serve-admit-embed",
+             "serve-admit-inject", "serve-admit-book")
+    for step in steps:
+        assert len(parents[step]) == 5, step
+        assert all("serve-admission" in above
+                   for above in parents[step]), step
+    begins = [e for e in events if e["ph"] == "B"]
+    for ev in begins:
+        assert not ev["name"].startswith(("bench-", "step-"))
+        if ev["name"].startswith(("serve-admit-", "serve-prefill")):
+            assert "rid" in ev["args"], ev["name"]
+        if ev["name"].startswith("serve-admit-"):
+            assert "slot" in ev["args"], ev["name"]
+    assert all(above[-1:] == ["serve-decode"]
+               for above in parents["serve-decode-dispatch"])
+    assert len(parents["serve-decode-dispatch"]) == \
+        len(parents["serve-decode"]) == len(parents["serve-decode-plan"])
+    # every dispatched unit is waited for exactly once, inside the
+    # decode span at a window boundary or under a drain
+    assert len(parents["serve-decode-sync"]) == report["decode_units"]
+    assert parents["serve-drain"]
+    syncs = [e for e in begins if e["name"] == "serve-decode-sync"]
+    assert all(e["args"]["k"] >= 1 for e in syncs)
+
+    by_rid = {}
+    for ev in events:
+        if ev["ph"] == "i" and ev.get("cat") == "request":
+            by_rid.setdefault(ev["args"]["rid"], []).append(ev["name"])
+    assert sorted(by_rid) == [0, 1, 2, 3, 4]
+    for rid, names in by_rid.items():
+        assert names == ["request-arrived", "request-admitted",
+                         "request-prefill", "request-completed"], rid
+
+
+def test_event_emits_nothing_without_a_tracer(baseline_engine):
+    """No tracer: ``_event`` is a global load and the span entry point
+    still hands back the one shared null context."""
+    from dlbb_tpu.obs import spans
+
+    assert spans.active() is None
+    assert spans.span("serve-admit-plan", rid=0, slot=0) is \
+        spans.span("serve-decode")
+    baseline_engine._event("request-arrived", 0)     # must not raise
+    assert spans.active() is None
 
 
 # ---------------------------------------------------------------------------

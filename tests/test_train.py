@@ -1,6 +1,8 @@
 """Training-loop tests: DDP + ZeRO-{1,2,3} on the simulated (dp, tp) mesh
 (reference's training capability: ``test/ccl.py:59-117`` ZeRO train step)."""
 
+import re
+
 import jax
 import numpy as np
 import optax
@@ -310,3 +312,28 @@ def test_zero3_compiles_param_allgather_pattern(devices):
     assert len(re.findall(r"\ball-reduce", hlo0)) >= 1
     assert len(re.findall(r"\ball-gather", hlo3)) > \
         len(re.findall(r"\ball-gather", hlo0))
+
+
+def test_train_step_lowers_under_its_name_with_every_phase(devices):
+    """The jitted step is ``train_step`` in a device trace, and its
+    lowered text carries every phase scope of the block (forward,
+    recompute and backward alike) plus ``loss`` and ``optimizer``."""
+    from dlbb_tpu.models.transformer import BLOCK_PHASES
+    from dlbb_tpu.train.loop import LOSS, OPTIMIZER
+
+    mesh = build_mesh(MeshSpec.grid((4, 2), ("dp", "tp")))
+    remat = ModelConfig(hidden_size=32, num_layers=2, num_heads=4,
+                        ffn_intermediate=64, attention="full",
+                        dtype="float32", remat=True, remat_policy="dots")
+    params = init_params(remat, jax.random.key(0))
+    jit_step, state = make_train_step(remat, mesh, optax.adam(1e-3),
+                                      params)
+    x = np.zeros((8, 16, 32), np.float32)
+    text = jit_step.lower(state, x, x).as_text(debug_info=True)
+    assert "module @jit_train_step" in text
+    for phase in BLOCK_PHASES + (LOSS, OPTIMIZER):
+        assert re.search(rf'[/("]{phase}[/)]', text), phase
+    # the backward of a phase keeps the phase's name: the rematted
+    # block's under ``checkpoint/``, the loss's under ``transpose(jvp())``
+    assert "checkpoint/mlp_down/" in text
+    assert "transpose(jvp(loss))" in text
