@@ -514,8 +514,8 @@ def verify_step_expectation(dp: int, tp: int, gamma: int,
     weighting (a per-token re-verify loop would show up as a γ+1-trip
     while body, and its trip-weighted wire lands past the committed
     baseline's ``analyze diff`` gate) — and every instruction is capped
-    at (γ+1) x one step's activation bytes.  The γ+1 one-hot cache
-    appends must lower to collective-free elementwise selects, exactly
+    at (γ+1) x one step's activation bytes.  The γ+1 cache appends
+    (one scatter under ``shard_map``) must lower collective-free, exactly
     like the decode step's single append: ``act_bytes`` is the ONE-step
     ceiling, so a cache regather trips the byte axis identically."""
     return TargetExpectation(

@@ -934,7 +934,7 @@ def _decode_step_target(dp: int = 2, tp: int = 4) -> AuditTarget:
 def _prefill_target(dp: int = 2, tp: int = 4) -> AuditTarget:
     """The serving prefill (cache-append) step: full causal attention
     over one request's bucketed prompt, K/V written into the request's
-    slot by masked select.  Same kind set as decode; the ceiling is one
+    slot by one in-place block write.  Same kind set as decode; the ceiling is one
     bucket of activations — the cache write itself must lower to zero
     collectives (a write that round-trips the wire would trip it)."""
     def build():
@@ -1032,8 +1032,8 @@ def _verify_step_target(dp: int = 2, tp: int = 4,
     (``verify_step_expectation``) pins the "one fused forward, zero
     per-draft-token collectives" contract: per-token decode kinds only,
     one psum per scanned layer, every instruction within (γ+1) x one
-    step's activation bytes — the γ+1 one-hot cache appends must lower
-    to collective-free selects exactly like the decode step's single
+    step's activation bytes — the γ+1 cache appends must lower
+    collective-free exactly like the decode step's single
     append, and the acceptance math (argmax + cumprod + gather) is
     elementwise/local."""
     from dlbb_tpu.analysis.expectations import verify_step_expectation
@@ -1158,7 +1158,7 @@ def _compact_target(what: str, tp: int = 4) -> AuditTarget:
 
 def _prefix_attach_target(tp: int = 4) -> AuditTarget:
     """The shared-prefix attach jit (``serve/engine.py::prefix_attach``,
-    dp=1 by contract): a masked-select copy of the donor slot's matched
+    dp=1 by contract): an in-place block copy of the donor slot's matched
     blocks into the destination slot plus the dequantised fp prefix
     carry.  Pure LOCAL data movement — the slot dim is unsharded and
     the kv-head shard is untouched, so the lowering must contain ZERO

@@ -5,7 +5,7 @@ Two jitted device programs, fixed shapes for the whole run:
 - **prefill** (one compile per sequence-length *bucket*): runs the full
   transformer stack over one request's ``[1, bucket, H]`` prompt with
   ordinary causal attention, writes its K/V into the request's cache
-  slot (block-aligned masked select — see ``serve/kvcache.py``), sets
+  slot (one in-place block write — see ``serve/kvcache.py``), sets
   the slot length, and returns the last real token's output — the
   request's FIRST generated token (TTFT stops here).
 - **decode_step** (one compile, ``[max_batch, 1, H]``): appends each
@@ -141,12 +141,15 @@ from dlbb_tpu.serve.kvcache import (
     BlockLedger,
     KVCache,
     QuantKVCache,
+    append_token_rows,
     cache_shardings,
+    copy_slot_blocks,
     create_kv_cache,
     create_quant_kv_cache,
     dequantize_kv_blocks,
     quant_cache_shardings,
     quantize_kv_blocks,
+    write_slot_blocks,
 )
 from dlbb_tpu.serve.traffic import Request, TrafficTrace
 from dlbb_tpu.utils.metrics import Timer, summarize
@@ -747,8 +750,8 @@ def _serve_block(h, layer, config: ModelConfig, attention_step,
     cache_state) -> (attn [B, S, n*d], cache_state)`` owns everything
     that differs between prefill (dense causal + block write), decode
     (cached append + length-masked read), and chunked prefill (prefix
-    carry + offset block write); ``cache_state`` is an opaque per-layer
-    tuple (the scanned cache leaves, plus the prefix K/V for chunks)."""
+    carry + offset block write); ``cache_state`` is opaque to the block
+    (``_scan_layers`` says what the cache-writing programs put in it)."""
     with jax.named_scope(LN1):
         y = _layernorm(h, layer["ln1"]["scale"], layer["ln1"]["bias"])
     with jax.named_scope(ATTN_QKV):
@@ -772,6 +775,49 @@ def _serve_block(h, layer, config: ModelConfig, attention_step,
 
 
 KV_UPDATE, KV_ATTEND = SERVE_PHASES
+
+
+def _scan_layers(h, layers, planes, config: ModelConfig, attention_step,
+                 xs=()):
+    """The layer loop of every cache-writing program: ``h`` through the
+    stacked ``layers``, with the cache ``planes`` (each ``[L, ...]``)
+    riding the scan's CARRY beside the layer number, so that a write
+    into them (``serve/kvcache.py``'s helpers) is an in-place update of
+    the loop's buffer.  Scanned as ``xs``/``ys`` instead, a plane
+    enters the loop as one buffer and leaves as another, which cost two
+    whole-cache copies a program run on the v5e (``PERF.md`` §6, PR 26).
+
+    ``attention_step(q, k, v, (l, planes, *xs_l)) -> (attn, (planes,
+    ys_l))`` reads layer ``l`` of a plane by ``_layer_tokens`` and writes it
+    by the helpers; ``xs`` are further per-layer inputs (a chunk's
+    prefix K/V), ``ys_l`` per-layer outputs.  Returns ``(h, planes,
+    ys)``."""
+    def body(carry, layer_xs):
+        h, l, planes = carry
+        layer, *extra = layer_xs
+        h, (planes, ys) = _serve_block(h, layer, config, attention_step,
+                                       (l, planes, *extra))
+        return (h, l + 1, planes), ys
+
+    (h, _, planes), ys = jax.lax.scan(
+        body, (h, jnp.int32(0), tuple(planes)), (layers, *xs))
+    return h, planes, ys
+
+
+def _layer_of(plane: jax.Array, l: jax.Array) -> jax.Array:
+    """Layer ``l`` of a carried cache plane ``[L, ...]``."""
+    return jax.lax.dynamic_index_in_dim(plane, l, 0, keepdims=False)
+
+
+def _layer_tokens(plane: jax.Array, l: jax.Array) -> jax.Array:
+    """Layer ``l`` of a carried K/V plane as attention reads it,
+    token-major ``[B, S_max, kvh, d]``.  The plane is flattened BEFORE
+    the slice: then the v5e compiler takes the dynamic slice as the
+    prologue of the attention reduce.  Sliced first and flattened
+    after, it wrote the layer out in fp32 and read it back, per plane
+    and layer (``PERF.md`` §6, PR 26)."""
+    nl, b, nb, bs, kvh, d = plane.shape
+    return _layer_of(plane.reshape(nl, b, nb * bs, kvh, d), l)
 
 
 def _heads(t: jax.Array, nh: int, d: int) -> jax.Array:
@@ -811,44 +857,6 @@ def _cached_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
     return out.astype(k_flat.dtype)
 
 
-@jax.named_scope(KV_UPDATE)
-def _write_prompt_blocks(cache_layer: jax.Array, update: jax.Array,
-                         slot: jax.Array, start_blk: int = 0) -> jax.Array:
-    """Masked-select write of a prefill bucket (or chunk) into one slot's
-    blocks, starting at static block offset ``start_blk``.
-
-    cache_layer: ``[B, nb, bs, kvh, d]``; update: ``[wb, bs, kvh, d]``
-    (``wb`` = bucket/block_size, static).  One-hot over the slot dim and
-    a static block mask — pure elementwise, so GSPMD keeps the write
-    local to the shard owning the slot (no collective, no regather)."""
-    b_dim, nb = cache_layer.shape[:2]
-    wb = update.shape[0]
-    padded = jnp.pad(update, ((start_blk, nb - start_blk - wb),
-                              (0, 0), (0, 0), (0, 0)))
-    slot_mask = (jnp.arange(b_dim) == slot)[:, None, None, None, None]
-    blk = jnp.arange(nb)
-    blk_mask = ((blk >= start_blk)
-                & (blk < start_blk + wb))[None, :, None, None, None]
-    return jnp.where(slot_mask & blk_mask, padded[None], cache_layer)
-
-
-@jax.named_scope(KV_UPDATE)
-def _write_scale_blocks(scale_layer: jax.Array, update: jax.Array,
-                        slot: jax.Array, start_blk: int = 0) -> jax.Array:
-    """``_write_prompt_blocks`` for the int8 side-channel scale plane:
-    scale_layer ``[B, nb, kvh]``, update ``[wb, kvh]`` — same one-hot
-    slot mask + static block mask, so the scale write is exactly as
-    shard-local as the block write it accompanies."""
-    b_dim, nb = scale_layer.shape[:2]
-    wb = update.shape[0]
-    padded = jnp.pad(update, ((start_blk, nb - start_blk - wb), (0, 0)))
-    slot_mask = (jnp.arange(b_dim) == slot)[:, None, None]
-    blk = jnp.arange(nb)
-    blk_mask = ((blk >= start_blk)
-                & (blk < start_blk + wb))[None, :, None]
-    return jnp.where(slot_mask & blk_mask, padded[None], scale_layer)
-
-
 def build_prefill(config: ModelConfig, mesh: Mesh,
                   quantized: bool = False, name: str = "serve_prefill"):
     """Jitted ``prefill(cache, params, x, slot, length) -> (cache,
@@ -860,8 +868,8 @@ def build_prefill(config: ModelConfig, mesh: Mesh,
 
     ``quantized`` writes the int8 layout (``QuantKVCache``): each
     freshly-computed K/V block is quantised per (block, kv-head) and
-    the fp32 scales land in the side-channel plane via
-    ``_write_scale_blocks``.  Prefill attention runs over the chunk's
+    the fp32 scales land in the side-channel plane by the same
+    ``write_slot_blocks``.  Prefill attention runs over the chunk's
     own fp K/V (it never reads the cache), so quantisation touches
     only the write."""
     n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
@@ -873,10 +881,7 @@ def build_prefill(config: ModelConfig, mesh: Mesh,
         wb = s_bucket // bs
 
         def attention_step(q, k, v, cache_state):
-            if quantized:
-                k_l, v_l, ks_l, vs_l = cache_state
-            else:
-                k_l, v_l = cache_state
+            l, planes = cache_state
             qh, kh, vh = (_heads(q, n, d), _heads(k, kvh, d),
                           _heads(v, kvh, d))
             attn = dense_attention(qh, kh, vh, causal=config.causal)
@@ -887,28 +892,16 @@ def build_prefill(config: ModelConfig, mesh: Mesh,
             if quantized:
                 kq, ks = quantize_kv_blocks(k_blocks)
                 vq, vs = quantize_kv_blocks(v_blocks)
-                k_l = _write_prompt_blocks(k_l, kq, slot)
-                v_l = _write_prompt_blocks(v_l, vq, slot)
-                ks_l = _write_scale_blocks(ks_l, ks, slot)
-                vs_l = _write_scale_blocks(vs_l, vs, slot)
-                state = (k_l, v_l, ks_l, vs_l)
+                updates = (kq, vq, ks, vs)
             else:
-                k_l = _write_prompt_blocks(k_l, k_blocks, slot)
-                v_l = _write_prompt_blocks(v_l, v_blocks, slot)
-                state = (k_l, v_l)
+                updates = (k_blocks, v_blocks)
+            planes = tuple(write_slot_blocks(p, u, l, slot)
+                           for p, u in zip(planes, updates))
             return (attn.transpose(0, 2, 1, 3).reshape(1, s_bucket, n * d),
-                    state)
+                    (planes, None))
 
-        def body(h, layer_and_cache):
-            layer, *cache_state = layer_and_cache
-            return _serve_block(h, layer, config, attention_step,
-                                tuple(cache_state))
-
-        planes = ((cache.k, cache.v, cache.k_scale, cache.v_scale)
-                  if quantized else (cache.k, cache.v))
-        h, new_planes = jax.lax.scan(
-            body, x, (params["layers"], *planes)
-        )
+        h, new_planes, _ = _scan_layers(
+            x, params["layers"], cache[:-1], config, attention_step)
         y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
         y_last = jax.lax.dynamic_slice(
             y, (0, length - 1, 0), (1, 1, y.shape[-1])
@@ -991,8 +984,8 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
     index, the "bucketed chunk jit").
 
     The chunk's K/V blocks are written into the slot exactly as
-    monolithic prefill writes its bucket (``_write_prompt_blocks`` at
-    block offset ``start/block_size`` — masked select, shard-local);
+    monolithic prefill writes its bucket (``write_slot_blocks`` at
+    block offset ``start/block_size`` — one in-place block write);
     attention runs over the explicitly-carried prefix K/V (``[L, start,
     kvh, d]``, no slot dim) concatenated with the chunk, so the
     dp-sharded cache is never re-read.  ``length`` is the TRUE prompt
@@ -1014,50 +1007,30 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
         start_blk = start // bs
 
         def attention_step(q, k, v, cache_state):
-            if quantized:
-                k_l, v_l, ks_l, vs_l, pk_l, pv_l = cache_state
-            else:
-                k_l, v_l, pk_l, pv_l = cache_state
+            l, planes, pk_l, pv_l = cache_state
             qh = _heads(q, n, d)                        # [1, n, C, d]
             k_chunk = k[0].reshape(chunk_len, kvh, d)
             v_chunk = v[0].reshape(chunk_len, kvh, d)
             k_all = jnp.concatenate([pk_l, k_chunk], axis=0)
             v_all = jnp.concatenate([pv_l, v_chunk], axis=0)
             attn = _chunk_attention(qh, k_all, v_all, start)
+            k_blocks = k_chunk.reshape(wb, bs, kvh, d)
+            v_blocks = v_chunk.reshape(wb, bs, kvh, d)
             if quantized:
-                kq, ks = quantize_kv_blocks(
-                    k_chunk.reshape(wb, bs, kvh, d))
-                vq, vs = quantize_kv_blocks(
-                    v_chunk.reshape(wb, bs, kvh, d))
-                k_l = _write_prompt_blocks(k_l, kq, slot, start_blk)
-                v_l = _write_prompt_blocks(v_l, vq, slot, start_blk)
-                ks_l = _write_scale_blocks(ks_l, ks, slot, start_blk)
-                vs_l = _write_scale_blocks(vs_l, vs, slot, start_blk)
-                state = (k_l, v_l, ks_l, vs_l, k_all, v_all)
+                kq, ks = quantize_kv_blocks(k_blocks)
+                vq, vs = quantize_kv_blocks(v_blocks)
+                updates = (kq, vq, ks, vs)
             else:
-                k_l = _write_prompt_blocks(
-                    k_l, k_chunk.reshape(wb, bs, kvh, d), slot,
-                    start_blk)
-                v_l = _write_prompt_blocks(
-                    v_l, v_chunk.reshape(wb, bs, kvh, d), slot,
-                    start_blk)
-                state = (k_l, v_l, k_all, v_all)
+                updates = (k_blocks, v_blocks)
+            planes = tuple(write_slot_blocks(p, u, l, slot, start_blk)
+                           for p, u in zip(planes, updates))
             return (attn.transpose(0, 2, 1, 3).reshape(1, chunk_len,
                                                        n * d),
-                    state)
+                    (planes, (k_all, v_all)))
 
-        def body(h, layer_and_cache):
-            layer, *cache_state = layer_and_cache
-            return _serve_block(h, layer, config, attention_step,
-                                tuple(cache_state))
-
-        pk, pv = prefix
-        planes = ((cache.k, cache.v, cache.k_scale, cache.v_scale)
-                  if quantized else (cache.k, cache.v))
-        h, new_state = jax.lax.scan(
-            body, x, (params["layers"], *planes, pk, pv)
-        )
-        new_planes, (pk_new, pv_new) = new_state[:-2], new_state[-2:]
+        h, new_planes, (pk_new, pv_new) = _scan_layers(
+            x, params["layers"], cache[:-1], config, attention_step,
+            xs=prefix)
         y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
         local = jnp.clip(length - 1 - start, 0, chunk_len - 1)
         y_last = jax.lax.dynamic_slice(
@@ -1091,10 +1064,10 @@ def build_prefix_attach(config: ModelConfig, mesh: Mesh,
 
     Copies the donor slot ``src``'s first ``matched_len/block_size``
     blocks (every plane — K/V, and the scale side-channel in the int8
-    layout) into the admitted slot ``dst`` via the same one-hot masked
-    select as ``_write_prompt_blocks`` — pure elementwise on a dp=1
-    slot dim (``ServingConfig.validate`` pins prefix_caching to dp=1),
-    so the attach lowers to ZERO collectives (audited).  Also returns
+    layout) into the admitted slot ``dst`` by ``copy_slot_blocks`` — a
+    slice read and one in-place block write on a dp=1 slot dim
+    (``ServingConfig.validate`` pins prefix_caching to dp=1), so the
+    attach lowers to ZERO collectives (audited).  Also returns
     the matched prefix as the fp chunk-prefill carry ``[L, matched_len,
     kvh, d]``, exactly what the chunk jits would have produced for the
     same token blocks (bit-identical in the fp layout — the cache
@@ -1106,32 +1079,18 @@ def build_prefix_attach(config: ModelConfig, mesh: Mesh,
     kvh, d = config.kv_heads, config.head_dim
     dtype = _dtype_of(config.dtype)
 
-    def copy(plane, src, dst):
-        donor = jnp.take(plane, src, axis=1)     # slot dim dropped
-        slot_mask = (jnp.arange(plane.shape[1]) == dst).reshape(
-            (1, -1) + (1,) * (plane.ndim - 2))
-        blk_mask = (jnp.arange(plane.shape[2]) < nb_m).reshape(
-            (1, 1, -1) + (1,) * (plane.ndim - 3))
-        return jnp.where(slot_mask & blk_mask, donor[:, None], plane)
-
     @named("serve_prefix_attach")
     def attach(cache, src, dst):
         nl = cache.k.shape[0]
-        k_q = jnp.take(cache.k, src, axis=1)[:, :nb_m]
-        v_q = jnp.take(cache.v, src, axis=1)[:, :nb_m]
+        planes, donors = zip(*(copy_slot_blocks(p, src, dst, nb_m)
+                               for p in cache[:-1]))
         if quantized:
-            ks = jnp.take(cache.k_scale, src, axis=1)[:, :nb_m]
-            vs = jnp.take(cache.v_scale, src, axis=1)[:, :nb_m]
+            k_q, v_q, ks, vs = donors
             pk = dequantize_kv_blocks(k_q, ks, dtype)
             pv = dequantize_kv_blocks(v_q, vs, dtype)
-            new_cache = QuantKVCache(
-                copy(cache.k, src, dst), copy(cache.v, src, dst),
-                copy(cache.k_scale, src, dst),
-                copy(cache.v_scale, src, dst), cache.lengths)
         else:
-            pk, pv = k_q, v_q
-            new_cache = KVCache(copy(cache.k, src, dst),
-                                copy(cache.v, src, dst), cache.lengths)
+            pk, pv = donors
+        new_cache = type(cache)(*planes, cache.lengths)
         prefix = (pk.reshape(nl, matched_len, kvh, d),
                   pv.reshape(nl, matched_len, kvh, d))
         return new_cache, prefix
@@ -1198,7 +1157,7 @@ def decode_batch_spec(mesh: Mesh) -> P:
 
 
 def _decode_step_math(carry, params, active, config: ModelConfig,
-                      quantized: bool = False):
+                      mesh: Mesh, quantized: bool = False):
     """The decode-step computation shared VERBATIM by the per-step jit
     and every trip of the fused scan (the equivalence contract between
     the two engines is that this is the one copy of the math).
@@ -1216,62 +1175,63 @@ def _decode_step_math(carry, params, active, config: ModelConfig,
     n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
     cache, x = carry
     b_dim, s_max = cache.max_batch, cache.max_seq
-    nb, bs = cache.num_blocks, cache.block_size
     lengths = cache.lengths
     pos = jnp.arange(s_max)[None, :]
-    write_mask = (pos == lengths[:, None]) & active[:, None]
     valid = pos <= lengths[:, None]
-    sel5 = active[:, None, None, None, None]
-    sel3 = active[:, None, None]
 
     def attention_step(q, k, v, cache_state):
-        if quantized:
-            k_l, v_l, ks_l, vs_l = cache_state
-            k_fp = dequantize_kv_blocks(k_l, ks_l, jnp.float32)
-            v_fp = dequantize_kv_blocks(v_l, vs_l, jnp.float32)
-        else:
-            k_l, v_l = cache_state
-            k_fp, v_fp = k_l, v_l
+        l, planes = cache_state
         qh = _heads(q, n, d)                        # [B, n, 1, d]
-        k_new = k[:, 0].reshape(b_dim, kvh, d).astype(k_fp.dtype)
-        v_new = v[:, 0].reshape(b_dim, kvh, d).astype(v_fp.dtype)
-        # append at each active slot's own length (masked select —
-        # elementwise, shard-local; see serve/kvcache.py)
+        k_new = k.reshape(b_dim, 1, kvh, d)
+        v_new = v.reshape(b_dim, 1, kvh, d)
+        if quantized:
+            attn, planes = quant_append_attend(qh, k_new, v_new, l, planes)
+        else:
+            # append at each active slot's own length, in place in the
+            # carried planes, then read the layer back for attention
+            k_c, v_c = planes
+            k_c = append_token_rows(k_c, k_new, l, lengths, active, mesh)
+            v_c = append_token_rows(v_c, v_new, l, lengths, active, mesh)
+            attn = _cached_attention(qh, _layer_tokens(k_c, l),
+                                     _layer_tokens(v_c, l), valid)
+            planes = (k_c, v_c)
+        return (attn.transpose(0, 2, 1, 3).reshape(b_dim, 1, n * d),
+                (planes, None))
+
+    def quant_append_attend(qh, k_new, v_new, l, planes):
+        """The int8 layout's append still rewrites its whole layer:
+        dequantise, masked-select append, attend, requantise, and put
+        the layer back into the carried planes."""
+        nb, bs = cache.num_blocks, cache.block_size
+        write_mask = (pos == lengths[:, None]) & active[:, None]
+        k_l, v_l, ks_l, vs_l = (_layer_of(p, l) for p in planes)
+        k_fp = dequantize_kv_blocks(k_l, ks_l, jnp.float32)
+        v_fp = dequantize_kv_blocks(v_l, vs_l, jnp.float32)
         with jax.named_scope(KV_UPDATE):
-            k_flat = k_fp.reshape(b_dim, s_max, kvh, d)
-            v_flat = v_fp.reshape(b_dim, s_max, kvh, d)
             k_flat = jnp.where(write_mask[..., None, None],
-                               k_new[:, None], k_flat)
+                               k_new.astype(jnp.float32),
+                               k_fp.reshape(b_dim, s_max, kvh, d))
             v_flat = jnp.where(write_mask[..., None, None],
-                               v_new[:, None], v_flat)
+                               v_new.astype(jnp.float32),
+                               v_fp.reshape(b_dim, s_max, kvh, d))
         attn = _cached_attention(qh, k_flat.astype(x.dtype),
                                  v_flat.astype(x.dtype), valid)
         with jax.named_scope(KV_UPDATE):
-            if quantized:
-                kq, ks = quantize_kv_blocks(
-                    k_flat.reshape(b_dim, nb, bs, kvh, d))
-                vq, vs = quantize_kv_blocks(
-                    v_flat.reshape(b_dim, nb, bs, kvh, d))
-                state = (jnp.where(sel5, kq, k_l),
-                         jnp.where(sel5, vq, v_l),
-                         jnp.where(sel3, ks, ks_l),
-                         jnp.where(sel3, vs, vs_l))
-            else:
-                state = (k_flat.reshape(b_dim, nb, bs, kvh, d),
-                         v_flat.reshape(b_dim, nb, bs, kvh, d))
-        return (attn.transpose(0, 2, 1, 3).reshape(b_dim, 1, n * d),
-                state)
+            kq, ks = quantize_kv_blocks(
+                k_flat.reshape(b_dim, nb, bs, kvh, d))
+            vq, vs = quantize_kv_blocks(
+                v_flat.reshape(b_dim, nb, bs, kvh, d))
+            sel5 = active[:, None, None, None, None]
+            sel3 = active[:, None, None]
+            layer = (jnp.where(sel5, kq, k_l), jnp.where(sel5, vq, v_l),
+                     jnp.where(sel3, ks, ks_l), jnp.where(sel3, vs, vs_l))
+            planes = tuple(
+                jax.lax.dynamic_update_index_in_dim(p, new, l, 0)
+                for p, new in zip(planes, layer))
+        return attn, planes
 
-    def body(h, layer_and_cache):
-        layer, *cache_state = layer_and_cache
-        return _serve_block(h, layer, config, attention_step,
-                            tuple(cache_state))
-
-    planes = ((cache.k, cache.v, cache.k_scale, cache.v_scale)
-              if quantized else (cache.k, cache.v))
-    h, new_planes = jax.lax.scan(
-        body, x, (params["layers"], *planes)
-    )
+    h, new_planes, _ = _scan_layers(
+        x, params["layers"], cache[:-1], config, attention_step)
     y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
     lengths = lengths + active.astype(jnp.int32)
     cache_cls = QuantKVCache if quantized else KVCache
@@ -1289,7 +1249,7 @@ def build_decode_step(config: ModelConfig, mesh: Mesh,
 
     @named("serve_decode_step")
     def decode_step(carry, params, active):
-        return _decode_step_math(carry, params, active, config,
+        return _decode_step_math(carry, params, active, config, mesh,
                                  quantized=quantized)
 
     x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
@@ -1343,7 +1303,7 @@ def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int,
             lengths_i = lengths0 + act_i32 * jnp.minimum(i, remaining)
             (cache, x2), y = _decode_step_math(
                 (cache_cls(*planes, lengths_i), x), params, step_active,
-                config, quantized=quantized)
+                config, mesh, quantized=quantized)
             return (*cache[:-1], x2, i + 1), y
 
         final, ys = jax.lax.scan(
@@ -1450,7 +1410,8 @@ def build_decode_token_step(config: ModelConfig, mesh: Mesh):
 
     @named("serve_decode_token_step")
     def decode_token_step(carry, params, table, active):
-        (cache, y), _ = _decode_step_math(carry, params, active, config)
+        (cache, y), _ = _decode_step_math(carry, params, active, config,
+                                              mesh)
         tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
         x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(y.dtype)
         return (cache, x2), tok
@@ -1484,7 +1445,7 @@ def build_decode_fused_token(config: ModelConfig, mesh: Mesh, k: int):
             lengths_i = lengths0 + act_i32 * jnp.minimum(i, remaining)
             (cache, _x2), y = _decode_step_math(
                 (KVCache(k_c, v_c, lengths_i), x), params, step_active,
-                config)
+                config, mesh)
             tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
             x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(x.dtype)
             return (cache.k, cache.v, x2, i + 1), tok
@@ -1505,6 +1466,45 @@ def build_decode_fused_token(config: ModelConfig, mesh: Mesh, k: int):
     )
 
 
+def _verify_forward(carry, params, table, draft_ids, active,
+                    config: ModelConfig, mesh: Mesh):
+    """The batched verify forward both verify programs run: the carry
+    token and the γ drafted tokens of every slot through ONE ``[B, γ+1,
+    H]`` ``_serve_block`` stack.  Per layer the γ+1 positions append
+    their K/V at ``lengths + i`` (``append_token_rows``, the decode
+    step's in-place row write with γ+1 rows a slot), exactly as γ+1
+    sequential decode steps would, and attend under the per-slot
+    offset-causal mask.  Returns ``(k, v, y [B, γ+1, H])``; what is
+    committed of it is the caller's business."""
+    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
+    cache, x = carry
+    b_dim, s_max = cache.max_batch, cache.max_seq
+    g1 = draft_ids.shape[1] + 1
+    lengths = cache.lengths
+    d_emb = jnp.take(table, draft_ids, axis=0).astype(x.dtype)
+    h0 = jnp.concatenate([x, d_emb], axis=1)        # [B, γ+1, H]
+    pos = jnp.arange(s_max)[None, :]                # [1, S]
+    offs = lengths[:, None] + jnp.arange(g1)[None, :]   # [B, γ+1]
+    valid = pos[:, None, :] <= offs[:, :, None]     # [B, γ+1, S]
+
+    def attention_step(q, k, v, cache_state):
+        l, (k_c, v_c) = cache_state
+        qh = _heads(q, n, d)                        # [B, n, γ+1, d]
+        k_c = append_token_rows(k_c, k.reshape(b_dim, g1, kvh, d), l,
+                                lengths, active, mesh)
+        v_c = append_token_rows(v_c, v.reshape(b_dim, g1, kvh, d), l,
+                                lengths, active, mesh)
+        attn = _verify_attention(qh, _layer_tokens(k_c, l),
+                                 _layer_tokens(v_c, l), valid)
+        return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
+                ((k_c, v_c), None))
+
+    h, (k_new, v_new), _ = _scan_layers(
+        h0, params["layers"], (cache.k, cache.v), config, attention_step)
+    y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
+    return k_new, v_new, y
+
+
 def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
     """Jitted draft-and-verify target forward: the γ proposed tokens of
     every slot run through ONE batched ``[max_batch, γ+1, H]``
@@ -1516,8 +1516,9 @@ def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
     Inputs: the donated ``(cache, x)`` carry, the token table, the
     drafters' ``draft_ids [B, γ]``, ``active`` and ``remaining`` (each
     slot's output-token budget).  Per layer, all γ+1 positions append
-    K/V at ``lengths + i`` via one-hot masked writes (the decode-step
-    append, γ+1 times), exactly as γ+1 sequential decode steps would.
+    K/V at ``lengths + i`` (``append_token_rows``, the decode-step
+    append with γ+1 rows a slot), exactly as γ+1 sequential decode
+    steps would.
 
     Greedy acceptance: ``tok = argmax(y)`` gives the target's true
     token at every position; the accepted prefix length is the run of
@@ -1535,52 +1536,13 @@ def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
     Returns ``(carry, tok [B, γ+1], commits [B])``; tok/commits stay
     dp-sharded (no boundary gather — the host reads them at the unit's
     sync)."""
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-    g1 = gamma + 1
 
     @named(f"serve_spec_verify_g{gamma}")
     def verify_step(carry, params, table, draft_ids, active, remaining):
         cache, x = carry
-        b_dim, s_max = cache.max_batch, cache.max_seq
-        nb, bs = cache.num_blocks, cache.block_size
         lengths = cache.lengths
-        d_emb = jnp.take(table, draft_ids, axis=0).astype(x.dtype)
-        h0 = jnp.concatenate([x, d_emb], axis=1)        # [B, γ+1, H]
-        pos = jnp.arange(s_max)[None, :]                # [1, S]
-        offs = lengths[:, None] + jnp.arange(g1)[None, :]   # [B, γ+1]
-        valid = pos[:, None, :] <= offs[:, :, None]     # [B, γ+1, S]
-
-        def attention_step(q, k, v, cache_state):
-            k_l, v_l = cache_state
-            qh = _heads(q, n, d)                        # [B, n, γ+1, d]
-            k_new = k.reshape(b_dim, g1, kvh, d)
-            v_new = v.reshape(b_dim, g1, kvh, d)
-            k_flat = k_l.reshape(b_dim, s_max, kvh, d)
-            v_flat = v_l.reshape(b_dim, s_max, kvh, d)
-            # γ+1 one-hot appends at each slot's own running length —
-            # the decode-step masked write, unrolled over the verify
-            # positions (static γ, so this stays collective-free
-            # elementwise selects)
-            with jax.named_scope(KV_UPDATE):
-                for i in range(g1):
-                    m = ((pos == lengths[:, None] + i)
-                         & active[:, None])[..., None, None]
-                    k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
-                    v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
-            attn = _verify_attention(qh, k_flat, v_flat, valid)
-            return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
-                    (k_flat.reshape(b_dim, nb, bs, kvh, d),
-                     v_flat.reshape(b_dim, nb, bs, kvh, d)))
-
-        def body(h, layer_and_cache):
-            layer, k_l, v_l = layer_and_cache
-            return _serve_block(h, layer, config, attention_step,
-                                (k_l, v_l))
-
-        h, (k_new, v_new) = jax.lax.scan(
-            body, h0, (params["layers"], cache.k, cache.v)
-        )
-        y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
+        k_new, v_new, y = _verify_forward(carry, params, table, draft_ids,
+                                          active, config, mesh)
         tok = jnp.argmax(y, axis=-1).astype(jnp.int32)  # [B, γ+1]
         match = (tok[:, :gamma] == draft_ids).astype(jnp.int32)
         accepted = jnp.sum(jnp.cumprod(match, axis=1), axis=1)  # [B]
@@ -1607,8 +1569,8 @@ def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
 
 def build_verify_probs(config: ModelConfig, mesh: Mesh, gamma: int):
     """The SAMPLED verify's device half: ``build_verify_step``'s exact
-    batched γ+1-position forward (same one-hot K/V appends at
-    ``lengths + i``, same offset-causal mask), but acceptance moves to
+    batched γ+1-position forward (same K/V appends at ``lengths +
+    i``, same offset-causal mask), but acceptance moves to
     the HOST — the program returns the raw verify logits ``y [B, γ+1,
     H]`` and commits NOTHING: lengths and ``x`` come back unchanged,
     so the appended-but-uncommitted cache positions sit past every
@@ -1621,49 +1583,13 @@ def build_verify_probs(config: ModelConfig, mesh: Mesh, gamma: int):
     ``gamma=0`` degenerates to a plain decode step that returns its
     softmax-able logits without committing — the sampled path's
     cold-drafter fallback unit (one sampled token per trip)."""
-    g1 = gamma + 1
 
     @named(f"serve_spec_probs_g{gamma}")
     def verify_probs(carry, params, table, draft_ids, active):
         cache, x = carry
-        b_dim, s_max = cache.max_batch, cache.max_seq
-        nb, bs = cache.num_blocks, cache.block_size
-        n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-        lengths = cache.lengths
-        d_emb = jnp.take(table, draft_ids, axis=0).astype(x.dtype)
-        h0 = jnp.concatenate([x, d_emb], axis=1)        # [B, γ+1, H]
-        pos = jnp.arange(s_max)[None, :]                # [1, S]
-        offs = lengths[:, None] + jnp.arange(g1)[None, :]   # [B, γ+1]
-        valid = pos[:, None, :] <= offs[:, :, None]     # [B, γ+1, S]
-
-        def attention_step(q, k, v, cache_state):
-            k_l, v_l = cache_state
-            qh = _heads(q, n, d)
-            k_new = k.reshape(b_dim, g1, kvh, d)
-            v_new = v.reshape(b_dim, g1, kvh, d)
-            k_flat = k_l.reshape(b_dim, s_max, kvh, d)
-            v_flat = v_l.reshape(b_dim, s_max, kvh, d)
-            with jax.named_scope(KV_UPDATE):
-                for i in range(g1):
-                    m = ((pos == lengths[:, None] + i)
-                         & active[:, None])[..., None, None]
-                    k_flat = jnp.where(m, k_new[:, i][:, None], k_flat)
-                    v_flat = jnp.where(m, v_new[:, i][:, None], v_flat)
-            attn = _verify_attention(qh, k_flat, v_flat, valid)
-            return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
-                    (k_flat.reshape(b_dim, nb, bs, kvh, d),
-                     v_flat.reshape(b_dim, nb, bs, kvh, d)))
-
-        def body(h, layer_and_cache):
-            layer, k_l, v_l = layer_and_cache
-            return _serve_block(h, layer, config, attention_step,
-                                (k_l, v_l))
-
-        h, (k_new, v_new) = jax.lax.scan(
-            body, h0, (params["layers"], cache.k, cache.v)
-        )
-        y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-        return (KVCache(k_new, v_new, lengths), x), y
+        k_new, v_new, y = _verify_forward(carry, params, table, draft_ids,
+                                          active, config, mesh)
+        return (KVCache(k_new, v_new, cache.lengths), x), y
 
     x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
     dp_ax = decode_batch_spec(mesh)[0]
@@ -1727,7 +1653,7 @@ def build_draft_scan(config: ModelConfig, mesh: Mesh, gamma: int):
             lengths_i = lengths + act_i32 * i
             (cache_i, _x2), y = _decode_step_math(
                 (KVCache(k_c, v_c, lengths_i), x_c), params, active,
-                config)
+                config, mesh)
             tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
             x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(x_c.dtype)
             return (cache_i.k, cache_i.v, x2, i + 1), tok
@@ -3486,7 +3412,7 @@ class ServingEngine:
             """The prefill dispatch for one admitted request (chunked or
             monolithic) — returns ``(bucket, y_last, dt)``.  Raised
             through by the retry wrapper below; idempotent on retry:
-            chunk writes are deterministic masked selects of identical
+            chunk writes are deterministic block writes of identical
             values, and interleaved decode units commit independently.
             With a prefix-attach ``plan``, the matched chunks' prefills
             are replaced by ONE donor-block copy (``build_prefix_attach``)
@@ -3524,7 +3450,7 @@ class ServingEngine:
                     decode_spent = 0.0
                     cache = carry[0]
                     if m_chunks:
-                        # copy-on-attach: one masked-select copy of the
+                        # copy-on-attach: one in-place block copy of the
                         # donor's matched blocks stands in for the
                         # matched chunks' prefill dispatches (the TTFT
                         # win), and its returned fp prefix carry is

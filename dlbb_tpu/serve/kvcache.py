@@ -16,10 +16,22 @@ run:
 - ``lengths``: ``[max_batch] int32``, tokens currently valid per slot —
   replicated (tiny; every shard needs it to build attention masks).
 
-Writes are pure masked selects (one-hot over the slot / flat-position
-dim), never gather/scatter with cross-shard indices — elementwise ops
-GSPMD partitions without inserting a single collective.  XLA turns them
-into in-place updates because every step donates the cache.
+Writes touch only the rows they write (:func:`append_token_rows`,
+:func:`write_slot_blocks`, :func:`copy_slot_blocks` — the one set of
+helpers every cache-writing program calls).  The planes ride the
+layer loop's carry, indexed by the layer number, and each write is a
+scatter of ``[kvh, d]`` rows or one ``dynamic_update_slice`` of whole
+blocks into that carry, which XLA updates in place.  Both stay on the
+shard that owns the slot and add no collective (audited): the scatter
+runs under ``shard_map`` over :func:`cache_specs`, and GSPMD
+partitions a ``dynamic_update_slice`` at a sharded slot index into a
+local write of the update or of what was there.  Until PR 26 the
+writes were masked selects over the whole ``[slots, S_max, kvh, d]``
+layer and the planes went through the layer ``lax.scan`` as
+``xs``/``ys``; donation only aliases a program's argument with its
+result, and the v5e trace showed the plane rewritten by the select (45%
+of device time in ``serve7b_backlog``) and copied whole twice a program
+run (28%) inside it (``PERF.md`` §5-6).
 
 Host side, :class:`BlockLedger` does the alloc/free/append accounting
 against a global block budget: admission *reserves* a request's
@@ -65,7 +77,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlbb_tpu.compat import shard_map
 from dlbb_tpu.models.configs import ModelConfig
+from dlbb_tpu.models.transformer import SERVE_PHASES, _dtype_of
 
 
 class KVCache(NamedTuple):
@@ -120,8 +134,6 @@ def create_kv_cache(
     """Zero-initialised cache, created *directly sharded* onto the mesh
     (jit with explicit out-shardings — same trick as
     ``init_params_sharded``: no device ever holds the replicated cache)."""
-    from dlbb_tpu.models.transformer import _dtype_of
-
     dtype = _dtype_of(config.dtype)
     shape = (config.num_layers, max_batch, num_blocks, block_size,
              config.kv_heads, config.head_dim)
@@ -136,6 +148,74 @@ def create_kv_cache(
     if mesh is None:
         return build()
     return jax.jit(build, out_shardings=cache_shardings(mesh))()
+
+
+KV_UPDATE = SERVE_PHASES[0]
+
+
+@jax.named_scope(KV_UPDATE)
+def append_token_rows(plane: jax.Array, rows: jax.Array, layer: jax.Array,
+                      lengths: jax.Array, active: jax.Array,
+                      mesh: Mesh) -> jax.Array:
+    """The decode append: write ``rows[b, i]`` (``[B, G, kvh, d]``, G
+    consecutive tokens a slot — 1 for a decode step, gamma+1 for a
+    verify) into ``plane`` ``[L, B, nb, bs, kvh, d]`` at ``(layer, b,
+    p // bs, p % bs)`` with ``p = lengths[b] + i``.  One scatter of
+    B x G rows; an inactive slot's rows, and any position past the
+    slot's last block, are given an out-of-range block index and
+    dropped, so what is not written stays bit for bit as it was.
+
+    The scatter runs under ``shard_map`` over the cache's own specs,
+    each shard writing its own slots' rows at its own kv-heads: left to
+    GSPMD, a scatter with a sharded slot dim all-gathers its indices
+    and rows over ``dp`` first (two collectives a layer and plane)."""
+    kv_spec = cache_specs(mesh).k
+    dp, tp = kv_spec[1], kv_spec[4]
+
+    def write(plane, rows, layer, lengths, active):
+        b_dim, nb, bs = plane.shape[1:4]
+        pos = lengths[:, None] + jnp.arange(rows.shape[1])[None, :]  # [B, G]
+        blk = jnp.where(active[:, None], pos // bs, nb)
+        slot = jnp.arange(b_dim)[:, None]
+        return plane.at[layer, slot, blk, pos % bs].set(
+            rows.astype(plane.dtype), mode="drop", unique_indices=True)
+
+    return shard_map(
+        write, mesh=mesh,
+        in_specs=(kv_spec, P(dp, None, tp, None), P(), P(dp), P(dp)),
+        out_specs=kv_spec,
+    )(plane, rows, layer, lengths, active)
+
+
+@jax.named_scope(KV_UPDATE)
+def write_slot_blocks(plane: jax.Array, blocks: jax.Array, layer: jax.Array,
+                      slot: jax.Array, start_blk: int = 0) -> jax.Array:
+    """The prompt write: one ``dynamic_update_slice`` of a prefill
+    bucket's (or chunk's) ``wb`` whole blocks into ``plane`` ``[L, B,
+    nb, ...]`` at ``(layer, slot, start_blk)``.  ``blocks`` is ``[wb,
+    ...]`` with the plane's trailing dims — ``[wb, bs, kvh, d]`` for a
+    K/V plane, ``[wb, kvh]`` for the int8 layout's scale plane."""
+    start = (layer, slot, start_blk) + (0,) * (plane.ndim - 3)
+    return jax.lax.dynamic_update_slice(
+        plane, blocks[None, None].astype(plane.dtype), start)
+
+
+@jax.named_scope(KV_UPDATE)
+def copy_slot_blocks(plane: jax.Array, src: jax.Array, dst: jax.Array,
+                     num_blocks: int) -> tuple[jax.Array, jax.Array]:
+    """The shared-prefix attach: copy slot ``src``'s first
+    ``num_blocks`` blocks of every layer into slot ``dst`` (any plane
+    ``[L, B, nb, ...]``) — one slice read and one
+    ``dynamic_update_slice``.  Returns the plane and the copied blocks
+    ``[L, num_blocks, ...]``, read back from ``dst``: the caller wants
+    them as the chunk-prefill carry, and a second read of the plane it
+    came in as, beside the write, made XLA:CPU copy the whole plane
+    twice (the simulated-mesh memory audit counts it)."""
+    zeros = (0,) * (plane.ndim - 2)
+    size = (plane.shape[0], 1, num_blocks) + plane.shape[3:]
+    donor = jax.lax.dynamic_slice(plane, (0, src) + zeros, size)
+    plane = jax.lax.dynamic_update_slice(plane, donor, (0, dst) + zeros)
+    return plane, jax.lax.dynamic_slice(plane, (0, dst) + zeros, size)[:, 0]
 
 
 def gather_cache_slots(cache: KVCache, idx: jax.Array) -> KVCache:

@@ -23,12 +23,15 @@ from dlbb_tpu.serve.engine import (
     ServingConfig,
     ServingEngine,
     _inject_token,
+    build_decode_fused,
     build_decode_step,
     build_prefill,
 )
 from dlbb_tpu.serve.kvcache import (
     BlockLedger,
     CacheOverflow,
+    KVCache,
+    cache_shardings,
     create_kv_cache,
 )
 from dlbb_tpu.serve.traffic import TrafficTrace, generate_trace
@@ -303,6 +306,57 @@ BF16_TOL = 0.05
 def test_prefill_decode_matches_forward_bf16(mesh2x4):
     cfg = ModelConfig(**{**TINY, "dtype": "bfloat16"})
     _equivalence_case(cfg, mesh2x4, dp=2, tol=BF16_TOL)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "decode_k4"])
+def test_decode_append_writes_only_the_active_slots_own_rows(mesh2x4,
+                                                             program):
+    """The in-place append's contract, on a cache full of noise: a
+    decode step and a fused scan leave an inactive slot's K/V rows bit
+    for bit as they were, and change in an active slot exactly the rows
+    ``lengths[slot] .. lengths[slot] + steps`` and nothing else — also
+    for a slot whose next token lands on its last row."""
+    cfg = ModelConfig(**TINY)
+    sv = ServingConfig(max_batch=4, block_size=8, max_seq=32,
+                       hbm_budget_gb=None)
+    params = init_params_sharded(cfg, jax.random.key(0), mesh2x4)
+    rng = np.random.default_rng(3)
+    shape = (cfg.num_layers, sv.max_batch, sv.num_blocks, sv.block_size,
+             cfg.kv_heads, cfg.head_dim)
+    before = {"k": rng.standard_normal(shape, dtype=np.float32),
+              "v": rng.standard_normal(shape, dtype=np.float32)}
+    lengths = np.array([5, 17, sv.max_seq - 1, 9], np.int32)
+    active = np.array([True, False, True, False])
+    cache = jax.device_put(
+        KVCache(jnp.asarray(before["k"]), jnp.asarray(before["v"]),
+                jnp.asarray(lengths)),
+        cache_shardings(mesh2x4))
+    x = jax.device_put(
+        jnp.asarray(rng.standard_normal(
+            (sv.max_batch, 1, cfg.hidden_size), dtype=np.float32)),
+        NamedSharding(mesh2x4, P("dp", None, None)))
+    if program == "decode_step":
+        steps = np.where(active, 1, 0)
+        (cache, _), _ = build_decode_step(cfg, mesh2x4)(
+            (cache, x), params, jnp.asarray(active))
+    else:
+        # slot 0 runs 3 of the scan's 4 trips; slot 2 has one row left
+        steps = np.array([3, 0, 1, 0])
+        (cache, _), _ = build_decode_fused(cfg, mesh2x4, 4)(
+            (cache, x), params, jnp.asarray(active),
+            jnp.asarray(steps, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(cache.lengths),
+                                  lengths + steps)
+    flat = (cfg.num_layers, sv.max_batch, sv.max_seq, cfg.kv_heads,
+            cfg.head_dim)
+    for name in ("k", "v"):
+        was = before[name].reshape(flat)
+        now = np.asarray(getattr(cache, name)).reshape(flat)
+        changed = (was != now).any(axis=(0, 3, 4))          # [B, S]
+        want = np.zeros_like(changed)
+        for slot, n in enumerate(steps):
+            want[slot, lengths[slot]:lengths[slot] + n] = True
+        np.testing.assert_array_equal(changed, want, err_msg=name)
 
 
 # ---------------------------------------------------------------------------

@@ -12,11 +12,14 @@ overshoots every remaining output length.
 """
 
 import json
+import os
+import re
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.comm.mesh import build_parallelism_mesh
 from dlbb_tpu.models.configs import ModelConfig
@@ -525,6 +528,155 @@ def test_serving_program_lowers_with_its_name_and_every_phase(
     assert f"module @jit_{program}" in text
     for phase in BLOCK_PHASES + SERVE_PHASES:
         assert f"{phase}/" in text, phase
+
+
+# ---------------------------------------------------------------------------
+# a cache write touches only the rows it writes, in place (PR 26): read
+# from the optimised HLO, on the simulated mesh and compiled for the v5e
+# ---------------------------------------------------------------------------
+
+_HLO_INSTRUCTION = re.compile(
+    r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*?) ([a-z][\w\-]*)\(")
+# what may hold one whole cache plane: the program's arguments, the layer
+# loop's carry, and the update that writes into that carry's buffer
+_PLANE_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
+                   "bitcast"}
+_PLANE_UPDATES = {"scatter", "dynamic-update-slice"}
+
+
+def _plane_producers(hlo: str, plane: tuple, dtype: str) -> dict:
+    """``{instruction name: op}`` of every instruction of an optimised
+    HLO module whose result holds one whole (per-device) cache plane,
+    plumbing left out; a fusion is given as ``fusion:<its root's op>``.
+    Listed too: a ``select`` over one whole layer of the plane (the
+    masked-select append this replaced), and, outside fused
+    computations, any other instruction that writes one whole layer out
+    (``layer:<op>``: the attention read is to take its slice of the
+    plane as a prologue, not from a copy of the layer)."""
+    l, b, nb, bs, kvh, d = plane
+    full = f"{dtype}[{','.join(map(str, plane))}]"
+    layers = {f"[{','.join(map(str, dims))}]"
+              for dims in ((b, nb, bs, kvh, d), (1, b, nb, bs, kvh, d),
+                           (b, nb * bs, kvh, d), (1, b, nb * bs, kvh, d))}
+    roots, found, computation = {}, {}, ""
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if not m:
+            continue
+        name, result, op = m.groups()
+        if line.lstrip().startswith("ROOT "):
+            roots[computation] = op
+        shape = re.sub(r"^[a-z0-9]+|\{.*$", "", result)
+        if full in result and op not in _PLANE_PLUMBING:
+            called = re.search(r"calls=%([\w.\-]+)", line)
+            found[name] = (op, called.group(1) if called else None)
+        elif shape in layers and op == "select":
+            found[name] = ("select-over-a-layer", None)
+        elif (shape in layers and op not in _PLANE_PLUMBING
+              and "fused_computation" not in computation):
+            found[name] = (f"layer:{op}", None)
+    return {name: f"fusion:{roots.get(called)}" if called else op
+            for name, (op, called) in found.items()}
+
+
+def _assert_writes_in_place(hlo: str, plane: tuple, dtype: str) -> None:
+    producers = _plane_producers(hlo, plane, dtype)
+    allowed = _PLANE_UPDATES | {f"fusion:{op}" for op in _PLANE_UPDATES}
+    extra = {n: op for n, op in producers.items() if op not in allowed}
+    assert not extra, (
+        f"a whole plane or layer written beside the in-place write: {extra}")
+    # K and V each written at least once: the shapes matched something
+    assert len(producers) >= 2, producers
+
+
+def _cache_writing_program(program, cfg, mesh, cache, params, x, chunk):
+    """The jitted program and its arguments (arrays or shapes)."""
+    from dlbb_tpu.serve import engine as E
+
+    b = cache.k.shape[1]
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=NamedSharding(mesh, P()))
+    active, i32 = like((b,), jnp.bool_), like((), jnp.int32)
+    if program == "serve_decode_step":
+        return E.build_decode_step(cfg, mesh), ((cache, x), params, active)
+    if program == "serve_decode_k4":
+        return (E.build_decode_fused(cfg, mesh, 4),
+                ((cache, x), params, active, like((b,), jnp.int32)))
+    pre = jax.ShapeDtypeStruct(
+        (cfg.num_layers, chunk, cfg.kv_heads, cfg.head_dim), x.dtype,
+        sharding=NamedSharding(mesh, E.prefix_spec(mesh)))
+    return (E.build_prefill_chunk(cfg, mesh, chunk, chunk),
+            (cache, (pre, pre), params,
+             like((1, chunk, cfg.hidden_size), x.dtype), i32, i32))
+
+
+CACHE_WRITERS = ["serve_decode_step", "serve_decode_k4",
+                 "serve_prefill_chunk"]
+
+
+@pytest.mark.parametrize("program", CACHE_WRITERS)
+def test_cache_writes_are_in_place_on_the_simulated_mesh(mesh2x4, program):
+    """On dp=2 x tp=4 no instruction of the compiled program but the
+    in-place write produces a whole cache plane (per device): no
+    ``copy``, no ``select``, no other fusion."""
+    sv = ServingConfig(**SERVE)
+    params, cache, x, _ = _decode_args(mesh2x4, sv)
+    jitted, args = _cache_writing_program(program, MODEL, mesh2x4, cache,
+                                          params, x, chunk=8)
+    hlo = jitted.lower(*args).compile().as_text()
+    _assert_writes_in_place(
+        hlo, cache.k.sharding.shard_shape(cache.k.shape), "f32")
+
+
+@pytest.fixture(scope="module")
+def v5e_chip():
+    """One described (not attached) v5e chip as a 1 x 1 (dp, tp) mesh."""
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
+    os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return build_parallelism_mesh(1, 1, 1, 1, 1, devices=topo.devices[:1])
+
+
+@pytest.mark.parametrize("program", CACHE_WRITERS)
+def test_cache_writes_are_in_place_compiled_for_the_v5e(v5e_chip, program):
+    """The same, as the TPU's own compiler leaves the program at the
+    benchmark cell's widths (7B, 16 slots x 1024 tokens, bf16; 2 layers,
+    the layer loop's body does not depend on their number): the parent
+    had a whole-plane ``select`` fusion and two whole-plane ``copy`` a
+    program run here."""
+    from dlbb_tpu.models.transformer import init_params
+    from dlbb_tpu.serve.kvcache import KVCache, cache_shardings
+
+    cfg = ModelConfig(hidden_size=4096, num_layers=2, num_heads=32,
+                      ffn_intermediate=16384, dtype="bfloat16",
+                      attention="full")
+    mesh, rep = v5e_chip, NamedSharding(v5e_chip, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
+    plane = (cfg.num_layers, 16, 64, 16, cfg.kv_heads, cfg.head_dim)
+    sh = cache_shardings(mesh)
+    cache = KVCache(
+        jax.ShapeDtypeStruct(plane, jnp.bfloat16, sharding=sh.k),
+        jax.ShapeDtypeStruct(plane, jnp.bfloat16, sharding=sh.v),
+        jax.ShapeDtypeStruct((16,), jnp.int32, sharding=sh.lengths))
+    x = jax.ShapeDtypeStruct((16, 1, cfg.hidden_size), jnp.bfloat16,
+                             sharding=rep)
+    jitted, args = _cache_writing_program(program, cfg, mesh, cache,
+                                          params, x, chunk=128)
+    hlo = jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    _assert_writes_in_place(hlo, plane, "bf16")
 
 
 def test_every_serving_program_has_a_stable_name(mesh2x4):

@@ -85,7 +85,7 @@ def test_decode_hot_path_static_zero_injection_pin():
     tree = ast.parse(src)
     device_fns = {
         "_decode_step_math", "_serve_block", "_cached_attention",
-        "_chunk_attention", "_write_prompt_blocks", "_inject_token",
+        "_chunk_attention", "_scan_layers", "_inject_token",
         "build_decode_fused", "build_decode_step", "build_prefill",
         "build_prefill_chunk", "build_compact_gather",
         "build_compact_scatter",
