@@ -130,6 +130,23 @@ def request_embeddings(
     return jnp.asarray(host, dtype=dtype)
 
 
+def prompt_ids_from_seed(seed: int, prompt_len: int, vocab_size: int,
+                         pad_to: Optional[int] = None) -> np.ndarray:
+    """A request's prompt for a model WITH a vocabulary (``ModelConfig.
+    vocab_size``): ``prompt_len`` token ids drawn uniformly from the
+    request's seed, ``[1, pad_to or prompt_len] int32``, zeros after the
+    prompt.  The serving engine embeds them on the device; the draw is a
+    few kilobytes on the host where :func:`request_embeddings` is
+    megabytes."""
+    if pad_to is not None and pad_to < prompt_len:
+        raise ValueError(
+            f"pad_to={pad_to} is shorter than prompt_len={prompt_len}")
+    ids = np.zeros((1, pad_to or prompt_len), np.int32)
+    ids[0, :prompt_len] = np.random.default_rng(seed).integers(
+        0, vocab_size, size=prompt_len)
+    return ids
+
+
 def prompt_token_ids(seed: int, prompt_len: int, hidden_size: int,
                      period: Optional[int] = None,
                      prefix_len: Optional[int] = None,

@@ -8,8 +8,14 @@ attention — an option the reference lacks but a real framework needs.
 
 from __future__ import annotations
 
-from dataclasses import dataclass, replace
-from typing import Any
+from dataclasses import dataclass, fields as dataclass_fields, replace
+from typing import Any, Optional
+
+# the kinds of layer a ``layer_types`` pattern may name (the published
+# config's own words)
+LINEAR_ATTENTION = "linear_attention"
+FULL_ATTENTION = "full_attention"
+LAYER_KINDS = (LINEAR_ATTENTION, FULL_ATTENTION)
 
 
 @dataclass(frozen=True)
@@ -85,8 +91,40 @@ class ModelConfig:
     #   attention are dot outputs, so "dots" keeps them resident — at long
     #   S prefer "full" or flash attention).
     remat_policy: str = "full"
+    # -- the block family.  The defaults are the reference's GPT block
+    # (LayerNorm with bias, GELU, biased projections, no vocabulary:
+    # hidden states in, hidden states out).  The second family is the
+    # hybrid of ``models/hybrid.py`` (Olmo-Hybrid): RMSNorm applied to
+    # each sub-layer's OUTPUT, bias-free projections, a SwiGLU MLP,
+    # QK-norm, a token embedding and an untied output head, and a stack
+    # whose layers follow the repeating pattern ``layer_types``.  The
+    # field names are the ones the model's published ``config.json``
+    # uses, so that a benchmark configuration's ``published`` section
+    # compares with ``program.model`` key by key.  Only these two
+    # combinations are implemented; any other is an error, not a guess.
+    norm: str = "layernorm"             # "layernorm" | "rmsnorm"
+    mlp: str = "gelu"                   # "gelu" (up, down) | "swiglu"
+    bias: bool = True
+    qk_norm: bool = False
+    rms_norm_eps: float = 1e-6
+    vocab_size: int = 0                 # 0 = no embedding, no head
+    # one PERIOD of the layer pattern, e.g. ("linear_attention",) * 3 +
+    # ("full_attention",); num_layers is a whole number of periods.
+    # None = one kind of layer (the GPT block).
+    layer_types: Optional[tuple[str, ...]] = None
+    # the linear-attention (gated delta rule) layers' sizes
+    linear_num_key_heads: int = 0
+    linear_num_value_heads: int = 0
+    linear_key_head_dim: int = 0
+    linear_value_head_dim: int = 0
+    linear_conv_kernel_dim: int = 0
+    linear_allow_neg_eigval: bool = False
 
     def __post_init__(self) -> None:
+        if self.layer_types is not None:
+            # a YAML/JSON list arrives as a list; the dataclass is hashed
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        self._validate_family()
         if self.hidden_size % self.num_heads != 0:
             raise ValueError(
                 f"hidden_size {self.hidden_size} not divisible by "
@@ -136,6 +174,81 @@ class ModelConfig:
                     f"num_kv_heads={self.num_kv_heads}"
                 )
 
+    def _validate_family(self) -> None:
+        gpt = (self.norm == "layernorm" and self.mlp == "gelu" and self.bias
+               and not self.qk_norm and self.vocab_size == 0
+               and self.layer_types is None)
+        if gpt:
+            return
+        hybrid = (self.norm == "rmsnorm" and self.mlp == "swiglu"
+                  and not self.bias and self.qk_norm and self.vocab_size > 0
+                  and self.layer_types is not None)
+        if not hybrid:
+            raise ValueError(
+                "model family not implemented: the program runs the GPT "
+                "block (norm='layernorm', mlp='gelu', bias=true, "
+                "qk_norm=false, no vocab_size, no layer_types) or the "
+                "hybrid block (norm='rmsnorm', mlp='swiglu', bias=false, "
+                "qk_norm=true, vocab_size > 0, layer_types given); got "
+                f"norm={self.norm!r}, mlp={self.mlp!r}, bias={self.bias}, "
+                f"qk_norm={self.qk_norm}, vocab_size={self.vocab_size}, "
+                f"layer_types={self.layer_types}")
+        unknown = set(self.layer_types) - set(LAYER_KINDS)
+        if not self.layer_types or unknown:
+            raise ValueError(
+                f"layer_types must be a non-empty pattern of {LAYER_KINDS}, "
+                f"got {self.layer_types}")
+        if self.num_layers % len(self.layer_types):
+            raise ValueError(
+                f"num_layers={self.num_layers} is not a whole number of "
+                f"periods of {len(self.layer_types)} layers (layer_types="
+                f"{self.layer_types})")
+        if LINEAR_ATTENTION in self.layer_types:
+            sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
+                     self.linear_key_head_dim, self.linear_value_head_dim,
+                     self.linear_conv_kernel_dim)
+            if min(sizes) < 1:
+                raise ValueError(
+                    "linear_attention layers need linear_num_key_heads, "
+                    "linear_num_value_heads, linear_key_head_dim, "
+                    "linear_value_head_dim and linear_conv_kernel_dim >= 1, "
+                    f"got {sizes}")
+            if self.linear_num_key_heads != self.linear_num_value_heads:
+                raise ValueError(
+                    "linear_num_key_heads != linear_num_value_heads "
+                    f"({self.linear_num_key_heads} != "
+                    f"{self.linear_num_value_heads}): grouped value heads "
+                    "in the gated delta rule are not implemented")
+        if self.num_kv_heads not in (None, self.num_heads):
+            raise ValueError("the hybrid family's full-attention layers are "
+                             "plain MHA here (num_kv_heads == num_heads)")
+        if (self.is_moe or self.tp_overlap != "off" or self.remat
+                or self.attention not in ("full", "dense")
+                or not self.causal):
+            raise ValueError(
+                "the hybrid family runs causal exact attention with a dense "
+                "MLP: no experts, tp_overlap, remat, or attention modes "
+                "other than 'full'/'dense'")
+
+    @property
+    def is_hybrid(self) -> bool:
+        """The heterogeneous stack of ``models/hybrid.py``."""
+        return self.layer_types is not None
+
+    def layers_of(self, kind: str) -> int:
+        """How many of the ``num_layers`` layers are of ``kind``."""
+        if self.layer_types is None:
+            return self.num_layers if kind == FULL_ATTENTION else 0
+        periods = self.num_layers // len(self.layer_types)
+        return periods * self.layer_types.count(kind)
+
+    @property
+    def linear_conv_channels(self) -> int:
+        """Channels of the linear layers' short convolution: q, k and v
+        of every head."""
+        return (2 * self.linear_num_key_heads * self.linear_key_head_dim
+                + self.linear_num_value_heads * self.linear_value_head_dim)
+
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
@@ -167,14 +280,17 @@ class ModelConfig:
         d = dict(d)
         size = d.pop("size", None)
         base = MODEL_CONFIGS[size] if size else None
+        known = {f.name for f in dataclass_fields(cls)}
+        unknown = sorted(set(d) - known)
+        if unknown:
+            # a misspelt key must not fall back to a default: a typo in
+            # ``layer_types`` would run the GPT block under another
+            # model's name
+            raise ValueError(
+                f"unknown model key(s) {unknown}; ModelConfig has "
+                f"{sorted(known)}")
         fields = {}
-        for k in (
-            "hidden_size", "num_layers", "num_heads", "ffn_intermediate",
-            "attention", "dtype", "num_kv_heads", "causal",
-            "num_experts", "moe_top_k",
-            "moe_dispatch", "moe_capacity_factor", "tp_overlap",
-            "remat", "remat_policy",
-        ):
+        for k in known:
             if k in d:
                 fields[k] = d[k]
             elif base is not None:
@@ -191,6 +307,11 @@ SP_CAPABLE_ATTENTION = ("ring", "ulysses")
 def validate_attention_parallelism(config: ModelConfig, sp: int) -> None:
     """Reject attention-mode / sequence-parallel combinations that would
     silently compute the wrong thing or replicate work per sp shard."""
+    if config.is_hybrid and sp > 1:
+        raise ValueError(
+            f"parallelism.sequence_parallel={sp} is not implemented for "
+            "layer_types models: the gated delta rule's state would have "
+            "to be handed from one sequence shard to the next")
     if config.attention in SP_CAPABLE_ATTENTION and sp <= 1:
         raise ValueError(
             f"attention={config.attention!r} requires "
@@ -303,18 +424,51 @@ def kv_cache_bytes_raw(num_layers: int, max_batch: int, max_seq: int,
     return elems * head_dim * _DTYPE_BYTES.get(dtype, 2)
 
 
+def cache_kv_heads(config: ModelConfig, tp: int = 1) -> int:
+    """K/V heads a cache plane holds.  The GPT block's planes hold
+    ``kv_heads``.  A ``layer_types`` model's hold them rounded up to a
+    whole number of 8 when the head dim is not sharded (the added heads
+    stay zero and are attended by zero queries): the TPU tiles a plane
+    by (8, 128) over (heads, head_dim), so the memory is spent either
+    way, and with 30 heads the v5e compiler re-laid both whole planes
+    out inside every decode step to get whole tiles (two 2 GB copies a
+    step, compiled for the chip without it, PR 27).  Under tp the planes
+    keep ``kv_heads``, so that query and key heads split alike."""
+    if config.is_hybrid and tp <= 1:
+        return -(-config.kv_heads // 8) * 8
+    return config.kv_heads
+
+
 def kv_cache_bytes(config: ModelConfig, max_batch: int,
                    max_seq: int, kv_quantization: str = "none",
-                   block_size: Optional[int] = None) -> int:
+                   block_size: Optional[int] = None, tp: int = 1) -> int:
     """Total (unsharded) KV-cache footprint of a serving config: K + V,
     every layer, every slot, ``max_seq`` tokens at GQA ``kv_heads``
     width, in the model dtype (or the int8 + fp32-scale layout when
     quantized)."""
-    return kv_cache_bytes_raw(config.num_layers, max_batch, max_seq,
-                              config.kv_heads, config.head_dim,
+    return kv_cache_bytes_raw(config.layers_of(FULL_ATTENTION), max_batch,
+                              max_seq, cache_kv_heads(config, tp),
+                              config.head_dim,
                               config.dtype,
                               kv_quantization=kv_quantization,
                               block_size=block_size)
+
+
+def state_cache_bytes(config: ModelConfig, max_batch: int) -> int:
+    """Total (unsharded) footprint of the linear-attention layers'
+    slot-indexed state (``serve/kvcache.py::StateCache``): per layer and
+    slot one float32 ``[heads, d_v, d_k]`` recurrent state and the last
+    ``conv_kernel - 1`` inputs of the short convolution in the model
+    dtype.  It does not grow with a slot's length, so the block ledger
+    never counts it; 0 for a model without such layers."""
+    n_lin = config.layers_of(LINEAR_ATTENTION)
+    if not n_lin:
+        return 0
+    state = (config.linear_num_value_heads * config.linear_value_head_dim
+             * config.linear_key_head_dim * 4)
+    conv = ((config.linear_conv_kernel_dim - 1) * config.linear_conv_channels
+            * _DTYPE_BYTES.get(config.dtype, 2))
+    return n_lin * max_batch * (state + conv)
 
 
 def kv_cache_bytes_per_device(config: ModelConfig, max_batch: int,
@@ -333,9 +487,12 @@ def kv_cache_bytes_per_device(config: ModelConfig, max_batch: int,
     same dp × tp axes as the data it scales, so one divisor covers
     both."""
     shards = max(1, dp) * (tp if tp > 1 else 1)
-    return kv_cache_bytes(config, max_batch, max_seq,
-                          kv_quantization=kv_quantization,
-                          block_size=block_size) // shards
+    # the state planes shard like the K/V planes (slots over dp, heads
+    # over tp), so the one divisor covers them too
+    return (kv_cache_bytes(config, max_batch, max_seq,
+                           kv_quantization=kv_quantization,
+                           block_size=block_size, tp=tp)
+            + state_cache_bytes(config, max_batch)) // shards
 
 
 def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
@@ -371,6 +528,25 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             f"serving.kv_quantization={kv_quantization!r} not in "
             f"{KV_QUANTIZATION_MODES}"
         )
+    if config.is_hybrid:
+        # what the hybrid family's serving path does not have yet, each
+        # refused by its mechanism (ROADMAP.md, Queue 2)
+        if kv_quantization != "none":
+            raise ValueError(
+                f"serving.kv_quantization={kv_quantization!r} is not "
+                "implemented for layer_types models: the hybrid programs "
+                "read and write the fp K/V layout only")
+        if draft_config is not None:
+            raise ValueError(
+                "speculation='draft-model' is not implemented for "
+                "layer_types models: a rejected draft needs the recurrent "
+                "state rolled back, and the state cache keeps no snapshots")
+        lin_heads = config.linear_num_value_heads
+        if (tp > 1 and LINEAR_ATTENTION in config.layer_types
+                and lin_heads % tp != 0):
+            raise ValueError(
+                f"linear_num_value_heads={lin_heads} not divisible by "
+                f"tp={tp}: the recurrent state shards its head dim over tp")
     if config.attention not in SERVABLE_ATTENTION:
         raise ValueError(
             f"serving requires attention in {SERVABLE_ATTENTION} "
@@ -442,12 +618,15 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             raise ValueError(
                 f"serving KV-cache footprint {per_device / 2**30:.2f} GiB "
                 f"per device (max_batch={max_batch} x max_seq={max_seq} "
-                f"x {config.num_layers} layers x kv_heads="
+                f"x {config.layers_of(FULL_ATTENTION)} layers x kv_heads="
                 f"{config.kv_heads} x head_dim={config.head_dim} x 2 "
                 "(K+V), "
                 + (f"int8 + fp32 scales per {block_size}-token block"
                    if kv_quantization == "int8"
                    else f"{_DTYPE_BYTES[config.dtype]} B [{config.dtype}]")
+                + (f", + {state_cache_bytes(config, max_batch) / 2**30:.2f}"
+                   " GiB of recurrent state and convolution inputs"
+                   if config.is_hybrid else "")
                 + f", sharded over dp={dp} x tp={tp})"
                 f"{draft_note} "
                 f"exceeds the HBM budget of "

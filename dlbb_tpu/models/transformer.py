@@ -68,6 +68,10 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     Scaled-normal kernels (1/sqrt(fan_in)), zero biases, unit LN scales —
     standard init; the reference's randn-based init is at ``models.py:33-38``.
     """
+    if config.is_hybrid:
+        from dlbb_tpu.models import hybrid
+
+        return hybrid.init_params(config, key)
     h, f, L = config.hidden_size, config.ffn_intermediate, config.num_layers
     dtype = _dtype_of(config.dtype)
 
@@ -463,7 +467,16 @@ def forward(params: Params, x: jax.Array, config: ModelConfig,
     load-balancing loss (``moe_aux_loss``); under pipeline parallelism it
     is additionally averaged over microbatches (per-stage masked
     accumulation + psum — see ``pipeline_forward``).
+
+    A ``layer_types`` model (``models/hybrid.py``) takes token ids
+    ``[B, S]`` for ``x`` and returns float32 logits ``[B, S, vocab]``.
     """
+    if config.is_hybrid:
+        from dlbb_tpu.models import hybrid
+
+        if with_aux:
+            raise ValueError("with_aux is for MoE models")
+        return hybrid.forward(params, x, config, mesh)
     if (mesh is not None and pp_axis in mesh.axis_names
             and mesh.shape[pp_axis] > 1):
         from dlbb_tpu.parallel.pipeline import pipeline_forward
@@ -506,6 +519,10 @@ def forward(params: Params, x: jax.Array, config: ModelConfig,
 def num_parameters(config: ModelConfig) -> int:
     """Total parameter count (reference ``get_num_parameters``
     ``models.py:239-241``; MoE counts every expert + router)."""
+    if config.is_hybrid:
+        from dlbb_tpu.models import hybrid
+
+        return hybrid.num_parameters(config)
     h, f, L = config.hidden_size, config.ffn_intermediate, config.num_layers
     if config.is_moe:
         E = config.num_experts
@@ -576,6 +593,10 @@ def init_params_sharded(
     ``init_params`` + ``shard_params`` would materialise the whole model on
     the default device first.
     """
+    if config.is_hybrid:
+        from dlbb_tpu.models import hybrid
+
+        return hybrid.init_params_sharded(config, key, mesh)
     shardings = jax.tree.map(
         lambda s: NamedSharding(mesh, s),
         specs_for_mesh(mesh, tp_axis, moe=config.is_moe),

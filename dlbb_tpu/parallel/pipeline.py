@@ -97,6 +97,11 @@ def validate_pipeline(config: ModelConfig, n_stages: int, batch_size: int,
                       num_microbatches: Optional[int]) -> int:
     """Check divisibility and attention-mode constraints; returns the
     resolved microbatch count (default: one per stage)."""
+    if config.is_hybrid:
+        raise ValueError(
+            f"pipeline_parallel={n_stages} is not implemented for "
+            "layer_types models: the pipeline engine stages one "
+            "homogeneous stacked layer, not a period of mixed layers")
     m = num_microbatches if num_microbatches is not None else n_stages
     if m < 1:
         raise ValueError(f"num_microbatches must be >= 1, got {m}")

@@ -104,11 +104,18 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.data.synthetic import (
+    prompt_ids_from_seed,
     prompt_token_ids,
     request_embeddings,
     token_embedding_table,
 )
-from dlbb_tpu.models.configs import ModelConfig, validate_serving
+from dlbb_tpu.models.configs import (
+    FULL_ATTENTION,
+    ModelConfig,
+    state_cache_bytes,
+    kv_cache_bytes,
+    validate_serving,
+)
 from dlbb_tpu.models.attention import dense_attention
 from dlbb_tpu.models.transformer import (
     ATTN_CORE,
@@ -372,6 +379,28 @@ class ServingConfig:
                 f"serving.speculation={self.speculation!r} must be one "
                 f"of {SPECULATION_MODES}"
             )
+        if config.is_hybrid:
+            # what the hybrid family's serving path does not have yet,
+            # each by its mechanism (ROADMAP.md, Queue 2); int8 KV is
+            # refused in validate_serving below
+            if self.speculation != "off":
+                raise ValueError(
+                    f"serving.speculation={self.speculation!r} is not "
+                    "implemented for layer_types models: a rejected draft "
+                    "needs the recurrent state rolled back, and the state "
+                    "cache keeps no snapshots")
+            if self.prefix_caching:
+                raise ValueError(
+                    "serving.prefix_caching is not implemented for "
+                    "layer_types models: attaching to shared blocks needs "
+                    "the recurrent state as it was at the block boundary, "
+                    "and the state cache keeps no snapshots")
+            if self.prefill_chunk is None:
+                raise ValueError(
+                    "layer_types models are prefilled in chunks: set "
+                    "serving.prefill_chunk (the chunk program hands the "
+                    "recurrent state from chunk to chunk; there is no "
+                    "monolithic prefill program)")
         # speculation with tp_overlap != off or non-dense attention is
         # rejected inside validate_serving (those envelopes cannot serve
         # at all); the draft plane re-runs the same gate on its own
@@ -934,7 +963,8 @@ def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple[jax.Array,
     """The empty (start=0) prefix carry for a chunked prefill."""
     from dlbb_tpu.models.transformer import _dtype_of as _dt
 
-    shape = (config.num_layers, 0, config.kv_heads, config.head_dim)
+    shape = (config.layers_of(FULL_ATTENTION), 0, config.kv_heads,
+             config.head_dim)
     zeros = jnp.zeros(shape, _dt(config.dtype))
     sh = NamedSharding(mesh, prefix_spec(mesh))
     return (jax.device_put(zeros, sh), jax.device_put(zeros, sh))
@@ -1105,12 +1135,20 @@ def build_prefix_attach(config: ModelConfig, mesh: Mesh,
     )
 
 
-def build_compact_gather(mesh: Mesh):
+def _carry_shardings(mesh: Mesh):
+    """Shardings of the GPT block's decode carry ``(cache, x)``."""
+    return (cache_shardings(mesh),
+            NamedSharding(mesh, decode_batch_spec(mesh)))
+
+
+def build_compact_gather(mesh: Mesh, carry_shardings=None):
     """Jitted ``gather(carry, idx) -> small_carry``: repack the active
     slots named by ``idx`` into a smaller decode batch bucket (slot
     compaction, dp=1 only — the gather must stay shard-local).  The big
     carry is NOT donated: it survives on device and the compacted scan's
-    results are scattered back into it at scan exit."""
+    results are scattered back into it at scan exit.
+    ``carry_shardings``: those of ``(cache, x)`` when the carry is not
+    the GPT block's (a ``HybridCache`` and its token buffer)."""
     from dlbb_tpu.serve.kvcache import gather_cache_slots
 
     @named("serve_compact_gather")
@@ -1118,13 +1156,11 @@ def build_compact_gather(mesh: Mesh):
         cache, x = carry
         return (gather_cache_slots(cache, idx), x[idx])
 
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
     return jax.jit(
-        gather, out_shardings=(cache_shardings(mesh), x_sh),
-    )
+        gather, out_shardings=carry_shardings or _carry_shardings(mesh))
 
 
-def build_compact_scatter(mesh: Mesh):
+def build_compact_scatter(mesh: Mesh, carry_shardings=None):
     """Jitted ``scatter(carry, small_carry, idx) -> carry``: write the
     compacted rows back into their big-batch slots (only the big carry
     is donated — the small rows land inside larger output buffers;
@@ -1139,13 +1175,12 @@ def build_compact_scatter(mesh: Mesh):
         return (scatter_cache_slots(cache, s_cache, idx),
                 x.at[idx].set(s_x))
 
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
     # only the big carry is donated: the small rows land inside larger
     # output buffers, so their donation could never be honoured
     return jax.jit(
         scatter,
         donate_argnums=(0,),
-        out_shardings=(cache_shardings(mesh), x_sh),
+        out_shardings=carry_shardings or _carry_shardings(mesh),
     )
 
 
@@ -1925,26 +1960,68 @@ class ServingEngine:
                        else init_params_sharded(config, jax.random.key(seed),
                                                 mesh))
         self._prefill_jits: dict[int, Any] = {}
-        self._decode = build_decode_step(config, mesh,
-                                         quantized=self._quantized)
         self._fused_ks = serving.fused_horizons
-        self._decode_fused = {
-            k: build_decode_fused(config, mesh, k,
-                                  quantized=self._quantized)
-            for k in self._fused_ks
-        }
+        # a layer_types model (models/hybrid.py) runs the programs of
+        # serve/hybrid.py under the same names, through the same
+        # scheduler: token ids in, tokens fed back on the device
+        self._hybrid = None
+        carry_sh = None
+        if config.is_hybrid:
+            from dlbb_tpu.serve import hybrid as serve_hybrid
+
+            self._hybrid = serve_hybrid
+            self._probe_rids: tuple[int, ...] = ()
+            self.probed: dict[int, dict[str, Any]] = {}
+            # -1 names no probed request (the programs then return the
+            # last slot's logits, which nobody keeps)
+            self._probe_slots = np.full((serve_hybrid.PROBES,), -1, np.int32)
+            self._probe_dev = jnp.array(self._probe_slots)  # a copy
+            self._decode = self._with_probe(
+                serve_hybrid.build_decode_step(config, mesh))
+            self._decode_fused = {
+                k: self._with_probe(
+                    serve_hybrid.build_decode_fused(config, mesh, k))
+                for k in self._fused_ks
+            }
+            carry_sh = (serve_hybrid.hybrid_cache_shardings(mesh),
+                        NamedSharding(mesh, serve_hybrid.token_spec(mesh)))
+            self.registry.inc(
+                "serve_state_resets", 0,
+                help="recycled slots whose recurrent state a new "
+                     "request's first prompt chunk cleared")
+            self.registry.set_gauge(
+                "serve_state_bytes",
+                state_cache_bytes(config, serving.max_batch),
+                help="bytes of slot-indexed recurrent state and "
+                     "convolution inputs the cache holds")
+            self.registry.set_gauge(
+                "serve_kv_bytes",
+                kv_cache_bytes(config, serving.max_batch, serving.max_seq,
+                               tp=self.tp),
+                help="bytes of paged K/V the cache holds (full-attention "
+                     "layers only)")
+        else:
+            self._decode = build_decode_step(config, mesh,
+                                             quantized=self._quantized)
+            self._decode_fused = {
+                k: build_decode_fused(config, mesh, k,
+                                      quantized=self._quantized)
+                for k in self._fused_ks
+            }
         self._prefill_chunk_jits: dict[int, Any] = {}
         self._attach_jits: dict[int, Any] = {}
         self._compact_gather_fn = None
         self._compact_scatter_fn = None
         if serving.compact_threshold is not None:
-            self._compact_gather_fn = build_compact_gather(mesh)
-            self._compact_scatter_fn = build_compact_scatter(mesh)
+            self._compact_gather_fn = build_compact_gather(mesh, carry_sh)
+            self._compact_scatter_fn = build_compact_scatter(mesh, carry_sh)
         self._fast = (serving.decode_horizon > 1
                       or serving.inflight_window > 1
                       or serving.prefill_chunk is not None
                       or serving.compact_threshold is not None)
-        self._inject = jax.jit(_inject_token, donate_argnums=(0,))
+        self._inject = jax.jit(
+            self._hybrid.inject_token if self._hybrid else _inject_token,
+            donate_argnums=(0,))
         self._x_sharding = NamedSharding(mesh, decode_batch_spec(mesh))
         self._active_sharding = NamedSharding(mesh, P())
         # -- speculative decoding (docs/serving.md) --
@@ -2036,7 +2113,96 @@ class ServingEngine:
 
     # -- setup -------------------------------------------------------------
 
+    def _with_probe(self, program):
+        """A hybrid decode program under the GPT programs' signature
+        ``(carry, params, active[, remaining]) -> (carry, ys)``: the
+        probed slots go in as its last argument, and ``ys`` is the
+        pair ``(tokens, logits of the probed slots)``."""
+        def call(carry, params, *masks, probe=None):
+            carry, toks, seen = program(
+                carry, params, *masks,
+                self._probe_dev if probe is None else probe)
+            return carry, (toks, seen)
+        return call
+
+    def probe(self, rids) -> None:
+        """Keep, for the requests ``rids`` (at most ``PROBES`` resident
+        at once), the logits the serving programs themselves produced:
+        the last prompt position's and every decode step's, held on the
+        device with the committed tokens until :meth:`probe_results`
+        fetches them.  Nothing is synced or copied to the host while a
+        trace is served, and the same programs run whether or not a
+        request is probed.  Each ``run_trace`` starts the record anew.
+        Only ``layer_types`` models return logits."""
+        if self._hybrid is None:
+            raise ValueError("probe() needs a layer_types model: the GPT "
+                             "block has no vocabulary and no logits")
+        self._probe_rids = tuple(int(r) for r in rids)
+
+    def probe_results(self) -> dict[int, dict[str, Any]]:
+        """``rid -> {"slot", "recycled", "prompt_ids", "tokens",
+        "logits", "prompt_state", "end_state"}`` of the last
+        ``run_trace``: ``logits[i]`` (float32 ``[vocab]``, numpy) are the
+        logits token ``tokens[i]`` was the ``argmax`` of; ``recycled``
+        says whether the slot had served another request before; the two
+        states are the slot's recurrent state ``[L_lin, heads, d_v,
+        d_k]`` after the prompt and after the last decode step (which
+        took in ``tokens[-2]``; None if the request did not finish)."""
+        out = {}
+        for rid, rec in self.probed.items():
+            logits = [np.asarray(rec["first_logits"])]
+            tokens = [int(np.argmax(logits[0]))]
+            for toks, seen, row, col, steps in rec["units"]:
+                toks, seen = np.asarray(toks), np.asarray(seen)
+                if toks.ndim == 1:          # a per-step unit
+                    toks, seen = toks[None], seen[None]
+                tokens += [int(t) for t in toks[:steps, row]]
+                logits += [seen[i, col] for i in range(steps)]
+            out[rid] = {"slot": rec["slot"], "recycled": rec["recycled"],
+                        "prompt_ids": rec["prompt_ids"], "tokens": tokens,
+                        "logits": logits,
+                        "prompt_state": np.asarray(rec["prompt_state"]),
+                        "end_state": (None if rec["end_state"] is None
+                                      else np.asarray(rec["end_state"]))}
+        return out
+
+    def _probe_slot(self, req: Request, slot: int, recycled: bool,
+                    first_logits: jax.Array, cache: Any) -> None:
+        """Start the record of a probed request just admitted into
+        ``slot``, and name the slot to the decode programs (in the
+        place of a probed request that is done, else the first)."""
+        done = [i for i, s in enumerate(self._probe_slots)
+                if not any(r["slot"] == s and not r["done"]
+                           for r in self.probed.values())]
+        self._probe_slots[done[0] if done else 0] = slot
+        self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self.probed[req.rid] = {
+            "slot": slot, "recycled": recycled, "done": False,
+            "prompt_ids": prompt_ids_from_seed(
+                req.seed, req.prompt_len, self.config.vocab_size)[0],
+            "first_logits": first_logits, "units": [],
+            # the slot's recurrent state as the prompt's last chunk
+            # left it: a copy of one slot, dispatched and not waited for
+            "prompt_state": self._hybrid.slot_state(cache, np.int32(slot)),
+            "end_state": None}
+
+    def _prompt_input(self, req: Request, pad_to: int) -> jax.Array:
+        """A request's prompt as the prefill programs take it: seeded
+        embeddings ``[1, pad_to, hidden]``, or for a ``layer_types``
+        model token ids ``[1, pad_to]`` (embedded on the device)."""
+        if self._hybrid is not None:
+            return jnp.asarray(prompt_ids_from_seed(
+                req.seed, req.prompt_len, self.config.vocab_size,
+                pad_to=pad_to))
+        return request_embeddings(
+            req.seed, req.prompt_len, self.config.hidden_size,
+            dtype=self._dtype, pad_to=pad_to,
+            prefix_len=req.prefix_len, prefix_seed=req.prefix_seed)
+
     def _fresh_carry(self):
+        if self._hybrid is not None:
+            return self._hybrid.fresh_carry(self.config, self.serving,
+                                            self.mesh)
         create = (create_quant_kv_cache if self._quantized
                   else create_kv_cache)
         cache = create(
@@ -2049,6 +2215,12 @@ class ServingEngine:
             self._x_sharding,
         )
         return (cache, x)
+
+    def _create_prefix(self):
+        """The carry a prompt's first chunk starts from."""
+        if self._hybrid is not None:
+            return self._hybrid.create_prefix(self.config, self.mesh)
+        return create_prefix(self.config, self.mesh)
 
     def _fresh_draft_cache(self) -> Optional[KVCache]:
         """The draft model's own paged KV plane (same slot/block
@@ -2076,6 +2248,11 @@ class ServingEngine:
         sweep captures are."""
         from dlbb_tpu.obs import capture as obs_capture
 
+        if self._hybrid is not None:
+            raise ValueError(
+                "capture_device_traces is not wired for layer_types "
+                "models (it replays a monolithic prefill, which they do "
+                "not have); trace a run with benchmarks/run.py --trace 1")
         cfg = self.serving
         bucket = cfg.prefill_buckets[0]
 
@@ -2197,9 +2374,13 @@ class ServingEngine:
         jit = self._prefill_chunk_jits.get(chunk_index)
         if jit is None:
             chunk = self.serving.prefill_chunk
-            jit = build_prefill_chunk(self.config, self.mesh, chunk,
-                                      chunk_index * chunk,
-                                      quantized=self._quantized)
+            if self._hybrid is not None:
+                jit = self._hybrid.build_prefill_chunk(
+                    self.config, self.mesh, chunk, chunk_index * chunk)
+            else:
+                jit = build_prefill_chunk(self.config, self.mesh, chunk,
+                                          chunk_index * chunk,
+                                          quantized=self._quantized)
             self._prefill_chunk_jits[chunk_index] = jit
         return jit
 
@@ -2236,9 +2417,10 @@ class ServingEngine:
         if max_chunks:
             chunk = cfg.prefill_chunk
             total = max_chunks * chunk
-            dummy = request_embeddings(0, total, self.config.hidden_size,
-                                       dtype=self._dtype, pad_to=total)
-            prefix = create_prefix(self.config, self.mesh)
+            dummy = self._prompt_input(
+                Request(rid=-1, arrival_s=0.0, prompt_len=total,
+                        output_len=1, seed=0), total)
+            prefix = self._create_prefix()
             cache = carry[0]
             for ci in range(max_chunks):
                 cache, prefix, y_last = self._chunk_jit(ci)(
@@ -2311,6 +2493,8 @@ class ServingEngine:
             jax.block_until_ready(carry[1])
             return
         carry = self._inject(carry, np.int32(0), y_last)
+        if self._hybrid is not None:
+            self._hybrid.slot_state(carry[0], np.int32(0))
         carry, _y = self._decode(carry, self.params, active)
         for k in self._fused_ks:
             carry, _ys = self._decode_fused[k](carry, self.params, active,
@@ -2440,8 +2624,16 @@ class ServingEngine:
             series["shared_blocks"] = []
         carry = self._fresh_carry()
         active_np = np.zeros((cfg.max_batch,), bool)
-        active_dev = jax.device_put(jnp.asarray(active_np),
+        active_dev = jax.device_put(jnp.array(active_np),
                                     self._active_sharding)
+        # layer_types models: slots that have served a request in this
+        # run (the next one's first chunk clears their state), and the
+        # record of the probed requests (``probe``)
+        used_slots: set[int] = set()
+        if self._hybrid is not None:
+            self.probed = {}
+            self._probe_slots[:] = -1
+            self._probe_dev = jnp.array(self._probe_slots)  # a copy
         rejected_detail: list[dict[str, Any]] = []
         tokens_by_rid: dict[int, list[int]] = {}
         # -- speculative decoding state (docs/serving.md) --
@@ -2486,9 +2678,13 @@ class ServingEngine:
         active_dirty = [False]
 
         def refresh_active() -> None:
+            # ``jnp.array``, a copy: ``active_np`` is edited in place
+            # after a unit is dispatched, and on the CPU backend
+            # ``jnp.asarray`` may alias an aligned numpy buffer, so that a
+            # unit not yet run would see a completing slot as inactive
             nonlocal active_dev
             if active_dirty[0]:
-                active_dev = jax.device_put(jnp.asarray(active_np),
+                active_dev = jax.device_put(jnp.array(active_np),
                                             self._active_sharding)
                 active_dirty[0] = False
 
@@ -2504,6 +2700,8 @@ class ServingEngine:
             active_dirty[0] = True
             free_slots.append(slot)
             free_slots.sort()
+            if self._hybrid is not None and st.req.rid in self.probed:
+                self.probed[st.req.rid]["done"] = True
             return st
 
         def finish(st: _SlotState, done_at: float) -> None:
@@ -2794,10 +2992,18 @@ class ServingEngine:
                     s_rem = jax.device_put(jnp.asarray(s_rem_np),
                                            self._active_sharding)
 
+                    extra = {}
+                    if self._hybrid is not None:
+                        # the probed slots by their rows in the
+                        # compacted batch
+                        extra["probe"] = jnp.array(
+                            [act.index(s) if s in act else -1
+                             for s in self._probe_slots], jnp.int32)
+
                     def compact_unit():
                         small = self._compact_gather_fn(carry, idx)
                         small, ys = self._decode_fused[k](
-                            small, self.params, s_act, s_rem)
+                            small, self.params, s_act, s_rem, **extra)
                         return (self._compact_scatter_fn(carry, small,
                                                          idx), ys)
 
@@ -2873,11 +3079,31 @@ class ServingEngine:
                 stats.decode_steps += k
                 stats.decode_units += 1
                 self.registry.inc("serve_decode_steps", k)
+                if self._hybrid is not None:
+                    # ys = (tokens, logits of the probed slots): a
+                    # probed request keeps both, on the device
+                    toks, seen = ys
+                    for row, slot_, rid, m in rows:
+                        if rid in self.probed:
+                            col = int(np.flatnonzero(
+                                self._probe_slots == slot_)[0])
+                            self.probed[rid]["units"].append(
+                                (toks, seen, row, col, m))
+                    ys = toks
+                    for s in completions:
+                        # a probed request's state as its last step
+                        # left it, copied before the slot is given away
+                        rec = self.probed.get(slots[s].req.rid)
+                        if rec is not None:
+                            rec["end_state"] = self._hybrid.slot_state(
+                                carry[0], np.int32(s))
                 done_states = [release(s) for s in completions]
                 if completions:
                     refresh_active()
                 inflight.append({"t0": t0, "ys": ys, "k_exec": k,
-                                 "rows": rows, "tokens": token_mode,
+                                 "rows": rows,
+                                 "tokens": (token_mode
+                                            or self._hybrid is not None),
                                  "completions": done_states})
                 # a k==1 unit's y is the SAME logical value as the
                 # carry's x (decode_step returns ((cache, y), y)); on
@@ -3436,13 +3662,7 @@ class ServingEngine:
                         m_chunks = plan["attach_tokens"] // chunk
                 with spans.span("serve-admit-embed", rid=req.rid,
                                 slot=slot):
-                    x_prompt = request_embeddings(
-                        req.seed, req.prompt_len,
-                        self.config.hidden_size,
-                        dtype=self._dtype, pad_to=bucket,
-                        prefix_len=req.prefix_len,
-                        prefix_seed=req.prefix_seed,
-                    )
+                    x_prompt = self._prompt_input(req, bucket)
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot,
                                 chunks=n_chunks - m_chunks):
@@ -3464,7 +3684,7 @@ class ServingEngine:
                                 np.int32(slot))
                         plan["attached_tokens"] = m_chunks * chunk
                     else:
-                        prefix = create_prefix(self.config, self.mesh)
+                        prefix = self._create_prefix()
                     for ci in range(m_chunks, n_chunks):
                         with spans.span("serve-prefill-chunk",
                                         rid=req.rid, chunk=ci):
@@ -3763,6 +3983,20 @@ class ServingEngine:
                             else:
                                 carry = self._inject(carry, np.int32(slot),
                                                      y_last)
+                            if self._hybrid is not None:
+                                recycled = slot in used_slots
+                                used_slots.add(slot)
+                                if recycled:
+                                    # the prompt's first chunk started
+                                    # from a zero state and overwrote
+                                    # what the slot's last request left
+                                    spans.instant("state-reset",
+                                                  cat="request",
+                                                  rid=req.rid, slot=slot)
+                                    self.registry.inc("serve_state_resets")
+                                if req.rid in self._probe_rids:
+                                    self._probe_slot(req, slot, recycled,
+                                                     y_last, carry[0])
                         with spans.span("serve-admit-book", rid=req.rid,
                                         slot=slot):
                             ledger.append(slot, req.prompt_len)

@@ -78,7 +78,12 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.compat import shard_map
-from dlbb_tpu.models.configs import ModelConfig
+from dlbb_tpu.models.configs import (
+    FULL_ATTENTION,
+    LINEAR_ATTENTION,
+    ModelConfig,
+    cache_kv_heads,
+)
 from dlbb_tpu.models.transformer import SERVE_PHASES, _dtype_of
 
 
@@ -135,8 +140,8 @@ def create_kv_cache(
     (jit with explicit out-shardings — same trick as
     ``init_params_sharded``: no device ever holds the replicated cache)."""
     dtype = _dtype_of(config.dtype)
-    shape = (config.num_layers, max_batch, num_blocks, block_size,
-             config.kv_heads, config.head_dim)
+    shape = (config.layers_of(FULL_ATTENTION), max_batch, num_blocks,
+             block_size, config.kv_heads, config.head_dim)
 
     def build() -> KVCache:
         return KVCache(
@@ -218,31 +223,136 @@ def copy_slot_blocks(plane: jax.Array, src: jax.Array, dst: jax.Array,
     return plane, jax.lax.dynamic_slice(plane, (0, dst) + zeros, size)[:, 0]
 
 
-def gather_cache_slots(cache: KVCache, idx: jax.Array) -> KVCache:
+def gather_cache_slots(cache, idx: jax.Array):
     """Repack the slots named by ``idx`` (``[b'] int32``, b' <
     max_batch) into a smaller cache — the device half of slot
     compaction (``serve/engine.py``).  The slot dim must be UNSHARDED
     (dp=1, enforced by ``ServingConfig.validate``): then the take is a
     purely local gather and the compaction jit lowers to zero
-    collectives (audited — ``serve/engine.py::compact[tp]``)."""
-    return KVCache(
-        k=jnp.take(cache.k, idx, axis=1),
-        v=jnp.take(cache.v, idx, axis=1),
-        lengths=jnp.take(cache.lengths, idx, axis=0),
+    collectives (audited — ``serve/engine.py::compact[tp]``).  Every
+    plane of a :class:`KVCache` or a :class:`HybridCache` has its slots
+    on axis 1, so a slot's recurrent state moves with its K/V."""
+    return type(cache)(
+        *(jnp.take(plane, idx, axis=1) for plane in cache[:-1]),
+        jnp.take(cache.lengths, idx, axis=0),
     )
 
 
-def scatter_cache_slots(cache: KVCache, small: KVCache,
-                        idx: jax.Array) -> KVCache:
+def scatter_cache_slots(cache, small, idx: jax.Array):
     """Write a compacted cache's rows back into their big-batch slots
     (inverse of :func:`gather_cache_slots`; ``idx`` rows must be
     distinct — the engine pads the active-slot list with distinct FREE
     slots, never duplicates, so the scatter is well-defined)."""
-    return KVCache(
-        k=cache.k.at[:, idx].set(small.k),
-        v=cache.v.at[:, idx].set(small.v),
-        lengths=cache.lengths.at[idx].set(small.lengths),
+    return type(cache)(
+        *(plane.at[:, idx].set(rows)
+          for plane, rows in zip(cache[:-1], small[:-1])),
+        cache.lengths.at[idx].set(small.lengths),
     )
+
+
+# ---------------------------------------------------------------------------
+# two kinds of state in one cache (``ModelConfig.layer_types``)
+# ---------------------------------------------------------------------------
+
+
+class HybridCache(NamedTuple):
+    """The cache of a model whose layers are of two kinds.  The
+    ``full_attention`` layers keep paged K/V planes exactly as
+    :class:`KVCache` does, but only for themselves (``L_full`` of the
+    layers).  The ``linear_attention`` layers keep, per layer and SLOT,
+    a float32 recurrent state and the last ``conv_kernel - 1`` inputs of
+    their short convolution: not paged and not growing with the slot's
+    length, so the :class:`BlockLedger` never counts them (its blocks
+    are K/V blocks of the full layers) and the build-time gate prices
+    them apart (``models.configs.state_cache_bytes``).
+
+    A slot's state is valid from the prefill that claimed the slot: the
+    first prompt chunk starts from a ZERO state
+    (``serve/hybrid.py::create_prefix``) and every chunk overwrites the
+    slot's state with what it carried, so nothing of the slot's previous
+    request survives."""
+
+    # kvh: ``models.configs.cache_kv_heads`` (whole tiles of 8 heads)
+    k: jax.Array        # [L_full, max_batch, num_blocks, block_size, kvh, d]
+    v: jax.Array        # same
+    state: jax.Array    # f32 [L_lin, max_batch, heads, d_v, d_k]
+    conv: jax.Array     # [L_lin, max_batch, conv_kernel - 1, heads, 2 d_k + d_v]
+    lengths: jax.Array  # [max_batch] int32
+
+    @property
+    def max_batch(self) -> int:
+        return self.k.shape[1]
+
+    @property
+    def num_blocks(self) -> int:
+        return self.k.shape[2]
+
+    @property
+    def block_size(self) -> int:
+        return self.k.shape[3]
+
+    @property
+    def max_seq(self) -> int:
+        return self.num_blocks * self.block_size
+
+
+def hybrid_cache_specs(mesh: Optional[Mesh]) -> HybridCache:
+    """PartitionSpecs for :class:`HybridCache`: K/V as
+    :func:`cache_specs`; state and convolution inputs with their slots
+    over ``dp`` and their heads over ``tp``."""
+    kv = cache_specs(mesh)
+    dp, tp = kv.k[1], kv.k[4]
+    return HybridCache(k=kv.k, v=kv.v,
+                       state=P(None, dp, tp, None, None),
+                       conv=P(None, dp, None, tp, None),
+                       lengths=kv.lengths)
+
+
+def hybrid_cache_shardings(mesh: Mesh) -> HybridCache:
+    return jax.tree.map(
+        lambda s: NamedSharding(mesh, s), hybrid_cache_specs(mesh),
+        is_leaf=lambda x: isinstance(x, P),
+    )
+
+
+def create_hybrid_cache(config: ModelConfig, max_batch: int,
+                        num_blocks: int, block_size: int,
+                        mesh: Optional[Mesh] = None,
+                        state_dtype=jnp.float32) -> HybridCache:
+    """Zero-initialised, created directly on its shards."""
+    dtype = _dtype_of(config.dtype)
+    n_lin = config.layers_of(LINEAR_ATTENTION)
+    heads = config.linear_num_value_heads
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    kv_shape = (config.layers_of(FULL_ATTENTION), max_batch, num_blocks,
+                block_size, cache_kv_heads(config, tp), config.head_dim)
+    state_shape = (n_lin, max_batch, heads, config.linear_value_head_dim,
+                   config.linear_key_head_dim)
+    conv_shape = (n_lin, max_batch, config.linear_conv_kernel_dim - 1,
+                  heads, config.linear_conv_channels // heads)
+
+    def build() -> HybridCache:
+        return HybridCache(
+            k=jnp.zeros(kv_shape, dtype), v=jnp.zeros(kv_shape, dtype),
+            state=jnp.zeros(state_shape, state_dtype),
+            conv=jnp.zeros(conv_shape, dtype),
+            lengths=jnp.zeros((max_batch,), jnp.int32),
+        )
+
+    if mesh is None:
+        return build()
+    return jax.jit(build, out_shardings=hybrid_cache_shardings(mesh))()
+
+
+def write_slot_state(plane: jax.Array, value: jax.Array, layer: jax.Array,
+                     slot: jax.Array) -> jax.Array:
+    """One slot's state (or convolution inputs) of one layer into a
+    plane ``[L, B, ...]``: a ``dynamic_update_slice`` at ``(layer,
+    slot)``, in place in a carried plane, as :func:`write_slot_blocks`
+    writes a slot's K/V blocks."""
+    start = (layer, slot) + (0,) * (plane.ndim - 2)
+    return jax.lax.dynamic_update_slice(
+        plane, value[None, None].astype(plane.dtype), start)
 
 
 # ---------------------------------------------------------------------------

@@ -241,6 +241,12 @@ def make_train_step(
     The returned step donates its state argument, and the ``device_put``
     here may alias the caller's ``params`` buffers — treat the input
     ``params`` pytree as consumed once the first step has run."""
+    if config.is_hybrid:
+        raise ValueError(
+            "the train step is not implemented for layer_types models: "
+            "the chunked gated delta rule has no backward pass here and "
+            "the loss is over hidden states, not a vocabulary "
+            "(ROADMAP.md, Queue 2)")
     if grad_accum < 1:
         raise ValueError(f"grad_accum must be >= 1, got {grad_accum}")
     if pipeline_schedule not in ("gpipe", "1f1b"):
