@@ -382,10 +382,13 @@ def merge_reports(partial: dict[str, Any],
 
     fast = dict(resumed.get("fast_path", {}))
     for key in ("fused_scans", "fused_steps", "single_steps",
-                "prefill_chunks", "compacted_scans"):
+                "prefill_chunks", "compacted_scans", "kv_tiles_live",
+                "kv_tiles_held"):
         fast[key] = (partial.get("fast_path", {}).get(key, 0)
                      + resumed.get("fast_path", {}).get(key, 0))
     merged["fast_path"] = fast
+    merged["kv_live_share"] = (fast["kv_tiles_live"] / fast["kv_tiles_held"]
+                               if fast["kv_tiles_held"] else 0.0)
 
     res_a = partial.get("resilience", {})
     res_b = resumed.get("resilience", {})

@@ -10,8 +10,10 @@ Two jitted device programs, fixed shapes for the whole run:
   request's FIRST generated token (TTFT stops here).
 - **decode_step** (one compile, ``[max_batch, 1, H]``): appends each
   active slot's pending token to the cache at its own length, attends
-  over the slot's valid prefix (length-masked, GQA-grouped at
-  ``kv_heads`` width), and produces every active slot's next token.
+  over the slot's valid prefix (GQA-grouped at ``kv_heads`` width; the
+  fp layout through ``ops/decode_attention.py``, which fetches only the
+  tiles of tokens a slot holds), and produces every active slot's next
+  token.
   The output hidden state IS the next step's input embedding (the model
   is its own next-token function — same convention as the chained
   timing loop), so the decode carry ``(cache, x)`` feeds back without
@@ -134,6 +136,12 @@ from dlbb_tpu.models.transformer import (
 )
 from dlbb_tpu.obs import spans
 from dlbb_tpu.obs.export import MetricsRegistry
+from dlbb_tpu.ops.decode_attention import (
+    check_kernel_takes,
+    decode_attention,
+    live_tile_counts,
+    plane_tile_tokens,
+)
 from dlbb_tpu.resilience import inject
 from dlbb_tpu.resilience.errors import (
     CorruptStats,
@@ -817,10 +825,10 @@ def _scan_layers(h, layers, planes, config: ModelConfig, attention_step,
     whole-cache copies a program run on the v5e (``PERF.md`` §6, PR 26).
 
     ``attention_step(q, k, v, (l, planes, *xs_l)) -> (attn, (planes,
-    ys_l))`` reads layer ``l`` of a plane by ``_layer_tokens`` and writes it
-    by the helpers; ``xs`` are further per-layer inputs (a chunk's
-    prefix K/V), ``ys_l`` per-layer outputs.  Returns ``(h, planes,
-    ys)``."""
+    ys_l))`` reads layer ``l`` of a plane by ``decode_attention`` (or
+    ``_layer_tokens``) and writes it by the helpers; ``xs`` are further
+    per-layer inputs (a chunk's prefix K/V), ``ys_l`` per-layer outputs.
+    Returns ``(h, planes, ys)``."""
     def body(carry, layer_xs):
         h, l, planes = carry
         layer, *extra = layer_xs
@@ -1223,12 +1231,11 @@ def _decode_step_math(carry, params, active, config: ModelConfig,
             attn, planes = quant_append_attend(qh, k_new, v_new, l, planes)
         else:
             # append at each active slot's own length, in place in the
-            # carried planes, then read the layer back for attention
+            # carried planes, then attend the tokens each slot holds
             k_c, v_c = planes
             k_c = append_token_rows(k_c, k_new, l, lengths, active, mesh)
             v_c = append_token_rows(v_c, v_new, l, lengths, active, mesh)
-            attn = _cached_attention(qh, _layer_tokens(k_c, l),
-                                     _layer_tokens(v_c, l), valid)
+            attn = decode_attention(qh, k_c, v_c, l, lengths, active, mesh)
             planes = (k_c, v_c)
         return (attn.transpose(0, 2, 1, 3).reshape(b_dim, 1, n * d),
                 (planes, None))
@@ -1855,6 +1862,10 @@ class _RunStats:
     single_steps: int = 0
     prefill_chunks: int = 0
     compacted_scans: int = 0
+    # K/V tiles the decode units' steps fetched (ops/decode_attention.py)
+    # and tiles the planes they ran over hold, times steps
+    kv_tiles_live: int = 0
+    kv_tiles_held: int = 0
     # resilience accounting (docs/resilience.md, serving-faults section)
     retries: int = 0
     hung_dispatches: int = 0
@@ -2024,6 +2035,23 @@ class ServingEngine:
             donate_argnums=(0,))
         self._x_sharding = NamedSharding(mesh, decode_batch_spec(mesh))
         self._active_sharding = NamedSharding(mesh, P())
+        # the fp layout's decode attention fetches tiles of this many
+        # tokens under each slot's length: the kernel's own reckoning
+        # from the K plane this engine carries (the int8 layout reads the
+        # whole layer and counts nothing)
+        self._kv_tile = 0
+        if not self._quantized:
+            k_plane = jax.eval_shape(self._fresh_carry)[0].k
+            check_kernel_takes(k_plane, mesh)
+            self._kv_tile = plane_tile_tokens(k_plane, mesh)
+            for name, hlp in (
+                ("serve_kv_tiles_live",
+                 "K/V tiles of a layer the decode steps fetched (tokens "
+                 "under the active slots' lengths)"),
+                ("serve_kv_tiles_held",
+                 "K/V tiles of a layer the planes hold, times decode steps"),
+            ):
+                self.registry.inc(name, 0, help=hlp)
         # -- speculative decoding (docs/serving.md) --
         # token-feedback modes quantise decode through the greedy token
         # table; the legacy jits above stay built (jax.jit is lazy, so
@@ -3034,6 +3062,22 @@ class ServingEngine:
                     self.registry.inc("serve_fused_scan_steps", k)
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, steps[s]))
+                if self._kv_tile:
+                    # what the unit's steps fetch of the K/V planes: step
+                    # i of a slot reads the tiles under its length + i
+                    max_tiles = cfg.max_seq // self._kv_tile
+                    trip = np.arange(k)[:, None]
+                    start = np.array([ledger.tokens(s) for s in steps])
+                    live = int(live_tile_counts(
+                        start[None, :] + trip,
+                        trip < np.array(list(steps.values()))[None, :],
+                        self._kv_tile, max_tiles).sum())
+                    held = k * max_tiles * (cfg.max_batch // 2 if compact
+                                            else cfg.max_batch)
+                    stats.kv_tiles_live += live
+                    stats.kv_tiles_held += held
+                    self.registry.inc("serve_kv_tiles_live", live)
+                    self.registry.inc("serve_kv_tiles_held", held)
                 # host bookkeeping at scan exit: the ledger's known
                 # lengths make every step's outcome deterministic at
                 # dispatch time.  A torn half-applied update
@@ -4180,6 +4224,9 @@ class ServingEngine:
             "generated_tokens": stats.generated_tokens,
             "decode_steps": stats.decode_steps,
             "decode_units": stats.decode_units,
+            # share of the K/V planes' tiles the decode steps fetched
+            "kv_live_share": (stats.kv_tiles_live / stats.kv_tiles_held
+                              if stats.kv_tiles_held else 0.0),
             "fast_path": {
                 "enabled": self._fast,
                 "decode_horizon": cfg.decode_horizon,
@@ -4191,6 +4238,8 @@ class ServingEngine:
                 "single_steps": stats.single_steps,
                 "prefill_chunks": stats.prefill_chunks,
                 "compacted_scans": stats.compacted_scans,
+                "kv_tiles_live": stats.kv_tiles_live,
+                "kv_tiles_held": stats.kv_tiles_held,
             },
             "speculation": {
                 "mode": cfg.speculation,

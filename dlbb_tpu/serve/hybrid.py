@@ -44,6 +44,7 @@ from dlbb_tpu.models.configs import (
 )
 from dlbb_tpu.models.hybrid import LIN_CONV, LIN_CORE
 from dlbb_tpu.models.transformer import _dtype_of, named
+from dlbb_tpu.ops.decode_attention import decode_attention
 from dlbb_tpu.ops.gated_delta import (
     causal_conv,
     gated_delta_chunked,
@@ -226,19 +227,16 @@ class DecodeMixer:
         return None
 
     def attention(self, q, k, v, l, planes):
-        eng = _engine()
         k_c, v_c, st, cv = planes
         heads, held = q.shape[2], k_c.shape[-2]
         k_c = append_token_rows(k_c, _pad_heads(k, held), l, self.lengths,
                                 self.active, self.mesh)
         v_c = append_token_rows(v_c, _pad_heads(v, held), l, self.lengths,
                                 self.active, self.mesh)
-        s_max = k_c.shape[2] * k_c.shape[3]
-        valid = jnp.arange(s_max)[None, :] <= self.lengths[:, None]
         # the plane's added heads are attended by zero queries and cut
-        attn = eng._cached_attention(
-            _pad_heads(q, held).transpose(0, 2, 1, 3),
-            eng._layer_tokens(k_c, l), eng._layer_tokens(v_c, l), valid)
+        attn = decode_attention(
+            _pad_heads(q, held).transpose(0, 2, 1, 3), k_c, v_c, l,
+            self.lengths, self.active, self.mesh)
         return (attn.transpose(0, 2, 1, 3)[:, :, :heads],
                 (k_c, v_c, st, cv))
 

@@ -736,6 +736,11 @@ class BlockLedger:
             )
         self.peak_in_use = max(self.peak_in_use, self.blocks_in_use)
 
+    def tokens(self, slot: int) -> int:
+        """Tokens appended to ``slot`` so far: the slot's length on the
+        device before the next decode step."""
+        return self._tokens[slot]
+
     def free(self, slot: int) -> int:
         """Release a slot's reservation; returns the blocks actually
         returned to the pool: its private blocks plus every shared

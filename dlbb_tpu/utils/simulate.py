@@ -17,8 +17,10 @@ measures) calls it for library users.
 
 from __future__ import annotations
 
+import importlib
 import os
 import re
+import threading
 from typing import Any, Optional
 
 # Set by force_cpu_simulation: the CPU backend was explicitly requested
@@ -61,12 +63,37 @@ def simulation_forced() -> bool:
     return _SIMULATION_FORCED
 
 
+_PALLAS_IMPORT: Optional[threading.Thread] = None
+
+
+def _import_pallas_meanwhile() -> None:
+    """Start importing Pallas on a thread of its own, once.  Every device
+    command's programs hold a Pallas kernel (``ops/``) and the import
+    takes 1.5 s on the chip's host (two thirds of it Mosaic GPU, which
+    ``pallas_call`` imports whatever the backend; ``PERF.md`` §6, PR 28).
+    The caller's next step starts the TPU client, 4-5 s in which the main
+    thread waits outside the interpreter: the import runs then.  Whoever
+    imports the same modules before it is done waits on the import lock
+    for what is left, as for any import."""
+    global _PALLAS_IMPORT
+    if _PALLAS_IMPORT is None:
+        _PALLAS_IMPORT = threading.Thread(
+            target=importlib.import_module,
+            args=("jax.experimental.pallas.tpu",),
+            name="import-pallas", daemon=True)
+        _PALLAS_IMPORT.start()
+
+
 def require_accelerator() -> None:
     """Raise :class:`NoAcceleratorError` when the backend is the CPU and
     :func:`force_cpu_simulation` was not called.  Initialises the backend
-    (so it must follow any ``jax.distributed`` handshake)."""
+    (so it must follow any ``jax.distributed`` handshake); where that is
+    to be an accelerator's client, Pallas is imported beside its
+    start-up."""
     import jax
 
+    if not _SIMULATION_FORCED and jax.config.jax_platforms != "cpu":
+        _import_pallas_meanwhile()
     if jax.default_backend() == "cpu" and not _SIMULATION_FORCED:
         raise NoAcceleratorError(
             "JAX found no accelerator (backend 'cpu', JAX_PLATFORMS="

@@ -647,20 +647,25 @@ def v5e_chip():
     return build_parallelism_mesh(1, 1, 1, 1, 1, devices=topo.devices[:1])
 
 
-@pytest.mark.parametrize("program", CACHE_WRITERS)
-def test_cache_writes_are_in_place_compiled_for_the_v5e(v5e_chip, program):
-    """The same, as the TPU's own compiler leaves the program at the
-    benchmark cell's widths (7B, 16 slots x 1024 tokens, bf16; 2 layers,
-    the layer loop's body does not depend on their number): the parent
-    had a whole-plane ``select`` fusion and two whole-plane ``copy`` a
-    program run here."""
+@pytest.fixture()
+def as_on_the_chip(monkeypatch):
+    """The code that asks which backend it runs on takes its TPU branch
+    (the decode kernel compiled by Mosaic, not interpreted), as in a
+    process that holds the chip."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _gpt7b_decode_shapes(mesh):
+    """The 7B serving cell's decode carry and parameters as shapes (16
+    slots x 1024 tokens, bf16; 2 layers: the layer loop's body does not
+    depend on their number)."""
     from dlbb_tpu.models.transformer import init_params
     from dlbb_tpu.serve.kvcache import KVCache, cache_shardings
 
     cfg = ModelConfig(hidden_size=4096, num_layers=2, num_heads=32,
                       ffn_intermediate=16384, dtype="bfloat16",
                       attention="full")
-    mesh, rep = v5e_chip, NamedSharding(v5e_chip, P())
+    rep = NamedSharding(mesh, P())
     params = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
         jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))))
@@ -672,11 +677,104 @@ def test_cache_writes_are_in_place_compiled_for_the_v5e(v5e_chip, program):
         jax.ShapeDtypeStruct((16,), jnp.int32, sharding=sh.lengths))
     x = jax.ShapeDtypeStruct((16, 1, cfg.hidden_size), jnp.bfloat16,
                              sharding=rep)
+    return cfg, params, cache, x, plane
+
+
+@pytest.mark.parametrize("program", CACHE_WRITERS)
+def test_cache_writes_are_in_place_compiled_for_the_v5e(v5e_chip, program,
+                                                        as_on_the_chip):
+    """The same, as the TPU's own compiler leaves the program at the
+    benchmark cell's widths: the parent had a whole-plane ``select``
+    fusion and two whole-plane ``copy`` a program run here."""
+    mesh = v5e_chip
+    cfg, params, cache, x, plane = _gpt7b_decode_shapes(mesh)
     jitted, args = _cache_writing_program(program, cfg, mesh, cache,
                                           params, x, chunk=128)
     hlo = jitted.trace(*args).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     _assert_writes_in_place(hlo, plane, "bf16")
+
+
+def _hybrid_decode_step(mesh):
+    """``serve_decode_step`` of the Olmo-Hybrid cell (32 slots x 2048
+    tokens, 30 heads held as 32) at two periods of its four."""
+    from benchmarks.harness import cells
+    from dlbb_tpu.models import hybrid
+    from dlbb_tpu.serve import hybrid as serve_hybrid
+    from dlbb_tpu.serve.kvcache import HybridCache, hybrid_cache_shardings
+
+    model = dict(cells.resolve_cell("olmohyb_longgen_backlog")
+                 .config["program"]["model"], num_layers=8)
+    cfg = ModelConfig.from_dict(model)
+    rep = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        jax.eval_shape(lambda: hybrid.init_params(cfg, jax.random.key(0))))
+    plane = (2, 32, 128, 16, 32, cfg.head_dim)
+    heads = cfg.linear_num_value_heads
+    shapes = HybridCache(
+        (plane, jnp.bfloat16), (plane, jnp.bfloat16),
+        ((6, 32, heads, cfg.linear_value_head_dim,
+          cfg.linear_key_head_dim), jnp.float32),
+        ((6, 32, cfg.linear_conv_kernel_dim - 1, heads,
+          cfg.linear_conv_channels // heads), jnp.bfloat16),
+        ((32,), jnp.int32))
+    cache = HybridCache(*(
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+        for (shape, dtype), sh in zip(shapes, hybrid_cache_shardings(mesh))))
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=rep)
+    return (serve_hybrid.build_decode_step(cfg, mesh),
+            ((cache, like((32,), jnp.int32)), params, like((32,), jnp.bool_),
+             like((serve_hybrid.PROBES,), jnp.int32)), plane)
+
+
+@pytest.mark.parametrize("family", ["gpt", "hybrid"])
+def test_decode_step_attends_through_the_kernel_compiled_for_the_v5e(
+        v5e_chip, as_on_the_chip, family):
+    """``serve_decode_step`` of both families at their cells' widths: ONE
+    Mosaic kernel, ``kv_attend_decode``, is handed the carried planes
+    whole, and nothing of a layer's shape (``[B, S_max, kvh, d]``, flat
+    or in blocks: the dense path's fp32 ``convert``, a ``copy`` in front
+    of the custom call) is left in the program."""
+    mesh = v5e_chip
+    if family == "gpt":
+        cfg, params, cache, x, plane = _gpt7b_decode_shapes(mesh)
+        jitted, args = _cache_writing_program(
+            "serve_decode_step", cfg, mesh, cache, params, x, chunk=128)
+    else:
+        jitted, args, plane = _hybrid_decode_step(mesh)
+    hlo = jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo)
+    assert len(calls) == 1 and calls[0].startswith("kv_attend_decode"), calls
+    _, b, nb, bs, kvh, d = plane
+    layer = {f"[{','.join(map(str, dims))}]" for dims in (
+        (b, nb, bs, kvh, d), (1, b, nb, bs, kvh, d), (b, nb * bs, kvh, d),
+        (1, b, nb * bs, kvh, d), (b, kvh, nb * bs, d), (b, nb * bs, kvh))}
+    left = {}
+    for line in hlo.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if (m and m.group(3) not in _PLANE_PLUMBING
+                and re.sub(r"^[a-z0-9]+|\{.*$", "", m.group(2)) in layer):
+            left[m.group(1)] = m.group(3)
+    assert not left, f"ops of a whole layer's shape: {left}"
+
+
+def test_toy_widths_are_refused_on_the_chip_when_the_engine_is_built(
+        mesh2x4, as_on_the_chip):
+    """Heads of 16 floats are no whole tiles for the kernel's copies, and
+    no dense path stands behind the kernel: on the chip an engine of
+    such a model says so when it is built (``tests/test_tpu.py`` serves
+    real head widths there); the int8 layout, which reads its layers
+    whole, is built as ever."""
+    with pytest.raises(ValueError, match="1 kv-heads of 16"):
+        ServingEngine(MODEL, ServingConfig(**SERVE), mesh2x4,
+                      verbose=False)
+    engine = ServingEngine(
+        MODEL, ServingConfig(kv_quantization="int8", **SERVE), mesh2x4,
+        verbose=False)
+    assert engine._kv_tile == 0
 
 
 def test_every_serving_program_has_a_stable_name(mesh2x4):

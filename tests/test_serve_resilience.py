@@ -111,9 +111,12 @@ def test_decode_hot_path_static_zero_injection_pin():
                         f"inject.{sub.attr} inside device program "
                         f"{node.name}")
     assert seen == device_fns, f"missing device fns: {device_fns - seen}"
-    # the KV-cache module (the other half of the device path) too
-    assert "inject" not in (
-        REPO / "dlbb_tpu" / "serve" / "kvcache.py").read_text()
+    # the KV-cache module (the other half of the device path) too, and
+    # the decode attention kernel with its wrapper
+    for module in ("serve/kvcache.py", "ops/decode_attention.py"):
+        assert "inject" not in (REPO / "dlbb_tpu" / module).read_text()
+    assert "def decode_attention(" in (
+        REPO / "dlbb_tpu" / "ops" / "decode_attention.py").read_text()
 
 
 # ---------------------------------------------------------------------------

@@ -236,13 +236,25 @@ def test_train_step_smoke_on_chip():
 
 
 def test_serve_smoke_on_chip(tmp_path):
-    """One real serving run on the chip at small widths, bf16, through the
-    fused-scan / chunked-prefill fast path: every request completes with
-    no retry, failure or carry reset, and the device reports its peak."""
+    """One real serving run on the chip at a small depth, bf16, through
+    the fused-scan / chunked-prefill fast path and the decode kernel:
+    every request completes with no retry, failure or carry reset, and
+    the device reports its peak.  Eight heads of 128 on one chip: the
+    kernel reads whole (8, 128) tiles of a shard's planes, and the CLI's
+    default toy model (heads of 16 floats) is refused on the chip."""
+    import yaml
+
     from dlbb_tpu.serve.bench import run_serve_from_config
 
+    config = tmp_path / "serve_small.yaml"
+    config.write_text(yaml.safe_dump({
+        "model": {"hidden_size": 1024, "num_layers": 2, "num_heads": 8,
+                  "ffn_intermediate": 2048, "dtype": "bfloat16",
+                  "attention": "full"},
+        "parallelism": {"data_parallel": 1, "world_size": 1},
+    }))
     report = run_serve_from_config(
-        None, trace="poisson", num_requests=12, rate=50.0, seed=7,
+        str(config), trace="poisson", num_requests=12, rate=50.0, seed=7,
         output_dir=str(tmp_path), verbose=False,
         overrides={"max_batch": 8, "max_seq": 256, "decode_horizon": 8,
                    "inflight_window": 2, "prefill_chunk": 64},
@@ -254,6 +266,7 @@ def test_serve_smoke_on_chip(tmp_path):
             res["hung_dispatches"]) == (0, 0, 0)
     assert report["fast_path"]["fused_scans"] > 0
     assert report["fast_path"]["prefill_chunks"] > 0
+    assert 0.0 < report["kv_live_share"] < 1.0
     info = report["system_info"]
     assert info["backend"] == "tpu"
     assert info["devices"][0]["memory_stats"]["peak_bytes_in_use"] > 0
