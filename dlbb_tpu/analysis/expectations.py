@@ -529,15 +529,6 @@ def verify_step_expectation(dp: int, tp: int, gamma: int,
     )
 
 
-def compact_expectation() -> TargetExpectation:
-    """Expectation for the slot-compaction gather/scatter jits
-    (``serve/engine.py``): pure LOCAL data movement — the slot dim is
-    unsharded (dp=1 is enforced at config validation), so the lowered
-    program must contain ZERO collectives.  Any collective here means
-    the repack crossed the wire and compaction cannot win."""
-    return TargetExpectation(allowed=set(), required_any=None)
-
-
 def overlap_op_expectation(p: int, chunk_bytes: int,
                            slack: float = 1.25) -> TargetExpectation:
     """Expectation for a RING-DECOMPOSED collective matmul (either op,

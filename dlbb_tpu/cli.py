@@ -355,11 +355,6 @@ def build_parser() -> argparse.ArgumentParser:
                          "block-size multiple), interleaved with decode "
                          "steps so long prompts stop head-of-line "
                          "blocking the batch (default: monolithic)")
-    sv.add_argument("--compact-threshold", type=float, default=None,
-                    dest="compact_threshold",
-                    help="occupancy fraction (0, 0.5] at or below which "
-                         "fused scans run on a gather-compacted half "
-                         "batch (dp=1 meshes only; default: off)")
     sv.add_argument("--speculation", default=None,
                     choices=["off", "greedy", "ngram", "draft-model"],
                     help="decode feedback / drafting mode: off = legacy "
@@ -963,7 +958,6 @@ def _dispatch(args) -> int:
                 "decode_horizon": args.decode_horizon,
                 "inflight_window": args.inflight_window,
                 "prefill_chunk": args.prefill_chunk,
-                "compact_threshold": args.compact_threshold,
                 "speculation": args.speculation,
                 "spec_gamma": args.spec_gamma,
                 "spec_adaptive": args.spec_adaptive,

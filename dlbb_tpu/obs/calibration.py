@@ -116,7 +116,7 @@ def measure_target(target: Any, warmup: int = 5,
     # carry protocols, probed in order: "head" feeds out[0] back as the
     # next first argument (train steps returning (state, metrics)),
     # "whole" feeds the entire output back (programs whose output IS the
-    # donated carry, e.g. the serving compaction scatter)
+    # donated carry)
     carry = None
     try:
         out = jitted(*cur_args)
@@ -234,7 +234,7 @@ def run_calibration(
             cp = base.get("critical_path_us")
             if not cp:
                 # cm1 prices this program at zero (no collectives, no
-                # dots — e.g. the serving compaction jits): nothing to
+                # dots — e.g. the serving prefix-attach jit): nothing to
                 # compare, BUT its measured time is the purest
                 # per-dispatch-γ sample the fit corpus can get, so
                 # measure it and carry the number on the skip record

@@ -257,7 +257,7 @@ def ingest_calibration(path: Path, data: dict[str, Any],
     priced with.  Micro rows alone cannot separate the per-dispatch γ
     from the per-collective α (every micro dispatch posts >= 1
     collective); a calibration row with ZERO collectives (the serving
-    compaction programs) pins γ directly, and the many-instruction train
+    prefix-attach program) pins γ directly, and the many-instruction train
     steps anchor the effective peak.  ``measured_us`` is
     model-independent, so reports priced with either model ingest
     identically."""

@@ -227,7 +227,6 @@ def serving_metrics(report: dict[str, Any],
         ("fused_scans", "fused decode scans dispatched"),
         ("fused_steps", "decode steps executed inside fused scans"),
         ("prefill_chunks", "prefill chunks processed"),
-        ("compacted_scans", "fused scans run on a compacted batch"),
     ):
         if key in fast:
             registry.set_gauge(f"serve_fastpath_{key}", fast[key])

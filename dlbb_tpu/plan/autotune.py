@@ -9,7 +9,7 @@ predict-prune-measure autotuner loop:
 
 1. **Enumerate** the full plan space for a ModelConfig + mesh:
    (dp, tp) factorizations x decode_horizon x inflight_window x
-   prefill_chunk x compact_threshold for serving targets;
+   prefill_chunk for serving targets;
    (dp, sp, pp, tp) factorizations x tp_overlap x grad_compression x
    zero_stage x attention variant (ring/ulysses when sp > 1) for train
    targets.
@@ -198,7 +198,6 @@ class PlanPoint:
     # serving knobs
     decode_horizon: int = 1
     prefill_chunk: Optional[int] = None
-    compact_threshold: Optional[float] = None
     inflight_window: int = 1
 
     def key(self) -> str:
@@ -209,8 +208,6 @@ class PlanPoint:
                      f"K{self.decode_horizon}", f"W{self.inflight_window}"]
             if self.prefill_chunk is not None:
                 parts.append(f"chunk{self.prefill_chunk}")
-            if self.compact_threshold is not None:
-                parts.append(f"compact{self.compact_threshold:g}")
             return "serve[" + ",".join(parts) + "]"
         parts = [f"dp{self.dp}", f"tp{self.tp}", f"sp{self.sp}",
                  f"pp{self.pp}"]
@@ -232,7 +229,6 @@ class PlanPoint:
             n += int(self.decode_horizon > 1)
             n += int(self.inflight_window > 1)
             n += int(self.prefill_chunk is not None)
-            n += int(self.compact_threshold is not None)
         else:
             n += int(self.tp_overlap != "off")
             n += int(self.grad_compression != "none")
@@ -258,8 +254,8 @@ def enumerate_serving_space(
     serving: dict[str, Any],
 ) -> list[PlanPoint]:
     """Full serving grid: every (dp, tp) factorization of the mesh x
-    decode horizon x in-flight window x chunked prefill {off, 2 blocks}
-    x slot compaction {off, 0.5}.  Infeasible combinations are NOT
+    decode horizon x in-flight window x chunked prefill {off, 2
+    blocks}.  Infeasible combinations are NOT
     filtered here — pruning journals them with reasons."""
     block = int(serving.get("block_size", 16))
     pts = []
@@ -267,13 +263,11 @@ def enumerate_serving_space(
         for k in SERVE_HORIZONS:
             for w in SERVE_INFLIGHT:
                 for chunk in (None, 2 * block):
-                    for compact in (None, 0.5):
-                        pts.append(PlanPoint(
-                            target="serving", dp=dp, tp=tp,
-                            decode_horizon=k, inflight_window=w,
-                            prefill_chunk=chunk,
-                            compact_threshold=compact,
-                        ))
+                    pts.append(PlanPoint(
+                        target="serving", dp=dp, tp=tp,
+                        decode_horizon=k, inflight_window=w,
+                        prefill_chunk=chunk,
+                    ))
     return pts
 
 
@@ -391,15 +385,16 @@ def prune_point(
     try:
         if point.target == "serving":
             serving = serving or DEFAULT_PLAN_SERVING
-            from dlbb_tpu.serve.engine import ServingConfig
+            from dlbb_tpu.serve.config import ServingConfig
+            from dlbb_tpu.serve.engine import family_for
 
             cfg = ServingConfig.from_dict({
                 **serving,
                 "decode_horizon": point.decode_horizon,
                 "inflight_window": point.inflight_window,
                 "prefill_chunk": point.prefill_chunk,
-                "compact_threshold": point.compact_threshold,
             })
+            family_for(model_pt).check_serving(model_pt, cfg)
             cfg.validate(model_pt, dp=point.dp, tp=point.tp)
         else:
             input_cfg = input_cfg or DEFAULT_PLAN_INPUT
@@ -678,7 +673,6 @@ def _measure_serving(
             "decode_horizon": point.decode_horizon,
             "inflight_window": point.inflight_window,
             "prefill_chunk": point.prefill_chunk,
-            "compact_threshold": point.compact_threshold,
         },
         "parallelism": {"world_size": point.tp,
                         "data_parallel": point.dp},
@@ -1177,8 +1171,7 @@ def run_capacity_plan(
                 k: best[k] for k in (
                     "target", "dp", "tp", "sp", "pp", "tp_overlap",
                     "grad_compression", "zero_stage", "attention",
-                    "decode_horizon", "prefill_chunk",
-                    "compact_threshold", "inflight_window")
+                    "decode_horizon", "prefill_chunk", "inflight_window")
             })
             if pt.key() not in {p.key() for p in plans}:
                 plans.append(pt)

@@ -37,7 +37,8 @@ from pathlib import Path
 from typing import Any, Optional, Sequence
 
 from dlbb_tpu.models.configs import ModelConfig
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine
 from dlbb_tpu.serve.traffic import TRACE_KINDS, TrafficTrace, generate_trace
 
 SERVING_MANIFEST_SCHEMA = "dlbb_serving_manifest_v1"
@@ -382,8 +383,7 @@ def merge_reports(partial: dict[str, Any],
 
     fast = dict(resumed.get("fast_path", {}))
     for key in ("fused_scans", "fused_steps", "single_steps",
-                "prefill_chunks", "compacted_scans", "kv_tiles_live",
-                "kv_tiles_held"):
+                "prefill_chunks", "kv_tiles_live", "kv_tiles_held"):
         fast[key] = (partial.get("fast_path", {}).get(key, 0)
                      + resumed.get("fast_path", {}).get(key, 0))
     merged["fast_path"] = fast
@@ -595,7 +595,7 @@ def run_serve_from_config(
 ) -> dict[str, Any]:
     """CLI entry: optional experiment YAML + flag overrides (including
     the decode fast-path knobs — decode_horizon / inflight_window /
-    prefill_chunk / compact_threshold — and the resilience knobs,
+    prefill_chunk — and the resilience knobs,
     docs/serving.md).  ``--resume`` finishes a preempted run from its
     ``serving_resume.json`` checkpoint; ``--slo SEC`` stamps generated
     requests with a per-request deadline; ``--fault-plan`` activates

@@ -1,83 +1,38 @@
-"""Continuous-batching inference engine over the paged KV-cache.
+"""The scheduler of the serving engine: continuous batching over the
+paged cache, for whichever block family the model is of.
 
-Two jitted device programs, fixed shapes for the whole run:
+Three boxes, arrows one way: this file -> a family's device programs
+(``serve/gpt.py``, ``serve/hybrid.py``) -> the cache and its attention
+helpers (``serve/kvcache.py``, ``serve/attend.py``), with the envelope
+(``serve/config.py``) beside them.  :func:`family_for` is the one place
+that looks at which family a model is of; :class:`ServingEngine` holds
+the module it returns and asks it for everything that differs (the
+seam: ``docs/serving.md``, "Adding a block family").
 
-- **prefill** (one compile per sequence-length *bucket*): runs the full
-  transformer stack over one request's ``[1, bucket, H]`` prompt with
-  ordinary causal attention, writes its K/V into the request's cache
-  slot (one in-place block write — see ``serve/kvcache.py``), sets
-  the slot length, and returns the last real token's output — the
-  request's FIRST generated token (TTFT stops here).
-- **decode_step** (one compile, ``[max_batch, 1, H]``): appends each
-  active slot's pending token to the cache at its own length, attends
-  over the slot's valid prefix (GQA-grouped at ``kv_heads`` width; the
-  fp layout through ``ops/decode_attention.py``, which fetches only the
-  tiles of tokens a slot holds), and produces every active slot's next
-  token.
-  The output hidden state IS the next step's input embedding (the model
-  is its own next-token function — same convention as the chained
-  timing loop), so the decode carry ``(cache, x)`` feeds back without
-  any host round-trip, and both leaves are donated.
-
-Around them, a host-side continuous-batching scheduler (Orca-style
-iteration-level scheduling): arrivals from a ``TrafficTrace`` pass
-admission control (bounded queue — overflow is a *rejected* request),
-waiting requests are granted slots + worst-case block reservations at
-step boundaries, completed requests free both immediately, and the next
-decode step runs with whatever mix of old and new requests is resident.
-Per-phase obs spans (``serve-admission`` / ``serve-prefill`` /
-``serve-decode``), request-lifecycle events into the resilience journal,
-and live MetricsRegistry counters/gauges come for free from the
-machinery the sweep engine already has.
-
-Communication contract (audited — ``analysis/hlo_audit.py`` decode and
-prefill targets, ``plan_expected_kinds(decode=True)``): a decode step
-may contain only the tiny per-token TP collectives (row-parallel psums
-of ``[max_batch, 1, H]`` + QKV realignment permutes); the cache never
-crosses the wire.  A byte ceiling of activation size proves no step
-accidentally re-gathers the KV-cache.
-
-Decode fast path (``docs/serving.md``, all off by default so the
-engine's legacy per-step behaviour is bit-for-bit preserved):
-
-- **fused multi-step decode** (``decode_horizon > 1``): when the ledger
-  knows no scheduling event is imminent, the next K decode steps run as
-  ONE jitted ``lax.scan`` over the donated ``(cache, x)`` carry — one
-  host dispatch instead of K.  K is chosen per step as
-  ``min(horizon_cap, steps_until_next_event)`` (next event = the
-  earliest completion while anything is waiting for a slot, else the
-  batch's full drain), rounded down to a power-of-two bucket so the
-  scan retraces at most ``log2(horizon)`` times.  Slots that complete
-  mid-scan are masked inactive INSIDE the scan by a per-slot
-  ``remaining`` step budget, so logits stay equivalent to the per-step
-  engine; their block frees happen at scan exit.
-- **host-overlap dispatch** (``inflight_window > 1``): decode units are
-  dispatched without ``block_until_ready`` into a bounded in-flight
-  window (dispatch N+1 while N computes); syncs happen only at scan
-  boundaries — window full, an admission about to prefill, idle, or
-  run end.  TTFT stays honest: the first token is synced exactly as in
-  the per-step engine (prefill blocks on ``y_last``).
-- **chunked prefill** (``prefill_chunk``): long prompts split into
-  fixed-size chunks (block-multiples, one jit per static chunk offset
-  reusing ``_serve_block``) interleaved with decode steps, so a long
-  admission no longer head-of-line-blocks the resident decode batch.
-  Each chunk writes its K/V blocks exactly as monolithic prefill does
-  and carries the running prefix K/V explicitly ([L, start, kvh, d],
-  no slot dim) so the cache is never re-read across the slot shard.
-- **slot compaction** (``compact_threshold``, dp=1 meshes only): when
-  occupancy drops to or below the threshold, active slots are
-  gather-repacked into a half-size decode batch bucket for the fused
-  scan and scattered back at scan exit — priced as a measured variant
-  (``scripts/bench_serving.py``), never assumed to win.
+What is here is host code (Orca-style iteration-level scheduling):
+arrivals from a ``TrafficTrace`` pass admission control (bounded queue:
+overflow is a *rejected* request), waiting requests are granted slots
+and worst-case block reservations at step boundaries, a prompt is
+prefilled in chunks interleaved with the resident batch's decode steps
+(or at once, per bucket, without ``prefill_chunk``), completed requests
+free slot and blocks at once, and each decode unit runs over whatever
+mix of old and new requests is resident: one step, or, when the ledger
+knows no scheduling event is nearer, K steps fused into one scan
+(``decode_horizon``), up to ``inflight_window`` units dispatched before
+the oldest is waited for.  Around that: prefix-cache attach, the
+draft-and-verify units of speculative decoding, per-phase spans
+(``serve-admission`` / ``serve-prefill`` / ``serve-decode``),
+request-lifecycle events into the resilience journal, and live
+MetricsRegistry counters and gauges.
 
 Resilience (``docs/resilience.md``, serving faults): every fault site
-fires strictly on the HOST side of a dispatch boundary — the jitted
-programs above are byte-identical with or without an active plan
+fires strictly on the HOST side of a dispatch boundary; the families'
+jitted programs are byte-identical with or without an active plan
 (statically pinned).  A transiently-failed prefill/decode dispatch
 rolls the host ledger/slot bookkeeping back to a pre-dispatch snapshot
 and re-issues with exponential backoff; exhausted retries fail only
 the affected requests, journaled ``request-failed`` with full
-exception chains — never the run.  ``dispatch_deadline_factor`` arms
+exception chains, never the run.  ``dispatch_deadline_factor`` arms
 an EMA-scaled watchdog (the PR-5 daemon-thread pattern) that abandons
 a hung dispatch or window sync and continues on a fresh carry.
 Requests may carry per-arrival SLO deadlines (blown queue heads shed
@@ -97,7 +52,8 @@ import signal
 import threading
 import time
 from collections import deque
-from dataclasses import dataclass, field, replace as dc_replace
+from dataclasses import dataclass, field
+from types import ModuleType
 from typing import Any, Optional
 
 import jax
@@ -111,34 +67,12 @@ from dlbb_tpu.data.synthetic import (
     request_embeddings,
     token_embedding_table,
 )
-from dlbb_tpu.models.configs import (
-    FULL_ATTENTION,
-    ModelConfig,
-    state_cache_bytes,
-    kv_cache_bytes,
-    validate_serving,
-)
-from dlbb_tpu.models.attention import dense_attention
-from dlbb_tpu.models.transformer import (
-    ATTN_CORE,
-    ATTN_OUT,
-    ATTN_QKV,
-    LN1,
-    LN2,
-    MLP_ACT,
-    MLP_DOWN,
-    MLP_UP,
-    SERVE_PHASES,
-    _dtype_of,
-    _layernorm,
-    init_params_sharded,
-    named,
-)
+from dlbb_tpu.models.configs import ModelConfig
+from dlbb_tpu.models.transformer import _dtype_of, init_params_sharded
 from dlbb_tpu.obs import spans
 from dlbb_tpu.obs.export import MetricsRegistry
 from dlbb_tpu.ops.decode_attention import (
     check_kernel_takes,
-    decode_attention,
     live_tile_counts,
     plane_tile_tokens,
 )
@@ -149,1651 +83,32 @@ from dlbb_tpu.resilience.errors import (
     InjectedFault,
     TransientFault,
     exception_chain,
-    is_transient,
 )
 from dlbb_tpu.resilience.preempt import PreemptionGuard
-from dlbb_tpu.serve.kvcache import (
-    BlockLedger,
-    KVCache,
-    QuantKVCache,
-    append_token_rows,
-    cache_shardings,
-    copy_slot_blocks,
-    create_kv_cache,
-    create_quant_kv_cache,
-    dequantize_kv_blocks,
-    quant_cache_shardings,
-    quantize_kv_blocks,
-    write_slot_blocks,
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.kvcache import BlockLedger, KVCache, create_kv_cache
+from dlbb_tpu.serve.speculative import (
+    _ngram_propose,
+    softmax_np,
+    speculative_sample,
 )
 from dlbb_tpu.serve.traffic import Request, TrafficTrace
 from dlbb_tpu.utils.metrics import Timer, summarize
 
 SERVING_REPORT_SCHEMA = "dlbb_serving_report_v1"
 
-# decode feedback / drafting modes (ServingConfig.speculation):
-# "off" = legacy continuous hidden-state feedback; "greedy" = token
-# feedback without drafting (the speculative modes' pinned oracle);
-# "ngram" / "draft-model" = draft-and-verify speculative decoding
-SPECULATION_MODES = ("off", "greedy", "ngram", "draft-model")
 
+def family_for(config: ModelConfig) -> ModuleType:
+    """The module that holds ``config``'s block family's serving
+    programs and answers the scheduler's questions about it (the seam:
+    ``docs/serving.md``, "Adding a block family")."""
+    if config.is_hybrid:
+        from dlbb_tpu.serve import hybrid
 
-# ---------------------------------------------------------------------------
-# configuration
-# ---------------------------------------------------------------------------
+        return hybrid
+    from dlbb_tpu.serve import gpt
 
-
-def _default_buckets(block_size: int, max_seq: int) -> tuple[int, ...]:
-    """Doubling bucket ladder: block_size, 2x, 4x, ... up to max_seq."""
-    buckets = []
-    b = block_size
-    while b < max_seq:
-        buckets.append(b)
-        b *= 2
-    buckets.append(max_seq)
-    return tuple(buckets)
-
-
-@dataclass(frozen=True)
-class ServingConfig:
-    """The serving envelope (YAML ``serving:`` section).
-
-    max_batch:       decode slots (the fixed decode batch dim).
-    block_size:      tokens per cache block.
-    max_seq:         per-slot capacity (prompt + output ceiling); must be
-                     a block multiple — ``num_blocks = max_seq/block_size``.
-    prefill_buckets: sequence-length buckets prefill compiles at
-                     (block-multiples; default: doubling ladder up to
-                     max_seq).  A prompt pads to the smallest bucket >= it.
-    queue_capacity:  admission-control bound; an arrival finding the
-                     queue full is REJECTED (counted, journaled).
-    blocks_budget:   global cache-block budget the ledger enforces
-                     (default: the physical pool, max_batch x num_blocks;
-                     set lower to model cache pressure).
-    hbm_budget_gb:   per-device HBM budget the build-time footprint gate
-                     (``models.configs.validate_serving``) checks the
-                     KV-cache against; None disables the gate.
-    decode_horizon:  fused-scan horizon cap K (1 = the legacy per-step
-                     engine; >1 fuses up to K decode steps into one
-                     jitted lax.scan dispatch, bucketed by powers of 2).
-    inflight_window: bounded in-flight decode dispatch window (1 = sync
-                     every unit, the legacy behaviour; >1 dispatches the
-                     next unit while the previous computes and syncs
-                     only at scan boundaries).
-    prefill_chunk:   tokens per prefill chunk (a block multiple; None =
-                     monolithic bucketed prefill).  Long prompts are
-                     processed chunk-by-chunk, interleaved with decode
-                     steps for the resident batch.
-    compact_threshold: occupancy fraction (0, 0.5] at or below which the
-                     fused decode scan runs on a gather-compacted
-                     half-size batch bucket (dp=1 meshes only; None
-                     disables).  A measured variant, not a default win.
-    reject_infeasible: reject-and-journal requests the envelope cannot
-                     serve (reason="infeasible") instead of failing the
-                     whole trace up front (the strict default).
-    max_dispatch_retries: bounded retries (exponential backoff) for a
-                     transiently-failed prefill/decode dispatch; each
-                     retry rolls the host ledger/slot state back to the
-                     pre-dispatch snapshot first.  Exhaustion fails only
-                     the affected requests (journaled ``request-failed``
-                     with the exception chain), never the run.
-    retry_backoff_s: base backoff delay; attempt N sleeps
-                     ``retry_backoff_s * 2**(N-1)``.
-    dispatch_deadline_factor: arms the in-flight dispatch watchdog: a
-                     decode unit (or its sync) exceeding
-                     ``max(dispatch_deadline_min_s, factor * k *
-                     per-step-EMA)`` wall seconds is abandoned on its
-                     daemon thread (the PR-5 pattern), its slots'
-                     requests journaled ``request-failed[reason=
-                     hung-dispatch]`` and freed, and the engine
-                     continues on a fresh carry.  None (default)
-                     disables — zero threads, zero overhead.
-    dispatch_deadline_min_s: watchdog floor while the per-step EMA is
-                     still cold (and for tiny EMAs).
-    speculation:     decode feedback / drafting mode ("off" = the legacy
-                     continuous hidden-state feedback, bit-for-bit
-                     preserved).  The token modes quantise decode
-                     through the deterministic greedy token table
-                     (``data.synthetic.token_embedding_table``):
-                     "greedy" is token feedback WITHOUT drafting (the
-                     pinned per-step/fused oracle the speculative modes
-                     are token-identical to); "ngram" adds host-side
-                     prompt-lookup self-speculation (zero extra model);
-                     "draft-model" adds a shallow draft transformer on
-                     the same ParallelismPlan with its own paged KV
-                     plane (docs/serving.md, "Speculative decoding").
-    spec_gamma:      draft tokens proposed per verify step (the γ of
-                     draft-and-verify); requires a drafting mode.
-    spec_adaptive:   per-request adaptive γ — back off to a smaller
-                     verify ladder bucket on low acceptance EMA, climb
-                     back on high (requires a drafting mode).
-    spec_draft_layers: draft-model depth (layers of the shallow draft
-                     transformer; every other dim matches the target).
-    spec_draft_kv_heads: draft-model GQA kv_heads override (None =
-                     the target's; must keep kv_heads % tp == 0).
-    prefix_caching:  refcounted content-addressed shared-prefix KV
-                     blocks (docs/serving.md, "Prefix cache & quantized
-                     KV").  Full prompt blocks are indexed by their
-                     token-block chain in a host-side radix trie inside
-                     the ``BlockLedger``; an admitted request whose
-                     prompt matches an existing chain attaches to the
-                     matched blocks (one copy-on-attach jit replaces
-                     the matched chunks' prefills — TTFT drops by the
-                     matched fraction) and pays blocks only for its
-                     unmatched suffix.  Requires ``prefill_chunk`` (the
-                     suffix-only prefill IS the chunk machinery),
-                     dp=1 (the donor->slot block copy must stay
-                     shard-local, like compaction), and
-                     speculation="off".
-    kv_quantization: "none" (fp cache, bit-identical legacy layout) or
-                     "int8": K/V planes stored as int8 blocks with a
-                     per-(block, kv-head) fp32 scale side-channel
-                     plane, dequantised inside the length-masked
-                     attention — ~3.9x smaller cache, so
-                     ``hbm_budget_gb`` admits proportionally more
-                     resident requests (``kv_cache_bytes_per_device``
-                     prices the quantized layout statically).
-                     Requires speculation="off" and no
-                     compact_threshold (fp-cache-only programs).
-    temperature:     softmax temperature of the SAMPLED decode path
-                     (0.0 = the greedy argmax law, bit-for-bit
-                     untouched).  temperature > 0 routes every decode
-                     unit through the residual-sampling verify
-                     (``speculative_sample`` — Leviathan et al. 2023):
-                     the target's verify logits come to host, each
-                     drafted position is accepted with probability
-                     ``p[draft]`` and rejected positions resample from
-                     ``residual_distribution`` — the composite law is
-                     exactly the temperature-``T`` softmax of the
-                     target, so sampled speculative decode is
-                     distribution-identical (not token-identical) to a
-                     sequential sampler.  Requires a drafting
-                     speculation mode, decode_horizon=1 and no
-                     prefill_chunk (the fused/chunk-interleave token
-                     programs are greedy-argmax only — running them
-                     would silently emit greedy tokens mid-sampled-run).
-    sample_seed:     host RNG seed of the sampled path (with the trace
-                     seed this makes sampled runs replayable); only
-                     meaningful with temperature > 0.
-    hedge_factor:    fleet-level straggler hedging knob (``serve/
-                     fleet.py``; ignored by a single-engine run): a
-                     request still outstanding past ``hedge_factor`` x
-                     the observed p99 end-to-end latency is duplicated
-                     onto a second replica — first completion wins, the
-                     loser is canceled and its blocks freed.  Greedy
-                     token sequences depend only on (params, request
-                     seed), so the committed tokens are identical
-                     whichever copy wins.  None (default) disables
-                     hedging; must be > 1.0 when set.
-    """
-
-    max_batch: int = 8
-    block_size: int = 16
-    max_seq: int = 256
-    prefill_buckets: tuple[int, ...] = ()
-    queue_capacity: int = 64
-    blocks_budget: Optional[int] = None
-    hbm_budget_gb: Optional[float] = 12.0
-    decode_horizon: int = 1
-    inflight_window: int = 1
-    prefill_chunk: Optional[int] = None
-    compact_threshold: Optional[float] = None
-    reject_infeasible: bool = False
-    max_dispatch_retries: int = 2
-    retry_backoff_s: float = 0.05
-    dispatch_deadline_factor: Optional[float] = None
-    dispatch_deadline_min_s: float = 0.25
-    speculation: str = "off"
-    spec_gamma: int = 0
-    spec_adaptive: bool = False
-    spec_draft_layers: int = 1
-    spec_draft_kv_heads: Optional[int] = None
-    prefix_caching: bool = False
-    kv_quantization: str = "none"
-    temperature: float = 0.0
-    sample_seed: int = 0
-    hedge_factor: Optional[float] = None
-
-    def __post_init__(self) -> None:
-        if not self.prefill_buckets:
-            object.__setattr__(
-                self, "prefill_buckets",
-                _default_buckets(self.block_size, self.max_seq),
-            )
-        else:
-            # normalise: bucket_for's first-match walk and every
-            # "buckets[-1] is the largest" consumer assume ascending
-            # unique buckets
-            object.__setattr__(
-                self, "prefill_buckets",
-                tuple(sorted(set(self.prefill_buckets))),
-            )
-
-    @property
-    def num_blocks(self) -> int:
-        return self.max_seq // self.block_size
-
-    @property
-    def total_blocks(self) -> int:
-        return (self.blocks_budget if self.blocks_budget is not None
-                else self.max_batch * self.num_blocks)
-
-    def validate(self, config: ModelConfig, dp: int = 1,
-                 tp: int = 1) -> None:
-        budget = (None if self.hbm_budget_gb is None
-                  else int(self.hbm_budget_gb * 2**30))
-        if self.speculation not in SPECULATION_MODES:
-            raise ValueError(
-                f"serving.speculation={self.speculation!r} must be one "
-                f"of {SPECULATION_MODES}"
-            )
-        if config.is_hybrid:
-            # what the hybrid family's serving path does not have yet,
-            # each by its mechanism (ROADMAP.md, Queue 2); int8 KV is
-            # refused in validate_serving below
-            if self.speculation != "off":
-                raise ValueError(
-                    f"serving.speculation={self.speculation!r} is not "
-                    "implemented for layer_types models: a rejected draft "
-                    "needs the recurrent state rolled back, and the state "
-                    "cache keeps no snapshots")
-            if self.prefix_caching:
-                raise ValueError(
-                    "serving.prefix_caching is not implemented for "
-                    "layer_types models: attaching to shared blocks needs "
-                    "the recurrent state as it was at the block boundary, "
-                    "and the state cache keeps no snapshots")
-            if self.prefill_chunk is None:
-                raise ValueError(
-                    "layer_types models are prefilled in chunks: set "
-                    "serving.prefill_chunk (the chunk program hands the "
-                    "recurrent state from chunk to chunk; there is no "
-                    "monolithic prefill program)")
-        # speculation with tp_overlap != off or non-dense attention is
-        # rejected inside validate_serving (those envelopes cannot serve
-        # at all); the draft plane re-runs the same gate on its own
-        # config below, so a draft kv plane breaking kv_heads % tp
-        # fails here at build time too
-        draft = (self.draft_model_config(config)
-                 if self.speculation == "draft-model" else None)
-        validate_serving(config, self.max_batch, self.max_seq,
-                         self.block_size, dp=dp, tp=tp,
-                         hbm_budget_bytes=budget, draft_config=draft,
-                         kv_quantization=self.kv_quantization)
-        for b in self.prefill_buckets:
-            if b % self.block_size != 0 or not 0 < b <= self.max_seq:
-                raise ValueError(
-                    f"prefill bucket {b} must be a block_size="
-                    f"{self.block_size} multiple in (0, {self.max_seq}]"
-                )
-        if self.queue_capacity < 1:
-            raise ValueError(
-                f"serving.queue_capacity must be >= 1, got "
-                f"{self.queue_capacity}"
-            )
-        if self.hedge_factor is not None and self.hedge_factor <= 1.0:
-            raise ValueError(
-                f"serving.hedge_factor must be > 1.0 (it scales the "
-                f"observed p99 latency), got {self.hedge_factor}"
-            )
-        if self.total_blocks < 1:
-            raise ValueError(
-                f"serving.blocks_budget must be >= 1, got "
-                f"{self.total_blocks}"
-            )
-        if self.decode_horizon < 1:
-            raise ValueError(
-                f"serving.decode_horizon must be >= 1, got "
-                f"{self.decode_horizon}"
-            )
-        if self.inflight_window < 1:
-            raise ValueError(
-                f"serving.inflight_window must be >= 1, got "
-                f"{self.inflight_window}"
-            )
-        if self.inflight_window > 1 and self.decode_horizon < 2:
-            raise ValueError(
-                "serving.inflight_window > 1 requires decode_horizon "
-                ">= 2: per-step (k=1) units never stay in flight (their "
-                "y may alias the donated carry), so the window would be "
-                "a silent no-op on the per-step engine"
-            )
-        if self.prefill_chunk is not None:
-            if (self.prefill_chunk % self.block_size != 0
-                    or not 0 < self.prefill_chunk <= self.max_seq):
-                raise ValueError(
-                    f"serving.prefill_chunk={self.prefill_chunk} must be "
-                    f"a block_size={self.block_size} multiple in "
-                    f"(0, {self.max_seq}]"
-                )
-            if self.max_seq % self.prefill_chunk != 0:
-                # a prompt near max_seq pads to ceil(prompt/chunk)*chunk;
-                # unless the chunk divides max_seq that rounding can
-                # overrun the slot's block ring for a perfectly feasible
-                # request — reject the geometry up front
-                raise ValueError(
-                    f"serving.prefill_chunk={self.prefill_chunk} must "
-                    f"divide serving.max_seq={self.max_seq} (chunk "
-                    "rounding of a near-max_seq prompt would overrun "
-                    "the slot's block ring)"
-                )
-        if self.compact_threshold is not None:
-            if not 0.0 < self.compact_threshold <= 0.5:
-                raise ValueError(
-                    f"serving.compact_threshold must be in (0, 0.5] — "
-                    f"compaction repacks into the half-size batch bucket "
-                    f"(got {self.compact_threshold})"
-                )
-            if self.decode_horizon < 2:
-                raise ValueError(
-                    "serving.compact_threshold requires decode_horizon "
-                    ">= 2: compaction only engages on fused scans, so "
-                    "with the per-step engine it would be a silent no-op "
-                    "that still pays the gather/scatter compiles"
-                )
-            if self.max_batch < 2:
-                raise ValueError(
-                    "serving.compact_threshold needs max_batch >= 2 "
-                    "(nothing to compact into)"
-                )
-            if dp > 1:
-                raise ValueError(
-                    "serving.compact_threshold requires dp=1: the slot "
-                    "gather/scatter must stay shard-local, and the slot "
-                    f"dim is sharded over dp={dp}"
-                )
-        if self.max_dispatch_retries < 0:
-            raise ValueError(
-                f"serving.max_dispatch_retries must be >= 0, got "
-                f"{self.max_dispatch_retries}"
-            )
-        if self.retry_backoff_s < 0:
-            raise ValueError(
-                f"serving.retry_backoff_s must be >= 0, got "
-                f"{self.retry_backoff_s}"
-            )
-        if (self.dispatch_deadline_factor is not None
-                and self.dispatch_deadline_factor <= 0):
-            raise ValueError(
-                f"serving.dispatch_deadline_factor must be > 0, got "
-                f"{self.dispatch_deadline_factor}"
-            )
-        if self.dispatch_deadline_min_s <= 0:
-            raise ValueError(
-                f"serving.dispatch_deadline_min_s must be > 0 seconds, "
-                f"got {self.dispatch_deadline_min_s}"
-            )
-        # -- speculation ladder (same no-op-trap contract as
-        #    compact_threshold/inflight_window: a knob that would
-        #    silently do nothing is a config error) --
-        if self.spec_drafting:
-            if self.spec_gamma < 1:
-                raise ValueError(
-                    f"serving.speculation={self.speculation!r} requires "
-                    f"spec_gamma >= 1 (got {self.spec_gamma}): a drafter "
-                    "with zero proposals per verify is a silent no-op "
-                    "that still pays the verify compiles"
-                )
-            if self.spec_gamma + 1 > self.max_seq:
-                raise ValueError(
-                    f"serving.spec_gamma={self.spec_gamma} cannot exceed "
-                    f"max_seq-1={self.max_seq - 1}: a verify step "
-                    "appends gamma+1 positions to one slot"
-                )
-        else:
-            if self.spec_gamma:
-                raise ValueError(
-                    f"serving.spec_gamma={self.spec_gamma} requires a "
-                    "drafting speculation mode ('ngram' or "
-                    "'draft-model'); with speculation="
-                    f"{self.speculation!r} no verify step ever runs, so "
-                    "the knob would be a silent no-op"
-                )
-            if self.spec_adaptive:
-                raise ValueError(
-                    "serving.spec_adaptive requires a drafting "
-                    "speculation mode ('ngram' or 'draft-model'): "
-                    "there is no acceptance EMA to adapt to with "
-                    f"speculation={self.speculation!r}"
-                )
-        if self.speculation != "off" and self.compact_threshold is not None:
-            raise ValueError(
-                "serving.compact_threshold cannot combine with "
-                f"speculation={self.speculation!r}: token-feedback and "
-                "verify units run on the full decode batch (no "
-                "compacted token/verify program exists), so compaction "
-                "would be a silent no-op that still pays the gather/"
-                "scatter compiles"
-            )
-        if self.speculation == "draft-model":
-            if self.spec_draft_layers < 1:
-                raise ValueError(
-                    f"serving.spec_draft_layers must be >= 1, got "
-                    f"{self.spec_draft_layers}"
-                )
-            if self.prefill_chunk is not None:
-                raise ValueError(
-                    "serving.prefill_chunk cannot combine with "
-                    "speculation='draft-model': the draft KV plane is "
-                    "prefilled monolithically at admission, and a "
-                    "chunked target prefill would leave it silently "
-                    "unfilled"
-                )
-        # -- shared-prefix cache + quantized KV planes (same no-op-trap
-        #    contract: a knob that cannot engage is a config error) --
-        if self.prefix_caching:
-            if self.prefill_chunk is None:
-                raise ValueError(
-                    "serving.prefix_caching requires prefill_chunk: the "
-                    "suffix-only prefill of a prefix hit IS the chunked-"
-                    "prefill machinery (attach replaces the matched "
-                    "chunks), so without it every admission would pay "
-                    "the full prefill and the trie would be a silent "
-                    "no-op"
-                )
-            if dp > 1:
-                raise ValueError(
-                    "serving.prefix_caching requires dp=1: the prefix "
-                    "attach copies donor-slot blocks into the admitted "
-                    "slot, and that copy must stay shard-local — the "
-                    f"slot dim is sharded over dp={dp} (same constraint "
-                    "as compact_threshold)"
-                )
-            if self.speculation != "off":
-                raise ValueError(
-                    "serving.prefix_caching cannot combine with "
-                    f"speculation={self.speculation!r}: prefix attach "
-                    "rides the chunked prefill, which the speculative "
-                    "modes exclude (and generated tokens are never "
-                    "indexed in the trie, so drafting gains nothing)"
-                )
-        if self.kv_quantization == "int8":
-            if self.speculation != "off":
-                raise ValueError(
-                    "serving.kv_quantization='int8' cannot combine with "
-                    f"speculation={self.speculation!r}: the token/"
-                    "verify programs read and write the fp cache layout "
-                    "only"
-                )
-            if self.compact_threshold is not None:
-                raise ValueError(
-                    "serving.kv_quantization='int8' cannot combine with "
-                    "compact_threshold: the slot gather/scatter programs "
-                    "repack the fp cache layout only, so compaction "
-                    "would silently run on stale scale planes"
-                )
-        # -- sampled decode (same no-op-trap contract) --
-        if self.temperature < 0:
-            raise ValueError(
-                f"serving.temperature must be >= 0, got "
-                f"{self.temperature}"
-            )
-        if self.temperature > 0:
-            if not self.spec_drafting:
-                raise ValueError(
-                    f"serving.temperature={self.temperature} requires a "
-                    "drafting speculation mode ('ngram' or "
-                    "'draft-model'): the sampled path runs inside the "
-                    "verify unit (residual sampling over the verify "
-                    "logits), and with speculation="
-                    f"{self.speculation!r} every decode program is the "
-                    "greedy argmax law — the knob would silently emit "
-                    "greedy tokens"
-                )
-            if self.decode_horizon != 1:
-                raise ValueError(
-                    f"serving.temperature={self.temperature} requires "
-                    f"decode_horizon=1 (got {self.decode_horizon}): the "
-                    "fused token scans are greedy-argmax programs, so a "
-                    "fused unit mid-sampled-run would silently emit "
-                    "greedy tokens (the verify window is the sampled "
-                    "path's multi-token mechanism)"
-                )
-            if self.prefill_chunk is not None:
-                raise ValueError(
-                    f"serving.temperature={self.temperature} cannot "
-                    "combine with prefill_chunk: the chunk interleave's "
-                    "per-step decode units are greedy token programs, "
-                    "so a long admission would silently emit greedy "
-                    "tokens mid-sampled-run"
-                )
-        elif self.sample_seed:
-            raise ValueError(
-                f"serving.sample_seed={self.sample_seed} requires "
-                "temperature > 0: the greedy path never consumes the "
-                "host RNG, so the knob would be a silent no-op"
-            )
-
-    @property
-    def spec_drafting(self) -> bool:
-        """True when a drafter runs (verify steps exist)."""
-        return self.speculation in ("ngram", "draft-model")
-
-    @property
-    def spec_gammas(self) -> tuple[int, ...]:
-        """The verify-step γ ladder: powers of two 1, 2, 4, ... below
-        ``spec_gamma``, plus ``spec_gamma`` itself (adaptive γ backs
-        off through these buckets; empty when not drafting)."""
-        if not self.spec_drafting:
-            return ()
-        gs = []
-        g = 1
-        while g < self.spec_gamma:
-            gs.append(g)
-            g *= 2
-        gs.append(self.spec_gamma)
-        return tuple(sorted(set(gs)))
-
-    def draft_model_config(self, config: ModelConfig) -> ModelConfig:
-        """The draft transformer's config: the target at
-        ``spec_draft_layers`` depth (and an optional kv_heads
-        override), everything else — hidden size, heads, dtype,
-        attention — identical, so the draft shares the target's
-        ParallelismPlan and its outputs live in the same hidden/token
-        space the verify step argmaxes over."""
-        kwargs: dict[str, Any] = {"num_layers": self.spec_draft_layers}
-        if self.spec_draft_kv_heads is not None:
-            kwargs["num_kv_heads"] = self.spec_draft_kv_heads
-        return dc_replace(config, **kwargs)
-
-    def bucket_for(self, prompt_len: int) -> int:
-        for b in self.prefill_buckets:
-            if prompt_len <= b:
-                return b
-        raise ValueError(
-            f"prompt_len={prompt_len} exceeds the largest prefill bucket "
-            f"{self.prefill_buckets[-1]} (serving.max_seq={self.max_seq})"
-        )
-
-    @classmethod
-    def from_dict(cls, d: dict[str, Any]) -> "ServingConfig":
-        fields = {}
-        for k in ("max_batch", "block_size", "max_seq", "queue_capacity",
-                  "blocks_budget", "hbm_budget_gb", "decode_horizon",
-                  "inflight_window", "prefill_chunk", "compact_threshold",
-                  "reject_infeasible", "max_dispatch_retries",
-                  "retry_backoff_s", "dispatch_deadline_factor",
-                  "dispatch_deadline_min_s", "speculation", "spec_gamma",
-                  "spec_adaptive", "spec_draft_layers",
-                  "spec_draft_kv_heads", "prefix_caching",
-                  "kv_quantization", "temperature", "sample_seed",
-                  "hedge_factor"):
-            if k in d:
-                fields[k] = d[k]
-        if "prefill_buckets" in d:
-            fields["prefill_buckets"] = tuple(d["prefill_buckets"])
-        return cls(**fields)
-
-    def to_dict(self) -> dict[str, Any]:
-        return {
-            "max_batch": self.max_batch,
-            "block_size": self.block_size,
-            "max_seq": self.max_seq,
-            "num_blocks": self.num_blocks,
-            "prefill_buckets": list(self.prefill_buckets),
-            "queue_capacity": self.queue_capacity,
-            "blocks_budget": self.total_blocks,
-            "hbm_budget_gb": self.hbm_budget_gb,
-            "decode_horizon": self.decode_horizon,
-            "inflight_window": self.inflight_window,
-            "prefill_chunk": self.prefill_chunk,
-            "compact_threshold": self.compact_threshold,
-            "reject_infeasible": self.reject_infeasible,
-            "max_dispatch_retries": self.max_dispatch_retries,
-            "retry_backoff_s": self.retry_backoff_s,
-            "dispatch_deadline_factor": self.dispatch_deadline_factor,
-            "dispatch_deadline_min_s": self.dispatch_deadline_min_s,
-            "speculation": self.speculation,
-            "spec_gamma": self.spec_gamma,
-            "spec_adaptive": self.spec_adaptive,
-            "spec_draft_layers": self.spec_draft_layers,
-            "spec_draft_kv_heads": self.spec_draft_kv_heads,
-            "prefix_caching": self.prefix_caching,
-            "kv_quantization": self.kv_quantization,
-            "temperature": self.temperature,
-            "sample_seed": self.sample_seed,
-            "hedge_factor": self.hedge_factor,
-        }
-
-    @property
-    def fused_horizons(self) -> tuple[int, ...]:
-        """The power-of-two fused-scan bucket ladder: 2, 4, ... up to
-        ``decode_horizon`` (empty when the fast path is off)."""
-        ks = []
-        k = 2
-        while k <= self.decode_horizon:
-            ks.append(k)
-            k *= 2
-        return tuple(ks)
-
-
-# ---------------------------------------------------------------------------
-# device programs
-# ---------------------------------------------------------------------------
-
-
-def _split_qkv(qkv: jax.Array, config: ModelConfig):
-    """[..., qkv_width] -> q [..., H], k/v [..., kv_heads * head_dim]."""
-    h, kvd = config.hidden_size, config.kv_heads * config.head_dim
-    return qkv[..., :h], qkv[..., h:h + kvd], qkv[..., h + kvd:]
-
-
-def _serve_block(h, layer, config: ModelConfig, attention_step,
-                 cache_state):
-    """One transformer block with a pluggable attention step — the ONE
-    copy of the ln1/qkv/out/ln2/ffn structure every serving program
-    shares (the serving twin of ``transformer._block``, whose math the
-    equivalence tests pin it against).  ``attention_step(q, k, v,
-    cache_state) -> (attn [B, S, n*d], cache_state)`` owns everything
-    that differs between prefill (dense causal + block write), decode
-    (cached append + length-masked read), and chunked prefill (prefix
-    carry + offset block write); ``cache_state`` is opaque to the block
-    (``_scan_layers`` says what the cache-writing programs put in it)."""
-    with jax.named_scope(LN1):
-        y = _layernorm(h, layer["ln1"]["scale"], layer["ln1"]["bias"])
-    with jax.named_scope(ATTN_QKV):
-        qkv = y @ layer["qkv"]["kernel"] + layer["qkv"]["bias"]
-        q, k, v = _split_qkv(qkv, config)
-    with jax.named_scope(ATTN_CORE):
-        attn, cache_state = attention_step(q, k, v, cache_state)
-    with jax.named_scope(ATTN_OUT):
-        h = attn @ layer["out"]["kernel"] + layer["out"]["bias"] + h
-    residual = h
-    with jax.named_scope(LN2):
-        y2 = _layernorm(h, layer["ln2"]["scale"], layer["ln2"]["bias"])
-    with jax.named_scope(MLP_UP):
-        y2 = y2 @ layer["ffn_up"]["kernel"] + layer["ffn_up"]["bias"]
-    with jax.named_scope(MLP_ACT):
-        y2 = jax.nn.gelu(y2)
-    with jax.named_scope(MLP_DOWN):
-        h = (y2 @ layer["ffn_down"]["kernel"]
-             + layer["ffn_down"]["bias"] + residual)
-    return h, cache_state
-
-
-KV_UPDATE, KV_ATTEND = SERVE_PHASES
-
-
-def _scan_layers(h, layers, planes, config: ModelConfig, attention_step,
-                 xs=()):
-    """The layer loop of every cache-writing program: ``h`` through the
-    stacked ``layers``, with the cache ``planes`` (each ``[L, ...]``)
-    riding the scan's CARRY beside the layer number, so that a write
-    into them (``serve/kvcache.py``'s helpers) is an in-place update of
-    the loop's buffer.  Scanned as ``xs``/``ys`` instead, a plane
-    enters the loop as one buffer and leaves as another, which cost two
-    whole-cache copies a program run on the v5e (``PERF.md`` §6, PR 26).
-
-    ``attention_step(q, k, v, (l, planes, *xs_l)) -> (attn, (planes,
-    ys_l))`` reads layer ``l`` of a plane by ``decode_attention`` (or
-    ``_layer_tokens``) and writes it by the helpers; ``xs`` are further
-    per-layer inputs (a chunk's prefix K/V), ``ys_l`` per-layer outputs.
-    Returns ``(h, planes, ys)``."""
-    def body(carry, layer_xs):
-        h, l, planes = carry
-        layer, *extra = layer_xs
-        h, (planes, ys) = _serve_block(h, layer, config, attention_step,
-                                       (l, planes, *extra))
-        return (h, l + 1, planes), ys
-
-    (h, _, planes), ys = jax.lax.scan(
-        body, (h, jnp.int32(0), tuple(planes)), (layers, *xs))
-    return h, planes, ys
-
-
-def _layer_of(plane: jax.Array, l: jax.Array) -> jax.Array:
-    """Layer ``l`` of a carried cache plane ``[L, ...]``."""
-    return jax.lax.dynamic_index_in_dim(plane, l, 0, keepdims=False)
-
-
-def _layer_tokens(plane: jax.Array, l: jax.Array) -> jax.Array:
-    """Layer ``l`` of a carried K/V plane as attention reads it,
-    token-major ``[B, S_max, kvh, d]``.  The plane is flattened BEFORE
-    the slice: then the v5e compiler takes the dynamic slice as the
-    prologue of the attention reduce.  Sliced first and flattened
-    after, it wrote the layer out in fp32 and read it back, per plane
-    and layer (``PERF.md`` §6, PR 26)."""
-    nl, b, nb, bs, kvh, d = plane.shape
-    return _layer_of(plane.reshape(nl, b, nb * bs, kvh, d), l)
-
-
-def _heads(t: jax.Array, nh: int, d: int) -> jax.Array:
-    """[B, S, nh*d] -> [B, nh, S, d]."""
-    b, s, _ = t.shape
-    return t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
-
-
-@jax.named_scope(KV_ATTEND)
-def _cached_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
-                      valid: jax.Array) -> jax.Array:
-    """Length-masked decode attention over the flattened cache.
-
-    q: ``[B, n, 1, d]``; k_flat/v_flat: ``[B, S_max, kvh, d]``;
-    valid: ``[B, S_max]`` bool.  Same math as
-    ``models.attention.dense_attention`` (fp32 softmax, 1/sqrt(d),
-    grouped-query einsum broadcasting) with the causal mask replaced by
-    the per-slot validity mask — positions past a slot's length
-    contribute exactly zero (softmax of -inf)."""
-    b, n, _, d = q.shape
-    kvh = k_flat.shape[2]
-    q32 = q.astype(jnp.float32)
-    k32 = k_flat.transpose(0, 2, 1, 3).astype(jnp.float32)  # [B, kvh, S, d]
-    v32 = v_flat.transpose(0, 2, 1, 3).astype(jnp.float32)
-    if kvh != n:
-        q32 = q32.reshape(b, kvh, n // kvh, 1, d)
-        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(valid[:, None, None, None, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v32)
-        out = out.reshape(b, n, 1, d)
-    else:
-        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(valid[:, None, None, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bnqk,bnkd->bnqd", probs, v32)
-    return out.astype(k_flat.dtype)
-
-
-def build_prefill(config: ModelConfig, mesh: Mesh,
-                  quantized: bool = False, name: str = "serve_prefill"):
-    """Jitted ``prefill(cache, params, x, slot, length) -> (cache,
-    y_last)`` — retraces once per prompt bucket (x's static shape).  The
-    cache is donated (argnum 0), so the carried protocol matches the
-    train-step convention the audit and calibration understand.
-    ``name`` is the program's name in a device trace: the engine builds
-    one jit per bucket, ``serve_prefill_b<bucket>``.
-
-    ``quantized`` writes the int8 layout (``QuantKVCache``): each
-    freshly-computed K/V block is quantised per (block, kv-head) and
-    the fp32 scales land in the side-channel plane by the same
-    ``write_slot_blocks``.  Prefill attention runs over the chunk's
-    own fp K/V (it never reads the cache), so quantisation touches
-    only the write."""
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-
-    @named(name)
-    def prefill(cache, params, x, slot, length):
-        bs = cache.block_size
-        s_bucket = x.shape[1]
-        wb = s_bucket // bs
-
-        def attention_step(q, k, v, cache_state):
-            l, planes = cache_state
-            qh, kh, vh = (_heads(q, n, d), _heads(k, kvh, d),
-                          _heads(v, kvh, d))
-            attn = dense_attention(qh, kh, vh, causal=config.causal)
-            # write this layer's K/V blocks into the slot ([S, kvh, d]
-            # token-major, re-tiled to whole blocks)
-            k_blocks = kh.transpose(0, 2, 1, 3)[0].reshape(wb, bs, kvh, d)
-            v_blocks = vh.transpose(0, 2, 1, 3)[0].reshape(wb, bs, kvh, d)
-            if quantized:
-                kq, ks = quantize_kv_blocks(k_blocks)
-                vq, vs = quantize_kv_blocks(v_blocks)
-                updates = (kq, vq, ks, vs)
-            else:
-                updates = (k_blocks, v_blocks)
-            planes = tuple(write_slot_blocks(p, u, l, slot)
-                           for p, u in zip(planes, updates))
-            return (attn.transpose(0, 2, 1, 3).reshape(1, s_bucket, n * d),
-                    (planes, None))
-
-        h, new_planes, _ = _scan_layers(
-            x, params["layers"], cache[:-1], config, attention_step)
-        y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-        y_last = jax.lax.dynamic_slice(
-            y, (0, length - 1, 0), (1, 1, y.shape[-1])
-        )[0, 0]
-        lengths = jnp.where(jnp.arange(cache.max_batch) == slot,
-                            length, cache.lengths).astype(jnp.int32)
-        cache_cls = QuantKVCache if quantized else KVCache
-        return cache_cls(*new_planes, lengths), y_last
-
-    cache_sh = (quant_cache_shardings(mesh) if quantized
-                else cache_shardings(mesh))
-    return jax.jit(
-        prefill,
-        donate_argnums=(0,),
-        out_shardings=(cache_sh, NamedSharding(mesh, P())),
-    )
-
-
-def prefix_spec(mesh: Mesh) -> P:
-    """Chunked-prefill prefix K/V ``[L, start, kvh, d]``: kv-head dim
-    over tp (the cache's own head split), no slot dim at all — the
-    prefix never touches the dp shard."""
-    axes = getattr(mesh, "axis_names", ())
-    tp = "tp" if "tp" in axes and mesh.shape["tp"] > 1 else None
-    return P(None, None, tp, None)
-
-
-def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple[jax.Array,
-                                                            jax.Array]:
-    """The empty (start=0) prefix carry for a chunked prefill."""
-    from dlbb_tpu.models.transformer import _dtype_of as _dt
-
-    shape = (config.layers_of(FULL_ATTENTION), 0, config.kv_heads,
-             config.head_dim)
-    zeros = jnp.zeros(shape, _dt(config.dtype))
-    sh = NamedSharding(mesh, prefix_spec(mesh))
-    return (jax.device_put(zeros, sh), jax.device_put(zeros, sh))
-
-
-@jax.named_scope(KV_ATTEND)
-def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
-                     start: int) -> jax.Array:
-    """Offset-causal fp32 attention for one prefill chunk.
-
-    qh: ``[1, n, C, d]`` (the chunk's queries, global positions
-    ``start..start+C``); k_all/v_all: ``[start+C, kvh, d]`` (prefix +
-    chunk keys).  Same math as ``_cached_attention`` (fp32 softmax,
-    1/sqrt(d), grouped-query broadcasting) with the per-slot validity
-    mask replaced by the STATIC offset-causal mask ``j <= start + qi``
-    — for real query positions this reaches only real keys, so pad
-    positions in a final partial chunk never contaminate a real
-    output (their own rows are discarded by the caller)."""
-    b, n, c, d = qh.shape
-    kvh = k_all.shape[1]
-    s_tot = k_all.shape[0]
-    q32 = qh.astype(jnp.float32)
-    k32 = k_all.transpose(1, 0, 2).astype(jnp.float32)[None]  # [1,kvh,S,d]
-    v32 = v_all.transpose(1, 0, 2).astype(jnp.float32)[None]
-    mask = (jnp.arange(s_tot)[None, :]
-            <= (start + jnp.arange(c))[:, None])            # [C, S]
-    if kvh != n:
-        q32 = q32.reshape(b, kvh, n // kvh, c, d)
-        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(mask[None, None, None], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v32)
-        out = out.reshape(b, n, c, d)
-    else:
-        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(mask[None, None], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bnqk,bnkd->bnqd", probs, v32)
-    return out.astype(k_all.dtype)
-
-
-def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
-                        start: int, quantized: bool = False):
-    """Jitted ``prefill_chunk(cache, prefix, params, x, slot, length) ->
-    (cache, prefix, y_last)`` — one chunk of a chunked prefill at STATIC
-    global offset ``start`` (a block multiple; one retrace per chunk
-    index, the "bucketed chunk jit").
-
-    The chunk's K/V blocks are written into the slot exactly as
-    monolithic prefill writes its bucket (``write_slot_blocks`` at
-    block offset ``start/block_size`` — one in-place block write);
-    attention runs over the explicitly-carried prefix K/V (``[L, start,
-    kvh, d]``, no slot dim) concatenated with the chunk, so the
-    dp-sharded cache is never re-read.  ``length`` is the TRUE prompt
-    length; ``y_last`` is the output at the last real position when it
-    falls inside this chunk (the engine uses only the final chunk's).
-    Only the cache is donated (the returned prefix is larger than the
-    input one, so its buffers can never alias).
-
-    ``quantized`` writes the chunk's blocks in the int8 layout (scales
-    into the side-channel plane); the carried prefix K/V stays fp —
-    attention always runs over exact chunk values, so quantisation
-    touches only the cache write, exactly as in monolithic prefill."""
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-
-    @named(f"serve_prefill_chunk_o{start}")
-    def prefill_chunk(cache, prefix, params, x, slot, length):
-        bs = cache.block_size
-        wb = chunk_len // bs
-        start_blk = start // bs
-
-        def attention_step(q, k, v, cache_state):
-            l, planes, pk_l, pv_l = cache_state
-            qh = _heads(q, n, d)                        # [1, n, C, d]
-            k_chunk = k[0].reshape(chunk_len, kvh, d)
-            v_chunk = v[0].reshape(chunk_len, kvh, d)
-            k_all = jnp.concatenate([pk_l, k_chunk], axis=0)
-            v_all = jnp.concatenate([pv_l, v_chunk], axis=0)
-            attn = _chunk_attention(qh, k_all, v_all, start)
-            k_blocks = k_chunk.reshape(wb, bs, kvh, d)
-            v_blocks = v_chunk.reshape(wb, bs, kvh, d)
-            if quantized:
-                kq, ks = quantize_kv_blocks(k_blocks)
-                vq, vs = quantize_kv_blocks(v_blocks)
-                updates = (kq, vq, ks, vs)
-            else:
-                updates = (k_blocks, v_blocks)
-            planes = tuple(write_slot_blocks(p, u, l, slot, start_blk)
-                           for p, u in zip(planes, updates))
-            return (attn.transpose(0, 2, 1, 3).reshape(1, chunk_len,
-                                                       n * d),
-                    (planes, (k_all, v_all)))
-
-        h, new_planes, (pk_new, pv_new) = _scan_layers(
-            x, params["layers"], cache[:-1], config, attention_step,
-            xs=prefix)
-        y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-        local = jnp.clip(length - 1 - start, 0, chunk_len - 1)
-        y_last = jax.lax.dynamic_slice(
-            y, (0, local, 0), (1, 1, y.shape[-1])
-        )[0, 0]
-        new_len = jnp.minimum(length, start + chunk_len)
-        lengths = jnp.where(jnp.arange(cache.max_batch) == slot,
-                            new_len, cache.lengths).astype(jnp.int32)
-        cache_cls = QuantKVCache if quantized else KVCache
-        return (cache_cls(*new_planes, lengths), (pk_new, pv_new), y_last)
-
-    pre_sh = NamedSharding(mesh, prefix_spec(mesh))
-    cache_sh = (quant_cache_shardings(mesh) if quantized
-                else cache_shardings(mesh))
-    # only the cache is donated: the returned prefix is LARGER than the
-    # input one (start -> start + C), so its buffers can never alias
-    return jax.jit(
-        prefill_chunk,
-        donate_argnums=(0,),
-        out_shardings=(cache_sh, (pre_sh, pre_sh),
-                       NamedSharding(mesh, P())),
-    )
-
-
-def build_prefix_attach(config: ModelConfig, mesh: Mesh,
-                        matched_len: int, block_size: int,
-                        quantized: bool = False):
-    """Jitted ``attach(cache, src, dst) -> (cache, prefix)`` — the
-    copy-on-attach step of the shared-prefix cache (one retrace per
-    matched chunk count, like the bucketed chunk jits).
-
-    Copies the donor slot ``src``'s first ``matched_len/block_size``
-    blocks (every plane — K/V, and the scale side-channel in the int8
-    layout) into the admitted slot ``dst`` by ``copy_slot_blocks`` — a
-    slice read and one in-place block write on a dp=1 slot dim
-    (``ServingConfig.validate`` pins prefix_caching to dp=1), so the
-    attach lowers to ZERO collectives (audited).  Also returns
-    the matched prefix as the fp chunk-prefill carry ``[L, matched_len,
-    kvh, d]``, exactly what the chunk jits would have produced for the
-    same token blocks (bit-identical in the fp layout — the cache
-    blocks ARE the chunk values; dequantised in the int8 layout), so
-    the suffix chunks resume at static offset ``matched_len`` with no
-    recompute.  The engine's scheduler replaces the matched chunks'
-    prefill dispatches with this single copy — that is the TTFT win."""
-    nb_m = matched_len // block_size
-    kvh, d = config.kv_heads, config.head_dim
-    dtype = _dtype_of(config.dtype)
-
-    @named("serve_prefix_attach")
-    def attach(cache, src, dst):
-        nl = cache.k.shape[0]
-        planes, donors = zip(*(copy_slot_blocks(p, src, dst, nb_m)
-                               for p in cache[:-1]))
-        if quantized:
-            k_q, v_q, ks, vs = donors
-            pk = dequantize_kv_blocks(k_q, ks, dtype)
-            pv = dequantize_kv_blocks(v_q, vs, dtype)
-        else:
-            pk, pv = donors
-        new_cache = type(cache)(*planes, cache.lengths)
-        prefix = (pk.reshape(nl, matched_len, kvh, d),
-                  pv.reshape(nl, matched_len, kvh, d))
-        return new_cache, prefix
-
-    pre_sh = NamedSharding(mesh, prefix_spec(mesh))
-    cache_sh = (quant_cache_shardings(mesh) if quantized
-                else cache_shardings(mesh))
-    return jax.jit(
-        attach,
-        donate_argnums=(0,),
-        out_shardings=(cache_sh, (pre_sh, pre_sh)),
-    )
-
-
-def _carry_shardings(mesh: Mesh):
-    """Shardings of the GPT block's decode carry ``(cache, x)``."""
-    return (cache_shardings(mesh),
-            NamedSharding(mesh, decode_batch_spec(mesh)))
-
-
-def build_compact_gather(mesh: Mesh, carry_shardings=None):
-    """Jitted ``gather(carry, idx) -> small_carry``: repack the active
-    slots named by ``idx`` into a smaller decode batch bucket (slot
-    compaction, dp=1 only — the gather must stay shard-local).  The big
-    carry is NOT donated: it survives on device and the compacted scan's
-    results are scattered back into it at scan exit.
-    ``carry_shardings``: those of ``(cache, x)`` when the carry is not
-    the GPT block's (a ``HybridCache`` and its token buffer)."""
-    from dlbb_tpu.serve.kvcache import gather_cache_slots
-
-    @named("serve_compact_gather")
-    def gather(carry, idx):
-        cache, x = carry
-        return (gather_cache_slots(cache, idx), x[idx])
-
-    return jax.jit(
-        gather, out_shardings=carry_shardings or _carry_shardings(mesh))
-
-
-def build_compact_scatter(mesh: Mesh, carry_shardings=None):
-    """Jitted ``scatter(carry, small_carry, idx) -> carry``: write the
-    compacted rows back into their big-batch slots (only the big carry
-    is donated — the small rows land inside larger output buffers;
-    ``idx`` rows are distinct by construction — active slots padded
-    with distinct free slots, so the scatter is unambiguous)."""
-    from dlbb_tpu.serve.kvcache import scatter_cache_slots
-
-    @named("serve_compact_scatter")
-    def scatter(carry, small_carry, idx):
-        cache, x = carry
-        s_cache, s_x = small_carry
-        return (scatter_cache_slots(cache, s_cache, idx),
-                x.at[idx].set(s_x))
-
-    # only the big carry is donated: the small rows land inside larger
-    # output buffers, so their donation could never be honoured
-    return jax.jit(
-        scatter,
-        donate_argnums=(0,),
-        out_shardings=carry_shardings or _carry_shardings(mesh),
-    )
-
-
-def decode_batch_spec(mesh: Mesh) -> P:
-    """Decode activations ``[max_batch, 1, H]``: slots over dp."""
-    axes = getattr(mesh, "axis_names", ())
-    dp = "dp" if "dp" in axes and mesh.shape["dp"] > 1 else None
-    return P(dp, None, None)
-
-
-def _decode_step_math(carry, params, active, config: ModelConfig,
-                      mesh: Mesh, quantized: bool = False):
-    """The decode-step computation shared VERBATIM by the per-step jit
-    and every trip of the fused scan (the equivalence contract between
-    the two engines is that this is the one copy of the math).
-
-    ``quantized`` reads/writes the int8 layout: each layer's blocks are
-    dequantised to fp32 (exact — int8 times an fp32 scale), the token
-    appended in fp, attention length-masked as ever, and the layer
-    requantised with an active-slot select so an INACTIVE slot's int8/
-    scale planes pass through verbatim.  An active slot's untouched
-    blocks survive the dequant->requant round trip bit-stably: every
-    stored value is ``q*s`` with ``|q| <= 127``, the recomputed scale
-    differs from ``s`` only by fp32 rounding, so the re-rounded code is
-    the same ``q`` (error ~2^-22 * 127, far below the 0.5 rounding
-    threshold)."""
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-    cache, x = carry
-    b_dim, s_max = cache.max_batch, cache.max_seq
-    lengths = cache.lengths
-    pos = jnp.arange(s_max)[None, :]
-    valid = pos <= lengths[:, None]
-
-    def attention_step(q, k, v, cache_state):
-        l, planes = cache_state
-        qh = _heads(q, n, d)                        # [B, n, 1, d]
-        k_new = k.reshape(b_dim, 1, kvh, d)
-        v_new = v.reshape(b_dim, 1, kvh, d)
-        if quantized:
-            attn, planes = quant_append_attend(qh, k_new, v_new, l, planes)
-        else:
-            # append at each active slot's own length, in place in the
-            # carried planes, then attend the tokens each slot holds
-            k_c, v_c = planes
-            k_c = append_token_rows(k_c, k_new, l, lengths, active, mesh)
-            v_c = append_token_rows(v_c, v_new, l, lengths, active, mesh)
-            attn = decode_attention(qh, k_c, v_c, l, lengths, active, mesh)
-            planes = (k_c, v_c)
-        return (attn.transpose(0, 2, 1, 3).reshape(b_dim, 1, n * d),
-                (planes, None))
-
-    def quant_append_attend(qh, k_new, v_new, l, planes):
-        """The int8 layout's append still rewrites its whole layer:
-        dequantise, masked-select append, attend, requantise, and put
-        the layer back into the carried planes."""
-        nb, bs = cache.num_blocks, cache.block_size
-        write_mask = (pos == lengths[:, None]) & active[:, None]
-        k_l, v_l, ks_l, vs_l = (_layer_of(p, l) for p in planes)
-        k_fp = dequantize_kv_blocks(k_l, ks_l, jnp.float32)
-        v_fp = dequantize_kv_blocks(v_l, vs_l, jnp.float32)
-        with jax.named_scope(KV_UPDATE):
-            k_flat = jnp.where(write_mask[..., None, None],
-                               k_new.astype(jnp.float32),
-                               k_fp.reshape(b_dim, s_max, kvh, d))
-            v_flat = jnp.where(write_mask[..., None, None],
-                               v_new.astype(jnp.float32),
-                               v_fp.reshape(b_dim, s_max, kvh, d))
-        attn = _cached_attention(qh, k_flat.astype(x.dtype),
-                                 v_flat.astype(x.dtype), valid)
-        with jax.named_scope(KV_UPDATE):
-            kq, ks = quantize_kv_blocks(
-                k_flat.reshape(b_dim, nb, bs, kvh, d))
-            vq, vs = quantize_kv_blocks(
-                v_flat.reshape(b_dim, nb, bs, kvh, d))
-            sel5 = active[:, None, None, None, None]
-            sel3 = active[:, None, None]
-            layer = (jnp.where(sel5, kq, k_l), jnp.where(sel5, vq, v_l),
-                     jnp.where(sel3, ks, ks_l), jnp.where(sel3, vs, vs_l))
-            planes = tuple(
-                jax.lax.dynamic_update_index_in_dim(p, new, l, 0)
-                for p, new in zip(planes, layer))
-        return attn, planes
-
-    h, new_planes, _ = _scan_layers(
-        x, params["layers"], cache[:-1], config, attention_step)
-    y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    lengths = lengths + active.astype(jnp.int32)
-    cache_cls = QuantKVCache if quantized else KVCache
-    new_cache = cache_cls(*new_planes, lengths)
-    return (new_cache, y), y
-
-
-def build_decode_step(config: ModelConfig, mesh: Mesh,
-                      quantized: bool = False):
-    """Jitted ``decode_step(carry, params, active) -> (carry, y)`` with
-    ``carry = (cache, x)`` — ONE fixed-shape compile for the whole run.
-    The carry is donated; its returned ``x`` is this step's output, so
-    the engine (and the calibration harness's carry protocol) feeds
-    ``out[0]`` straight back in."""
-
-    @named("serve_decode_step")
-    def decode_step(carry, params, active):
-        return _decode_step_math(carry, params, active, config, mesh,
-                                 quantized=quantized)
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    cache_sh = (quant_cache_shardings(mesh) if quantized
-                else cache_shardings(mesh))
-    return jax.jit(
-        decode_step,
-        donate_argnums=(0,),
-        out_shardings=((cache_sh, x_sh), x_sh),
-    )
-
-
-def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int,
-                       quantized: bool = False):
-    """Jitted ``decode_fused(carry, params, active, remaining) ->
-    (carry, ys)`` — ``k`` decode steps fused into ONE ``lax.scan``
-    dispatch over the donated ``(cache, x)`` carry (static ``k``; the
-    engine keeps a power-of-two ladder of these).
-
-    ``remaining[b]`` is slot ``b``'s step budget within this scan
-    (``min(k, tokens_left)``, 0 for inactive slots): step ``i`` runs
-    with ``active & (i < remaining)``, so a slot that completes
-    mid-scan is masked inactive for the rest of the trips — its cache
-    stops advancing exactly as if the per-step engine had deactivated
-    it, and the ledger frees its blocks at scan exit.  ``ys`` stacks
-    every step's output ``[k, max_batch, 1, H]`` (step t's row is the
-    token each then-active slot generated at trip t)."""
-    cache_cls = QuantKVCache if quantized else KVCache
-
-    @named(f"serve_decode_k{k}")
-    def decode_fused(carry, params, active, remaining):
-        # the slot-lengths vector deliberately stays OUT of the scan
-        # carry: its trajectory is fully determined by the replicated
-        # (lengths0, active, remaining) inputs — lengths at trip i are
-        # ``lengths0 + active * min(i, remaining)`` — so recomputing it
-        # per trip keeps it replicated everywhere.  Carried through the
-        # loop instead, GSPMD propagates the cache's dp sharding onto
-        # it and re-gathers at the loop boundary — a (tiny, but
-        # contract-breaking) collective the decode kind-set forbids.
-        # The trip index rides the carry as a scalar for the same
-        # reason (an arange-xs array invites an iota reshard).  The
-        # cache's data planes ride positionally (``cache[:-1]`` — K/V,
-        # plus the int8 scale planes when quantized), lengths excluded.
-        cache0, x0 = carry
-        lengths0 = cache0.lengths
-        act_i32 = active.astype(jnp.int32)
-
-        def step(c, _):
-            *planes, x, i = c
-            step_active = active & (i < remaining)
-            lengths_i = lengths0 + act_i32 * jnp.minimum(i, remaining)
-            (cache, x2), y = _decode_step_math(
-                (cache_cls(*planes, lengths_i), x), params, step_active,
-                config, mesh, quantized=quantized)
-            return (*cache[:-1], x2, i + 1), y
-
-        final, ys = jax.lax.scan(
-            step, (*cache0[:-1], x0, jnp.int32(0)), None, length=k)
-        *planes, x, _i = final
-        lengths_f = lengths0 + act_i32 * jnp.minimum(jnp.int32(k),
-                                                     remaining)
-        return (cache_cls(*planes, lengths_f), x), ys
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    ys_sh = NamedSharding(mesh, P(None, *decode_batch_spec(mesh)))
-    cache_sh = (quant_cache_shardings(mesh) if quantized
-                else cache_shardings(mesh))
-    return jax.jit(
-        decode_fused,
-        donate_argnums=(0,),
-        out_shardings=((cache_sh, x_sh), ys_sh),
-    )
-
-
-@named("serve_inject")
-def _inject_token(carry, slot, vec):
-    """Place a freshly-prefilled request's first token into the decode
-    input buffer: ``x[slot, 0] = vec``."""
-    cache, x = carry
-    mask = (jnp.arange(x.shape[0]) == slot)[:, None, None]
-    return cache, jnp.where(mask, vec[None, None, :].astype(x.dtype), x)
-
-
-# ---------------------------------------------------------------------------
-# speculative decoding (docs/serving.md, "Speculative decoding")
-# ---------------------------------------------------------------------------
-
-
-@named("serve_inject_greedy")
-def _inject_token_greedy(carry, slot, vec, table):
-    """Token-mode admission inject: quantise the prefill's last output
-    through the greedy token table (``tok = argmax(vec)``, ``x[slot, 0]
-    = table[tok]``) and return the token id — the 4-byte scalar is the
-    only thing that ever comes to host (the n-gram drafter's history
-    seed + the equivalence gate's capture)."""
-    cache, x = carry
-    tok = jnp.argmax(vec).astype(jnp.int32)
-    emb = jnp.take(table, tok, axis=0)
-    return ((cache,
-             jnp.where((jnp.arange(x.shape[0]) == slot)[:, None, None],
-                       emb[None, None, :].astype(x.dtype), x)),
-            tok)
-
-
-@named("serve_inject_sampled")
-def _inject_token_sampled(carry, slot, tok, table):
-    """Sampled-mode admission inject: the HOST already sampled the
-    first token from the prefill's softmax (``temperature > 0``), so
-    the device only embeds the committed id — ``x[slot, 0] =
-    table[tok]`` (the greedy inject with the argmax replaced by the
-    host's draw)."""
-    cache, x = carry
-    emb = jnp.take(table, tok.astype(jnp.int32), axis=0)
-    return (cache,
-            jnp.where((jnp.arange(x.shape[0]) == slot)[:, None, None],
-                      emb[None, None, :].astype(x.dtype), x))
-
-
-@jax.named_scope(KV_ATTEND)
-def _verify_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
-                      valid: jax.Array) -> jax.Array:
-    """Offset-causal length-masked attention for one verify step.
-
-    q: ``[B, n, G, d]`` (G = gamma+1 verify positions per slot);
-    k_flat/v_flat: ``[B, S_max, kvh, d]``; valid: ``[B, G, S_max]`` bool
-    — query ``i`` of slot ``b`` reaches keys ``j <= lengths[b] + i``
-    (the per-slot offset-causal mask, ``_chunk_attention``'s static mask
-    made per-slot dynamic).  Same math as ``_cached_attention`` (fp32
-    softmax, 1/sqrt(d), grouped-query broadcasting), of which it is the
-    G>1 generalisation."""
-    b, n, g, d = q.shape
-    kvh = k_flat.shape[2]
-    q32 = q.astype(jnp.float32)
-    k32 = k_flat.transpose(0, 2, 1, 3).astype(jnp.float32)  # [B, kvh, S, d]
-    v32 = v_flat.transpose(0, 2, 1, 3).astype(jnp.float32)
-    if kvh != n:
-        q32 = q32.reshape(b, kvh, n // kvh, g, d)
-        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(valid[:, None, None, :, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v32)
-        out = out.reshape(b, n, g, d)
-    else:
-        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / math.sqrt(d)
-        logits = jnp.where(valid[:, None, :, :], logits, -jnp.inf)
-        probs = jax.nn.softmax(logits, axis=-1)
-        out = jnp.einsum("bnqk,bnkd->bnqd", probs, v32)
-    return out.astype(k_flat.dtype)
-
-
-def build_decode_token_step(config: ModelConfig, mesh: Mesh):
-    """Jitted token-feedback decode step: the per-step decode math
-    (verbatim ``_decode_step_math``) followed by the greedy token
-    quantisation — ``tok = argmax(y)``, next input ``table[tok]``.
-    Returns ``(carry, tok [B])``; the token ids are the committed
-    output (device argmax, never a host float transfer).  This is the
-    speculative modes' pinned per-step oracle."""
-
-    @named("serve_decode_token_step")
-    def decode_token_step(carry, params, table, active):
-        (cache, y), _ = _decode_step_math(carry, params, active, config,
-                                              mesh)
-        tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
-        x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(y.dtype)
-        return (cache, x2), tok
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    dp_ax = decode_batch_spec(mesh)[0]
-    return jax.jit(
-        decode_token_step,
-        donate_argnums=(0,),
-        out_shardings=((cache_shardings(mesh), x_sh),
-                       NamedSharding(mesh, P(dp_ax))),
-    )
-
-
-def build_decode_fused_token(config: ModelConfig, mesh: Mesh, k: int):
-    """The fused K-step scan in token-feedback mode: identical trip
-    structure to ``build_decode_fused`` (lengths recomputed per trip
-    from the replicated inputs — same dp-reshard hazard, same fix) with
-    the greedy token quantisation between trips.  Returns ``(carry,
-    toks [k, B])``."""
-
-    @named(f"serve_decode_token_k{k}")
-    def decode_fused_token(carry, params, table, active, remaining):
-        cache0, x0 = carry
-        lengths0 = cache0.lengths
-        act_i32 = active.astype(jnp.int32)
-
-        def step(c, _):
-            k_c, v_c, x, i = c
-            step_active = active & (i < remaining)
-            lengths_i = lengths0 + act_i32 * jnp.minimum(i, remaining)
-            (cache, _x2), y = _decode_step_math(
-                (KVCache(k_c, v_c, lengths_i), x), params, step_active,
-                config, mesh)
-            tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
-            x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(x.dtype)
-            return (cache.k, cache.v, x2, i + 1), tok
-
-        (k_c, v_c, x, _i), toks = jax.lax.scan(
-            step, (cache0.k, cache0.v, x0, jnp.int32(0)), None, length=k)
-        lengths_f = lengths0 + act_i32 * jnp.minimum(jnp.int32(k),
-                                                     remaining)
-        return (KVCache(k_c, v_c, lengths_f), x), toks
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    dp_ax = decode_batch_spec(mesh)[0]
-    return jax.jit(
-        decode_fused_token,
-        donate_argnums=(0,),
-        out_shardings=((cache_shardings(mesh), x_sh),
-                       NamedSharding(mesh, P(None, dp_ax))),
-    )
-
-
-def _verify_forward(carry, params, table, draft_ids, active,
-                    config: ModelConfig, mesh: Mesh):
-    """The batched verify forward both verify programs run: the carry
-    token and the γ drafted tokens of every slot through ONE ``[B, γ+1,
-    H]`` ``_serve_block`` stack.  Per layer the γ+1 positions append
-    their K/V at ``lengths + i`` (``append_token_rows``, the decode
-    step's in-place row write with γ+1 rows a slot), exactly as γ+1
-    sequential decode steps would, and attend under the per-slot
-    offset-causal mask.  Returns ``(k, v, y [B, γ+1, H])``; what is
-    committed of it is the caller's business."""
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-    cache, x = carry
-    b_dim, s_max = cache.max_batch, cache.max_seq
-    g1 = draft_ids.shape[1] + 1
-    lengths = cache.lengths
-    d_emb = jnp.take(table, draft_ids, axis=0).astype(x.dtype)
-    h0 = jnp.concatenate([x, d_emb], axis=1)        # [B, γ+1, H]
-    pos = jnp.arange(s_max)[None, :]                # [1, S]
-    offs = lengths[:, None] + jnp.arange(g1)[None, :]   # [B, γ+1]
-    valid = pos[:, None, :] <= offs[:, :, None]     # [B, γ+1, S]
-
-    def attention_step(q, k, v, cache_state):
-        l, (k_c, v_c) = cache_state
-        qh = _heads(q, n, d)                        # [B, n, γ+1, d]
-        k_c = append_token_rows(k_c, k.reshape(b_dim, g1, kvh, d), l,
-                                lengths, active, mesh)
-        v_c = append_token_rows(v_c, v.reshape(b_dim, g1, kvh, d), l,
-                                lengths, active, mesh)
-        attn = _verify_attention(qh, _layer_tokens(k_c, l),
-                                 _layer_tokens(v_c, l), valid)
-        return (attn.transpose(0, 2, 1, 3).reshape(b_dim, g1, n * d),
-                ((k_c, v_c), None))
-
-    h, (k_new, v_new), _ = _scan_layers(
-        h0, params["layers"], (cache.k, cache.v), config, attention_step)
-    y = _layernorm(h, params["ln_f"]["scale"], params["ln_f"]["bias"])
-    return k_new, v_new, y
-
-
-def build_verify_step(config: ModelConfig, mesh: Mesh, gamma: int):
-    """Jitted draft-and-verify target forward: the γ proposed tokens of
-    every slot run through ONE batched ``[max_batch, γ+1, H]``
-    ``_serve_block`` stack under the per-slot offset-causal mask
-    (``_verify_attention``) — one fused forward per verify unit, zero
-    per-draft-token dispatches or collectives (audited:
-    ``verify_step_expectation``).
-
-    Inputs: the donated ``(cache, x)`` carry, the token table, the
-    drafters' ``draft_ids [B, γ]``, ``active`` and ``remaining`` (each
-    slot's output-token budget).  Per layer, all γ+1 positions append
-    K/V at ``lengths + i`` (``append_token_rows``, the decode-step
-    append with γ+1 rows a slot), exactly as γ+1 sequential decode
-    steps would.
-
-    Greedy acceptance: ``tok = argmax(y)`` gives the target's true
-    token at every position; the accepted prefix length is the run of
-    leading draft/target matches, and ``commits = min(accepted+1,
-    remaining)`` (the +1 is the verify's own bonus token — the target
-    output at the first mismatch position, whose input was still a
-    verified token).  New lengths advance by ``commits``; the rejected
-    suffix's cache entries are DEAD BY CONSTRUCTION — attention is
-    length-masked, and the next unit's writes land at the committed
-    lengths, overwriting every rejected position before any later
-    query's mask can reach it (asserted by the token-identity tests,
-    never copied or zeroed).  ``x'`` is the last committed token's
-    embedding, so the carry protocol is unchanged.
-
-    Returns ``(carry, tok [B, γ+1], commits [B])``; tok/commits stay
-    dp-sharded (no boundary gather — the host reads them at the unit's
-    sync)."""
-
-    @named(f"serve_spec_verify_g{gamma}")
-    def verify_step(carry, params, table, draft_ids, active, remaining):
-        cache, x = carry
-        lengths = cache.lengths
-        k_new, v_new, y = _verify_forward(carry, params, table, draft_ids,
-                                          active, config, mesh)
-        tok = jnp.argmax(y, axis=-1).astype(jnp.int32)  # [B, γ+1]
-        match = (tok[:, :gamma] == draft_ids).astype(jnp.int32)
-        accepted = jnp.sum(jnp.cumprod(match, axis=1), axis=1)  # [B]
-        commits = jnp.where(active,
-                            jnp.minimum(accepted + 1, remaining),
-                            0).astype(jnp.int32)
-        lengths_f = (lengths + commits).astype(jnp.int32)
-        last = jnp.take_along_axis(
-            tok, jnp.maximum(commits - 1, 0)[:, None], axis=1)[:, 0]
-        x_new = jnp.take(table, last, axis=0)[:, None, :].astype(x.dtype)
-        x_f = jnp.where(active[:, None, None], x_new, x)
-        return (KVCache(k_new, v_new, lengths_f), x_f), tok, commits
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    dp_ax = decode_batch_spec(mesh)[0]
-    return jax.jit(
-        verify_step,
-        donate_argnums=(0,),
-        out_shardings=((cache_shardings(mesh), x_sh),
-                       NamedSharding(mesh, P(dp_ax, None)),
-                       NamedSharding(mesh, P(dp_ax))),
-    )
-
-
-def build_verify_probs(config: ModelConfig, mesh: Mesh, gamma: int):
-    """The SAMPLED verify's device half: ``build_verify_step``'s exact
-    batched γ+1-position forward (same K/V appends at ``lengths +
-    i``, same offset-causal mask), but acceptance moves to
-    the HOST — the program returns the raw verify logits ``y [B, γ+1,
-    H]`` and commits NOTHING: lengths and ``x`` come back unchanged,
-    so the appended-but-uncommitted cache positions sit past every
-    slot's length (dead by the usual mask construction) until the
-    host's residual-sampling pass decides the true commits and the
-    tiny ``build_spec_commit`` program advances the carry.  Re-running
-    the program on the returned carry is therefore idempotent — the
-    retry ladder's contract.
-
-    ``gamma=0`` degenerates to a plain decode step that returns its
-    softmax-able logits without committing — the sampled path's
-    cold-drafter fallback unit (one sampled token per trip)."""
-
-    @named(f"serve_spec_probs_g{gamma}")
-    def verify_probs(carry, params, table, draft_ids, active):
-        cache, x = carry
-        k_new, v_new, y = _verify_forward(carry, params, table, draft_ids,
-                                          active, config, mesh)
-        return (KVCache(k_new, v_new, cache.lengths), x), y
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    dp_ax = decode_batch_spec(mesh)[0]
-    return jax.jit(
-        verify_probs,
-        donate_argnums=(0,),
-        out_shardings=((cache_shardings(mesh), x_sh),
-                       NamedSharding(mesh, P(dp_ax, None, None))),
-    )
-
-
-def build_spec_commit(config: ModelConfig, mesh: Mesh):
-    """The sampled verify's commit half: the host's residual-sampling
-    pass decided ``commits`` (per-slot committed window length) and
-    ``next_ids`` (each slot's LAST committed token — the next unit's
-    input); this tiny program advances lengths by the commits and
-    re-embeds ``x`` from the token table, completing exactly the carry
-    protocol ``build_verify_step`` applies on device for the greedy
-    law.  The rejected suffix needs no cleanup — same dead-by-
-    construction argument as the greedy verify."""
-
-    @named("serve_spec_commit")
-    def spec_commit(carry, table, next_ids, commits, active):
-        cache, x = carry
-        lengths_f = (cache.lengths + commits).astype(jnp.int32)
-        emb = jnp.take(table, next_ids, axis=0)[:, None, :].astype(x.dtype)
-        x_f = jnp.where(active[:, None, None], emb, x)
-        return (KVCache(cache.k, cache.v, lengths_f), x_f)
-
-    x_sh = NamedSharding(mesh, decode_batch_spec(mesh))
-    return jax.jit(
-        spec_commit,
-        donate_argnums=(0,),
-        out_shardings=(cache_shardings(mesh), x_sh),
-    )
-
-
-def build_draft_scan(config: ModelConfig, mesh: Mesh, gamma: int):
-    """Jitted draft-model proposal scan: γ greedy token-feedback decode
-    steps of the SHALLOW draft transformer over its own donated paged
-    cache plane — ``draft_scan(cache, params, table, x, lengths,
-    active) -> (cache, draft_ids [B, γ])``.
-
-    ``x`` is the TARGET's current carry input (the draft shares the
-    target's hidden size and token table, so the committed-token
-    embedding is the right draft input); ``lengths`` are the HOST'S
-    committed lengths, passed explicitly — this IS the draft plane's
-    rejection rollback: the cache's own lengths leaf (advanced by γ
-    last unit) is simply overridden, and entries past the committed
-    lengths are dead by the same length-mask construction as the
-    target's.  The ids stay on device (dp-sharded) and flow straight
-    into the verify step — no host round-trip in the draft-verify
-    chain."""
-
-    @named(f"serve_spec_draft_g{gamma}")
-    def draft_scan(cache, params, table, x, lengths, active):
-        act_i32 = active.astype(jnp.int32)
-
-        def step(c, _):
-            k_c, v_c, x_c, i = c
-            lengths_i = lengths + act_i32 * i
-            (cache_i, _x2), y = _decode_step_math(
-                (KVCache(k_c, v_c, lengths_i), x_c), params, active,
-                config, mesh)
-            tok = jnp.argmax(y[:, 0, :], axis=-1).astype(jnp.int32)
-            x2 = jnp.take(table, tok, axis=0)[:, None, :].astype(x_c.dtype)
-            return (cache_i.k, cache_i.v, x2, i + 1), tok
-
-        (k_c, v_c, _x, _i), toks = jax.lax.scan(
-            step, (cache.k, cache.v, x, jnp.int32(0)), None, length=gamma)
-        lengths_f = lengths + act_i32 * gamma
-        return KVCache(k_c, v_c, lengths_f), toks.T    # ids [B, γ]
-
-    dp_ax = decode_batch_spec(mesh)[0]
-    return jax.jit(
-        draft_scan,
-        donate_argnums=(0,),
-        out_shardings=(cache_shardings(mesh),
-                       NamedSharding(mesh, P(dp_ax, None))),
-    )
-
-
-def _ngram_propose(hist: list, gamma: int,
-                   max_ngram: int = 3) -> Optional[list]:
-    """Prompt-lookup / n-gram drafting (Saxena 2023): find the most
-    recent earlier occurrence of the history's trailing n-gram (n from
-    ``max_ngram`` down to 1) in ``hist`` (= the request's prompt token
-    ids + every committed token) and propose the γ ids that followed
-    it.  When the match sits d < γ positions back, the continuation
-    runs off the end of the history after d tokens — but a trailing
-    match at distance d means the history is locally d-periodic, so
-    the proposal extends CYCLICALLY through that period rather than
-    flat-padding (greedy feedback through a fixed table falls into
-    short cycles, and cyclic extension is what lets a γ≫d proposal
-    stay correct for the whole window).  Pure, deterministic function
-    of the history — drafter determinism from trace seeds is a test
-    invariant.  None = cold (no occurrence of even the last token):
-    the scheduler falls back to a plain decode unit."""
-    ln = len(hist)
-    for n in range(min(max_ngram, ln - 1), 0, -1):
-        key = hist[ln - n:]
-        for start in range(ln - n - 1, -1, -1):
-            if hist[start:start + n] == key:
-                cont = list(hist[start + n:start + n + gamma])
-                if len(cont) < gamma:
-                    d = len(cont)  # == distance back to the match
-                    cont += [cont[i % d] for i in range(d, gamma)]
-                return cont
-    return None
-
-
-def softmax_np(logits: np.ndarray, temperature: float) -> np.ndarray:
-    """Host-side temperature softmax (float64, max-subtracted) — the
-    sampled path's target law ``p``.  The device never softmaxes: the
-    verify logits come to host raw and every probability the sampler
-    consumes is computed here, so the sampled law is exactly
-    reproducible from the journal'd seeds."""
-    z = np.asarray(logits, np.float64) / float(temperature)
-    z = z - z.max(axis=-1, keepdims=True)
-    e = np.exp(z)
-    return e / e.sum(axis=-1, keepdims=True)
-
-
-def residual_distribution(p_target: np.ndarray,
-                          q_draft: np.ndarray) -> np.ndarray:
-    """The rejection-correction distribution of speculative SAMPLING
-    (Leviathan et al. 2023): ``norm(max(p - q, 0))``.  Degenerates to
-    ``p`` when ``q`` dominates it everywhere (rejection then has zero
-    probability, so the branch is never taken)."""
-    resid = np.maximum(np.asarray(p_target, np.float64)
-                       - np.asarray(q_draft, np.float64), 0.0)
-    z = resid.sum()
-    if z <= 0.0:
-        return np.asarray(p_target, np.float64)
-    return resid / z
-
-
-def speculative_sample(p_target: np.ndarray, q_draft: np.ndarray,
-                       draft_id: int,
-                       rng: np.random.Generator) -> tuple[int, bool]:
-    """One position of the residual-sampling correction — HOW the
-    equivalence gate weakens for sampled (temperature > 0) decode:
-    accept the drafted token with probability ``min(1, p/q)``; on
-    rejection, sample from ``residual_distribution(p, q)``.  The
-    composite law is exactly ``p`` (distribution-identity, pinned by
-    ``tests/test_speculative.py``), so sampled speculative decode is
-    distribution-identical — not token-identical — to the sequential
-    sampler.  The engine's default serving path is greedy (argmax),
-    which this correction degenerates to as temperature -> 0; with
-    ``serving.temperature > 0`` the scheduler's verify units run this
-    helper position-by-position over the host-side verify softmax
-    (``q`` = the deterministic drafter's one-hot, so acceptance is
-    ``p[draft]`` and the residual is ``p`` with the draft's mass
-    removed — docs/serving.md)."""
-    p = float(p_target[draft_id])
-    q = float(q_draft[draft_id])
-    accept_p = 1.0 if q <= 0.0 and p > 0.0 else (
-        min(1.0, p / q) if q > 0.0 else 0.0)
-    if rng.uniform() < accept_p:
-        return int(draft_id), True
-    resid = residual_distribution(p_target, q_draft)
-    return int(rng.choice(len(resid), p=resid)), False
+    return gpt
 
 
 def _with_deadline(fn, deadline: Optional[float], label: str,
@@ -1861,7 +176,6 @@ class _RunStats:
     fused_steps: int = 0
     single_steps: int = 0
     prefill_chunks: int = 0
-    compacted_scans: int = 0
     # K/V tiles the decode units' steps fetched (ops/decode_attention.py)
     # and tiles the planes they ran over hold, times steps
     kv_tiles_live: int = 0
@@ -1909,6 +223,11 @@ class ServingEngine:
         axes = mesh.axis_names
         self.dp = mesh.shape["dp"] if "dp" in axes else 1
         self.tp = mesh.shape["tp"] if "tp" in axes else 1
+        # the block family's programs, looked up once (never per
+        # dispatch); what it cannot serve is refused before the envelope
+        # is held to the model
+        self._family = family = family_for(config)
+        family.check_serving(config, serving)
         serving.validate(config, dp=self.dp, tp=self.tp)
         self.config = config
         self.serving = serving
@@ -1972,68 +291,24 @@ class ServingEngine:
                                                 mesh))
         self._prefill_jits: dict[int, Any] = {}
         self._fused_ks = serving.fused_horizons
-        # a layer_types model (models/hybrid.py) runs the programs of
-        # serve/hybrid.py under the same names, through the same
-        # scheduler: token ids in, tokens fed back on the device
-        self._hybrid = None
-        carry_sh = None
-        if config.is_hybrid:
-            from dlbb_tpu.serve import hybrid as serve_hybrid
-
-            self._hybrid = serve_hybrid
-            self._probe_rids: tuple[int, ...] = ()
-            self.probed: dict[int, dict[str, Any]] = {}
-            # -1 names no probed request (the programs then return the
-            # last slot's logits, which nobody keeps)
-            self._probe_slots = np.full((serve_hybrid.PROBES,), -1, np.int32)
-            self._probe_dev = jnp.array(self._probe_slots)  # a copy
-            self._decode = self._with_probe(
-                serve_hybrid.build_decode_step(config, mesh))
-            self._decode_fused = {
-                k: self._with_probe(
-                    serve_hybrid.build_decode_fused(config, mesh, k))
-                for k in self._fused_ks
-            }
-            carry_sh = (serve_hybrid.hybrid_cache_shardings(mesh),
-                        NamedSharding(mesh, serve_hybrid.token_spec(mesh)))
-            self.registry.inc(
-                "serve_state_resets", 0,
-                help="recycled slots whose recurrent state a new "
-                     "request's first prompt chunk cleared")
-            self.registry.set_gauge(
-                "serve_state_bytes",
-                state_cache_bytes(config, serving.max_batch),
-                help="bytes of slot-indexed recurrent state and "
-                     "convolution inputs the cache holds")
-            self.registry.set_gauge(
-                "serve_kv_bytes",
-                kv_cache_bytes(config, serving.max_batch, serving.max_seq,
-                               tp=self.tp),
-                help="bytes of paged K/V the cache holds (full-attention "
-                     "layers only)")
-        else:
-            self._decode = build_decode_step(config, mesh,
-                                             quantized=self._quantized)
-            self._decode_fused = {
-                k: build_decode_fused(config, mesh, k,
-                                      quantized=self._quantized)
-                for k in self._fused_ks
-            }
+        # probed requests (``probe``), for a family whose decode programs
+        # return logits: -1 names no probed request (the programs then
+        # return the last slot's logits, which nobody keeps)
+        self._probes = family.PROBES
+        self._probe_rids: tuple[int, ...] = ()
+        self.probed: dict[int, dict[str, Any]] = {}
+        self._probe_slots = np.full((self._probes,), -1, np.int32)
+        self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self._decode, self._decode_fused = family.decode_programs(
+            config, mesh, self._fused_ks, quantized=self._quantized,
+            probe=lambda: self._probe_dev)
+        family.register_metrics(self.registry, config, serving, self.tp)
         self._prefill_chunk_jits: dict[int, Any] = {}
         self._attach_jits: dict[int, Any] = {}
-        self._compact_gather_fn = None
-        self._compact_scatter_fn = None
-        if serving.compact_threshold is not None:
-            self._compact_gather_fn = build_compact_gather(mesh, carry_sh)
-            self._compact_scatter_fn = build_compact_scatter(mesh, carry_sh)
         self._fast = (serving.decode_horizon > 1
                       or serving.inflight_window > 1
-                      or serving.prefill_chunk is not None
-                      or serving.compact_threshold is not None)
-        self._inject = jax.jit(
-            self._hybrid.inject_token if self._hybrid else _inject_token,
-            donate_argnums=(0,))
-        self._x_sharding = NamedSharding(mesh, decode_batch_spec(mesh))
+                      or serving.prefill_chunk is not None)
+        self._inject = jax.jit(family.inject_token, donate_argnums=(0,))
         self._active_sharding = NamedSharding(mesh, P())
         # the fp layout's decode attention fetches tiles of this many
         # tokens under each slot's length: the kernel's own reckoning
@@ -2054,9 +329,8 @@ class ServingEngine:
                 self.registry.inc(name, 0, help=hlp)
         # -- speculative decoding (docs/serving.md) --
         # token-feedback modes quantise decode through the greedy token
-        # table; the legacy jits above stay built (jax.jit is lazy, so
-        # an unused ladder costs nothing) and the "off" path is
-        # bit-for-bit untouched
+        # table; the programs above stay built (jax.jit is lazy, so an
+        # unused ladder costs nothing)
         self._token_mode = serving.speculation != "off"
         # non-adaptive runs verify at exactly spec_gamma; adaptive runs
         # need the whole back-off ladder compiled
@@ -2075,14 +349,14 @@ class ServingEngine:
             self._table = jax.device_put(
                 token_embedding_table(config.hidden_size, self._dtype),
                 NamedSharding(mesh, P()))
-            self._decode_token = build_decode_token_step(config, mesh)
+            self._decode_token = family.build_decode_token_step(config, mesh)
             self._decode_fused_token = {
-                k: build_decode_fused_token(config, mesh, k)
+                k: family.build_decode_fused_token(config, mesh, k)
                 for k in self._fused_ks
             }
-            self._inject_greedy = jax.jit(_inject_token_greedy,
+            self._inject_greedy = jax.jit(family.inject_token_greedy,
                                           donate_argnums=(0,))
-            dp_ax = decode_batch_spec(mesh)[0]
+            dp_ax = family.decode_batch_spec(mesh)[0]
             self._ids_sharding = NamedSharding(mesh, P(dp_ax, None))
         # sampled (temperature > 0) decode: host residual sampling over
         # the verify logits — verify_probs/spec_commit replace the
@@ -2097,17 +371,18 @@ class ServingEngine:
             probs_gammas = set(self._spec_gammas)
             if serving.speculation == "ngram":
                 probs_gammas.add(0)     # the cold-drafter fallback unit
-            self._verify_probs = {g: build_verify_probs(config, mesh, g)
-                                  for g in sorted(probs_gammas)}
-            self._spec_commit = build_spec_commit(config, mesh)
-            self._inject_sampled = jax.jit(_inject_token_sampled,
+            self._verify_probs = {
+                g: family.build_verify_probs(config, mesh, g)
+                for g in sorted(probs_gammas)}
+            self._spec_commit = family.build_spec_commit(config, mesh)
+            self._inject_sampled = jax.jit(family.inject_token_sampled,
                                            donate_argnums=(0,))
             self.registry.inc(
                 "serve_sampled_tokens", 0,
                 help="tokens committed by the sampled (temperature > 0) "
                      "residual-sampling path")
         if serving.spec_drafting:
-            self._verify = {g: build_verify_step(config, mesh, g)
+            self._verify = {g: family.build_verify_step(config, mesh, g)
                             for g in self._spec_gammas}
             self._spec_proposed = self.registry.labeled_counter(
                 "serve_spec_proposed_total", "drafter",
@@ -2126,10 +401,10 @@ class ServingEngine:
             # identically; sharded by the same ParallelismPlan
             self._draft_params = init_params_sharded(
                 self._draft_config, jax.random.key(seed + 1), mesh)
-            self._draft_prefill = build_prefill(
+            self._draft_prefill = family.build_prefill(
                 self._draft_config, mesh, name="serve_spec_draft_prefill")
             self._draft_scan = {
-                g: build_draft_scan(self._draft_config, mesh, g)
+                g: family.build_draft_scan(self._draft_config, mesh, g)
                 for g in self._spec_gammas
             }
         self._t0 = time.perf_counter()
@@ -2141,18 +416,6 @@ class ServingEngine:
 
     # -- setup -------------------------------------------------------------
 
-    def _with_probe(self, program):
-        """A hybrid decode program under the GPT programs' signature
-        ``(carry, params, active[, remaining]) -> (carry, ys)``: the
-        probed slots go in as its last argument, and ``ys`` is the
-        pair ``(tokens, logits of the probed slots)``."""
-        def call(carry, params, *masks, probe=None):
-            carry, toks, seen = program(
-                carry, params, *masks,
-                self._probe_dev if probe is None else probe)
-            return carry, (toks, seen)
-        return call
-
     def probe(self, rids) -> None:
         """Keep, for the requests ``rids`` (at most ``PROBES`` resident
         at once), the logits the serving programs themselves produced:
@@ -2161,10 +424,9 @@ class ServingEngine:
         fetches them.  Nothing is synced or copied to the host while a
         trace is served, and the same programs run whether or not a
         request is probed.  Each ``run_trace`` starts the record anew.
-        Only ``layer_types`` models return logits."""
-        if self._hybrid is None:
-            raise ValueError("probe() needs a layer_types model: the GPT "
-                             "block has no vocabulary and no logits")
+        Only a family whose decode programs return logits has it."""
+        if not self._probes:
+            raise ValueError(self._family.LACKS["probe"])
         self._probe_rids = tuple(int(r) for r in rids)
 
     def probe_results(self) -> dict[int, dict[str, Any]]:
@@ -2211,44 +473,23 @@ class ServingEngine:
             "first_logits": first_logits, "units": [],
             # the slot's recurrent state as the prompt's last chunk
             # left it: a copy of one slot, dispatched and not waited for
-            "prompt_state": self._hybrid.slot_state(cache, np.int32(slot)),
+            "prompt_state": self._family.slot_state(cache, np.int32(slot)),
             "end_state": None}
 
     def _prompt_input(self, req: Request, pad_to: int) -> jax.Array:
-        """A request's prompt as the prefill programs take it: seeded
-        embeddings ``[1, pad_to, hidden]``, or for a ``layer_types``
-        model token ids ``[1, pad_to]`` (embedded on the device)."""
-        if self._hybrid is not None:
-            return jnp.asarray(prompt_ids_from_seed(
-                req.seed, req.prompt_len, self.config.vocab_size,
-                pad_to=pad_to))
-        return request_embeddings(
-            req.seed, req.prompt_len, self.config.hidden_size,
-            dtype=self._dtype, pad_to=pad_to,
-            prefix_len=req.prefix_len, prefix_seed=req.prefix_seed)
+        """A request's prompt as the family's chunk programs take it
+        (seeded embeddings, or token ids embedded on the device)."""
+        return self._family.prompt_input(self.config, req, pad_to,
+                                         self._dtype)
 
     def _fresh_carry(self):
-        if self._hybrid is not None:
-            return self._hybrid.fresh_carry(self.config, self.serving,
-                                            self.mesh)
-        create = (create_quant_kv_cache if self._quantized
-                  else create_kv_cache)
-        cache = create(
-            self.config, self.serving.max_batch, self.serving.num_blocks,
-            self.serving.block_size, mesh=self.mesh,
-        )
-        x = jax.device_put(
-            jnp.zeros((self.serving.max_batch, 1, self.config.hidden_size),
-                      self._dtype),
-            self._x_sharding,
-        )
-        return (cache, x)
+        return self._family.fresh_carry(self.config, self.serving,
+                                        self.mesh)
 
     def _create_prefix(self):
-        """The carry a prompt's first chunk starts from."""
-        if self._hybrid is not None:
-            return self._hybrid.create_prefix(self.config, self.mesh)
-        return create_prefix(self.config, self.mesh)
+        """The carry a prompt's first chunk starts from (looked up in
+        the family at each call: a checker may replace it)."""
+        return self._family.create_prefix(self.config, self.mesh)
 
     def _fresh_draft_cache(self) -> Optional[KVCache]:
         """The draft model's own paged KV plane (same slot/block
@@ -2276,11 +517,8 @@ class ServingEngine:
         sweep captures are."""
         from dlbb_tpu.obs import capture as obs_capture
 
-        if self._hybrid is not None:
-            raise ValueError(
-                "capture_device_traces is not wired for layer_types "
-                "models (it replays a monolithic prefill, which they do "
-                "not have); trace a run with benchmarks/run.py --trace 1")
+        if "monolithic_prefill" in self._family.LACKS:
+            raise ValueError(self._family.LACKS["monolithic_prefill"])
         cfg = self.serving
         bucket = cfg.prefill_buckets[0]
 
@@ -2389,9 +627,9 @@ class ServingEngine:
         ``_compile``)."""
         jit = self._prefill_jits.get(bucket)
         if jit is None:
-            jit = build_prefill(self.config, self.mesh,
-                                quantized=self._quantized,
-                                name=f"serve_prefill_b{bucket}")
+            jit = self._family.build_prefill(
+                self.config, self.mesh, quantized=self._quantized,
+                name=f"serve_prefill_b{bucket}")
             self._prefill_jits[bucket] = jit
         return jit
 
@@ -2402,13 +640,9 @@ class ServingEngine:
         jit = self._prefill_chunk_jits.get(chunk_index)
         if jit is None:
             chunk = self.serving.prefill_chunk
-            if self._hybrid is not None:
-                jit = self._hybrid.build_prefill_chunk(
-                    self.config, self.mesh, chunk, chunk_index * chunk)
-            else:
-                jit = build_prefill_chunk(self.config, self.mesh, chunk,
-                                          chunk_index * chunk,
-                                          quantized=self._quantized)
+            jit = self._family.build_prefill_chunk(
+                self.config, self.mesh, chunk, chunk_index * chunk,
+                quantized=self._quantized)
             self._prefill_chunk_jits[chunk_index] = jit
         return jit
 
@@ -2419,17 +653,16 @@ class ServingEngine:
         jit = self._attach_jits.get(m_chunks)
         if jit is None:
             chunk = self.serving.prefill_chunk
-            jit = build_prefix_attach(self.config, self.mesh,
-                                      m_chunks * chunk,
-                                      self.serving.block_size,
-                                      quantized=self._quantized)
+            jit = self._family.build_prefix_attach(
+                self.config, self.mesh, m_chunks * chunk,
+                self.serving.block_size, quantized=self._quantized)
             self._attach_jits[m_chunks] = jit
         return jit
 
     def _compile(self, buckets: list[int], max_chunks: int = 0) -> None:
         """Warm every jit the trace will hit (prefill per bucket or per
-        chunk offset, decode + the fused-scan ladder, compaction,
-        inject) on scratch state, so compile time never lands in TTFT."""
+        chunk offset, decode + the fused-scan ladder, inject) on scratch
+        state, so compile time never lands in TTFT."""
         carry = self._fresh_carry()
         cfg = self.serving
         active = jax.device_put(
@@ -2466,7 +699,7 @@ class ServingEngine:
         remaining = jax.device_put(
             jnp.zeros((cfg.max_batch,), jnp.int32), self._active_sharding)
         if self._token_mode:
-            # token-feedback warms: the legacy inject/decode/fused jits
+            # token-feedback warms: the plain inject/decode/fused jits
             # are never dispatched in a token-mode run, so warming them
             # would only burn compile time — and a SAMPLED run likewise
             # never dispatches the greedy inject/decode/verify programs
@@ -2521,25 +754,12 @@ class ServingEngine:
             jax.block_until_ready(carry[1])
             return
         carry = self._inject(carry, np.int32(0), y_last)
-        if self._hybrid is not None:
-            self._hybrid.slot_state(carry[0], np.int32(0))
+        if self._probes:
+            self._family.slot_state(carry[0], np.int32(0))
         carry, _y = self._decode(carry, self.params, active)
         for k in self._fused_ks:
             carry, _ys = self._decode_fused[k](carry, self.params, active,
                                                remaining)
-        if self._compact_gather_fn is not None:
-            bucket = cfg.max_batch // 2
-            idx = jax.device_put(jnp.arange(bucket, dtype=jnp.int32),
-                                 self._active_sharding)
-            s_active = jax.device_put(jnp.zeros((bucket,), bool),
-                                      self._active_sharding)
-            s_rem = jax.device_put(jnp.zeros((bucket,), jnp.int32),
-                                   self._active_sharding)
-            small = self._compact_gather_fn(carry, idx)
-            for k in self._fused_ks:
-                small, _ys = self._decode_fused[k](small, self.params,
-                                                   s_active, s_rem)
-            carry = self._compact_scatter_fn(carry, small, idx)
         # block on the live carry, not an intermediate output: earlier
         # outputs may share buffers with a carry a later warm call donated
         jax.block_until_ready(carry[1])
@@ -2654,18 +874,18 @@ class ServingEngine:
         active_np = np.zeros((cfg.max_batch,), bool)
         active_dev = jax.device_put(jnp.array(active_np),
                                     self._active_sharding)
-        # layer_types models: slots that have served a request in this
-        # run (the next one's first chunk clears their state), and the
+        # slots that have served a request in this run (what the next
+        # one finds there is the family's: ``slot_recycled``), and the
         # record of the probed requests (``probe``)
         used_slots: set[int] = set()
-        if self._hybrid is not None:
-            self.probed = {}
-            self._probe_slots[:] = -1
-            self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self.probed = {}
+        self._probe_slots[:] = -1
+        self._probe_dev = jnp.array(self._probe_slots)  # a copy
         rejected_detail: list[dict[str, Any]] = []
         tokens_by_rid: dict[int, list[int]] = {}
         # -- speculative decoding state (docs/serving.md) --
         token_mode = self._token_mode
+        ys_are_tokens = token_mode or self._family.TOKENS_FED_BACK
         spec_on = cfg.spec_drafting
         # per-rid committed token history (prompt ids + every committed
         # token): the n-gram drafter's lookup context
@@ -2694,8 +914,8 @@ class ServingEngine:
         # silent skip (the serving twin of the sweep quarantine)
         failed_detail: list[dict[str, Any]] = []
         # bounded in-flight window: decode units dispatched but not yet
-        # synced (cfg.inflight_window == 1 syncs every unit — the
-        # legacy cadence); last_sync anchors the per-unit interval so
+        # synced (cfg.inflight_window == 1 syncs every unit);
+        # last_sync anchors the per-unit interval so
         # back-to-back units never double-count queued device time
         inflight: deque[dict[str, Any]] = deque()
         last_sync = [0.0]
@@ -2728,7 +948,7 @@ class ServingEngine:
             active_dirty[0] = True
             free_slots.append(slot)
             free_slots.sort()
-            if self._hybrid is not None and st.req.rid in self.probed:
+            if st.req.rid in self.probed:
                 self.probed[st.req.rid]["done"] = True
             return st
 
@@ -2942,7 +1162,7 @@ class ServingEngine:
             while inflight:
                 sync_one()
 
-        def decode_unit(k: int, steps: dict[int, int], compact: bool,
+        def decode_unit(k: int, steps: dict[int, int],
                         snap: dict[str, Any]) -> None:
             """One decode unit, committed: the device dispatch (under
             the watchdog when armed), torn-protected host bookkeeping,
@@ -2965,10 +1185,7 @@ class ServingEngine:
             # call of THIS unit) and ``serve-decode-sync`` (the wait,
             # with the ``k`` of the unit waited for); what is left is
             # the host bookkeeping at scan exit
-            span_args = dict(active=len(slots), steps=k)
-            if compact:
-                span_args["compacted"] = True
-            with spans.span("serve-decode", **span_args):
+            with spans.span("serve-decode", active=len(slots), steps=k):
                 if inject.fire("serve-decode-fail"):
                     # fires BEFORE the jit is invoked: the donated carry
                     # was never consumed, so a retry re-dispatches from
@@ -3003,45 +1220,6 @@ class ServingEngine:
                     stats.single_steps += 1
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, 1))
-                elif compact:
-                    bucket = cfg.max_batch // 2
-                    act = sorted(slots)
-                    idx_np = np.asarray(
-                        act + free_slots[:bucket - len(act)], np.int32)
-                    idx = jax.device_put(jnp.asarray(idx_np),
-                                         self._active_sharding)
-                    s_act_np = np.zeros((bucket,), bool)
-                    s_act_np[:len(act)] = True
-                    s_rem_np = np.zeros((bucket,), np.int32)
-                    for i, s in enumerate(act):
-                        s_rem_np[i] = steps[s]
-                    s_act = jax.device_put(jnp.asarray(s_act_np),
-                                           self._active_sharding)
-                    s_rem = jax.device_put(jnp.asarray(s_rem_np),
-                                           self._active_sharding)
-
-                    extra = {}
-                    if self._hybrid is not None:
-                        # the probed slots by their rows in the
-                        # compacted batch
-                        extra["probe"] = jnp.array(
-                            [act.index(s) if s in act else -1
-                             for s in self._probe_slots], jnp.int32)
-
-                    def compact_unit():
-                        small = self._compact_gather_fn(carry, idx)
-                        small, ys = self._decode_fused[k](
-                            small, self.params, s_act, s_rem, **extra)
-                        return (self._compact_scatter_fn(carry, small,
-                                                         idx), ys)
-
-                    carry, ys = dispatch(compact_unit)
-                    stats.fused_scans += 1
-                    stats.fused_steps += k
-                    stats.compacted_scans += 1
-                    self.registry.inc("serve_fused_scan_steps", k)
-                    for i, s in enumerate(act):
-                        rows.append((i, s, slots[s].req.rid, steps[s]))
                 else:
                     rem_np = np.zeros((cfg.max_batch,), np.int32)
                     for s, m in steps.items():
@@ -3072,8 +1250,7 @@ class ServingEngine:
                         start[None, :] + trip,
                         trip < np.array(list(steps.values()))[None, :],
                         self._kv_tile, max_tiles).sum())
-                    held = k * max_tiles * (cfg.max_batch // 2 if compact
-                                            else cfg.max_batch)
+                    held = k * max_tiles * cfg.max_batch
                     stats.kv_tiles_live += live
                     stats.kv_tiles_held += held
                     self.registry.inc("serve_kv_tiles_live", live)
@@ -3123,7 +1300,7 @@ class ServingEngine:
                 stats.decode_steps += k
                 stats.decode_units += 1
                 self.registry.inc("serve_decode_steps", k)
-                if self._hybrid is not None:
+                if self._probes:
                     # ys = (tokens, logits of the probed slots): a
                     # probed request keeps both, on the device
                     toks, seen = ys
@@ -3139,15 +1316,14 @@ class ServingEngine:
                         # left it, copied before the slot is given away
                         rec = self.probed.get(slots[s].req.rid)
                         if rec is not None:
-                            rec["end_state"] = self._hybrid.slot_state(
+                            rec["end_state"] = self._family.slot_state(
                                 carry[0], np.int32(s))
                 done_states = [release(s) for s in completions]
                 if completions:
                     refresh_active()
                 inflight.append({"t0": t0, "ys": ys, "k_exec": k,
                                  "rows": rows,
-                                 "tokens": (token_mode
-                                            or self._hybrid is not None),
+                                 "tokens": ys_are_tokens,
                                  "completions": done_states})
                 # a k==1 unit's y is the SAME logical value as the
                 # carry's x (decode_step returns ((cache, y), y)); on
@@ -3517,7 +1693,7 @@ class ServingEngine:
             """One decode unit over the resident batch: a single step,
             or — when no scheduling event needs an earlier boundary — a
             fused K-step scan (largest power-of-two bucket <= the
-            event horizon), optionally on a compacted half batch.
+            event horizon).
             ``max_k`` caps the horizon (the chunked-prefill interleave
             passes 1: the mid-admission request is itself a waiter, and
             a full fused scan between chunks would re-create the
@@ -3580,16 +1756,11 @@ class ServingEngine:
                     if cand <= horizon:
                         k = cand
                 steps = {s: min(k, r) for s, r in rem.items()}
-                compact = (
-                    self._compact_gather_fn is not None and k > 1
-                    and len(slots) <= cfg.compact_threshold * cfg.max_batch
-                    and len(slots) <= cfg.max_batch // 2
-                )
                 snap = take_snapshot()
             attempt = 0
             while True:
                 try:
-                    decode_unit(k, steps, compact, snap)
+                    decode_unit(k, steps, snap)
                     return
                 except (TransientFault, CorruptStats) as e:
                     # fired BEFORE the jit consumed the carry (the
@@ -4027,20 +2198,14 @@ class ServingEngine:
                             else:
                                 carry = self._inject(carry, np.int32(slot),
                                                      y_last)
-                            if self._hybrid is not None:
-                                recycled = slot in used_slots
-                                used_slots.add(slot)
-                                if recycled:
-                                    # the prompt's first chunk started
-                                    # from a zero state and overwrote
-                                    # what the slot's last request left
-                                    spans.instant("state-reset",
-                                                  cat="request",
-                                                  rid=req.rid, slot=slot)
-                                    self.registry.inc("serve_state_resets")
-                                if req.rid in self._probe_rids:
-                                    self._probe_slot(req, slot, recycled,
-                                                     y_last, carry[0])
+                            recycled = slot in used_slots
+                            used_slots.add(slot)
+                            if recycled:
+                                self._family.slot_recycled(
+                                    self.registry, req.rid, slot)
+                            if req.rid in self._probe_rids:
+                                self._probe_slot(req, slot, recycled,
+                                                 y_last, carry[0])
                         with spans.span("serve-admit-book", rid=req.rid,
                                         slot=slot):
                             ledger.append(slot, req.prompt_len)
@@ -4232,12 +2397,10 @@ class ServingEngine:
                 "decode_horizon": cfg.decode_horizon,
                 "inflight_window": cfg.inflight_window,
                 "prefill_chunk": cfg.prefill_chunk,
-                "compact_threshold": cfg.compact_threshold,
                 "fused_scans": stats.fused_scans,
                 "fused_steps": stats.fused_steps,
                 "single_steps": stats.single_steps,
                 "prefill_chunks": stats.prefill_chunks,
-                "compacted_scans": stats.compacted_scans,
                 "kv_tiles_live": stats.kv_tiles_live,
                 "kv_tiles_held": stats.kv_tiles_held,
             },

@@ -68,7 +68,8 @@ from dlbb_tpu.obs.export import MetricsRegistry
 from dlbb_tpu.resilience import inject
 from dlbb_tpu.resilience.errors import (DeadlineExceeded, InjectedFault,
                                         TornWrite, exception_chain)
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine, family_for
 from dlbb_tpu.serve.traffic import Request, TrafficTrace
 
 FLEET_REPORT_SCHEMA = "dlbb_fleet_report_v1"
@@ -408,7 +409,8 @@ def validate_fleet(config: dict[str, Any], model_cfg: ModelConfig,
     3. the per-replica (dp, tp) plan fits inside one domain;
     4. the per-replica serving envelope (incl. the HBM budget — each
        replica carries its OWN full KV planes) passes the engine's own
-       ``ServingConfig.validate``.
+       ``ServingConfig.validate`` and its block family's
+       ``check_serving``.
 
     Returns the per-replica ``(dp, tp)``."""
     fleet_cfg.validate()
@@ -435,6 +437,7 @@ def validate_fleet(config: dict[str, Any], model_cfg: ModelConfig,
             f"domains has only {per_domain} "
             f"({n_devices} devices total)"
         )
+    family_for(model_cfg).check_serving(model_cfg, serving_cfg)
     serving_cfg.validate(model_cfg, dp=dp, tp=tp)
     return dp, tp
 

@@ -1,5 +1,6 @@
-"""The serving programs of a ``layer_types`` model (``models/hybrid.py``):
-the same three kinds of program as ``serve/engine.py`` builds for the GPT
+"""The serving programs of a ``layer_types`` model (``models/hybrid.py``),
+one of the two block families the scheduler of ``serve/engine.py`` serves:
+the same three kinds of program as ``serve/gpt.py`` holds for the GPT
 block, under the same names, over a :class:`~dlbb_tpu.serve.kvcache.
 HybridCache`.
 
@@ -25,7 +26,9 @@ holds on the device to compare with a reference.  The programs are the
 same whether or not anything is probed.
 
 The block and the period are ``models/hybrid.py``'s; this file holds the
-three mixers that touch the cache.
+three mixers that touch the cache, and at its end what the scheduler
+asks of a family (the seam: ``docs/serving.md``, "Adding a block
+family").
 """
 
 from __future__ import annotations
@@ -36,11 +39,14 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dlbb_tpu.data.synthetic import prompt_ids_from_seed
 from dlbb_tpu.models import hybrid
 from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
     LINEAR_ATTENTION,
     ModelConfig,
+    kv_cache_bytes,
+    state_cache_bytes,
 )
 from dlbb_tpu.models.hybrid import LIN_CONV, LIN_CORE
 from dlbb_tpu.models.transformer import _dtype_of, named
@@ -50,6 +56,8 @@ from dlbb_tpu.ops.gated_delta import (
     gated_delta_chunked,
     gated_delta_step,
 )
+from dlbb_tpu.obs import spans
+from dlbb_tpu.serve.attend import _chunk_attention, _layer_of
 from dlbb_tpu.serve.kvcache import (
     HybridCache,
     append_token_rows,
@@ -59,17 +67,7 @@ from dlbb_tpu.serve.kvcache import (
     write_slot_blocks,
     write_slot_state,
 )
-
-# slots whose logits a decode program returns each step
-PROBES = 2
-
-
-def _engine():
-    # serve/engine.py imports this module when it builds a hybrid
-    # engine; its attention helpers are taken at trace time
-    from dlbb_tpu.serve import engine
-
-    return engine
+from dlbb_tpu.serve.traffic import Request
 
 
 def token_spec(mesh: Mesh) -> P:
@@ -79,7 +77,7 @@ def token_spec(mesh: Mesh) -> P:
 
 def prefix_specs(mesh: Mesh) -> tuple[P, P, P, P]:
     """The chunk carry ``(k, v, state, conv)``: no slot dim, heads over
-    tp (``engine.prefix_spec`` for K/V)."""
+    tp (``gpt.prefix_spec`` for K/V)."""
     tp = hybrid_cache_specs(mesh).k[4]
     kv = P(None, None, tp, None)
     return (kv, kv, P(None, tp, None, None), P(None, None, tp, None))
@@ -135,13 +133,12 @@ class ChunkMixer:
         return tuple(jnp.stack(o) for o in self.out)
 
     def attention(self, q, k, v, l, planes):
-        eng = _engine()
         pk, pv = self.xs[0], self.xs[1]
         j = len(self.out[0])
         k_all = jnp.concatenate([pk[j], k[0]], axis=0)
         v_all = jnp.concatenate([pv[j], v[0]], axis=0)
-        attn = eng._chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
-                                    self.start)
+        attn = _chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
+                                self.start)
         k_c, v_c, st, cv = planes
         blocks = (self.chunk_len // self.bs, self.bs) + k_c.shape[-2:]
         k_c = write_slot_blocks(
@@ -181,11 +178,13 @@ class ChunkMixer:
 
 
 def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
-                        start: int):
+                        start: int, quantized: bool = False):
     """Jitted ``prefill_chunk(cache, prefix, params, ids [1, chunk],
     slot, length) -> (cache, prefix, logits_last [vocab])``, the
-    signature of ``engine.build_prefill_chunk`` with token ids for
-    embeddings and float32 logits for the last hidden state."""
+    signature of ``gpt.build_prefill_chunk`` with token ids for
+    embeddings and float32 logits for the last hidden state.
+    ``quantized`` is the seam's: this family has the fp layout only
+    (``models.configs.validate_serving`` refuses int8)."""
     periods = config.num_layers // len(config.layer_types)
 
     @named(f"serve_prefill_chunk_o{start}")
@@ -241,18 +240,17 @@ class DecodeMixer:
                 (k_c, v_c, st, cv))
 
     def linear(self, qkv, log_alpha, beta, conv_w, l, planes):
-        eng = _engine()
         k_c, v_c, st, cv = planes
         keep = self.active[:, None, None, None]
         with jax.named_scope(LIN_CONV):
-            before = eng._layer_of(cv, l)
+            before = _layer_of(cv, l)
             ext = jnp.concatenate([before, qkv], axis=1)
             q, k, v = hybrid.split_qkv_heads(causal_conv(ext, conv_w),
                                              self.config)
             cv = jax.lax.dynamic_update_index_in_dim(
                 cv, jnp.where(keep, ext[:, 1:], before), l, 0)
         with jax.named_scope(LIN_CORE):
-            old = eng._layer_of(st, l)
+            old = _layer_of(st, l)
             o, new = gated_delta_step(q[:, 0], k[:, 0], v[:, 0],
                                       jnp.exp(log_alpha[:, 0]), beta[:, 0],
                                       old.astype(jnp.float32))
@@ -301,7 +299,7 @@ def build_decode_step(config: ModelConfig, mesh: Mesh):
 
 def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int):
     """``k`` decode steps in one ``lax.scan``, as
-    ``engine.build_decode_fused``: lengths recomputed each trip from the
+    ``gpt.build_decode_fused``: lengths recomputed each trip from the
     replicated inputs, planes and tokens in the carry.  Returns
     ``(carry, tokens [k, B], probe logits [k, PROBES, vocab])``."""
 
@@ -358,3 +356,98 @@ def fresh_carry(config: ModelConfig, serving: Any, mesh: Mesh):
     tok = jax.device_put(jnp.zeros((serving.max_batch,), jnp.int32),
                          NamedSharding(mesh, token_spec(mesh)))
     return cache, tok
+
+
+# ---------------------------------------------------------------------------
+# what the scheduler asks of a family (``serve/engine.py::family_for``)
+# ---------------------------------------------------------------------------
+
+# the decode programs feed token ids back on the device
+TOKENS_FED_BACK = True
+# slots whose logits a decode program returns each step
+PROBES = 2
+# what this family's serving path does not have, and the reason given
+LACKS = {
+    "monolithic_prefill":
+        "capture_device_traces is not wired for layer_types models (it "
+        "replays a monolithic prefill, which they do not have); trace a "
+        "run with benchmarks/run.py --trace 1",
+}
+
+
+def check_serving(config: ModelConfig, serving: Any) -> None:
+    """Refuse what this family's serving path does not have yet, each by
+    its mechanism (ROADMAP.md, Queue 2); int8 KV is refused in
+    ``models.configs.validate_serving``."""
+    if serving.speculation != "off":
+        raise ValueError(
+            f"serving.speculation={serving.speculation!r} is not "
+            "implemented for layer_types models: a rejected draft "
+            "needs the recurrent state rolled back, and the state "
+            "cache keeps no snapshots")
+    if serving.prefix_caching:
+        raise ValueError(
+            "serving.prefix_caching is not implemented for "
+            "layer_types models: attaching to shared blocks needs "
+            "the recurrent state as it was at the block boundary, "
+            "and the state cache keeps no snapshots")
+    if serving.prefill_chunk is None:
+        raise ValueError(
+            "layer_types models are prefilled in chunks: set "
+            "serving.prefill_chunk (the chunk program hands the "
+            "recurrent state from chunk to chunk; there is no "
+            "monolithic prefill program)")
+
+
+def register_metrics(registry: Any, config: ModelConfig, serving: Any,
+                     tp: int) -> None:
+    """This family's own counter and gauges: slot recycling, and what
+    each kind of cache holds."""
+    registry.inc(
+        "serve_state_resets", 0,
+        help="recycled slots whose recurrent state a new "
+             "request's first prompt chunk cleared")
+    registry.set_gauge(
+        "serve_state_bytes",
+        state_cache_bytes(config, serving.max_batch),
+        help="bytes of slot-indexed recurrent state and "
+             "convolution inputs the cache holds")
+    registry.set_gauge(
+        "serve_kv_bytes",
+        kv_cache_bytes(config, serving.max_batch, serving.max_seq,
+                       tp=tp),
+        help="bytes of paged K/V the cache holds (full-attention "
+             "layers only)")
+
+
+def slot_recycled(registry: Any, rid: int, slot: int) -> None:
+    """A slot that served a request is given to ``rid``: the prompt's
+    first chunk started from a zero state (:func:`create_prefix`) and
+    overwrote what the slot's last request left."""
+    spans.instant("state-reset", cat="request", rid=rid, slot=slot)
+    registry.inc("serve_state_resets")
+
+
+def prompt_input(config: ModelConfig, req: Request, pad_to: int,
+                 dtype: Any) -> jax.Array:
+    """A request's prompt as the chunk programs take it: token ids
+    ``[1, pad_to]``, embedded on the device."""
+    return jnp.asarray(prompt_ids_from_seed(
+        req.seed, req.prompt_len, config.vocab_size, pad_to=pad_to))
+
+
+def decode_programs(config: ModelConfig, mesh: Mesh, ks: tuple[int, ...],
+                    quantized: bool = False, probe: Any = None):
+    """The single step and the fused ladder ``{k: program}`` under the
+    scheduler's signature ``(carry, params, active[, remaining]) ->
+    (carry, ys)``: ``probe()`` gives the probed slots ``[PROBES]`` each
+    program takes as its last argument, and ``ys`` is the pair
+    ``(tokens, logits of the probed slots)``."""
+    def bound(program):
+        def call(carry, params, *masks):
+            carry, toks, seen = program(carry, params, *masks, probe())
+            return carry, (toks, seen)
+        return call
+
+    return (bound(build_decode_step(config, mesh)),
+            {k: bound(build_decode_fused(config, mesh, k)) for k in ks})

@@ -223,33 +223,6 @@ def copy_slot_blocks(plane: jax.Array, src: jax.Array, dst: jax.Array,
     return plane, jax.lax.dynamic_slice(plane, (0, dst) + zeros, size)[:, 0]
 
 
-def gather_cache_slots(cache, idx: jax.Array):
-    """Repack the slots named by ``idx`` (``[b'] int32``, b' <
-    max_batch) into a smaller cache — the device half of slot
-    compaction (``serve/engine.py``).  The slot dim must be UNSHARDED
-    (dp=1, enforced by ``ServingConfig.validate``): then the take is a
-    purely local gather and the compaction jit lowers to zero
-    collectives (audited — ``serve/engine.py::compact[tp]``).  Every
-    plane of a :class:`KVCache` or a :class:`HybridCache` has its slots
-    on axis 1, so a slot's recurrent state moves with its K/V."""
-    return type(cache)(
-        *(jnp.take(plane, idx, axis=1) for plane in cache[:-1]),
-        jnp.take(cache.lengths, idx, axis=0),
-    )
-
-
-def scatter_cache_slots(cache, small, idx: jax.Array):
-    """Write a compacted cache's rows back into their big-batch slots
-    (inverse of :func:`gather_cache_slots`; ``idx`` rows must be
-    distinct — the engine pads the active-slot list with distinct FREE
-    slots, never duplicates, so the scatter is well-defined)."""
-    return type(cache)(
-        *(plane.at[:, idx].set(rows)
-          for plane, rows in zip(cache[:-1], small[:-1])),
-        cache.lengths.at[idx].set(small.lengths),
-    )
-
-
 # ---------------------------------------------------------------------------
 # two kinds of state in one cache (``ModelConfig.layer_types``)
 # ---------------------------------------------------------------------------

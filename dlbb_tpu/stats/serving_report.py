@@ -380,7 +380,7 @@ def write_fastpath_report(bench_path: "str | Path",
                           output_dir: "str | Path") -> list[dict[str, Any]]:
     """The fast-path vs baseline comparison table: consolidate
     ``BENCH_serve.json`` (``scripts/bench_serving.py`` — per-step vs
-    fused-K x compaction over the same replayed trace) into
+    fused-K over the same replayed trace) into
     ``FASTPATH.md``.  Returns the rows (empty when the bench artifact
     is missing/unreadable — callers skip, never clobber)."""
     bench_path = Path(bench_path)
@@ -410,7 +410,6 @@ def write_fastpath_report(bench_path: "str | Path",
             "baseline": s.get("baseline", base_key),
             "trace": s.get("trace"),
             "decode_horizon": s.get("decode_horizon"),
-            "compaction": s.get("compact_threshold") is not None,
             "output_tok_s_median": med,
             "output_tok_s_min": tps.get("min"),
             "output_tok_s_max": tps.get("max"),
@@ -432,9 +431,9 @@ def write_fastpath_report(bench_path: "str | Path",
         "per-step PR-9 engine on the SAME mesh and trace "
         f"(default `{base_key}`).",
         "",
-        "| setting | trace | K | compaction | out tok/s (min..max) | "
+        "| setting | trace | K | out tok/s (min..max) | "
         "tok p50 ms | decode units | speedup vs baseline |",
-        "|---|---|---|---|---|---|---|---|",
+        "|---|---|---|---|---|---|---|",
     ]
     for r in rows:
         tps = ("-" if r["output_tok_s_median"] is None else
@@ -444,8 +443,7 @@ def write_fastpath_report(bench_path: "str | Path",
                  else f"{r['speedup_vs_baseline']:.2f}x")
         lines.append(
             f"| {r['setting']} | {r['trace'] or '-'} | "
-            f"{r['decode_horizon']} | "
-            f"{'on' if r['compaction'] else 'off'} | {tps} | "
+            f"{r['decode_horizon']} | {tps} | "
             f"{r['per_token_p50_ms']} | {r['decode_units']} | {speed} |"
         )
     lines.append("")

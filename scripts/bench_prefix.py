@@ -73,7 +73,8 @@ from dlbb_tpu.models.configs import (  # noqa: E402
     ModelConfig,
     kv_cache_bytes_per_device,
 )
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine  # noqa: E402
+from dlbb_tpu.serve.config import ServingConfig  # noqa: E402
+from dlbb_tpu.serve.engine import ServingEngine  # noqa: E402
 from dlbb_tpu.serve.traffic import generate_trace  # noqa: E402
 from dlbb_tpu.stats.serving_report import write_prefix_report  # noqa: E402
 from dlbb_tpu.utils.simulate import topology_record  # noqa: E402

@@ -1,5 +1,5 @@
 #!/usr/bin/env python
-"""Decode fast-path evidence: per-step vs fused-K x compaction.
+"""Decode fast-path evidence: per-step vs fused-K.
 
 Measures the serving engine's decode fast path (docs/serving.md) through
 the engine's own trace replay and writes ``BENCH_serve.json`` at the
@@ -8,7 +8,7 @@ repo root:
 - **throughput grid** — the SAME seeded poisson trace (decode-bound: a
   burst arrival so the batch stays full) replayed through the per-step
   PR-9 engine and the fused-scan engine at K in {4, 16, 64}, plus a
-  dp=1 pair pricing slot compaction on/off.  The acceptance bar —
+  tp-only pair on a staggered trace.  The acceptance bar —
   fused K=16 at >= 1.5x the per-step engine's per-output-token
   throughput on the simulated 8-rank mesh — is recorded as a checked
   claim, not prose.
@@ -55,7 +55,8 @@ import jax  # noqa: E402
 
 from dlbb_tpu.comm.mesh import build_parallelism_mesh  # noqa: E402
 from dlbb_tpu.models.configs import ModelConfig  # noqa: E402
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine  # noqa: E402
+from dlbb_tpu.serve.config import ServingConfig  # noqa: E402
+from dlbb_tpu.serve.engine import ServingEngine  # noqa: E402
 from dlbb_tpu.serve.traffic import generate_trace  # noqa: E402
 from dlbb_tpu.stats.serving_report import write_fastpath_report  # noqa: E402
 from dlbb_tpu.utils.simulate import topology_record  # noqa: E402
@@ -80,8 +81,7 @@ BENCH_MODEL = dict(hidden_size=128, num_layers=2, num_heads=8,
 # trace (one aligned admission wave, uniform long outputs — the
 # regime the acceptance bar describes); the tp4 rows replay the
 # STAGGERED trace (lognormal outputs, so occupancy decays through the
-# drain) on identical tp-only topology, pricing compaction on/off
-# apples-to-apples where it can actually engage.
+# drain) on identical tp-only topology.
 SETTINGS = {
     "per_step": ("dp8", "uniform", {}),
     "fused_k4": ("dp8", "uniform",
@@ -93,10 +93,6 @@ SETTINGS = {
     "tp4_per_step": ("tp4", "staggered", {}),
     "tp4_fused_k16": ("tp4", "staggered",
                       dict(decode_horizon=16, inflight_window=2)),
-    "tp4_fused_k16_compact": (
-        "tp4", "staggered",
-        dict(decode_horizon=16, inflight_window=2,
-             compact_threshold=0.5)),
 }
 BASELINE = "per_step"
 ACCEPTANCE = {"setting": "fused_k16", "min_speedup": 1.5}
@@ -124,7 +120,7 @@ def _traces(num_requests: int) -> dict:
     event horizon equals the drain, so fused scans reach full K.
     ``staggered``: lognormal outputs, so slots complete at different
     times and occupancy decays through the drain — the regime where
-    compaction can engage (and where overshoot masking is exercised).
+    overshoot masking is exercised.
     """
     return {
         "uniform": generate_trace(
@@ -205,8 +201,6 @@ def main() -> int:
                 "decode_units": report["decode_units"],
                 "decode_steps": report["decode_steps"],
                 "fused_steps": report["fast_path"]["fused_steps"],
-                "compacted_scans":
-                    report["fast_path"]["compacted_scans"],
             })
 
     settings_out = {}
@@ -218,7 +212,6 @@ def main() -> int:
             "trace": trace_key,
             "decode_horizon": extra.get("decode_horizon", 1),
             "inflight_window": extra.get("inflight_window", 1),
-            "compact_threshold": extra.get("compact_threshold"),
             "output_tokens_per_s": {
                 "median": _median(tok), "min": min(tok), "max": max(tok),
                 "reps": tok,
@@ -228,11 +221,9 @@ def main() -> int:
             "decode_units": _median([r["decode_units"] for r in reps]),
             "decode_steps": _median([r["decode_steps"] for r in reps]),
             "fused_steps": _median([r["fused_steps"] for r in reps]),
-            "compacted_scans": _median(
-                [r["compacted_scans"] for r in reps]),
         }
     # speedups are within-mesh, within-trace: the dp8 grid prices
-    # against per_step, the tp4 compaction rows against tp4_per_step
+    # against per_step, the tp4 rows against tp4_per_step
     for name, (mesh_key, _t, _e) in SETTINGS.items():
         base_name = "tp4_per_step" if mesh_key == "tp4" else BASELINE
         base_med = settings_out[base_name]["output_tokens_per_s"]["median"]
@@ -279,8 +270,7 @@ def main() -> int:
             "host dispatch (the committed cm1 calibration under-"
             "predicts ~289x geomean for exactly this reason), which is "
             "the overhead the fused scan removes — K dispatches become "
-            "one lax.scan.  Fabric-sensitive deltas (compaction's "
-            "gather cost on a real interconnect) re-price on chip."
+            "one lax.scan.  Fabric-sensitive deltas re-price on chip."
             if backend == "cpu" else
             "chip run: walls are device-honest; the fused rows price "
             "real dispatch amortisation on hardware."

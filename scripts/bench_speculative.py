@@ -62,7 +62,8 @@ import jax  # noqa: E402
 
 from dlbb_tpu.comm.mesh import build_parallelism_mesh  # noqa: E402
 from dlbb_tpu.models.configs import ModelConfig  # noqa: E402
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine  # noqa: E402
+from dlbb_tpu.serve.config import ServingConfig  # noqa: E402
+from dlbb_tpu.serve.engine import ServingEngine  # noqa: E402
 from dlbb_tpu.serve.traffic import generate_trace  # noqa: E402
 from dlbb_tpu.stats.serving_report import (  # noqa: E402
     write_speculative_report,

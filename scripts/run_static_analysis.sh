@@ -209,11 +209,11 @@ JAX_PLATFORMS=cpu python -m pytest tests/test_serve.py -q \
 # IDENTICAL completed-token sequences on a seeded mini-trace (fused
 # scans, in-flight window, chunked prefill all engaged), with
 # schema-valid artifacts and the fast-path metrics counters present.
-# The HLO-side contract for the three new jit families (fused-scan
+# The HLO-side contract for the two fast-path jit families (fused-scan
 # decode: trip-count-weighted tiny tp psums only; chunked prefill:
-# prefix-carry attention with zero cache reads across the slot shard;
-# compaction: zero collectives) is enforced by `analyze all` above via
-# the serve/engine.py::{decode_fused,prefill_chunk,compact_*} targets,
+# prefix-carry attention with zero cache reads across the slot shard)
+# is enforced by `analyze all` above via the
+# serve/engine.py::{decode_fused,prefill_chunk} targets,
 # and `analyze diff` against the committed baselines makes a cache
 # regather inside the scan body a CI failure — zero suppressions.
 JAX_PLATFORMS=cpu python -m pytest tests/test_serve_fastpath.py -q \

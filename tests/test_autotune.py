@@ -65,10 +65,10 @@ def tier():
 
 
 def test_serving_space_is_the_full_grid():
-    """(dp,tp) factorizations x K x W x chunk x compact — 4*5*2*2*2 for
-    an 8-device mesh, every key unique (the journal identifier)."""
+    """(dp,tp) factorizations x K x W x chunk — 4*5*2*2 for an
+    8-device mesh, every key unique (the journal identifier)."""
     pts = enumerate_serving_space(MODEL, 8, DEFAULT_PLAN_SERVING)
-    assert len(pts) == 4 * 5 * 2 * 2 * 2
+    assert len(pts) == 4 * 5 * 2 * 2
     keys = [p.key() for p in pts]
     assert len(set(keys)) == len(keys)
     assert all(p.dp * p.tp == 8 for p in pts)

@@ -18,7 +18,7 @@ from dlbb_tpu.ops.decode_attention import (
     plane_tile_tokens,
     tile_tokens,
 )
-from dlbb_tpu.serve import engine as E
+from dlbb_tpu.serve import attend as E
 
 # a plane of 3 layers x 5 slots x 8 blocks of 4 tokens, read in tiles of 8
 L, B, NB, BS, TILE, LAYER = 3, 5, 8, 4, 8, 1
@@ -206,7 +206,7 @@ def test_both_cells_planes_are_read_in_tiles_of_64_tokens(cell):
         sys.path.insert(0, root)
     from benchmarks.harness import cells
     from dlbb_tpu.models.configs import ModelConfig
-    from dlbb_tpu.serve.engine import ServingConfig
+    from dlbb_tpu.serve.config import ServingConfig
     from dlbb_tpu.serve.kvcache import create_hybrid_cache, create_kv_cache
 
     program = cells.resolve_cell(cell).config["program"]
@@ -231,7 +231,8 @@ def test_tile_counters_equal_the_share_of_the_traces_own_lengths(
     planes' tiles times the steps run.  Report and ``metrics.prom`` carry
     both."""
     from dlbb_tpu.models.configs import ModelConfig
-    from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+    from dlbb_tpu.serve.config import ServingConfig
+    from dlbb_tpu.serve.engine import ServingEngine
     from dlbb_tpu.serve.traffic import generate_trace
 
     # tiles of 16 of the 64 tokens a slot's ring holds (one kv-head a

@@ -23,7 +23,7 @@ import pytest
 from dlbb_tpu.comm.mesh import fault_domain_record, partition_devices
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.resilience import inject
-from dlbb_tpu.serve.engine import ServingConfig
+from dlbb_tpu.serve.config import ServingConfig
 from dlbb_tpu.serve.fleet import (DEGRADE_LEVELS, FleetConfig,
                                   FleetSupervisor, ReplicaControl,
                                   ReplicaKilled, RequestFeed, _StartGate,

@@ -5,9 +5,9 @@ toy widths on the CPU: everything against the plain float32 reference
 (a) ``models.forward``; (b) prefill then decode through the cache,
 per-step and fused, with prompts that cross chunk and block boundaries;
 (c) the chunked gated delta rule equals the token recurrence; (d) a
-recycled slot gives a fresh engine's logits; (e) compaction keeps a
-moved slot's logits; (f) a dp 2 x tp 2 mesh equals one device; (g) what
-the family does not run yet is refused with its reason.
+recycled slot gives a fresh engine's logits; (f) a dp 2 x tp 2 mesh
+equals one device; (g) what the family does not run yet is refused with
+its reason.
 """
 
 from __future__ import annotations
@@ -27,7 +27,8 @@ from dlbb_tpu.comm.mesh import build_parallelism_mesh
 from dlbb_tpu.models import forward, init_params
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.ops import gated_delta
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine, family_for
 from dlbb_tpu.serve.traffic import Request, TrafficTrace
 
 ROOT = Path(__file__).resolve().parents[1]
@@ -226,30 +227,6 @@ def test_recycled_slot_gives_a_fresh_engines_logits(tmp_path):
     assert _probed_against_reference(engine, {1: reused})[1] < TIGHT
 
 
-# -- (e) compaction moves the state with the slot ------------------------------
-
-
-def test_compaction_keeps_a_moved_slots_logits():
-    # two long answers in slots 2 and 3 outlive two short ones: the
-    # half-size bucket then holds them in rows 0 and 1
-    lengths = [(8, 2), (8, 2), (37, 20), (21, 18)]
-    plain = _engine(decode_horizon=4, inflight_window=2)
-    packed = _engine(decode_horizon=4, inflight_window=2,
-                     compact_threshold=0.5)
-    results = []
-    for engine in (plain, packed):
-        engine.probe([2, 3])
-        report = engine.run_trace(_trace(lengths))
-        results.append(engine.probe_results())
-    assert report["fast_path"]["compacted_scans"] > 0
-    for rid in (2, 3):
-        assert results[0][rid]["tokens"] == results[1][rid]["tokens"]
-        np.testing.assert_allclose(np.stack(results[0][rid]["logits"]),
-                                   np.stack(results[1][rid]["logits"]),
-                                   atol=1e-5)
-    assert max(_probed_against_reference(packed, results[1]).values()) < TIGHT
-
-
 # -- (f) a simulated mesh ------------------------------------------------------
 
 
@@ -298,8 +275,12 @@ def test_cache_holds_two_kinds_and_the_gate_prices_both():
     (dict(prefill_chunk=None), "prefilled in chunks"),
 ])
 def test_serving_refuses_what_the_family_lacks(serving, reason):
+    # what an engine checks when it is built: the family's own
+    # refusals, then the envelope against the model
+    sv = ServingConfig(**{**SERVING, **serving})
     with pytest.raises(ValueError, match=reason):
-        ServingConfig(**{**SERVING, **serving}).validate(CONFIG)
+        family_for(CONFIG).check_serving(CONFIG, sv)
+        sv.validate(CONFIG)
 
 
 def test_linear_heads_must_divide_over_tp():

@@ -459,8 +459,12 @@ def test_decode_step_cache_crosscheck(devices):
 @pytest.mark.memory_smoke
 def test_train_step_donation_proof(devices):
     """A donating train step shows its state aliased; the SAME program
-    jitted without donation trips unaliased-donation AND the peak
-    ceiling — the seeded violation the CI stage pins (exit 1)."""
+    jitted without donation trips unaliased-donation, the seeded
+    violation the CI stage pins (exit 1), and holds more at its peak.
+    How much more is the lowering's: XLA:CPU of jaxlib 0.9.0 computes
+    the donated step's new state into temporaries, so the undonated
+    one peaks 8 % higher, not by the whole state, and stays under the
+    ceiling (ROADMAP.md, Queue 3 item 6)."""
     import jax
     import optax
 
@@ -479,7 +483,7 @@ def test_train_step_donation_proof(devices):
     assert any(p["aliased"] for p in mem["donated_params"])
 
     # seeded violation: strip the donation (wrap the donating jit in an
-    # outer donation-free jit) — state doubles, both memory rules fire
+    # outer donation-free jit): nothing is aliased, the donation rule fires
     def undonated_build():
         jit_step, args = target.build()
         return jax.jit(lambda *a: jit_step(*a)), args
@@ -491,11 +495,10 @@ def test_train_step_donation_proof(devices):
     bad_findings, bad_meta = audit_target(bad, passes=("memory",))
     rules = {f.rule for f in bad_findings}
     assert "unaliased-donation" in rules
-    assert "peak-memory-ceiling" in rules
-    # the undonated lowering keeps input and output state resident:
-    # materially (> 25 %) more peak memory than the donating program
+    assert bad_meta["memory"]["donated_param_bytes"] == 0
+    # the undonated lowering keeps input and output state resident
     assert (bad_meta["memory"]["peak_live_bytes"]
-            > mem["peak_live_bytes"] * 1.25)
+            > mem["peak_live_bytes"])
     del optax, analysis
 
 

@@ -22,7 +22,8 @@ from dlbb_tpu.models.configs import (
     ModelConfig,
     kv_cache_bytes_per_device,
 )
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine
 from dlbb_tpu.serve.kvcache import (
     BlockLedger,
     CacheOverflow,
@@ -77,10 +78,9 @@ def test_kv_quantization_validation_fences():
     with pytest.raises(ValueError, match="speculation"):
         ServingConfig(**SERVE, kv_quantization="int8",
                       speculation="ngram", spec_gamma=2).validate(MODEL)
-    with pytest.raises(ValueError, match="compact_threshold"):
-        ServingConfig(**SERVE, kv_quantization="int8",
-                      decode_horizon=8,
-                      compact_threshold=0.5).validate(MODEL)
+    # the int8 layout has its own fused-scan programs
+    ServingConfig(**SERVE, kv_quantization="int8",
+                  decode_horizon=8).validate(MODEL)
     sv = ServingConfig(**SERVE, prefix_caching=True,
                        kv_quantization="int8")
     sv.validate(MODEL, dp=1)

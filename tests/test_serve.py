@@ -19,13 +19,13 @@ from dlbb_tpu.models.configs import (
     validate_serving,
 )
 from dlbb_tpu.models.transformer import forward, init_params_sharded
-from dlbb_tpu.serve.engine import (
-    ServingConfig,
-    ServingEngine,
-    _inject_token,
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine
+from dlbb_tpu.serve.gpt import (
     build_decode_fused,
     build_decode_step,
     build_prefill,
+    inject_token,
 )
 from dlbb_tpu.serve.kvcache import (
     BlockLedger,
@@ -272,7 +272,7 @@ def _equivalence_case(cfg, mesh, dp, tol):
     active = jnp.asarray(active)
     carry = (cache, x)
     for i in range(prompt, seq):
-        carry = _inject_token(carry, np.int32(slot), x_full[0, i])
+        carry = inject_token(carry, np.int32(slot), x_full[0, i])
         carry, y = decode(carry, params, active)
         errs.append(float(jnp.abs(y[slot, 0] - y_full[0, i]).max()))
     assert max(errs) <= tol, f"max divergence {max(errs)} > {tol}"

@@ -1,5 +1,5 @@
 """Decode fast-path tests (``docs/serving.md``): fused multi-step
-decode / chunked prefill / host-overlap window / slot compaction.
+decode / chunked prefill / host-overlap window.
 
 The load-bearing contract is EQUIVALENCE: every fast-path configuration
 must produce the identical completed-token sequences (argmax over each
@@ -11,6 +11,7 @@ admission arriving during an in-flight window, and a K horizon that
 overshoots every remaining output length.
 """
 
+import dataclasses
 import json
 import os
 import re
@@ -23,7 +24,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.comm.mesh import build_parallelism_mesh
 from dlbb_tpu.models.configs import ModelConfig
-from dlbb_tpu.serve.engine import ServingConfig, ServingEngine
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine
 from dlbb_tpu.serve.traffic import Request, TrafficTrace, generate_trace
 
 TINY = dict(hidden_size=64, num_layers=2, num_heads=4,
@@ -78,15 +80,16 @@ def test_fastpath_config_validation():
         ServingConfig(max_batch=8, block_size=8, max_seq=40,
                       hbm_budget_gb=None,
                       prefill_chunk=16).validate(MODEL)
-    with pytest.raises(ValueError, match="compact_threshold"):
-        ServingConfig(**SERVE, compact_threshold=0.9).validate(MODEL)
-    # compaction without fused scans would be a silent no-op
-    with pytest.raises(ValueError, match="decode_horizon"):
-        ServingConfig(**SERVE, compact_threshold=0.5).validate(MODEL)
-    # compaction demands an unsharded slot dim
-    with pytest.raises(ValueError, match="dp=1"):
-        ServingConfig(**SERVE, decode_horizon=16,
-                      compact_threshold=0.5).validate(MODEL, dp=2, tp=4)
+    # 25 knobs; a key this envelope does not have (an older artifact's
+    # knob, since removed) is ignored by from_dict, as any unknown key
+    # is, and refused by the constructor
+    assert len(dataclasses.fields(ServingConfig)) == 25
+    assert ServingConfig.from_dict({**SERVE, "removed_knob": 0.5}) == \
+        ServingConfig(**SERVE)
+    with pytest.raises(TypeError, match="removed_knob"):
+        ServingConfig(**SERVE, removed_knob=0.5)
+    # a fused ladder on a dp-sharded slot dim is a legal envelope
+    ServingConfig(**SERVE, decode_horizon=16).validate(MODEL, dp=2, tp=4)
     # the power-of-two fused bucket ladder
     assert ServingConfig(**SERVE).fused_horizons == ()
     assert ServingConfig(**SERVE,
@@ -242,27 +245,6 @@ def test_k_horizon_overshoots_every_remaining_length(mesh2x4):
     assert report["cache"]["blocks_reserved"] == 0
 
 
-def test_compaction_engine_equivalence():
-    """Slot compaction (dp=1): fused scans on the gather-compacted half
-    batch produce the same tokens; compacted_scans counts the variant's
-    engagements."""
-    mesh = build_parallelism_mesh(tensor_parallel=4,
-                                  devices=jax.devices()[:4])
-    trace = generate_trace("poisson", 8, seed=13, rate=500.0,
-                           prompt_range=(4, 16), output_range=(6, 20))
-    base = ServingEngine(MODEL, ServingConfig(**SERVE), mesh,
-                         verbose=False, capture_tokens=True)
-    comp = ServingEngine(
-        MODEL, ServingConfig(**SERVE, decode_horizon=16,
-                             compact_threshold=0.5),
-        mesh, verbose=False, capture_tokens=True,
-    )
-    rb = base.run_trace(trace)
-    rc = comp.run_trace(trace)
-    assert rb["completed_tokens"] == rc["completed_tokens"]
-    assert rc["fast_path"]["compacted_scans"] > 0
-
-
 # ---------------------------------------------------------------------------
 # chunked prefill: program-level equivalence
 # ---------------------------------------------------------------------------
@@ -274,7 +256,7 @@ def test_chunked_prefill_matches_monolithic(mesh2x4):
     prefill (the offset-causal prefix-carry attention is the same
     math)."""
     from dlbb_tpu.models.transformer import init_params_sharded
-    from dlbb_tpu.serve.engine import (
+    from dlbb_tpu.serve.gpt import (
         build_prefill,
         build_prefill_chunk,
         create_prefix,
@@ -488,15 +470,15 @@ def test_serve_blocks_share_the_one_phase_tuple():
     """Both block definitions unpack ``models.transformer.BLOCK_PHASES``:
     a phase renamed there is renamed in training and serving alike."""
     from dlbb_tpu.models import transformer
-    from dlbb_tpu.serve import engine
+    from dlbb_tpu.serve import attend, gpt
 
     names = ("LN1", "ATTN_QKV", "ATTN_CORE", "ATTN_OUT",
              "LN2", "MLP_UP", "MLP_ACT", "MLP_DOWN")
     assert tuple(getattr(transformer, n) for n in names) == \
         transformer.BLOCK_PHASES
-    assert tuple(getattr(engine, n) for n in names) == \
+    assert tuple(getattr(gpt, n) for n in names) == \
         transformer.BLOCK_PHASES
-    assert (engine.KV_UPDATE, engine.KV_ATTEND) == \
+    assert (attend.KV_UPDATE, attend.KV_ATTEND) == \
         transformer.SERVE_PHASES == ("kv_update", "kv_attend")
 
 
@@ -508,7 +490,7 @@ def test_serving_program_lowers_with_its_name_and_every_phase(
     called what it is (the module name the profile prints) and carries
     every phase scope of the block plus the two cache phases."""
     from dlbb_tpu.models.transformer import BLOCK_PHASES, SERVE_PHASES
-    from dlbb_tpu.serve.engine import (
+    from dlbb_tpu.serve.gpt import (
         build_decode_fused,
         build_prefill_chunk,
         create_prefix,
@@ -595,7 +577,7 @@ def _assert_writes_in_place(hlo: str, plane: tuple, dtype: str) -> None:
 
 def _cache_writing_program(program, cfg, mesh, cache, params, x, chunk):
     """The jitted program and its arguments (arrays or shapes)."""
-    from dlbb_tpu.serve import engine as E
+    from dlbb_tpu.serve import gpt as E
 
     b = cache.k.shape[1]
     like = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
@@ -780,7 +762,7 @@ def test_toy_widths_are_refused_on_the_chip_when_the_engine_is_built(
 def test_every_serving_program_has_a_stable_name(mesh2x4):
     """Program names say what the program is and which static shape it
     was built for; they are the jitted function's own name."""
-    from dlbb_tpu.serve import engine as E
+    from dlbb_tpu.serve import gpt as E
 
     def name(jitted):
         return jitted.__wrapped__.__name__
@@ -793,9 +775,6 @@ def test_every_serving_program_has_a_stable_name(mesh2x4):
     assert name(E.build_prefill(MODEL, mesh2x4)) == "serve_prefill"
     assert name(E.build_prefix_attach(MODEL, mesh2x4, 8, 8)) == \
         "serve_prefix_attach"
-    assert name(E.build_compact_gather(mesh2x4)) == "serve_compact_gather"
-    assert name(E.build_compact_scatter(mesh2x4)) == \
-        "serve_compact_scatter"
     assert name(E.build_decode_token_step(MODEL, mesh2x4)) == \
         "serve_decode_token_step"
     assert name(E.build_decode_fused_token(MODEL, mesh2x4, 4)) == \

@@ -21,9 +21,9 @@ import pytest
 from dlbb_tpu.comm.mesh import build_parallelism_mesh
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.resilience import inject
-from dlbb_tpu.serve.engine import (
-    ServingConfig,
-    ServingEngine,
+from dlbb_tpu.serve.config import ServingConfig
+from dlbb_tpu.serve.engine import ServingEngine
+from dlbb_tpu.serve.speculative import (
     _ngram_propose,
     residual_distribution,
     speculative_sample,
@@ -86,11 +86,9 @@ def test_spec_config_validation_ladder():
                       spec_gamma=96).validate(MODEL)
     with pytest.raises(ValueError, match="spec_adaptive"):
         ServingConfig(**SERVE, spec_adaptive=True).validate(MODEL)
-    # token-feedback modes and float-plane compaction are exclusive
-    with pytest.raises(ValueError, match="compact"):
-        ServingConfig(**SERVE, speculation="ngram", spec_gamma=4,
-                      decode_horizon=16,
-                      compact_threshold=0.5).validate(MODEL)
+    # token-feedback modes have their own fused-scan ladder
+    ServingConfig(**SERVE, speculation="ngram", spec_gamma=4,
+                  decode_horizon=16).validate(MODEL)
     with pytest.raises(ValueError, match="spec_draft_layers"):
         ServingConfig(**SERVE, speculation="draft-model", spec_gamma=4,
                       spec_draft_layers=0).validate(MODEL)
