@@ -15,11 +15,12 @@ overflow is a *rejected* request), waiting requests are granted slots
 and worst-case block reservations at step boundaries, a prompt is
 prefilled in chunks interleaved with the resident batch's decode steps
 (or at once, per bucket, without ``prefill_chunk``), completed requests
-free slot and blocks at once, and each decode unit runs over whatever
-mix of old and new requests is resident: one step, or, when the ledger
-knows no scheduling event is nearer, K steps fused into one scan
-(``decode_horizon``), up to ``inflight_window`` units dispatched before
-the oldest is waited for.  Around that: prefix-cache attach, the
+free slot and blocks at once (the inputs of the queue's head are
+prepared on a worker meanwhile: ``serve/lookahead.py``), and each decode
+unit runs over whatever mix of old and new requests is resident: one
+step, or, when the ledger knows no scheduling event is nearer, K steps
+fused into one scan (``decode_horizon``), up to ``inflight_window``
+units dispatched before the oldest is waited for.  Around that: prefix-cache attach, the
 draft-and-verify units of speculative decoding, per-phase spans
 (``serve-admission`` / ``serve-prefill`` / ``serve-decode``),
 request-lifecycle events into the resilience journal, and live
@@ -46,6 +47,7 @@ remaining-rid cursor ``serve/bench.py`` checkpoints for
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import signal
@@ -87,6 +89,7 @@ from dlbb_tpu.resilience.errors import (
 from dlbb_tpu.resilience.preempt import PreemptionGuard
 from dlbb_tpu.serve.config import ServingConfig
 from dlbb_tpu.serve.kvcache import BlockLedger, KVCache, create_kv_cache
+from dlbb_tpu.serve.lookahead import InputLookahead
 from dlbb_tpu.serve.speculative import (
     _ngram_propose,
     softmax_np,
@@ -273,6 +276,15 @@ class ServingEngine:
             ("serve_prefill_chunks", "prefill chunks processed"),
             ("serve_hung_dispatches",
              "decode units abandoned by the dispatch watchdog"),
+            ("serve_input_ready",
+             "admissions whose prompt input the look-ahead worker had "
+             "ready"),
+            ("serve_input_waited",
+             "admissions that waited for the worker or prepared their "
+             "input inline"),
+            ("serve_input_wait_seconds",
+             "time admissions spent waiting for, or preparing, their "
+             "input"),
         ):
             self.registry.inc(name, 0, help=hlp)
         self._quantized = serving.kv_quantization == "int8"
@@ -481,6 +493,18 @@ class ServingEngine:
         (seeded embeddings, or token ids embedded on the device)."""
         return self._family.prompt_input(self.config, req, pad_to,
                                          self._dtype)
+
+    def _padded_len(self, req: Request) -> int:
+        """The length a prompt is padded to: whole prefill chunks, or the
+        monolithic prefill's bucket."""
+        chunk = self.serving.prefill_chunk
+        if chunk is None:
+            return self.serving.bucket_for(req.prompt_len)
+        return -(-req.prompt_len // chunk) * chunk
+
+    def _request_input(self, req: Request) -> jax.Array:
+        """What the look-ahead prepares, on its worker or inline."""
+        return self._prompt_input(req, self._padded_len(req))
 
     def _fresh_carry(self):
         return self._family.fresh_carry(self.config, self.serving,
@@ -812,15 +836,24 @@ class ServingEngine:
         heartbeat, kill/hang fault sites, hedge cancels, degradation
         overrides, and the fleet-shared clock origin — checked strictly
         at the scheduler-loop boundary."""
-        if guard is None:
-            with PreemptionGuard() as own:
-                return self._serve_trace(trace, own, collect_raw,
-                                         feed, control)
-        return self._serve_trace(trace, guard, collect_raw, feed, control)
+        with contextlib.ExitStack() as stack:
+            if guard is None:
+                guard = stack.enter_context(PreemptionGuard())
+            # joined on every way out, with what it still holds dropped
+            lookahead = stack.enter_context(
+                InputLookahead(self._request_input))
+            try:
+                return self._serve_trace(trace, guard, collect_raw, feed,
+                                         control, lookahead)
+            finally:
+                self.registry.inc("serve_input_ready", lookahead.ready)
+                self.registry.inc("serve_input_waited", lookahead.waited)
+                self.registry.inc("serve_input_wait_seconds",
+                                  lookahead.wait_s)
 
     def _serve_trace(self, trace: TrafficTrace, guard: PreemptionGuard,
-                     collect_raw: bool, feed: Any = None,
-                     control: Any = None) -> dict[str, Any]:
+                     collect_raw: bool, feed: Any, control: Any,
+                     lookahead: InputLookahead) -> dict[str, Any]:
         self._control = control
         if not len(trace):
             raise ValueError("cannot serve an empty trace")
@@ -1057,6 +1090,7 @@ class ServingEngine:
             for r in list(queue):
                 if r.rid == rid:
                     queue.remove(r)
+                    lookahead.drop(rid)
                     outcomes[rid] = f"canceled[{reason}]"
                     self._requests["canceled"] += 1
                     self._event("request-canceled", rid, reason=reason,
@@ -1848,11 +1882,12 @@ class ServingEngine:
                         cow_blocks=depth - attach_tokens // bs)
             return plan
 
-        def prefill_once(req: Request, slot: int,
+        def prefill_once(req: Request, slot: int, x_prompt: jax.Array,
                          plan: Optional[dict[str, Any]] = None):
             """The prefill dispatch for one admitted request (chunked or
-            monolithic) — returns ``(bucket, y_last, dt)``.  Raised
-            through by the retry wrapper below; idempotent on retry:
+            monolithic) over its prepared input ``x_prompt`` — returns
+            ``(bucket, y_last, dt)``.  Raised through by the retry
+            wrapper below; idempotent on retry:
             chunk writes are deterministic block writes of identical
             values, and interleaved decode units commit independently.
             With a prefix-attach ``plan``, the matched chunks' prefills
@@ -1866,18 +1901,15 @@ class ServingEngine:
                 raise TransientFault(
                     "injected serve-prefill-fail at the prefill "
                     "dispatch boundary")
+            bucket = self._padded_len(req)
             if cfg.prefill_chunk is not None:
                 chunk = cfg.prefill_chunk
-                n_chunks = -(-req.prompt_len // chunk)
-                bucket = n_chunks * chunk
+                n_chunks = bucket // chunk
                 m_chunks = 0
                 if plan is not None and plan["attach_blocks"]:
                     plan["attached_tokens"] = 0
                     if carry_resets[0] == plan["resets"]:
                         m_chunks = plan["attach_tokens"] // chunk
-                with spans.span("serve-admit-embed", rid=req.rid,
-                                slot=slot):
-                    x_prompt = self._prompt_input(req, bucket)
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot,
                                 chunks=n_chunks - m_chunks):
@@ -1941,14 +1973,6 @@ class ServingEngine:
                     # keep prefill_s a PREFILL cost
                     dt = time.perf_counter() - t0 - decode_spent
             else:
-                bucket = cfg.bucket_for(req.prompt_len)
-                with spans.span("serve-admit-embed", rid=req.rid,
-                                slot=slot):
-                    x_prompt = request_embeddings(
-                        req.seed, req.prompt_len,
-                        self.config.hidden_size,
-                        dtype=self._dtype, pad_to=bucket,
-                    )
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot):
                     t0 = time.perf_counter()
@@ -1970,12 +1994,13 @@ class ServingEngine:
                 carry = (cache, carry[1])
             return bucket, y_last, dt
 
-        def prefill_dispatch(req: Request, slot: int,
+        def prefill_dispatch(req: Request, slot: int, x_prompt: jax.Array,
                              plan: Optional[dict[str, Any]] = None):
             """Bounded-retry wrapper around :func:`prefill_once` —
-            transient dispatch failures back off and re-issue (chunk
-            counters rolled back so a retried prefill never
-            double-counts); exhaustion raises to the admission loop's
+            transient dispatch failures back off and re-issue over the
+            same prepared input (chunk counters rolled back so a retried
+            prefill never double-counts); exhaustion raises to the
+            admission loop's
             fail-closed path.  The prefix-attach ``plan`` rides through
             unchanged: each attempt re-checks the carry generation
             itself, so a retry after a mid-prefill carry reset degrades
@@ -1984,7 +2009,7 @@ class ServingEngine:
             while True:
                 chunks_base = stats.prefill_chunks
                 try:
-                    return prefill_once(req, slot, plan)
+                    return prefill_once(req, slot, x_prompt, plan)
                 except (TransientFault, CorruptStats) as e:
                     stats.prefill_chunks = chunks_base
                     if attempt >= cfg.max_dispatch_retries:
@@ -1999,13 +2024,14 @@ class ServingEngine:
                                            error=str(e))
                     time.sleep(cfg.retry_backoff_s * (2 ** (attempt - 1)))
 
-        def fail_admission(req: Request, slot: int,
-                           exc: BaseException) -> None:
+        def fail_admission(req: Request, slot: int, exc: BaseException,
+                           dispatched: bool = True) -> None:
             """A permanently-failed prefill fails ONLY the admitting
             request: reservation undone, journaled with the chain.  A
-            real (non-injected) failure also consumed the donated
-            cache, so the resident batch fails closed too and the
-            engine continues on a fresh carry."""
+            real (non-injected) failure of a ``dispatched`` prefill also
+            consumed the donated cache, so the resident batch fails
+            closed too and the engine continues on a fresh carry; an
+            input that could not be prepared touched no cache."""
             nonlocal carry
             ledger.free(slot)
             if draft_ledger is not None:
@@ -2014,7 +2040,7 @@ class ServingEngine:
             free_slots.sort()
             fail_requests([_SlotState(req=req, tokens_done=0)], exc,
                           "dispatch-failed")
-            if not isinstance(exc, InjectedFault):
+            if dispatched and not isinstance(exc, InjectedFault):
                 fail_resident(exc, "dispatch-failed")
                 carry = self._fresh_carry()
                 draft_cache[0] = self._fresh_draft_cache()
@@ -2103,6 +2129,7 @@ class ServingEngine:
             while (queue and queue[0].deadline_s is not None
                     and now - queue[0].arrival_s > queue[0].deadline_s):
                 req = queue.popleft()
+                lookahead.drop(req.rid)
                 wait = now - req.arrival_s
                 self._requests["rejected"] += 1
                 self._rejections["deadline"] += 1
@@ -2162,9 +2189,20 @@ class ServingEngine:
                                         slot, req.total_tokens)
                         if not fits:
                             break
+                        # the input: taken ready from the look-ahead,
+                        # waited for, or prepared inline; the worker
+                        # goes on with the queue's new head meanwhile
+                        try:
+                            with spans.span("serve-admit-embed",
+                                            rid=req.rid, slot=slot):
+                                x_prompt = lookahead.take(req)
+                        except Exception as e:  # noqa: BLE001 — closed
+                            fail_admission(req, slot, e, dispatched=False)
+                            continue
+                        lookahead.top_up(queue)
                         try:
                             bucket, y_last, dt = prefill_dispatch(
-                                req, slot, plan)
+                                req, slot, x_prompt, plan)
                         except Exception as e:  # noqa: BLE001 — closed
                             fail_admission(req, slot, e)
                             continue
@@ -2276,6 +2314,10 @@ class ServingEngine:
                                 finish(release(slot), self._now())
                 if scheduled:
                     refresh_active()
+            # what still waits for a slot has its input prepared on the
+            # worker while the device decodes (a request that found a
+            # slot as it arrived never passes through it)
+            lookahead.top_up(queue)
             # 3. a decode unit over every resident request: one step, or
             #    a fused K-step scan on the fast path
             if slots:
@@ -2392,6 +2434,8 @@ class ServingEngine:
             # share of the K/V planes' tiles the decode steps fetched
             "kv_live_share": (stats.kv_tiles_live / stats.kv_tiles_held
                               if stats.kv_tiles_held else 0.0),
+            # share of admissions whose input the look-ahead had ready
+            "input_ready_share": lookahead.ready_share,
             "fast_path": {
                 "enabled": self._fast,
                 "decode_horizon": cfg.decode_horizon,
