@@ -15,7 +15,15 @@ from typing import Any, Optional
 # config's own words)
 LINEAR_ATTENTION = "linear_attention"
 FULL_ATTENTION = "full_attention"
-LAYER_KINDS = (LINEAR_ATTENTION, FULL_ATTENTION)
+# multi-head latent attention (MLA): one low-rank latent and one rotary
+# key a token, shared by every head (``model_type: deepseek_v3``)
+LATENT_ATTENTION = "latent_attention"
+LAYER_KINDS = (LINEAR_ATTENTION, FULL_ATTENTION, LATENT_ATTENTION)
+# lanes of one cached latent row: ``kv_lora_rank + qk_rope_head_dim``
+# rounded up to whole lanes of 128 (the TPU tiles the plane's last dim
+# by 128, so the memory is spent either way, and whole lanes are what
+# the decode kernel's copies and products take)
+LATENT_LANES = 128
 
 
 @dataclass(frozen=True)
@@ -119,6 +127,34 @@ class ModelConfig:
     linear_value_head_dim: int = 0
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
+    # where a sub-layer's RMSNorm sits: "post" (OLMo 2/3: on the
+    # sub-layer's OUTPUT, ``h = x + norm(f(x))``) or "pre" (on its INPUT,
+    # ``h = x + f(norm(x))``)
+    norm_placement: str = "post"
+    # the latent-attention (MLA) layers' sizes and rotary base, under
+    # the published config's own keys.  ``q_lora_rank`` is not here: the
+    # low-rank query path is not implemented (ROADMAP.md, Queue 2)
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    rope_theta: float = 0.0
+    # routed experts of the ``layer_types`` family (``ops/routed_experts
+    # .py``): the first ``first_k_dense_replace`` layers keep the dense
+    # SwiGLU of ``ffn_intermediate``; every later layer has
+    # ``n_routed_experts`` SwiGLU experts of ``moe_intermediate_size``,
+    # ``num_experts_per_tok`` a token chosen by ``sigmoid`` scores plus a
+    # selection bias (``noaux_tc`` with one group), weighted by the
+    # chosen scores normalised to ``routed_scaling_factor``, beside
+    # ``n_shared_experts`` shared experts every token takes.  0 routed
+    # experts = a dense MLP in every layer.  (``num_experts`` above is
+    # the GPT block's softmax-gated MoE, which serving refuses.)
+    n_routed_experts: int = 0
+    num_experts_per_tok: int = 0
+    n_shared_experts: int = 0
+    moe_intermediate_size: int = 0
+    first_k_dense_replace: int = 0
+    routed_scaling_factor: float = 1.0
 
     def __post_init__(self) -> None:
         if self.layer_types is not None:
@@ -180,29 +216,67 @@ class ModelConfig:
                and self.layer_types is None)
         if gpt:
             return
+        kinds = set(self.layer_types or ())
+        unknown = kinds - set(LAYER_KINDS)
+        if self.layer_types is not None and (not self.layer_types
+                                             or unknown):
+            raise ValueError(
+                f"layer_types must be a non-empty pattern of {LAYER_KINDS}, "
+                f"got {self.layer_types}")
+        # QK-norm belongs to the full-attention layers (OLMo 2/3); a
+        # stack of latent-attention layers norms its latent instead
         hybrid = (self.norm == "rmsnorm" and self.mlp == "swiglu"
-                  and not self.bias and self.qk_norm and self.vocab_size > 0
-                  and self.layer_types is not None)
+                  and not self.bias and self.vocab_size > 0
+                  and self.layer_types is not None
+                  and self.qk_norm == (FULL_ATTENTION in kinds))
         if not hybrid:
             raise ValueError(
                 "model family not implemented: the program runs the GPT "
                 "block (norm='layernorm', mlp='gelu', bias=true, "
                 "qk_norm=false, no vocab_size, no layer_types) or the "
                 "hybrid block (norm='rmsnorm', mlp='swiglu', bias=false, "
-                "qk_norm=true, vocab_size > 0, layer_types given); got "
+                "qk_norm=true, vocab_size > 0, layer_types given; "
+                "qk_norm=false for a stack without full_attention "
+                "layers); got "
                 f"norm={self.norm!r}, mlp={self.mlp!r}, bias={self.bias}, "
                 f"qk_norm={self.qk_norm}, vocab_size={self.vocab_size}, "
                 f"layer_types={self.layer_types}")
-        unknown = set(self.layer_types) - set(LAYER_KINDS)
-        if not self.layer_types or unknown:
-            raise ValueError(
-                f"layer_types must be a non-empty pattern of {LAYER_KINDS}, "
-                f"got {self.layer_types}")
-        if self.num_layers % len(self.layer_types):
+        period = len(self.layer_types)
+        lead = self.first_k_dense_replace
+        if lead < 0 or lead % period or (self.num_layers - lead) % period \
+                or lead > self.num_layers:
             raise ValueError(
                 f"num_layers={self.num_layers} is not a whole number of "
-                f"periods of {len(self.layer_types)} layers (layer_types="
-                f"{self.layer_types})")
+                f"periods of {period} layers (layer_types="
+                f"{self.layer_types}) after first_k_dense_replace={lead} "
+                "leading layers (themselves whole periods)")
+        if self.norm_placement not in ("post", "pre"):
+            raise ValueError(
+                f"unknown norm_placement {self.norm_placement!r} "
+                "(expected 'post' or 'pre')")
+        if LATENT_ATTENTION in kinds:
+            sizes = (self.kv_lora_rank, self.qk_nope_head_dim,
+                     self.qk_rope_head_dim, self.v_head_dim)
+            if min(sizes) < 1 or self.rope_theta <= 0 \
+                    or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "latent_attention layers need kv_lora_rank, "
+                    "qk_nope_head_dim, an even qk_rope_head_dim and "
+                    f"v_head_dim >= 1 and rope_theta > 0, got {sizes}, "
+                    f"rope_theta={self.rope_theta}")
+        if self.n_routed_experts:
+            sizes = (self.num_experts_per_tok, self.moe_intermediate_size)
+            if min(sizes) < 1 or self.num_experts_per_tok \
+                    > self.n_routed_experts or self.n_shared_experts < 0:
+                raise ValueError(
+                    "routed experts need 1 <= num_experts_per_tok <= "
+                    "n_routed_experts, moe_intermediate_size >= 1 and "
+                    f"n_shared_experts >= 0, got {sizes}, n_shared_experts="
+                    f"{self.n_shared_experts}")
+        elif lead:
+            raise ValueError(
+                f"first_k_dense_replace={lead} without n_routed_experts: "
+                "every layer's MLP is dense already")
         if LINEAR_ATTENTION in self.layer_types:
             sizes = (self.linear_num_key_heads, self.linear_num_value_heads,
                      self.linear_key_head_dim, self.linear_value_head_dim,
@@ -241,6 +315,30 @@ class ModelConfig:
             return self.num_layers if kind == FULL_ATTENTION else 0
         periods = self.num_layers // len(self.layer_types)
         return periods * self.layer_types.count(kind)
+
+    @property
+    def has_routed_experts(self) -> bool:
+        """Routed and shared experts after the leading dense layers
+        (``ops/routed_experts.py``), in the ``layer_types`` family."""
+        return self.n_routed_experts > 0
+
+    @property
+    def expert_layers(self) -> int:
+        """Layers whose MLP is the expert layer."""
+        return (self.num_layers - self.first_k_dense_replace
+                if self.has_routed_experts else 0)
+
+    @property
+    def latent_width(self) -> int:
+        """Values of one cached latent row as counted: the normed latent
+        and the rotated shared key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def latent_row(self) -> int:
+        """Values of one cached latent row as HELD: ``latent_width`` in
+        whole lanes (``LATENT_LANES``), the rest zeros."""
+        return -(-self.latent_width // LATENT_LANES) * LATENT_LANES
 
     @property
     def linear_conv_channels(self) -> int:
@@ -367,6 +465,12 @@ def validate_expert_parallelism(config: ModelConfig, ep: int) -> None:
     """Reject expert-parallel degrees that cannot shard the expert dim."""
     if ep <= 1:
         return
+    if config.has_routed_experts:
+        raise ValueError(
+            f"parallelism.expert_parallel={ep} is not implemented for the "
+            "layer_types family's routed experts: every expert of a layer "
+            "is held by the chip that holds the layer (no expert-parallel "
+            "share and no all-to-all; ROADMAP.md, Queue 2)")
     if not config.is_moe:
         raise ValueError(
             f"parallelism.expert_parallel={ep} requires a MoE model "
@@ -471,6 +575,21 @@ def state_cache_bytes(config: ModelConfig, max_batch: int) -> int:
     return n_lin * max_batch * (state + conv)
 
 
+def latent_cache_bytes(config: ModelConfig, max_batch: int, max_seq: int,
+                       held: bool = True) -> int:
+    """Total (unsharded) footprint of the latent-attention layers' paged
+    plane (``serve/kvcache.py::HybridCache.latent``): per layer, slot and
+    token ONE row of the normed latent and the rotated shared key, in the
+    model dtype; as ``held`` (whole lanes, ``ModelConfig.latent_row``) or
+    as counted (``latent_width``).  0 for a model without such layers."""
+    n_lat = config.layers_of(LATENT_ATTENTION)
+    if not n_lat:
+        return 0
+    row = config.latent_row if held else config.latent_width
+    return (n_lat * max_batch * max_seq * row
+            * _DTYPE_BYTES.get(config.dtype, 2))
+
+
 def kv_cache_bytes_per_device(config: ModelConfig, max_batch: int,
                               max_seq: int, dp: int = 1,
                               tp: int = 1,
@@ -492,7 +611,8 @@ def kv_cache_bytes_per_device(config: ModelConfig, max_batch: int,
     return (kv_cache_bytes(config, max_batch, max_seq,
                            kv_quantization=kv_quantization,
                            block_size=block_size, tp=tp)
-            + state_cache_bytes(config, max_batch)) // shards
+            + state_cache_bytes(config, max_batch)
+            + latent_cache_bytes(config, max_batch, max_seq)) // shards
 
 
 def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
@@ -547,6 +667,14 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             raise ValueError(
                 f"linear_num_value_heads={lin_heads} not divisible by "
                 f"tp={tp}: the recurrent state shards its head dim over tp")
+        if tp > 1 and (LATENT_ATTENTION in config.layer_types
+                       or config.has_routed_experts):
+            raise ValueError(
+                f"tp={tp} is not implemented for latent_attention layers "
+                "or routed experts: the one latent a token is shared by "
+                "every head, so the plane has no head dim to shard, and "
+                "the grouped expert products are not partitioned "
+                "(ROADMAP.md, Queue 2)")
     if config.attention not in SERVABLE_ATTENTION:
         raise ValueError(
             f"serving requires attention in {SERVABLE_ATTENTION} "
@@ -556,8 +684,13 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
         )
     if config.is_moe:
         raise ValueError(
-            "serving requires a dense FFN (model.num_experts == 0); the "
-            "MoE dispatch path is not wired into the decode step"
+            "serving requires a dense FFN in the GPT block "
+            "(model.num_experts == 0: models/transformer.py::_moe_ffn_dense "
+            "runs every expert on every token and _moe_ffn_capacity drops "
+            "tokens; neither is wired into the decode step).  The path "
+            "that serves experts is the layer_types family's "
+            "(model.n_routed_experts with layer_types: "
+            "ops/routed_experts.py, no capacity and no dropped token)"
         )
     if config.tp_overlap != "off":
         raise ValueError(
@@ -625,7 +758,9 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
                    if kv_quantization == "int8"
                    else f"{_DTYPE_BYTES[config.dtype]} B [{config.dtype}]")
                 + (f", + {state_cache_bytes(config, max_batch) / 2**30:.2f}"
-                   " GiB of recurrent state and convolution inputs"
+                   " GiB of recurrent state and convolution inputs, + "
+                   f"{latent_cache_bytes(config, max_batch, max_seq) / 2**30:.2f}"
+                   " GiB of latents"
                    if config.is_hybrid else "")
                 + f", sharded over dp={dp} x tp={tp})"
                 f"{draft_note} "

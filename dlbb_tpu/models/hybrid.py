@@ -1,24 +1,36 @@
-"""The hybrid block family (``ModelConfig.layer_types``): Olmo-Hybrid.
+"""The ``layer_types`` block family: Olmo-Hybrid, and (PR 31) the
+``deepseek_v3`` block of kanana-2-30b-a3b.
 
 A stack that repeats one PERIOD of layers, each ``linear_attention``
 (the gated delta rule of ``ops/gated_delta.py`` behind a short causal
-convolution) or ``full_attention`` (causal multi-head attention with
-QK-norm, no rotary embedding), every one followed by a SwiGLU MLP, in
-the OLMo 2/3 arrangement: a sub-layer's OUTPUT is RMS-normalised and
-added to the residual stream (``h = x + norm(mixer(x))``, ``out = h +
-norm(mlp(h))``).  No biases.  Token ids in, logits over ``vocab_size``
-out: an embedding table, a final RMSNorm and an untied head.
+convolution), ``full_attention`` (causal multi-head attention with
+QK-norm, no rotary embedding) or ``latent_attention`` (MLA: per token
+one normed low-rank latent and one rotary key shared by all heads, from
+which each head's keys and values are expanded, or into which its
+queries are absorbed), every one followed by an MLP: a SwiGLU, or after
+the ``first_k_dense_replace`` leading layers the routed and shared
+experts of ``ops/routed_experts.py``.  The RMSNorms sit where
+``norm_placement`` says: on a sub-layer's OUTPUT (OLMo 2/3: ``h = x +
+norm(mixer(x))``, ``out = h + norm(mlp(h))``) or on its INPUT (``h = x +
+mixer(norm(x))``, ``out = h + mlp(norm(h))``).  No biases.  Token ids in,
+logits over ``vocab_size`` out: an embedding table, a final RMSNorm and
+an untied head.
 
 ONE definition of the block (:func:`hybrid_block`) and of the period
 (:func:`scan_periods`), used by :func:`forward` here (a whole sequence,
 no cache) and by every serving program (``serve/hybrid.py``).  What
 differs between them is the *mixer*: an object with ``attention(q, k,
 v, l, state)`` and ``linear(qkv, log_alpha, beta, conv_w, l, state)``
-that owns everything that touches a cache.  ``state`` is opaque to the
-block.
+(and ``latent(q, c, k_rope, wkv_b, l, state)``) that owns everything
+that touches a cache or needs a position.  ``state`` is opaque to the
+block.  A mixer also says which tokens are real (``valid()``: the
+others take no expert's time) and is told what an expert layer chose
+(``routed``); ``collect()`` hands both kinds of a period's outputs on.
 
 Parameters are stacked over periods (``lax.scan`` runs one period a
-trip), one sub-tree per position of the period.  Projections keep the
+trip), one sub-tree per position of the period: ``params["periods"]``,
+and ``params["lead"]`` for the leading dense layers where there are
+any (:func:`scan_stack` runs the one scan after the other).  Projections keep the
 head as an axis of its own (``[h, heads, d]``) so that tensor
 parallelism shards whole heads and nothing is realigned after a split;
 the linear layers' q, k and v of one head share one fused projection
@@ -37,6 +49,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dlbb_tpu.models.attention import dense_attention
 from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
+    LATENT_ATTENTION,
     LINEAR_ATTENTION,
     ModelConfig,
 )
@@ -56,6 +69,7 @@ from dlbb_tpu.ops.gated_delta import (
     gated_delta_chunked,
     l2_normalise,
 )
+from dlbb_tpu.ops.routed_experts import expert_layer
 
 Params = dict[str, Any]
 
@@ -67,6 +81,14 @@ Params = dict[str, Any]
 HYBRID_PHASES = ("embed", "lm_head", "lin_proj", "lin_conv", "lin_core",
                  "lin_out")
 EMBED, LM_HEAD, LIN_PROJ, LIN_CONV, LIN_CORE, LIN_OUT = HYBRID_PHASES
+# ... and the latent-attention layers' (they keep ``attn_out``): the
+# rotation, the query projection, the down-projection to latent and
+# rotary key with the latent's norm, and the products with ``W_kv_b``
+# (the expansion to per-head keys and values in a chunk, the two
+# absorbed products in decode).  The expert layer's ``moe_*`` are
+# ``ops/routed_experts.py::MOE_PHASES``.
+MLA_PHASES = ("rope", "mla_q", "mla_kv_a", "mla_kv_b")
+ROPE, MLA_Q, MLA_KV_A, MLA_KV_B = MLA_PHASES
 
 # the recurrent state's precision, wherever it is kept or carried
 STATE_DTYPE = jnp.float32
@@ -75,19 +97,39 @@ STATE_DTYPE = jnp.float32
 # -- parameters ----------------------------------------------------------------
 
 
-def _layer_shapes(config: ModelConfig, kind: str) -> dict[str, tuple]:
+# float32 whatever the model's dtype: ``A_log`` and ``dt_bias`` feed an
+# exponential of an exponential, ``router_bias`` decides near-ties
+_FLOAT32 = ("A_log", "dt_bias", "router_bias")
+_SCALES = ("ln1", "ln2", "q_norm", "k_norm", "o_norm", "kv_norm")
+
+
+def _layer_shapes(config: ModelConfig, kind: str,
+                  experts: bool = False) -> dict[str, tuple]:
     """Shapes (without the leading period axis) of one layer of ``kind``
-    (``A_log`` and ``dt_bias`` are float32 whatever the model's dtype:
-    they feed an exponential of an exponential)."""
+    whose MLP is dense, or the ``experts`` layer."""
     h, f = config.hidden_size, config.ffn_intermediate
-    shapes: dict[str, tuple] = {
-        "ln1": (h,), "ln2": (h,),
-        "mlp_gate": (h, f), "mlp_up": (h, f), "mlp_down": (f, h),
-    }
+    shapes: dict[str, tuple] = {"ln1": (h,), "ln2": (h,)}
+    if experts:
+        e, fe = config.n_routed_experts, config.moe_intermediate_size
+        shapes.update(router=(h, e), router_bias=(e,),
+                      exp_gate=(e, h, fe), exp_up=(e, h, fe),
+                      exp_down=(e, fe, h))
+        if config.n_shared_experts:
+            fs = config.n_shared_experts * fe
+            shapes.update(shared_gate=(h, fs), shared_up=(h, fs),
+                          shared_down=(fs, h))
+    else:
+        shapes.update(mlp_gate=(h, f), mlp_up=(h, f), mlp_down=(f, h))
     if kind == FULL_ATTENTION:
         n, d = config.num_heads, config.head_dim
         shapes.update(wq=(h, n, d), wk=(h, n, d), wv=(h, n, d),
                       wo=(n, d, h), q_norm=(n, d), k_norm=(n, d))
+    elif kind == LATENT_ATTENTION:
+        n, r = config.num_heads, config.kv_lora_rank
+        dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
+        dv = config.v_head_dim
+        shapes.update(wq=(h, n, dn + dr), wkv_a=(h, r + dr), kv_norm=(r,),
+                      wkv_b=(r, n, dn + dv), wo=(n, dv, h))
     else:
         nh = config.linear_num_value_heads
         dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
@@ -102,12 +144,17 @@ def _layer_shapes(config: ModelConfig, kind: str) -> dict[str, tuple]:
 
 def init_params(config: ModelConfig, key: jax.Array) -> Params:
     """Seeded parameters: scaled-normal kernels (1/sqrt(fan_in)), unit
-    norm scales, a unit-normal embedding; the decay's ``A`` uniform in
+    norm scales (the latent's own, ``kv_norm``, uniform in (0.5, 1.5): a
+    latent is of unit size before its norm, so ones would hide whether
+    the norm is applied), a unit-normal embedding; the decay's ``A`` uniform in
     (1, 16) and the step's bias the inverse softplus of a step
     log-uniform in (0.001, 0.1), as Gated DeltaNet initialises them, so
-    that random weights give decays spread over (0, 1)."""
+    that random weights give decays spread over (0, 1); the router's
+    selection bias uniform in +/-0.01 (a trained model's is a learned
+    buffer of that order)."""
     dtype = _dtype_of(config.dtype)
-    periods = config.num_layers // len(config.layer_types)
+    lead = config.first_k_dense_replace // len(config.layer_types)
+    periods = config.num_layers // len(config.layer_types) - lead
     h, vocab = config.hidden_size, config.vocab_size
 
     def normal(key, shape, fan_in):
@@ -116,13 +163,16 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
         return (jax.random.normal(key, shape, dtype=jnp.float32)
                 / math.sqrt(fan_in)).astype(dtype)
 
-    def layer(key, kind):
+    def layer(key, kind, periods, experts):
         out = {}
-        shapes = _layer_shapes(config, kind)
+        shapes = _layer_shapes(config, kind, experts)
         for name, k in zip(sorted(shapes),
                            jax.random.split(key, len(shapes))):
             shape = shapes[name]
-            if name == "A_log":
+            if name == "router_bias":
+                out[name] = jax.random.uniform(
+                    k, (periods,) + shape, jnp.float32, -0.01, 0.01)
+            elif name == "A_log":
                 out[name] = jnp.log(jax.random.uniform(
                     k, (periods,) + shape, jnp.float32, 1.0, 16.0))
             elif name == "dt_bias":
@@ -130,23 +180,34 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                     k, (periods,) + shape, jnp.float32,
                     math.log(1e-3), math.log(1e-1)))
                 out[name] = dt + jnp.log(-jnp.expm1(-dt))
-            elif name in ("ln1", "ln2", "q_norm", "k_norm", "o_norm"):
+            elif name == "kv_norm":
+                out[name] = jax.random.uniform(
+                    k, (periods,) + shape, jnp.float32, 0.5, 1.5
+                ).astype(dtype)
+            elif name in _SCALES:
                 out[name] = jnp.ones((periods,) + shape, dtype)
             else:
                 fan_in = (math.prod(shape[:2]) if name in ("wo", "lin_out")
+                          else shape[1] if name.startswith("exp_")
                           else shape[0])
                 out[name] = normal(k, (periods,) + shape, fan_in)
         return out
 
     k_embed, k_head, *k_layers = jax.random.split(
         key, 2 + len(config.layer_types))
-    return {
+    params = {
         "embed": normal(k_embed, (vocab, h), 1),
-        "periods": tuple(layer(k, kind) for k, kind
-                         in zip(k_layers, config.layer_types)),
+        "periods": tuple(layer(k, kind, periods, config.has_routed_experts)
+                         for k, kind in zip(k_layers, config.layer_types)),
         "ln_f": jnp.ones((h,), dtype),
         "lm_head": normal(k_head, (h, vocab), h),
     }
+    if lead:
+        k_lead = jax.random.split(jax.random.fold_in(key, 1),
+                                  len(config.layer_types))
+        params["lead"] = tuple(layer(k, kind, lead, False) for k, kind
+                               in zip(k_lead, config.layer_types))
+    return params
 
 
 def param_specs(config: ModelConfig, mesh: Optional[Mesh],
@@ -168,15 +229,33 @@ def param_specs(config: ModelConfig, mesh: Optional[Mesh],
         "lin_a": P(None, None, t), "lin_b": P(None, None, t),
         "lin_gate": P(None, None, t, None), "lin_out": P(None, t, None, None),
         "o_norm": P(None, None), "A_log": P(None, t), "dt_bias": P(None, t),
+        # the latent layers' heads over tp as the full layers'; the
+        # latent itself and the routed experts whole (serving refuses tp
+        # for both: ``validate_serving``)
+        "wkv_a": P(None, None, None), "kv_norm": P(None, None),
+        "wkv_b": P(None, None, t, None),
+        "router": P(None, None, None), "router_bias": P(None, None),
+        "exp_gate": P(None, None, None, None),
+        "exp_up": P(None, None, None, None),
+        "exp_down": P(None, None, None, None),
+        "shared_gate": P(None, None, t), "shared_up": P(None, None, t),
+        "shared_down": P(None, t, None),
     }
-    return {
+
+    def stack(experts):
+        return tuple({name: by_name[name]
+                      for name in _layer_shapes(config, kind, experts)}
+                     for kind in config.layer_types)
+
+    specs = {
         "embed": P(None, None),
-        "periods": tuple({name: by_name[name]
-                          for name in _layer_shapes(config, kind)}
-                         for kind in config.layer_types),
+        "periods": stack(config.has_routed_experts),
         "ln_f": P(None),
         "lm_head": P(None, t),
     }
+    if config.first_k_dense_replace:
+        specs["lead"] = stack(False)
+    return specs
 
 
 def init_params_sharded(config: ModelConfig, key: jax.Array,
@@ -191,10 +270,14 @@ def init_params_sharded(config: ModelConfig, key: jax.Array,
 
 
 def num_parameters(config: ModelConfig) -> int:
-    per_kind = {kind: sum(math.prod(shape) for shape
-                          in _layer_shapes(config, kind).values())
-                for kind in set(config.layer_types)}
-    layers = sum(config.layers_of(kind) * n for kind, n in per_kind.items())
+    def period(experts):
+        return sum(math.prod(shape) for kind in config.layer_types for shape
+                   in _layer_shapes(config, kind, experts).values())
+
+    lead = config.first_k_dense_replace // len(config.layer_types)
+    periods = config.num_layers // len(config.layer_types) - lead
+    layers = (lead * period(False)
+              + periods * period(config.has_routed_experts))
     return (layers + 2 * config.vocab_size * config.hidden_size
             + config.hidden_size)
 
@@ -247,29 +330,110 @@ def split_qkv_heads(qkv: jax.Array, config: ModelConfig
     return l2_normalise(q, dk ** -0.5), l2_normalise(k), v
 
 
+def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+    """Rotary position embedding on ADJACENT pairs ``(2i, 2i+1)`` of the
+    last axis (the published ``rope_interleave``: the source permutes
+    pairs to halves before a half-split rotation, which gives the same
+    scores): pair ``i`` of a token at position ``t`` turns by ``t x
+    theta^(-2i/d)``.  ``positions`` broadcasts against ``x.shape[:-1]``.
+    Float32 inside, ``x``'s dtype out."""
+    with jax.named_scope(ROPE):
+        d = x.shape[-1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        angle = positions.astype(jnp.float32)[..., None] * inv
+        cos, sin = jnp.cos(angle), jnp.sin(angle)
+        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
+        a, b = pairs[..., 0], pairs[..., 1]
+        out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
+        return out.reshape(x.shape).astype(x.dtype)
+
+
+def expand_latent(c: jax.Array, wkv_b: jax.Array, config: ModelConfig
+                  ) -> tuple[jax.Array, jax.Array]:
+    """The per-head keys (their position-free part) and values of the
+    normed latents ``c`` ``[..., kv_lora_rank]``: ``[..., heads,
+    qk_nope_head_dim]`` and ``[..., heads, v_head_dim]``."""
+    with jax.named_scope(MLA_KV_B):
+        kv = jnp.einsum("...r,rnd->...nd", c, wkv_b)
+        return (kv[..., :config.qk_nope_head_dim],
+                kv[..., config.qk_nope_head_dim:])
+
+
+def latent_row(c: jax.Array, k_rope: jax.Array, config: ModelConfig
+               ) -> jax.Array:
+    """What the cache holds of a token: ``[c', rope(k_rope), zeros]`` in
+    whole lanes (``ModelConfig.latent_row``)."""
+    pad = config.latent_row - config.latent_width
+    return jnp.concatenate(
+        [c, k_rope, jnp.zeros(c.shape[:-1] + (pad,), c.dtype)], axis=-1)
+
+
+def _mlp(u: jax.Array, layer: Params, config: ModelConfig, mixer: Any,
+         experts: bool) -> jax.Array:
+    """The block's second sub-layer on ``u`` ``[B, S, hidden]``: the
+    dense SwiGLU, or the routed and shared experts."""
+    if experts:
+        b, s, h = u.shape
+        y, routing, counts = expert_layer(
+            u.reshape(b * s, h), layer, config.num_experts_per_tok,
+            config.routed_scaling_factor, mixer.valid(),
+            layer=layer.get("stack_index"))
+        mixer.routed(routing, counts)
+        return y.reshape(b, s, h)
+    with jax.named_scope(MLP_UP):
+        up = u @ layer["mlp_up"]
+        gate = u @ layer["mlp_gate"]
+    with jax.named_scope(MLP_ACT):
+        act = (jax.nn.silu(gate.astype(jnp.float32))
+               * up.astype(jnp.float32)).astype(u.dtype)
+    with jax.named_scope(MLP_DOWN):
+        return act @ layer["mlp_down"]
+
+
 def hybrid_block(h: jax.Array, layer: Params, kind: str,
                  config: ModelConfig, mixer: Any, l: jax.Array,
-                 state: Any) -> tuple[jax.Array, Any]:
+                 state: Any, experts: bool = False
+                 ) -> tuple[jax.Array, Any]:
     """One layer of ``kind`` on ``h`` ``[B, S, hidden]``; ``l`` is the
     layer's number among the layers of its kind (the index of its cache
-    planes).  Returns ``(h, state)``."""
+    planes); ``experts`` says whether its MLP is the expert layer.
+    Returns ``(h, state)``."""
     eps = config.rms_norm_eps
+    pre = config.norm_placement == "pre"
+    x = h
+    if pre:
+        with jax.named_scope(LN1):
+            x = rmsnorm(h, layer["ln1"], eps)
     if kind == FULL_ATTENTION:
         with jax.named_scope(ATTN_QKV):
-            q = _qk_norm(jnp.einsum("bsh,hnd->bsnd", h, layer["wq"]),
+            q = _qk_norm(jnp.einsum("bsh,hnd->bsnd", x, layer["wq"]),
                          layer["q_norm"], eps)
-            k = _qk_norm(jnp.einsum("bsh,hnd->bsnd", h, layer["wk"]),
+            k = _qk_norm(jnp.einsum("bsh,hnd->bsnd", x, layer["wk"]),
                          layer["k_norm"], eps)
-            v = jnp.einsum("bsh,hnd->bsnd", h, layer["wv"])
+            v = jnp.einsum("bsh,hnd->bsnd", x, layer["wv"])
         with jax.named_scope(ATTN_CORE):
             attn, state = mixer.attention(q, k, v, l, state)
         with jax.named_scope(ATTN_OUT):
             y = jnp.einsum("bsnd,ndh->bsh", attn, layer["wo"])
+    elif kind == LATENT_ATTENTION:
+        r = config.kv_lora_rank
+        with jax.named_scope(MLA_Q):
+            q = jnp.einsum("bsh,hnd->bsnd", x, layer["wq"])
+        with jax.named_scope(MLA_KV_A):
+            kv = x @ layer["wkv_a"]
+            # the latent has its own norm; the rotary key has none
+            c = rmsnorm(kv[..., :r], layer["kv_norm"], eps)
+        # the mixer knows the positions and owns the cache: ``rope``,
+        # ``mla_kv_b``, ``latent_update``, ``latent_attend`` open in it
+        attn, state = mixer.latent(q, c, kv[..., r:], layer["wkv_b"], l,
+                                   state)
+        with jax.named_scope(ATTN_OUT):
+            y = jnp.einsum("bsnd,ndh->bsh", attn, layer["wo"])
     elif kind == LINEAR_ATTENTION:
         with jax.named_scope(LIN_PROJ):
-            qkv = jnp.einsum("bsh,hnc->bsnc", h, layer["lin_qkv"])
-            gate = jnp.einsum("bsh,hnv->bsnv", h, layer["lin_gate"])
-            log_alpha, beta = linear_gates(h, layer, config)
+            qkv = jnp.einsum("bsh,hnc->bsnc", x, layer["lin_qkv"])
+            gate = jnp.einsum("bsh,hnv->bsnv", x, layer["lin_gate"])
+            log_alpha, beta = linear_gates(x, layer, config)
         # the mixer owns the convolution's carried inputs and the
         # state: ``lin_conv`` and ``lin_core`` open inside it
         o, state = mixer.linear(qkv, log_alpha, beta, layer["lin_conv"],
@@ -280,31 +444,31 @@ def hybrid_block(h: jax.Array, layer: Params, kind: str,
             y = jnp.einsum("bsnv,nvh->bsh", o, layer["lin_out"])
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
+    if pre:
+        h = h + y
+        with jax.named_scope(LN2):
+            u = rmsnorm(h, layer["ln2"], eps)
+        return h + _mlp(u, layer, config, mixer, experts), state
     with jax.named_scope(LN1):
         h = h + rmsnorm(y, layer["ln1"], eps)
-    with jax.named_scope(MLP_UP):
-        up = h @ layer["mlp_up"]
-        gate = h @ layer["mlp_gate"]
-    with jax.named_scope(MLP_ACT):
-        act = (jax.nn.silu(gate.astype(jnp.float32))
-               * up.astype(jnp.float32)).astype(h.dtype)
-    with jax.named_scope(MLP_DOWN):
-        y = act @ layer["mlp_down"]
+    y = _mlp(h, layer, config, mixer, experts)
     with jax.named_scope(LN2):
         h = h + rmsnorm(y, layer["ln2"], eps)
     return h, state
 
 
 def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
-                 make_mixer: Any, state: Any, xs: Any = None
+                 make_mixer: Any, state: Any, xs: Any = None,
+                 base: int = 0, experts: bool = False
                  ) -> tuple[jax.Array, Any, Any]:
-    """``h`` through the whole stack: a ``lax.scan`` over periods whose
-    body runs the period's layers in order.  ``state`` (cache planes or
-    nothing) rides the scan's CARRY beside the period's number, so that
-    a mixer's write into a plane is an in-place update of the loop's
-    buffer (``serve/engine.py::_scan_layers`` says what the other way
-    cost).  Layer ``i`` of period ``p`` is layer ``p * count + ordinal``
-    among the layers of its kind.
+    """``h`` through a stack of whole periods: a ``lax.scan`` over
+    periods whose body runs the period's layers in order.  ``state``
+    (cache planes or nothing) rides the scan's CARRY beside the period's
+    number, so that a mixer's write into a plane is an in-place update
+    of the loop's buffer (``serve/engine.py::_scan_layers`` says what
+    the other way cost).  Layer ``i`` of period ``p`` is layer ``(base +
+    p) * count + ordinal`` among the layers of its kind: ``base``
+    periods lie before this stack.
 
     ``make_mixer(xs_p)`` builds the period's mixer from the period's
     slice of ``xs`` (further per-period inputs with a leading period
@@ -314,19 +478,76 @@ def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
     kinds = config.layer_types
     count = {kind: kinds.count(kind) for kind in set(kinds)}
     ordinal = [kinds[:i].count(kind) for i, kind in enumerate(kinds)]
+    # the routed experts' weights do not ride the scan's ``xs``: the
+    # grouped product is handed the whole stack and the period's number
+    # (``ops/routed_experts.py::grouped_products`` says why)
+    whole = tuple({name: w for name, w in sub.items()
+                   if name.startswith("exp_")} for sub in periods)
+    periods = tuple({name: w for name, w in sub.items()
+                     if not name.startswith("exp_")} for sub in periods)
 
     def body(carry, inputs):
         h, p, state = carry
         layers, xs_p = inputs
         mixer = make_mixer(xs_p)
         for i, kind in enumerate(kinds):
-            h, state = hybrid_block(h, layers[i], kind, config, mixer,
-                                    p * count[kind] + ordinal[i], state)
+            layer = layers[i]
+            if whole[i]:
+                layer = {**layer, **whole[i], "stack_index": p - base}
+            h, state = hybrid_block(h, layer, kind, config, mixer,
+                                    p * count[kind] + ordinal[i], state,
+                                    experts)
         return (h, p + 1, state), mixer.collect()
 
-    (h, _, state), ys = jax.lax.scan(body, (h, jnp.int32(0), state),
+    (h, _, state), ys = jax.lax.scan(body, (h, jnp.int32(base), state),
                                      (periods, xs))
     return h, state, ys
+
+
+def scan_stack(h: jax.Array, params: Params, config: ModelConfig,
+               make_mixer: Any, state: Any, xs: Any = None
+               ) -> tuple[jax.Array, Any, Any, Any]:
+    """``h`` through the whole stack: the leading dense layers
+    (``params["lead"]``, where there are any) and then the periods, one
+    :func:`scan_periods` each.  ``xs``: per-LAYER inputs, a tuple of
+    arrays ``[L_kind, ...]`` (a prompt chunk's carried prefix) or None.
+    A mixer's ``collect()`` is ``(per_layer, routed)``: ``per_layer`` a
+    tuple of arrays with the period's layers of a kind leading, which
+    come back as ``[L_kind, ...]`` over both scans; ``routed`` what an
+    expert layer's mixer kept (None in the leading layers), which comes
+    back with the expert periods leading.  Returns ``(h, state,
+    per_layer, routed)``."""
+    n = len(config.layer_types)
+    lead = config.first_k_dense_replace // n
+    total = config.num_layers // n
+
+    def per_period(t):          # [L_kind, ...] -> [periods, L_kind / periods]
+        return t.reshape((total, t.shape[0] // total) + t.shape[1:])
+
+    def per_layer(t, more=None):
+        # a kind the period has no layer of collects ``()``
+        if isinstance(t, tuple):
+            return t
+        if more is not None:
+            t = jnp.concatenate([t, more], axis=0)
+        return t.reshape((t.shape[0] * t.shape[1],) + t.shape[2:])
+
+    if xs is not None:
+        xs = tuple(per_period(t) for t in xs)
+    if not lead:
+        h, state, (outs, routed) = scan_periods(
+            h, params["periods"], config, make_mixer, state, xs,
+            experts=config.has_routed_experts)
+        return h, state, tuple(per_layer(t) for t in outs), routed
+    first = None if xs is None else tuple(t[:lead] for t in xs)
+    rest = None if xs is None else tuple(t[lead:] for t in xs)
+    h, state, (outs_a, _) = scan_periods(h, params["lead"], config,
+                                         make_mixer, state, first)
+    h, state, (outs_b, routed) = scan_periods(
+        h, params["periods"], config, make_mixer, state, rest, base=lead,
+        experts=config.has_routed_experts)
+    outs = tuple(per_layer(a, b) for a, b in zip(outs_a, outs_b))
+    return h, state, outs, routed
 
 
 def embed_tokens(params: Params, ids: jax.Array) -> jax.Array:
@@ -347,19 +568,41 @@ def logits_of(params: Params, h: jax.Array,
 
 
 class SequenceMixer:
-    """The mixer of :func:`forward`: dense causal attention, and the
-    chunked delta rule from a zero state with zeros before the
-    convolution's first position."""
+    """The mixer of :func:`forward`: dense causal attention (the latent
+    layers in their expanded form), and the chunked delta rule from a
+    zero state with zeros before the convolution's first position."""
 
     def __init__(self, config: ModelConfig) -> None:
         self.config = config
+        self.chosen: list = []
+
+    def valid(self):
+        return None
+
+    def routed(self, routing, counts) -> None:
+        self.chosen.append(routing.experts)
 
     def collect(self):
-        return None
+        return (), (jnp.stack(self.chosen) if self.chosen else None)
 
     def attention(self, q, k, v, l, state):
         qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
         attn = dense_attention(qh, kh, vh, causal=True)
+        return attn.transpose(0, 2, 1, 3), state
+
+    def latent(self, q, c, k_rope, wkv_b, l, state):
+        cfg = self.config
+        dn, heads = cfg.qk_nope_head_dim, q.shape[2]
+        pos = jnp.arange(q.shape[1])
+        q_rope = rope(q[..., dn:], pos[None, :, None], cfg.rope_theta)
+        k_rope = rope(k_rope, pos[None, :], cfg.rope_theta)
+        k_nope, v = expand_latent(c, wkv_b, cfg)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_rope[:, :, None], k_rope.shape[:2]
+                                      + (heads, k_rope.shape[-1]))], axis=-1)
+        qh = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        attn = dense_attention(*(t.transpose(0, 2, 1, 3)
+                                 for t in (qh, k, v)), causal=True)
         return attn.transpose(0, 2, 1, 3), state
 
     def linear(self, qkv, log_alpha, beta, conv_w, l, state):
@@ -377,16 +620,21 @@ class SequenceMixer:
 
 
 def forward(params: Params, ids: jax.Array, config: ModelConfig,
-            mesh: Optional[Mesh] = None) -> jax.Array:
+            mesh: Optional[Mesh] = None, with_routing: bool = False
+            ) -> Any:
     """Token ids ``[B, S]`` to float32 logits ``[B, S, vocab]``: the
     whole sequence at once, no cache.  ``mesh`` only refuses what the
     family cannot run (pipeline stages); sharding comes from the
-    parameters' own placement."""
+    parameters' own placement.  ``with_routing`` also returns the
+    experts every token chose in every expert layer, ``[expert layers,
+    B * S, k]``."""
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         raise ValueError("pipeline parallelism is not implemented for "
                          "layer_types models")
     h = embed_tokens(params, ids)
-    mixer = SequenceMixer(config)
-    h, _, _ = scan_periods(h, params["periods"], config,
-                           lambda _xs: mixer, None)
-    return logits_of(params, h, config)
+    h, _, _, chosen = scan_stack(h, params, config,
+                                 lambda _xs: SequenceMixer(config), None)
+    logits = logits_of(params, h, config)
+    if with_routing:
+        return logits, chosen.reshape((-1,) + chosen.shape[2:])
+    return logits

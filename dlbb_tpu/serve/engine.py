@@ -73,11 +73,7 @@ from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.models.transformer import _dtype_of, init_params_sharded
 from dlbb_tpu.obs import spans
 from dlbb_tpu.obs.export import MetricsRegistry
-from dlbb_tpu.ops.decode_attention import (
-    check_kernel_takes,
-    live_tile_counts,
-    plane_tile_tokens,
-)
+from dlbb_tpu.ops.decode_attention import live_tile_counts
 from dlbb_tpu.resilience import inject
 from dlbb_tpu.resilience.errors import (
     CorruptStats,
@@ -183,6 +179,14 @@ class _RunStats:
     # and tiles the planes they ran over hold, times steps
     kv_tiles_live: int = 0
     kv_tiles_held: int = 0
+    # per decode unit: the slot-steps it ran and the cached tokens those
+    # steps attended over (each step's own token included), reckoned at
+    # the dispatch from the ledger's lengths
+    unit_slot_steps: list[int] = field(default_factory=list)
+    unit_live_tokens: list[int] = field(default_factory=list)
+    # the block family's own samples (``family.unit_counted`` /
+    # ``chunk_counted``); keys that start with "_" are its scratch
+    family: dict[str, Any] = field(default_factory=dict)
     # resilience accounting (docs/resilience.md, serving-faults section)
     retries: int = 0
     hung_dispatches: int = 0
@@ -310,7 +314,7 @@ class ServingEngine:
         self._probe_rids: tuple[int, ...] = ()
         self.probed: dict[int, dict[str, Any]] = {}
         self._probe_slots = np.full((self._probes,), -1, np.int32)
-        self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self._probe_dev = jnp.asarray(self._probe_slots.copy())
         self._decode, self._decode_fused = family.decode_programs(
             config, mesh, self._fused_ks, quantized=self._quantized,
             probe=lambda: self._probe_dev)
@@ -324,19 +328,20 @@ class ServingEngine:
         self._active_sharding = NamedSharding(mesh, P())
         # the fp layout's decode attention fetches tiles of this many
         # tokens under each slot's length: the kernel's own reckoning
-        # from the K plane this engine carries (the int8 layout reads the
-        # whole layer and counts nothing)
-        self._kv_tile = 0
+        # from the paged plane this engine carries, K/V or latent rows
+        # (``family.attend_tiles``; the int8 layout reads the whole layer
+        # and counts nothing)
+        self._kv_tile, self._tiles_of = 0, "kv"
         if not self._quantized:
-            k_plane = jax.eval_shape(self._fresh_carry)[0].k
-            check_kernel_takes(k_plane, mesh)
-            self._kv_tile = plane_tile_tokens(k_plane, mesh)
+            self._tiles_of, self._kv_tile = family.attend_tiles(
+                config, jax.eval_shape(self._fresh_carry)[0], mesh)
             for name, hlp in (
-                ("serve_kv_tiles_live",
-                 "K/V tiles of a layer the decode steps fetched (tokens "
-                 "under the active slots' lengths)"),
-                ("serve_kv_tiles_held",
-                 "K/V tiles of a layer the planes hold, times decode steps"),
+                (f"serve_{self._tiles_of}_tiles_live",
+                 f"{self._tiles_of} tiles of a layer the decode steps "
+                 "fetched (tokens under the active slots' lengths)"),
+                (f"serve_{self._tiles_of}_tiles_held",
+                 f"{self._tiles_of} tiles of a layer the planes hold, "
+                 "times decode steps"),
             ):
                 self.registry.inc(name, 0, help=hlp)
         # -- speculative decoding (docs/serving.md) --
@@ -443,26 +448,37 @@ class ServingEngine:
 
     def probe_results(self) -> dict[int, dict[str, Any]]:
         """``rid -> {"slot", "recycled", "prompt_ids", "tokens",
-        "logits", "prompt_state", "end_state"}`` of the last
+        "logits", "experts", "prompt_state", "end_state"}`` of the last
         ``run_trace``: ``logits[i]`` (float32 ``[vocab]``, numpy) are the
-        logits token ``tokens[i]`` was the ``argmax`` of; ``recycled``
+        logits token ``tokens[i]`` was the ``argmax`` of, ``experts[i]``
+        and ``gates[i]`` the experts chosen at that position in every
+        expert layer and the weights they got (``[expert layers, k]``;
+        None for a model without); ``recycled``
         says whether the slot had served another request before; the two
         states are the slot's recurrent state ``[L_lin, heads, d_v,
         d_k]`` after the prompt and after the last decode step (which
         took in ``tokens[-2]``; None if the request did not finish)."""
         out = {}
         for rid, rec in self.probed.items():
-            logits = [np.asarray(rec["first_logits"])]
-            tokens = [int(np.argmax(logits[0]))]
+            # ``seen`` is the logits, or (logits, experts, gates): a
+            # chunk's ``last`` gives the same parts for one position
+            parts = [[np.asarray(part)] for part in
+                     self._family.probe_parts(rec["first_logits"])]
+            tokens = [int(np.argmax(parts[0][0]))]
             for toks, seen, row, col, steps in rec["units"]:
-                toks, seen = np.asarray(toks), np.asarray(seen)
+                toks = np.asarray(toks)
+                seen = [np.asarray(part) for part in
+                        (seen if isinstance(seen, tuple) else (seen,))]
                 if toks.ndim == 1:          # a per-step unit
-                    toks, seen = toks[None], seen[None]
+                    toks, seen = toks[None], [part[None] for part in seen]
                 tokens += [int(t) for t in toks[:steps, row]]
-                logits += [seen[i, col] for i in range(steps)]
+                for kept, part in zip(parts, seen):
+                    kept += [part[i, col] for i in range(steps)]
             out[rid] = {"slot": rec["slot"], "recycled": rec["recycled"],
                         "prompt_ids": rec["prompt_ids"], "tokens": tokens,
-                        "logits": logits,
+                        "logits": parts[0],
+                        "experts": parts[1] if len(parts) > 1 else None,
+                        "gates": parts[2] if len(parts) > 2 else None,
                         "prompt_state": np.asarray(rec["prompt_state"]),
                         "end_state": (None if rec["end_state"] is None
                                       else np.asarray(rec["end_state"]))}
@@ -477,7 +493,7 @@ class ServingEngine:
                 if not any(r["slot"] == s and not r["done"]
                            for r in self.probed.values())]
         self._probe_slots[done[0] if done else 0] = slot
-        self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self._probe_dev = jnp.asarray(self._probe_slots.copy())
         self.probed[req.rid] = {
             "slot": slot, "recycled": recycled, "done": False,
             "prompt_ids": prompt_ids_from_seed(
@@ -905,7 +921,7 @@ class ServingEngine:
             series["shared_blocks"] = []
         carry = self._fresh_carry()
         active_np = np.zeros((cfg.max_batch,), bool)
-        active_dev = jax.device_put(jnp.array(active_np),
+        active_dev = jax.device_put(jnp.asarray(active_np.copy()),
                                     self._active_sharding)
         # slots that have served a request in this run (what the next
         # one finds there is the family's: ``slot_recycled``), and the
@@ -913,7 +929,7 @@ class ServingEngine:
         used_slots: set[int] = set()
         self.probed = {}
         self._probe_slots[:] = -1
-        self._probe_dev = jnp.array(self._probe_slots)  # a copy
+        self._probe_dev = jnp.asarray(self._probe_slots.copy())
         rejected_detail: list[dict[str, Any]] = []
         tokens_by_rid: dict[int, list[int]] = {}
         # -- speculative decoding state (docs/serving.md) --
@@ -959,13 +975,14 @@ class ServingEngine:
         active_dirty = [False]
 
         def refresh_active() -> None:
-            # ``jnp.array``, a copy: ``active_np`` is edited in place
-            # after a unit is dispatched, and on the CPU backend
-            # ``jnp.asarray`` may alias an aligned numpy buffer, so that a
-            # unit not yet run would see a completing slot as inactive
+            # a HOST copy: ``active_np`` is edited in place after a unit
+            # is dispatched, and on the CPU backend an upload may alias
+            # the numpy buffer (``jnp.array`` copies it on the DEVICE, in
+            # a program that runs when it runs): under load a unit not
+            # yet run saw a completing slot as inactive
             nonlocal active_dev
             if active_dirty[0]:
-                active_dev = jax.device_put(jnp.array(active_np),
+                active_dev = jax.device_put(jnp.asarray(active_np.copy()),
                                             self._active_sharding)
                 active_dirty[0] = False
 
@@ -1165,6 +1182,11 @@ class ServingEngine:
                 for _ in range(steps):
                     stats.per_token_s.append(dt / unit["k_exec"])
             done_at = self._now()
+            if self._probes:
+                # the family's small integers of this unit (None where
+                # it has none): ready with the tokens, no sync of their own
+                self._family.unit_counted(self.registry, self.config,
+                                          stats.family, unit.get("counts"))
             if unit.get("tokens"):
                 # token-feedback unit: ys are the committed token ids
                 # themselves ([B] per-step, [k, B] fused) — the n-gram
@@ -1219,7 +1241,8 @@ class ServingEngine:
             # call of THIS unit) and ``serve-decode-sync`` (the wait,
             # with the ``k`` of the unit waited for); what is left is
             # the host bookkeeping at scan exit
-            with spans.span("serve-decode", active=len(slots), steps=k):
+            with spans.span("serve-decode", active=len(slots), steps=k,
+                            unit=stats.decode_units):
                 if inject.fire("serve-decode-fail"):
                     # fires BEFORE the jit is invoked: the donated carry
                     # was never consumed, so a retry re-dispatches from
@@ -1274,21 +1297,26 @@ class ServingEngine:
                     self.registry.inc("serve_fused_scan_steps", k)
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, steps[s]))
+                # what the unit's steps attend over: step i of a slot
+                # reads the tokens (and the tiles) under its length + i
+                trip = np.arange(k)[:, None]
+                start = np.array([ledger.tokens(s) for s in steps])
+                stepping = trip < np.array(list(steps.values()))[None, :]
+                stats.unit_slot_steps.append(int(stepping.sum()))
+                stats.unit_live_tokens.append(
+                    int(((start[None, :] + trip + 1) * stepping).sum()))
                 if self._kv_tile:
-                    # what the unit's steps fetch of the K/V planes: step
-                    # i of a slot reads the tiles under its length + i
                     max_tiles = cfg.max_seq // self._kv_tile
-                    trip = np.arange(k)[:, None]
-                    start = np.array([ledger.tokens(s) for s in steps])
                     live = int(live_tile_counts(
-                        start[None, :] + trip,
-                        trip < np.array(list(steps.values()))[None, :],
+                        start[None, :] + trip, stepping,
                         self._kv_tile, max_tiles).sum())
                     held = k * max_tiles * cfg.max_batch
                     stats.kv_tiles_live += live
                     stats.kv_tiles_held += held
-                    self.registry.inc("serve_kv_tiles_live", live)
-                    self.registry.inc("serve_kv_tiles_held", held)
+                    self.registry.inc(
+                        f"serve_{self._tiles_of}_tiles_live", live)
+                    self.registry.inc(
+                        f"serve_{self._tiles_of}_tiles_held", held)
                 # host bookkeeping at scan exit: the ledger's known
                 # lengths make every step's outcome deterministic at
                 # dispatch time.  A torn half-applied update
@@ -1334,10 +1362,11 @@ class ServingEngine:
                 stats.decode_steps += k
                 stats.decode_units += 1
                 self.registry.inc("serve_decode_steps", k)
+                counts = None
                 if self._probes:
-                    # ys = (tokens, logits of the probed slots): a
-                    # probed request keeps both, on the device
-                    toks, seen = ys
+                    # ys = (tokens, seen of the probed slots, counts): a
+                    # probed request keeps the first two, on the device
+                    toks, seen, counts = ys
                     for row, slot_, rid, m in rows:
                         if rid in self.probed:
                             col = int(np.flatnonzero(
@@ -1356,7 +1385,7 @@ class ServingEngine:
                 if completions:
                     refresh_active()
                 inflight.append({"t0": t0, "ys": ys, "k_exec": k,
-                                 "rows": rows,
+                                 "rows": rows, "counts": counts,
                                  "tokens": ys_are_tokens,
                                  "completions": done_states})
                 # a k==1 unit's y is the SAME logical value as the
@@ -1932,9 +1961,11 @@ class ServingEngine:
                         plan["attached_tokens"] = m_chunks * chunk
                     else:
                         prefix = self._create_prefix()
+                    lasts = []
                     for ci in range(m_chunks, n_chunks):
                         with spans.span("serve-prefill-chunk",
-                                        rid=req.rid, chunk=ci):
+                                        rid=req.rid, chunk=ci,
+                                        seq=stats.prefill_chunks):
                             cache, prefix, y_last = \
                                 self._chunk_jit(ci)(
                                     cache, prefix,
@@ -1943,6 +1974,7 @@ class ServingEngine:
                                              (ci + 1) * chunk],
                                     np.int32(slot),
                                     np.int32(req.prompt_len))
+                        lasts.append(y_last)
                         stats.prefill_chunks += 1
                         self.registry.inc("serve_prefill_chunks")
                         if ci < n_chunks - 1 and slots:
@@ -1972,6 +2004,13 @@ class ServingEngine:
                     # already billed to decode_step_s/per_token_s —
                     # keep prefill_s a PREFILL cost
                     dt = time.perf_counter() - t0 - decode_spent
+                    if self._probes:
+                        # the family's small integers of each chunk:
+                        # ready with the last chunk's logits
+                        for last in lasts:
+                            self._family.chunk_counted(
+                                self.registry, self.config, stats.family,
+                                last)
             else:
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot):
@@ -2305,7 +2344,11 @@ class ServingEngine:
                                 # hidden state (host-transfer-in-loop)
                                 tokens_by_rid.setdefault(req.rid, []).append(
                                     first_id if token_mode
-                                    else int(jnp.argmax(y_last)))
+                                    # (a probing family's ``last`` may
+                                    # carry more behind the logits)
+                                    else int(jnp.argmax(
+                                        self._family.probe_parts(y_last)[0]
+                                        if self._probes else y_last)))
                             self._event(
                                 "request-prefill", req.rid, slot=slot,
                                 bucket=bucket,
@@ -2432,8 +2475,12 @@ class ServingEngine:
             "decode_steps": stats.decode_steps,
             "decode_units": stats.decode_units,
             # share of the K/V planes' tiles the decode steps fetched
-            "kv_live_share": (stats.kv_tiles_live / stats.kv_tiles_held
-                              if stats.kv_tiles_held else 0.0),
+            f"{self._tiles_of}_live_share": (
+                stats.kv_tiles_live / stats.kv_tiles_held
+                if stats.kv_tiles_held else 0.0),
+            # the block family's own (experts touched, the fullest
+            # expert's load)
+            **self._family.report_shares(self.config, stats.family),
             # share of admissions whose input the look-ahead had ready
             "input_ready_share": lookahead.ready_share,
             "fast_path": {
@@ -2505,6 +2552,15 @@ class ServingEngine:
                 "prefill_s": list(stats.prefill_s),
                 "decode_step_s": list(stats.decode_step_s),
                 "e2e_latency_s": list(stats.e2e_latency_s),
+                # per decode unit, in dispatch order (a ``serve-decode``
+                # span's ``unit``): slot-steps and cached tokens attended
+                "unit_slot_steps": list(stats.unit_slot_steps),
+                "unit_live_tokens": list(stats.unit_live_tokens),
+                # the family's, per decode unit (``unit``) and prompt
+                # chunk (a ``serve-prefill-chunk`` span's ``seq``)
+                **{key: list(values)
+                   for key, values in stats.family.items()
+                   if not key.startswith("_")},
             }
         if self.capture_tokens:
             report["completed_tokens"] = {

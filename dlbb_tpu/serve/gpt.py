@@ -52,7 +52,11 @@ from dlbb_tpu.models.transformer import (
     _layernorm,
     named,
 )
-from dlbb_tpu.ops.decode_attention import decode_attention
+from dlbb_tpu.ops.decode_attention import (
+    check_kernel_takes,
+    decode_attention,
+    plane_tile_tokens,
+)
 from dlbb_tpu.serve.attend import (
     KV_UPDATE,
     _cached_attention,
@@ -871,6 +875,20 @@ def register_metrics(registry: Any, config: ModelConfig, serving: Any,
 def slot_recycled(registry: Any, rid: int, slot: int) -> None:
     """A slot that served a request is given to ``rid``: nothing to
     clear, K/V past a slot's length is dead by the length mask."""
+
+
+def attend_tiles(config: ModelConfig, cache: Any,
+                 mesh: Mesh) -> tuple[str, int]:
+    """The decode kernel fetches the fp layout's K/V planes by tiles of
+    this many tokens (``serve_kv_tiles_live`` / ``_held`` count by
+    them); refuses planes it cannot read on the chip."""
+    check_kernel_takes(cache.k, mesh)
+    return "kv", plane_tile_tokens(cache.k, mesh)
+
+
+def report_shares(config: ModelConfig, samples: dict) -> dict:
+    """What the report says of this family's own layers: nothing."""
+    return {}
 
 
 def fresh_carry(config: ModelConfig, serving: Any, mesh: Mesh):

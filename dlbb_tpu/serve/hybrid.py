@@ -9,12 +9,18 @@ HybridCache`.
   attention over the carried prefix K/V, one block write through
   ``write_slot_blocks``); the linear-attention layers run the chunked
   gated delta rule from the state the previous chunk handed on and write
-  the slot's state and convolution inputs.  The carried ``prefix`` is
-  ``(k, v, state, conv)``; the one a prompt starts from is all zeros
-  (:func:`create_prefix`), which is what clears a recycled slot.
+  the slot's state and convolution inputs; the latent-attention layers
+  expand per-head keys and values from the carried prefix of latent rows
+  and the chunk's own (the EXPANDED form: 2.4 x fewer operations than
+  the absorbed one over a chunk) and write the chunk's rows as whole
+  blocks.  The carried ``prefix`` is ``(k, v, state, conv, latent)``; the
+  one a prompt starts from is all zeros (:func:`create_prefix`), which
+  is what clears a recycled slot.
 - ``serve_decode_step`` / ``serve_decode_k<K>``: embed each slot's
-  pending token, one recurrent step (state and K/V updated in place in
-  the carried planes), logits, greedy ``argmax`` fed back on the device.
+  pending token, one recurrent step (state, K/V and latent rows updated
+  in place in the carried planes; latent attention in its ABSORBED form,
+  ``ops/latent_attention.py``), logits, greedy ``argmax`` fed back on
+  the device.
 - ``serve_inject``: a finished prefill's first token into its slot.
 - ``serve_probe_state``: a copy of one slot's recurrent state, for a
   probed request only (twice in its life).
@@ -23,7 +29,14 @@ The decode carry is ``(cache, tokens [max_batch] int32)``.  Every decode
 program also returns the float32 logits of the two slots named by its
 ``probe`` argument, each step: what a checker (``ServingEngine.probe``)
 holds on the device to compare with a reference.  The programs are the
-same whether or not anything is probed.
+same whether or not anything is probed.  A model with routed experts
+returns beside them the experts those slots chose in every expert layer
+and the gates it gave them (``seen = (logits, experts, gates)``) and
+three small integers a step
+(``counts``: assignments, experts that got a token, the fullest expert's
+tokens, over the expert layers), and its chunk program the same for the
+prompt's last position and the chunk's real tokens (``last = (logits,
+experts, gates, counts)``).
 
 The block and the period are ``models/hybrid.py``'s; this file holds the
 three mixers that touch the cache, and at its end what the scheduler
@@ -37,20 +50,30 @@ from typing import Any
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.data.synthetic import prompt_ids_from_seed
 from dlbb_tpu.models import hybrid
 from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
+    LATENT_ATTENTION,
     LINEAR_ATTENTION,
     ModelConfig,
     kv_cache_bytes,
+    latent_cache_bytes,
     state_cache_bytes,
 )
-from dlbb_tpu.models.hybrid import LIN_CONV, LIN_CORE
+from dlbb_tpu.models.hybrid import LIN_CONV, LIN_CORE, MLA_KV_B
 from dlbb_tpu.models.transformer import _dtype_of, named
+from dlbb_tpu.ops import decode_attention as kv_kernel
+from dlbb_tpu.ops import latent_attention as latent_kernel
 from dlbb_tpu.ops.decode_attention import decode_attention
+from dlbb_tpu.ops.latent_attention import (
+    LATENT_ATTEND,
+    LATENT_UPDATE,
+    latent_decode_attention,
+)
 from dlbb_tpu.ops.gated_delta import (
     causal_conv,
     gated_delta_chunked,
@@ -60,6 +83,7 @@ from dlbb_tpu.obs import spans
 from dlbb_tpu.serve.attend import _chunk_attention, _layer_of
 from dlbb_tpu.serve.kvcache import (
     HybridCache,
+    append_latent_rows,
     append_token_rows,
     create_hybrid_cache,
     hybrid_cache_shardings,
@@ -75,17 +99,22 @@ def token_spec(mesh: Mesh) -> P:
     return P(hybrid_cache_specs(mesh).k[1])
 
 
-def prefix_specs(mesh: Mesh) -> tuple[P, P, P, P]:
-    """The chunk carry ``(k, v, state, conv)``: no slot dim, heads over
-    tp (``gpt.prefix_spec`` for K/V)."""
+def prefix_specs(mesh: Mesh) -> tuple[P, P, P, P, P]:
+    """The chunk carry ``(k, v, state, conv[, latent])``: no slot dim,
+    heads over tp (``gpt.prefix_spec`` for K/V).  The carry has its
+    fifth part only for a model with latent-attention layers
+    (:func:`create_prefix`)."""
     tp = hybrid_cache_specs(mesh).k[4]
     kv = P(None, None, tp, None)
-    return (kv, kv, P(None, tp, None, None), P(None, None, tp, None))
+    return (kv, kv, P(None, tp, None, None), P(None, None, tp, None),
+            P(None, None, None))
 
 
 def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple:
     """The carry a prompt's first chunk starts from: no prefix K/V, a
-    ZERO recurrent state and zeros before the convolution."""
+    ZERO recurrent state and zeros before the convolution, ``(k, v,
+    state, conv)``; with latent-attention layers a fifth part, no prefix
+    rows."""
     dtype = _dtype_of(config.dtype)
     n_lin = config.layers_of(LINEAR_ATTENTION)
     heads = config.linear_num_value_heads
@@ -93,10 +122,15 @@ def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple:
                     config.head_dim), dtype)
     state = jnp.zeros((n_lin, heads, config.linear_value_head_dim,
                        config.linear_key_head_dim), hybrid.STATE_DTYPE)
-    conv = jnp.zeros((n_lin, config.linear_conv_kernel_dim - 1, heads,
-                      config.linear_conv_channels // heads), dtype)
+    conv = jnp.zeros((n_lin, max(config.linear_conv_kernel_dim - 1, 0),
+                      heads, config.linear_conv_channels // max(heads, 1)),
+                     dtype)
+    parts = (kv, kv, state, conv)
+    if config.layers_of(LATENT_ATTENTION):
+        parts += (jnp.zeros((config.layers_of(LATENT_ATTENTION), 0,
+                             config.latent_row), dtype),)
     return tuple(jax.device_put(t, NamedSharding(mesh, s))
-                 for t, s in zip((kv, kv, state, conv), prefix_specs(mesh)))
+                 for t, s in zip(parts, prefix_specs(mesh)))
 
 
 def _pad_heads(t: jax.Array, heads: int) -> jax.Array:
@@ -108,29 +142,71 @@ def _pad_heads(t: jax.Array, heads: int) -> jax.Array:
     return jnp.pad(t, [(0, 0)] * (t.ndim - 2) + [(0, extra), (0, 0)])
 
 
-def _per_period(t: jax.Array, periods: int) -> jax.Array:
-    """``[L_kind, ...]`` as the period scan's ``xs``: ``[periods,
-    L_kind / periods, ...]``."""
-    return t.reshape((periods, t.shape[0] // periods) + t.shape[1:])
+def _cache_rows(c: jax.Array, k_rope: jax.Array, positions: jax.Array,
+                config: ModelConfig) -> jax.Array:
+    """What both mixers put into the latent plane for tokens at
+    ``positions``: the normed latent and the ROTATED shared key, in whole
+    lanes."""
+    return hybrid.latent_row(
+        c, hybrid.rope(k_rope, positions, config.rope_theta), config)
 
 
-def _per_layer(t: jax.Array) -> jax.Array:
-    return t.reshape((t.shape[0] * t.shape[1],) + t.shape[2:])
+def _sum_counts(counts: jax.Array) -> jax.Array:
+    """The expert layers' ``load_counts`` ``[..., 3]`` as one triple:
+    assignments and experts touched summed, the fullest expert's rows
+    the largest."""
+    c = counts.reshape(-1, 3)
+    return jnp.stack([jnp.sum(c[:, 0]), jnp.sum(c[:, 1]), jnp.max(c[:, 2])])
 
 
-class ChunkMixer:
+class _Mixer:
+    """What every serving mixer keeps of a period: its per-layer cache
+    outputs by kind ``(k, v, state, conv, latent)`` and what its expert
+    layers chose."""
+
+    def __init__(self) -> None:
+        self.out: tuple[list, ...] = ([], [], [], [], [])
+        self.kept: list = []
+        self.counts: list = []
+
+    def keep(self, per_token: jax.Array) -> jax.Array:
+        """Of an expert layer's choices ``[T, k]``, what a checker reads."""
+        raise NotImplementedError
+
+    def routed(self, routing, counts) -> None:
+        self.kept.append((self.keep(routing.experts),
+                          self.keep(routing.gates)))
+        self.counts.append(counts)
+
+    def collect(self):
+        routed = None
+        if self.kept:
+            routed = (jnp.stack([e for e, _ in self.kept]),
+                      jnp.stack([g for _, g in self.kept]),
+                      jnp.stack(self.counts))
+        return tuple(jnp.stack(o) if o else () for o in self.out), routed
+
+
+class ChunkMixer(_Mixer):
     """One period of one prompt chunk (batch 1) at static offset
     ``start``: ``xs`` are the period's slices of the carried prefix."""
 
     def __init__(self, config: ModelConfig, xs: tuple, slot, n_valid,
                  start: int, chunk_len: int, block_size: int) -> None:
+        super().__init__()
         self.config, self.xs = config, xs
         self.slot, self.n_valid = slot, n_valid
         self.start, self.chunk_len, self.bs = start, chunk_len, block_size
-        self.out: tuple[list, list, list, list] = ([], [], [], [])
 
-    def collect(self):
-        return tuple(jnp.stack(o) for o in self.out)
+    def valid(self):
+        # padding takes no expert's time
+        return jnp.arange(self.chunk_len) < self.n_valid
+
+    def keep(self, per_token):
+        # the prompt's last position, where it lies in this chunk
+        last = jnp.clip(self.n_valid - 1, 0, self.chunk_len - 1)
+        return jax.lax.dynamic_index_in_dim(per_token, last, 0,
+                                            keepdims=False)
 
     def attention(self, q, k, v, l, planes):
         pk, pv = self.xs[0], self.xs[1]
@@ -139,7 +215,7 @@ class ChunkMixer:
         v_all = jnp.concatenate([pv[j], v[0]], axis=0)
         attn = _chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
                                 self.start)
-        k_c, v_c, st, cv = planes
+        k_c, v_c, *rest = planes
         blocks = (self.chunk_len // self.bs, self.bs) + k_c.shape[-2:]
         k_c = write_slot_blocks(
             k_c, _pad_heads(k[0], k_c.shape[-2]).reshape(blocks), l,
@@ -149,7 +225,36 @@ class ChunkMixer:
             self.slot, self.start // self.bs)
         self.out[0].append(k_all)
         self.out[1].append(v_all)
-        return attn.transpose(0, 2, 1, 3), (k_c, v_c, st, cv)
+        return attn.transpose(0, 2, 1, 3), (k_c, v_c, *rest)
+
+    def latent(self, q, c, k_rope, wkv_b, l, planes):
+        cfg = self.config
+        dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        heads = q.shape[2]
+        j = len(self.out[4])
+        pos = self.start + jnp.arange(self.chunk_len)
+        q_rope = hybrid.rope(q[..., dn:], pos[None, :, None],
+                             cfg.rope_theta)
+        rows = _cache_rows(c, k_rope, pos[None, :], cfg)[0]
+        rows_all = jnp.concatenate([self.xs[4][j], rows], axis=0)
+        # the expanded form: every head's keys and values of the tokens
+        # the chunk attends over, from their rows
+        k_nope, v = hybrid.expand_latent(rows_all[:, :r], wkv_b, cfg)
+        k_all = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(
+                rows_all[:, None, r:cfg.latent_width],
+                (rows_all.shape[0], heads, cfg.qk_rope_head_dim))], axis=-1)
+        qh = jnp.concatenate([q[..., :dn], q_rope], axis=-1)
+        with jax.named_scope(LATENT_ATTEND):
+            attn = _chunk_attention(qh.transpose(0, 2, 1, 3), k_all, v,
+                                    self.start)
+        *rest, lat = planes
+        with jax.named_scope(LATENT_UPDATE):
+            lat = write_slot_blocks(
+                lat, rows.reshape(self.chunk_len // self.bs, self.bs, -1),
+                l, self.slot, self.start // self.bs)
+        self.out[4].append(rows_all)
+        return attn.transpose(0, 2, 1, 3), (*rest, lat)
 
     def linear(self, qkv, log_alpha, beta, conv_w, l, planes):
         cfg = self.config
@@ -169,64 +274,76 @@ class ChunkMixer:
                 jnp.where(real, beta, 0.0),
                 self.xs[2][j][None].astype(jnp.float32))
             state = state[0].astype(hybrid.STATE_DTYPE)
-            k_c, v_c, st, cv = planes
+            k_c, v_c, st, cv, lat = planes
             st = write_slot_state(st, state, l, self.slot)
             cv = write_slot_state(cv, tail, l, self.slot)
         self.out[2].append(state)
         self.out[3].append(tail)
-        return o, (k_c, v_c, st, cv)
+        return o, (k_c, v_c, st, cv, lat)
 
 
 def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
                         start: int, quantized: bool = False):
     """Jitted ``prefill_chunk(cache, prefix, params, ids [1, chunk],
-    slot, length) -> (cache, prefix, logits_last [vocab])``, the
-    signature of ``gpt.build_prefill_chunk`` with token ids for
-    embeddings and float32 logits for the last hidden state.
-    ``quantized`` is the seam's: this family has the fp layout only
-    (``models.configs.validate_serving`` refuses int8)."""
-    periods = config.num_layers // len(config.layer_types)
+    slot, length) -> (cache, prefix, last)``, the signature of
+    ``gpt.build_prefill_chunk`` with token ids for embeddings; ``last``
+    is the float32 logits ``[vocab]`` of the prompt's last position, and
+    with routed experts ``(logits, experts chosen there [expert layers,
+    k], their gates, counts [3])``.  ``quantized`` is the seam's: this family has the
+    fp layout only (``models.configs.validate_serving`` refuses int8)."""
 
     @named(f"serve_prefill_chunk_o{start}")
     def prefill_chunk(cache, prefix, params, ids, slot, length):
         n_valid = jnp.clip(length - start, 0, chunk_len)
-        xs = tuple(_per_period(t, periods) for t in prefix)
         h = hybrid.embed_tokens(params, ids)
-        h, planes, ys = hybrid.scan_periods(
-            h, params["periods"], config,
+        h, planes, ys, routed = hybrid.scan_stack(
+            h, params, config,
             lambda xs_p: ChunkMixer(config, xs_p, slot, n_valid, start,
                                     chunk_len, cache.block_size),
-            cache[:-1], xs)
+            cache[:-1], prefix)
         local = jnp.clip(length - 1 - start, 0, chunk_len - 1)
         h_last = jax.lax.dynamic_index_in_dim(h[0], local, 0, keepdims=False)
         new_len = jnp.minimum(length, start + chunk_len)
         lengths = jnp.where(jnp.arange(cache.max_batch) == slot,
                             new_len, cache.lengths).astype(jnp.int32)
-        return (HybridCache(*planes, lengths),
-                tuple(_per_layer(t) for t in ys),
-                hybrid.logits_of(params, h_last, config))
+        last = hybrid.logits_of(params, h_last, config)
+        if routed is not None:
+            chosen, gates, counts = routed
+            last = (last, chosen.reshape((-1,) + chosen.shape[2:]),
+                    gates.reshape((-1,) + gates.shape[2:]),
+                    _sum_counts(counts))
+        # a kind the model has no layer of hands its empty prefix on
+        ys = tuple(y if len(y) else p for y, p in zip(ys, prefix))
+        return HybridCache(*planes, lengths), ys, last
 
-    pre_sh = tuple(NamedSharding(mesh, s) for s in prefix_specs(mesh))
+    parts = 5 if config.layers_of(LATENT_ATTENTION) else 4
+    pre_sh = tuple(NamedSharding(mesh, s)
+                   for s in prefix_specs(mesh)[:parts])
     return jax.jit(
         prefill_chunk, donate_argnums=(0,),
         out_shardings=(hybrid_cache_shardings(mesh), pre_sh,
                        NamedSharding(mesh, P())))
 
 
-class DecodeMixer:
-    """One token a slot: append and attend in the K/V planes, one
-    recurrent step in the state planes, both in place in the carry."""
+class DecodeMixer(_Mixer):
+    """One token a slot: append and attend in the K/V or latent planes,
+    one recurrent step in the state planes, all in place in the carry."""
 
-    def __init__(self, config: ModelConfig, mesh: Mesh, lengths, active
-                 ) -> None:
+    def __init__(self, config: ModelConfig, mesh: Mesh, lengths, active,
+                 probe) -> None:
+        super().__init__()
         self.config, self.mesh = config, mesh
-        self.lengths, self.active = lengths, active
+        self.lengths, self.active, self.probe = lengths, active, probe
 
-    def collect(self):
-        return None
+    def valid(self):
+        # a slot that holds no request takes no expert's time
+        return self.active
+
+    def keep(self, per_token):
+        return jnp.take(per_token, self.probe, axis=0)
 
     def attention(self, q, k, v, l, planes):
-        k_c, v_c, st, cv = planes
+        k_c, v_c, *rest = planes
         heads, held = q.shape[2], k_c.shape[-2]
         k_c = append_token_rows(k_c, _pad_heads(k, held), l, self.lengths,
                                 self.active, self.mesh)
@@ -237,10 +354,36 @@ class DecodeMixer:
             _pad_heads(q, held).transpose(0, 2, 1, 3), k_c, v_c, l,
             self.lengths, self.active, self.mesh)
         return (attn.transpose(0, 2, 1, 3)[:, :, :heads],
-                (k_c, v_c, st, cv))
+                (k_c, v_c, *rest))
+
+    def latent(self, q, c, k_rope, wkv_b, l, planes):
+        cfg = self.config
+        dn, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+        q, pos = q[:, 0], self.lengths
+        q_rope = hybrid.rope(q[..., dn:], pos[:, None], cfg.rope_theta)
+        rows = _cache_rows(c[:, 0], k_rope[:, 0], pos, cfg)
+        *rest, lat = planes
+        with jax.named_scope(LATENT_UPDATE):
+            lat = append_latent_rows(lat, rows, l, self.lengths,
+                                     self.active, self.mesh)
+        # the ABSORBED form: the query taken into the latent's space, so
+        # that one row a token is key and value for every head
+        with jax.named_scope(MLA_KV_B):
+            q_abs = jnp.einsum("bnd,rnd->bnr", q[..., :dn],
+                               wkv_b[..., :dn])
+        pad = cfg.latent_row - cfg.latent_width
+        q_row = jnp.concatenate(
+            [q_abs, q_rope, jnp.zeros(q_abs.shape[:2] + (pad,),
+                                      q_abs.dtype)], axis=-1)
+        o = latent_decode_attention(
+            q_row, lat, l, self.lengths, self.active, self.mesh, r,
+            (dn + cfg.qk_rope_head_dim) ** -0.5)
+        with jax.named_scope(MLA_KV_B):
+            attn = jnp.einsum("bnr,rnd->bnd", o, wkv_b[..., dn:])
+        return attn[:, None], (*rest, lat)
 
     def linear(self, qkv, log_alpha, beta, conv_w, l, planes):
-        k_c, v_c, st, cv = planes
+        k_c, v_c, st, cv, lat = planes
         keep = self.active[:, None, None, None]
         with jax.named_scope(LIN_CONV):
             before = _layer_of(cv, l)
@@ -257,25 +400,36 @@ class DecodeMixer:
             # an inactive slot's state stays bit for bit as it was
             st = jax.lax.dynamic_update_index_in_dim(
                 st, jnp.where(keep, new.astype(st.dtype), old), l, 0)
-        return o[:, None], (k_c, v_c, st, cv)
+        return o[:, None], (k_c, v_c, st, cv, lat)
 
 
 def _decode_math(carry, params, active, probe, config: ModelConfig,
                  mesh: Mesh):
     """One decode step, shared verbatim by the per-step program and
-    every trip of the fused scan.  Returns ``(carry, tokens, logits of
-    the probed slots [PROBES, vocab])``."""
+    every trip of the fused scan.  Returns ``(carry, tokens, seen,
+    counts)``: ``seen`` the logits of the probed slots ``[PROBES,
+    vocab]``, with routed experts ``(logits, experts those slots chose
+    [PROBES, expert layers, k], their float32 gates)``; ``counts`` the
+    step's ``[3]`` then, else None."""
     cache, tok = carry
-    mixer = DecodeMixer(config, mesh, cache.lengths, active)
     h = hybrid.embed_tokens(params, tok)[:, None, :]
-    h, planes, _ = hybrid.scan_periods(h, params["periods"], config,
-                                       lambda _xs: mixer, cache[:-1])
+    h, planes, _, routed = hybrid.scan_stack(
+        h, params, config,
+        lambda _xs: DecodeMixer(config, mesh, cache.lengths, active, probe),
+        cache[:-1])
     logits = hybrid.logits_of(params, h[:, 0], config)
     new_tok = jnp.where(active, jnp.argmax(logits, axis=-1).astype(tok.dtype),
                         tok)
     lengths = cache.lengths + active.astype(jnp.int32)
-    return ((HybridCache(*planes, lengths), new_tok), new_tok,
-            jnp.take(logits, probe, axis=0))
+    seen, counts = jnp.take(logits, probe, axis=0), None
+    if routed is not None:
+        # [periods, in a period, PROBES, k] -> [PROBES, expert layers, k]
+        chosen, gates, counts = routed
+        seen = (seen,) + tuple(
+            t.reshape((-1,) + t.shape[2:]).transpose(1, 0, 2)
+            for t in (chosen, gates))
+        counts = _sum_counts(counts)
+    return (HybridCache(*planes, lengths), new_tok), new_tok, seen, counts
 
 
 def _decode_shardings(mesh: Mesh):
@@ -285,23 +439,24 @@ def _decode_shardings(mesh: Mesh):
 
 def build_decode_step(config: ModelConfig, mesh: Mesh):
     """Jitted ``decode_step(carry, params, active, probe) -> (carry,
-    tokens [B], probe logits [PROBES, vocab])``; the carry is donated."""
+    tokens [B], seen, counts)``; the carry is donated."""
 
     @named("serve_decode_step")
     def decode_step(carry, params, active, probe):
         return _decode_math(carry, params, active, probe, config, mesh)
 
     carry_sh, tok_sh = _decode_shardings(mesh)
+    rep = NamedSharding(mesh, P())
     return jax.jit(decode_step, donate_argnums=(0,),
-                   out_shardings=(carry_sh, tok_sh,
-                                  NamedSharding(mesh, P())))
+                   out_shardings=(carry_sh, tok_sh, rep, rep))
 
 
 def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int):
     """``k`` decode steps in one ``lax.scan``, as
     ``gpt.build_decode_fused``: lengths recomputed each trip from the
     replicated inputs, planes and tokens in the carry.  Returns
-    ``(carry, tokens [k, B], probe logits [k, PROBES, vocab])``."""
+    ``(carry, tokens [k, B], seen, counts)``, the last two with the
+    steps leading."""
 
     @named(f"serve_decode_k{k}")
     def decode_fused(carry, params, active, remaining, probe):
@@ -312,29 +467,30 @@ def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int):
         def step(c, _):
             *planes, tok, i = c
             lengths_i = lengths0 + act_i32 * jnp.minimum(i, remaining)
-            (cache, tok2), out, seen = _decode_math(
+            (cache, tok2), out, seen, counts = _decode_math(
                 (HybridCache(*planes, lengths_i), tok), params,
                 active & (i < remaining), probe, config, mesh)
-            return (*cache[:-1], tok2, i + 1), (out, seen)
+            return (*cache[:-1], tok2, i + 1), (out, seen, counts)
 
-        (*planes, tok, _i), (toks, seen) = jax.lax.scan(
+        (*planes, tok, _i), (toks, seen, counts) = jax.lax.scan(
             step, (*cache0[:-1], tok0, jnp.int32(0)), None, length=k)
         lengths_f = lengths0 + act_i32 * jnp.minimum(jnp.int32(k), remaining)
-        return (HybridCache(*planes, lengths_f), tok), toks, seen
+        return (HybridCache(*planes, lengths_f), tok), toks, seen, counts
 
     carry_sh, tok_sh = _decode_shardings(mesh)
     toks_sh = NamedSharding(mesh, P(None, *token_spec(mesh)))
+    rep = NamedSharding(mesh, P())
     return jax.jit(decode_fused, donate_argnums=(0,),
-                   out_shardings=(carry_sh, toks_sh,
-                                  NamedSharding(mesh, P())))
+                   out_shardings=(carry_sh, toks_sh, rep, rep))
 
 
 @named("serve_inject")
-def inject_token(carry, slot, logits):
+def inject_token(carry, slot, last):
     """A finished prefill's first token, the ``argmax`` of its last
-    position's logits, into the decode token buffer."""
+    position's logits (``last``, or its first part), into the decode
+    token buffer."""
     cache, tok = carry
-    first = jnp.argmax(logits).astype(tok.dtype)
+    first = jnp.argmax(probe_parts(last)[0]).astype(tok.dtype)
     return cache, jnp.where(jnp.arange(tok.shape[0]) == slot, first, tok)
 
 
@@ -346,6 +502,13 @@ def slot_state(cache: HybridCache, slot) -> jax.Array:
     prompt and after its last decode step."""
     return jax.lax.dynamic_index_in_dim(cache.state, slot, axis=1,
                                         keepdims=False)
+
+
+def probe_parts(last: Any) -> tuple:
+    """A chunk program's ``last`` as the decode programs' ``seen`` is
+    laid out for one slot: ``(logits,)``, or ``(logits, experts,
+    gates)``."""
+    return tuple(last[:-1]) if isinstance(last, tuple) else (last,)
 
 
 def fresh_carry(config: ModelConfig, serving: Any, mesh: Mesh):
@@ -377,32 +540,64 @@ LACKS = {
 
 def check_serving(config: ModelConfig, serving: Any) -> None:
     """Refuse what this family's serving path does not have yet, each by
-    its mechanism (ROADMAP.md, Queue 2); int8 KV is refused in
-    ``models.configs.validate_serving``."""
+    its mechanism (ROADMAP.md, Queue 2); int8 KV, a draft model and
+    ``tp`` over latents or experts are refused in
+    ``models.configs.validate_serving``, ``ep`` in
+    ``validate_expert_parallelism``."""
+    latent = LATENT_ATTENTION in config.layer_types
     if serving.speculation != "off":
         raise ValueError(
             f"serving.speculation={serving.speculation!r} is not "
             "implemented for layer_types models: a rejected draft "
             "needs the recurrent state rolled back, and the state "
-            "cache keeps no snapshots")
+            "cache keeps no snapshots"
+            + ("; over latents the verify step would need the absorbed "
+               "attention for several positions a slot" if latent else ""))
     if serving.prefix_caching:
         raise ValueError(
             "serving.prefix_caching is not implemented for "
             "layer_types models: attaching to shared blocks needs "
             "the recurrent state as it was at the block boundary, "
-            "and the state cache keeps no snapshots")
+            "and the state cache keeps no snapshots"
+            + ("; the latent plane has no attach program (the copy of a "
+               "donor's rows and the prefix they give a chunk)"
+               if latent else ""))
     if serving.prefill_chunk is None:
         raise ValueError(
             "layer_types models are prefilled in chunks: set "
             "serving.prefill_chunk (the chunk program hands the "
-            "recurrent state from chunk to chunk; there is no "
-            "monolithic prefill program)")
+            "recurrent state and the latent rows from chunk to chunk; "
+            "there is no monolithic prefill program)")
+
+
+def attend_tiles(config: ModelConfig, cache: HybridCache,
+                 mesh: Mesh) -> tuple[str, int]:
+    """Which paged plane the decode kernel of this model fetches, and by
+    tiles of how many tokens: ``("latent", T)`` or ``("kv", T)`` (what
+    the scheduler's ``serve_<name>_tiles_live`` / ``_held`` count by).
+    Refuses, with the reason, planes the kernel cannot read on the
+    chip."""
+    if config.layers_of(LATENT_ATTENTION):
+        latent_kernel.check_kernel_takes(cache.latent, config.kv_lora_rank)
+        return "latent", latent_kernel.plane_tile_tokens(cache.latent)
+    kv_kernel.check_kernel_takes(cache.k, mesh)
+    return "kv", kv_kernel.plane_tile_tokens(cache.k, mesh)
+
+
+_MOE_COUNTERS = (
+    ("serve_moe_assignments",
+     "(token, expert) assignments the expert layers computed (real "
+     "tokens only)"),
+    ("serve_moe_experts_touched",
+     "experts that got at least one token, summed over expert layers "
+     "and decode steps or prompt chunks"),
+)
 
 
 def register_metrics(registry: Any, config: ModelConfig, serving: Any,
                      tp: int) -> None:
-    """This family's own counter and gauges: slot recycling, and what
-    each kind of cache holds."""
+    """This family's own counters and gauges: slot recycling, what each
+    kind of cache holds, and what the expert layers were asked."""
     registry.inc(
         "serve_state_resets", 0,
         help="recycled slots whose recurrent state a new "
@@ -418,6 +613,75 @@ def register_metrics(registry: Any, config: ModelConfig, serving: Any,
                        tp=tp),
         help="bytes of paged K/V the cache holds (full-attention "
              "layers only)")
+    registry.set_gauge(
+        "serve_latent_bytes",
+        latent_cache_bytes(config, serving.max_batch, serving.max_seq),
+        help="bytes of paged latent rows the cache holds "
+             "(latent-attention layers only; whole lanes a row)")
+    if config.has_routed_experts:
+        for name, hlp in _MOE_COUNTERS:
+            registry.inc(name, 0, help=hlp)
+        registry.set_gauge(
+            "serve_moe_load_max", 0,
+            help="the fullest expert's tokens in one expert layer of one "
+                 "decode step or prompt chunk (largest seen)")
+
+
+def _moe_counted(registry: Any, config: ModelConfig,
+                 samples: dict[str, list], kind: str, counts: Any) -> None:
+    """Book the ``[steps, 3]`` counts of one decode unit or prompt chunk
+    (``kind``): counters, the gauge, and the report's samples."""
+    counts = np.asarray(counts).reshape(-1, 3)
+    samples["_moe_layer_runs"] = (samples.get("_moe_layer_runs", 0)
+                                  + len(counts) * config.expert_layers)
+    assigned, touched = int(counts[:, 0].sum()), int(counts[:, 1].sum())
+    fullest = int(counts[:, 2].max())
+    registry.inc("serve_moe_assignments", assigned)
+    registry.inc("serve_moe_experts_touched", touched)
+    samples["_load_max"] = max(fullest, samples.get("_load_max", 0))
+    registry.set_gauge("serve_moe_load_max", samples["_load_max"])
+    samples.setdefault(f"moe_{kind}_assignments", []).append(assigned)
+    samples.setdefault(f"moe_{kind}_touched", []).append(touched)
+    samples.setdefault(f"moe_{kind}_load_max", []).append(fullest)
+
+
+def unit_counted(registry: Any, config: ModelConfig,
+                 samples: dict[str, list], counts: Any) -> None:
+    """A decode unit is done and its ``counts`` (None without routed
+    experts) are on the host's side of the sync."""
+    if counts is not None:
+        _moe_counted(registry, config, samples, "unit", counts)
+
+
+def chunk_counted(registry: Any, config: ModelConfig,
+                  samples: dict[str, list], last: Any) -> None:
+    """A prompt chunk's ``last`` is ready."""
+    if isinstance(last, tuple):
+        _moe_counted(registry, config, samples, "chunk", last[-1])
+
+
+def report_shares(config: ModelConfig, samples: dict[str, list]
+                  ) -> dict[str, float]:
+    """What the report says of the expert layers over the whole run:
+    ``experts_touched_share`` (experts that got a token over experts
+    held, a layer and decode step or chunk) and
+    ``expert_load_max_over_mean`` (the fullest expert's tokens over the
+    mean of the experts that got any, largest single layer)."""
+    if not config.has_routed_experts:
+        return {}
+    touched = sum(samples.get("moe_unit_touched", ())) \
+        + sum(samples.get("moe_chunk_touched", ()))
+    assigned = sum(samples.get("moe_unit_assignments", ())) \
+        + sum(samples.get("moe_chunk_assignments", ()))
+    layers = samples.get("_moe_layer_runs", 0)
+    out = {}
+    if layers:
+        out["experts_touched_share"] = touched / (
+            layers * config.n_routed_experts)
+    if touched:
+        out["expert_load_max_over_mean"] = samples.get("_load_max", 0) / (
+            assigned / touched)
+    return out
 
 
 def slot_recycled(registry: Any, rid: int, slot: int) -> None:
@@ -441,12 +705,12 @@ def decode_programs(config: ModelConfig, mesh: Mesh, ks: tuple[int, ...],
     """The single step and the fused ladder ``{k: program}`` under the
     scheduler's signature ``(carry, params, active[, remaining]) ->
     (carry, ys)``: ``probe()`` gives the probed slots ``[PROBES]`` each
-    program takes as its last argument, and ``ys`` is the pair
-    ``(tokens, logits of the probed slots)``."""
+    program takes as its last argument, and ``ys`` is the triple
+    ``(tokens, seen of the probed slots, counts)``."""
     def bound(program):
         def call(carry, params, *masks):
-            carry, toks, seen = program(carry, params, *masks, probe())
-            return carry, (toks, seen)
+            carry, *ys = program(carry, params, *masks, probe())
+            return carry, tuple(ys)
         return call
 
     return (bound(build_decode_step(config, mesh)),

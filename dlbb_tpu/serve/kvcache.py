@@ -80,11 +80,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from dlbb_tpu.compat import shard_map
 from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
+    LATENT_ATTENTION,
     LINEAR_ATTENTION,
     ModelConfig,
     cache_kv_heads,
 )
 from dlbb_tpu.models.transformer import SERVE_PHASES, _dtype_of
+from dlbb_tpu.ops.latent_attention import latent_spec
 
 
 class KVCache(NamedTuple):
@@ -224,13 +226,19 @@ def copy_slot_blocks(plane: jax.Array, src: jax.Array, dst: jax.Array,
 
 
 # ---------------------------------------------------------------------------
-# two kinds of state in one cache (``ModelConfig.layer_types``)
+# several kinds of state in one cache (``ModelConfig.layer_types``)
 # ---------------------------------------------------------------------------
 
 
 class HybridCache(NamedTuple):
-    """The cache of a model whose layers are of two kinds.  The
-    ``full_attention`` layers keep paged K/V planes exactly as
+    """The cache of a ``layer_types`` model: every kind of layer keeps
+    its own planes, and a kind the model has no layer of keeps planes of
+    no layers (zero bytes).  The ``latent_attention`` layers keep ONE
+    paged plane ``latent``: per layer, slot and token one row ``[c',
+    rope(k_rope), zeros]`` (``ModelConfig.latent_row`` values, whole
+    lanes), in the same blocks of the same :class:`BlockLedger` as K/V
+    (a block is ``block_size`` tokens of a slot, whatever a token keeps).
+    The ``full_attention`` layers keep paged K/V planes exactly as
     :class:`KVCache` does, but only for themselves (``L_full`` of the
     layers).  The ``linear_attention`` layers keep, per layer and SLOT,
     a float32 recurrent state and the last ``conv_kernel - 1`` inputs of
@@ -250,6 +258,7 @@ class HybridCache(NamedTuple):
     v: jax.Array        # same
     state: jax.Array    # f32 [L_lin, max_batch, heads, d_v, d_k]
     conv: jax.Array     # [L_lin, max_batch, conv_kernel - 1, heads, 2 d_k + d_v]
+    latent: jax.Array   # [L_lat, max_batch, num_blocks, block_size, row]
     lengths: jax.Array  # [max_batch] int32
 
     @property
@@ -278,6 +287,7 @@ def hybrid_cache_specs(mesh: Optional[Mesh]) -> HybridCache:
     return HybridCache(k=kv.k, v=kv.v,
                        state=P(None, dp, tp, None, None),
                        conv=P(None, dp, None, tp, None),
+                       latent=latent_spec(mesh),
                        lengths=kv.lengths)
 
 
@@ -301,20 +311,48 @@ def create_hybrid_cache(config: ModelConfig, max_batch: int,
                 block_size, cache_kv_heads(config, tp), config.head_dim)
     state_shape = (n_lin, max_batch, heads, config.linear_value_head_dim,
                    config.linear_key_head_dim)
-    conv_shape = (n_lin, max_batch, config.linear_conv_kernel_dim - 1,
-                  heads, config.linear_conv_channels // heads)
+    conv_shape = (n_lin, max_batch,
+                  max(config.linear_conv_kernel_dim - 1, 0), heads,
+                  config.linear_conv_channels // max(heads, 1))
+    latent_shape = (config.layers_of(LATENT_ATTENTION), max_batch,
+                    num_blocks, block_size, config.latent_row)
 
     def build() -> HybridCache:
         return HybridCache(
             k=jnp.zeros(kv_shape, dtype), v=jnp.zeros(kv_shape, dtype),
             state=jnp.zeros(state_shape, state_dtype),
             conv=jnp.zeros(conv_shape, dtype),
+            latent=jnp.zeros(latent_shape, dtype),
             lengths=jnp.zeros((max_batch,), jnp.int32),
         )
 
     if mesh is None:
         return build()
     return jax.jit(build, out_shardings=hybrid_cache_shardings(mesh))()
+
+
+def append_latent_rows(plane: jax.Array, rows: jax.Array, layer: jax.Array,
+                       lengths: jax.Array, active: jax.Array,
+                       mesh: Mesh) -> jax.Array:
+    """The decode append of the latent plane: ``rows[b]`` ``[B, row]``
+    into ``plane`` ``[L, B, nb, bs, row]`` at ``(layer, b, p // bs, p %
+    bs)`` with ``p = lengths[b]``: :func:`append_token_rows` for one row
+    a token (an inactive slot's row, and a position past the slot's last
+    block, are dropped; each ``dp`` shard writes its own slots)."""
+    spec = hybrid_cache_specs(mesh).latent
+    dp = spec[1]
+
+    def write(plane, rows, layer, lengths, active):
+        b_dim, nb, bs = plane.shape[1:4]
+        blk = jnp.where(active, lengths // bs, nb)
+        return plane.at[layer, jnp.arange(b_dim), blk, lengths % bs].set(
+            rows.astype(plane.dtype), mode="drop", unique_indices=True)
+
+    return shard_map(
+        write, mesh=mesh,
+        in_specs=(spec, P(dp, None), P(), P(dp), P(dp)),
+        out_specs=spec,
+    )(plane, rows, layer, lengths, active)
 
 
 def write_slot_state(plane: jax.Array, value: jax.Array, layer: jax.Array,

@@ -23,13 +23,16 @@ SERVE = REPO / "dlbb_tpu" / "serve"
 SEAM_FUNCTIONS = (
     "check_serving", "register_metrics", "slot_recycled", "fresh_carry",
     "create_prefix", "prompt_input", "build_prefill_chunk",
-    "decode_programs", "inject_token",
+    "decode_programs", "inject_token", "attend_tiles", "report_shares",
 )
 SEAM_CONSTANTS = {"TOKENS_FED_BACK": bool, "PROBES": int, "LACKS": dict}
 # the scheduler's names for what a family may lack; what stands behind
 # each where the family has it
 CAPABILITIES = {
-    "probe": ("slot_state",),
+    # a probing family's decode programs return ``(tokens, seen,
+    # counts)``: it says how a chunk's ``last`` lays out as ``seen``, and
+    # books the counts of a unit and of a chunk
+    "probe": ("slot_state", "probe_parts", "unit_counted", "chunk_counted"),
     "monolithic_prefill": ("build_prefill", "build_prefix_attach"),
 }
 

@@ -700,7 +700,7 @@ def _hybrid_decode_step(mesh):
           cfg.linear_key_head_dim), jnp.float32),
         ((6, 32, cfg.linear_conv_kernel_dim - 1, heads,
           cfg.linear_conv_channels // heads), jnp.bfloat16),
-        ((32,), jnp.int32))
+        ((0, 32, 128, 16, 0), jnp.bfloat16), ((32,), jnp.int32))
     cache = HybridCache(*(
         jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
         for (shape, dtype), sh in zip(shapes, hybrid_cache_shardings(mesh))))
@@ -709,6 +709,64 @@ def _hybrid_decode_step(mesh):
     return (serve_hybrid.build_decode_step(cfg, mesh),
             ((cache, like((32,), jnp.int32)), params, like((32,), jnp.bool_),
              like((serve_hybrid.PROBES,), jnp.int32)), plane)
+
+
+def test_latent_decode_step_compiled_for_the_v5e_copies_no_plane_and_no_expert(
+        v5e_chip, as_on_the_chip):
+    """``serve_decode_step`` of the kanana-2 cell at its published widths
+    (its 64 slots and max_seq, one leading dense layer and two expert
+    layers: the layer loops' bodies do not depend on their number):
+    Mosaic takes the latent decode kernel and the three grouped expert
+    products of the loop's body, the carried latent plane is handed over
+    whole (no instruction but the in-place append produces a plane or a
+    layer of it), and no expert's weights are sliced out of their stack
+    in front of a kernel (``ops/routed_experts.py::grouped_products``)."""
+    from benchmarks.harness import cells
+    from dlbb_tpu.models import hybrid
+    from dlbb_tpu.serve import hybrid as serve_hybrid
+    from dlbb_tpu.serve.kvcache import create_hybrid_cache
+
+    mesh = v5e_chip
+    program = cells.resolve_cell("kanana_serve_longctx_backlog") \
+        .config["program"]
+    cfg = ModelConfig.from_dict(dict(program["model"], num_layers=3))
+    sv = ServingConfig.from_dict(program["serving"])
+    rep = NamedSharding(mesh, P())
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    params = shaped(jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.key(0))))
+    cache = shaped(jax.eval_shape(lambda: create_hybrid_cache(
+        cfg, sv.max_batch, sv.num_blocks, sv.block_size)))
+    like = lambda shape, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        shape, dtype, sharding=rep)
+    b = sv.max_batch
+    compiled = serve_hybrid.build_decode_step(cfg, mesh).trace(
+        (cache, like((b,), jnp.int32)), params, like((b,), jnp.bool_),
+        like((serve_hybrid.PROBES,), jnp.int32)).lower(
+        lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo)
+    assert sorted(c.split(".")[0] for c in calls) == [
+        "gmm", "gmm", "gmm", "latent_attend_decode", "latent_attend_decode"]
+    layers, _, nb, bs, row = cache.latent.shape
+    assert (layers, nb * bs, row) == (3, sv.max_seq, 640)
+    whole = {f"[{','.join(map(str, dims))}]" for dims in (
+        (layers, b, nb, bs, row), (b, nb, bs, row), (1, b, nb, bs, row),
+        (b, nb * bs, row), (128, 2048, 768), (128, 768, 2048),
+        (2, 128, 2048, 768), (2, 128, 768, 2048))}
+    left = {}
+    for line in hlo.splitlines():
+        m = _HLO_INSTRUCTION.match(line)
+        if (m and m.group(3) not in _PLANE_PLUMBING | {"scatter", "fusion"}
+                and re.sub(r"^[a-z0-9]+|\{.*$", "", m.group(2)) in whole):
+            left[m.group(1)] = m.group(3)
+    assert not left, f"ops of a whole plane's or expert stack's shape: {left}"
+    # ... and what the program holds beside its arguments is small
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
 @pytest.mark.parametrize("family", ["gpt", "hybrid"])
