@@ -105,17 +105,18 @@ OP_EXPECTED_KINDS: dict[str, dict] = {
 
 # Parallelism axis -> collective kinds that axis may introduce.
 #
-# tp additionally allows collective-permute: the fused-QKV kernel shards
-# its packed [H + 2*kv*d] output dim over tp, and the q/k/v (and
-# simplified-attention) slice boundaries do not align with the shard
-# boundaries, so GSPMD realigns with neighbour collective-permutes of
-# activation size (verified against the compiled HLO of the tiny TP
-# forward; an audit finding only if they exceed the activation-byte
-# ceiling).  The tripwire for TP mis-sharding remains all-gather: a
+# tp is the row-parallel psum and nothing else: the fused-QKV kernel's
+# columns are ordered by kv-head group (``models/transformer.py::
+# init_params``), so a tp shard of its packed [H + 2*kv*d] output holds
+# the q, k and v of its own heads and the split moves nothing between
+# chips (verified against the compiled HLO of the tiny TP forward and
+# the serving programs, ``tests/test_model.py``) — a collective-permute
+# under plain tp means the column order and ``split_qkv`` fell apart.
+# The other tripwire for TP mis-sharding remains all-gather: a
 # weight-sized gather means the Megatron layout collapsed to replication.
 AXIS_EXPECTED_KINDS: dict[str, set[str]] = {
     "dp": {"all-reduce", "reduce-scatter", "all-gather"},  # DDP / ZeRO
-    "tp": {"all-reduce", "collective-permute"},  # row psum + QKV realign
+    "tp": {"all-reduce"},                                   # row psum
     # tp with the overlapped collective-matmul schedule
     # (model.tp_overlap = ring|bidir): every projection's collective is a
     # ppermute chain; the ONLY legitimate all-gather is the single
@@ -151,10 +152,10 @@ def plan_expected_kinds(dp: int = 1, tp: int = 1, sp: int = 1, pp: int = 1,
     cache-append step, ``dlbb_tpu/serve/engine.py``): there is no
     gradient reduction, so dp — pure batch parallelism over the cache
     slots — contributes NOTHING, and the only legal collectives are tp's
-    tiny per-token row-parallel psums + QKV realignment permutes.  The
-    KV-cache itself must never reach the wire; the serving audit targets
-    pair this set with an activation-sized byte ceiling, so a cache
-    regather (slot-cache-sized all-gather) fails on BOTH axes."""
+    tiny per-token row-parallel psums.  The KV-cache itself must never
+    reach the wire; the serving audit targets pair this set with an
+    activation-sized byte ceiling, so a cache regather (slot-cache-sized
+    all-gather) fails on BOTH axes."""
     if decode:
         if sp > 1 or pp > 1 or ep > 1:
             raise ValueError(
@@ -507,10 +508,10 @@ def verify_step_expectation(dp: int, tp: int, gamma: int,
     decode step whose activations are (γ+1) wide, NOT like γ+1
     sequential decode steps.
 
-    Concretely: the kind set stays the per-token decode set (tp psums +
-    QKV realign permutes; the same single boundary all-gather artifact
-    the fused scan carries), ``min_required = 1`` — the row-parallel
-    psum fires once per scanned layer, with NO per-draft-token trip
+    Concretely: the kind set stays the per-token decode set (tp psums;
+    the same single boundary all-gather artifact the fused scan
+    carries), ``min_required = 1`` — the row-parallel psum fires once
+    per scanned layer, with NO per-draft-token trip
     weighting (a per-token re-verify loop would show up as a γ+1-trip
     while body, and its trip-weighted wire lands past the committed
     baseline's ``analyze diff`` gate) — and every instruction is capped

@@ -871,23 +871,23 @@ def _serve_build(dp: int, tp: int, what: str, k: int = 4):
 def _decode_step_target(dp: int = 2, tp: int = 4) -> AuditTarget:
     """The serving decode step (``serve/engine.py::decode_step``).  The
     contract is the serving-path comm story: ONLY tiny per-token tp
-    collectives (row-parallel psums of [max_batch, 1, H] + QKV realign
-    permutes) may exist — dp contributes nothing (no gradients) — and
-    the activation-sized byte ceiling is the proof that no step
-    re-gathers the KV-cache: even one slot's single-layer cache shard is
-    several times the ceiling, so a cache regather fails on both the
-    kind axis and the byte axis.  The cache carry must stay donated
-    (an undonated decode doubles cache HBM — fatal at real sizes)."""
+    collectives (row-parallel psums of [max_batch, 1, H]) may exist — dp
+    contributes nothing (no gradients) — and the activation-sized byte
+    ceiling is the proof that no step re-gathers the KV-cache: even one
+    slot's single-layer cache shard is several times the ceiling, so a
+    cache regather fails on both the kind axis and the byte axis.  The
+    cache carry must stay donated (an undonated decode doubles cache
+    HBM — fatal at real sizes)."""
     def build():
         return _serve_build(dp, tp, "decode")
 
     cfg_dict = _TINY_MODEL
-    # largest legitimate instruction: an all-reduce (or realign permute)
-    # of one decode step's activations — [max_batch, 1, qkv_width] f32
-    # bounds every projection collective.  One layer's k (or v) cache
-    # plane [max_batch, num_blocks, block_size, kvh, d] is ~8.5x this
-    # ceiling (a single slot's plane alone is ~2x), so any cache-sized
-    # transfer trips.
+    # largest legitimate instruction: an all-reduce of one decode step's
+    # activations — [max_batch, 1, qkv_width] f32 bounds every
+    # projection collective.  One layer's k (or v) cache plane
+    # [max_batch, num_blocks, block_size, kvh, d] is ~8.5x this ceiling
+    # (a single slot's plane alone is ~2x), so any cache-sized transfer
+    # trips.
     qkv_width = 3 * cfg_dict["hidden_size"]
     act_bytes = _SERVE_SHAPE["max_batch"] * qkv_width * 4
     cache_dev = _serve_cache_bytes_per_device(dp, tp)

@@ -8,6 +8,16 @@ output dim and the out-proj / FFN-down kernels on their input dim over the
 ``tp`` mesh axis, and XLA GSPMD inserts exactly the two per-layer
 all-reduces over ICI.
 
+What a ``tp`` shard of ``qkv`` holds: the kernel's columns lie by kv-head
+group (a group's query heads, its key head, its value head —
+``transformer.py::init_params``), and the shards are contiguous blocks of
+columns, so with ``tp`` dividing ``kv_heads`` each shard computes the q, k
+and v of ``kv_heads / tp`` whole groups: the heads the attention kernel's
+``shard_map`` (head axis over ``tp``) and the serving cache (kv-head axis
+over ``tp``) give that same shard.  Nothing moves between chips from the
+projection to the row-parallel sum.  Where ``tp`` does not divide
+``kv_heads`` a group straddles two shards and GSPMD realigns it.
+
 Layer params are stacked on a leading ``num_layers`` axis (scanned in the
 forward pass); that axis is ``None`` for pure TP and carries the ``pp``
 mesh axis under pipeline parallelism (each pipeline stage holds a

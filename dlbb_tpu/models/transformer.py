@@ -11,9 +11,9 @@ LN), re-designed for XLA:
 - parallelism comes from partition specs (see ``sharding.py``), not
   hand-written collectives;
 - layernorm statistics are computed in fp32 and cast back (bf16-safe);
-- ``attention="simplified"`` replicates the reference's take-the-query-third
-  shortcut (``models.py:162-167``); ``attention="full"`` is causal MHA with
-  fp32 softmax.
+- ``attention="simplified"`` replicates the reference's shortcut of taking
+  the query projection for the attention output (``models.py:162-167``);
+  ``attention="full"`` is causal MHA with fp32 softmax.
 
 No code is shared with the reference; citations are for parity auditing.
 """
@@ -67,6 +67,14 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
 
     Scaled-normal kernels (1/sqrt(fan_in)), zero biases, unit LN scales —
     standard init; the reference's randn-based init is at ``models.py:33-38``.
+
+    The columns of the fused ``qkv`` kernel ``[L, H, qkv_width]`` and bias
+    ``[L, qkv_width]`` are ordered by kv-head group, each head ``head_dim``
+    wide: for group ``j`` of ``kv_heads``, its ``num_heads / kv_heads``
+    query heads (heads ``j * g .. j * g + g - 1``), then key head ``j``,
+    then value head ``j`` (``split_qkv`` is the one reader).  A contiguous
+    ``tp`` shard of the columns therefore holds whole groups whenever
+    ``tp`` divides ``kv_heads``, and the layout does not depend on ``tp``.
     """
     if config.is_hybrid:
         from dlbb_tpu.models import hybrid
@@ -111,7 +119,7 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
         "ln1": {"scale": jnp.ones((L, h), dtype), "bias": jnp.zeros((L, h), dtype)},
         "qkv": {
             # qkv_width = H + 2 * kv_heads * head_dim (GQA shrinks the
-            # K/V thirds; == 3H for full MHA)
+            # K/V share; == 3H for full MHA); columns by kv-head group
             "kernel": kernel(ks[0], (L, h, config.qkv_width), h),
             "bias": jnp.zeros((L, config.qkv_width), dtype),
         },
@@ -136,24 +144,48 @@ def _layernorm(x, scale, bias):
     return (y * scale.astype(jnp.float32) + bias.astype(jnp.float32)).astype(x.dtype)
 
 
+def split_qkv(qkv, config: ModelConfig):
+    """The fused projection's output by head, the heads in front of the
+    tokens as the attention kernels take them: ``[..., S, qkv_width]``
+    -> q ``[..., num_heads, S, d]``, k and v ``[..., kv_heads, S, d]``.
+
+    The one reader of the column order ``init_params`` documents.  The
+    group axis of the reshape inherits a ``tp`` sharding of the columns,
+    so every shard takes its own heads' q, k and v from what it computed
+    and nothing is realigned between chips.
+
+    How it is written is for XLA:TPU (``PERF.md`` §6, PR 32).  The
+    barrier keeps the split out of the projection: without it the
+    reshape is folded into the matmul, whose kernel is then re-laid out
+    to suit, a kernel-sized copy a layer in every serving program
+    (``tests/test_serve_fastpath.py`` compiles them for the v5e and
+    holds that no such copy is there).  And the activation is moved
+    once, a kv-head group at a time, before the heads come off its last
+    axis at multiples of ``d``: split first and moved after, the 1B
+    training step is 0.7% slower than with q, k and v in thirds, and
+    indexed on a ``[..., kv_heads, g + 2, d]`` view 3.3%."""
+    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
+    g = n // kvh
+    *lead, s, _ = qkv.shape
+    qkv = jax.lax.optimization_barrier(qkv)
+    grouped = jnp.swapaxes(
+        qkv.reshape(*lead, s, kvh, (g + 2) * d), -2, -3)
+    q = jnp.swapaxes(
+        grouped[..., :g * d].reshape(*lead, kvh, s, g, d), -2, -3)
+    return (q.reshape(*lead, n, s, d), grouped[..., g * d:(g + 1) * d],
+            grouped[..., (g + 1) * d:])
+
+
 def _attention(qkv, config: ModelConfig, mesh=None, sp_axis: str = "sp"):
     """qkv: [B, S, qkv_width] -> [B, S, H]."""
+    b, s, _ = qkv.shape
+    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
+    q, k, v = split_qkv(qkv, config)  # [B, heads, S, d]
     if config.attention == "simplified":
         # reference's benchmarking shortcut: the query projection IS the
         # attention output (``models.py:162-167``)
-        return qkv[:, :, : config.hidden_size]
+        return q.transpose(0, 2, 1, 3).reshape(b, s, n * d)
 
-    b, s, _ = qkv.shape
-    n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
-    h = config.hidden_size
-    q = qkv[:, :, :h]
-    k = qkv[:, :, h:h + kvh * d]
-    v = qkv[:, :, h + kvh * d:]
-
-    def heads(t, nh):  # [B, S, nh*d] -> [B, nh, S, d]
-        return t.reshape(b, s, nh, d).transpose(0, 2, 1, 3)
-
-    q, k, v = heads(q, n), heads(k, kvh), heads(v, kvh)
     # Grouped K/V flow at kv_heads width end-to-end through every kernel
     # (dense einsum broadcasting; grouped flash blocks; grouped ring/
     # Ulysses).  The only broadcasts left are sharding fallbacks where a

@@ -51,6 +51,7 @@ from dlbb_tpu.models.transformer import (
     _dtype_of,
     _layernorm,
     named,
+    split_qkv,
 )
 from dlbb_tpu.ops.decode_attention import (
     check_kernel_takes,
@@ -83,9 +84,11 @@ from dlbb_tpu.serve.traffic import Request
 
 
 def _split_qkv(qkv: jax.Array, config: ModelConfig):
-    """[..., qkv_width] -> q [..., H], k/v [..., kv_heads * head_dim]."""
-    h, kvd = config.hidden_size, config.kv_heads * config.head_dim
-    return qkv[..., :h], qkv[..., h:h + kvd], qkv[..., h + kvd:]
+    """[..., S, qkv_width] -> q [..., S, H], k/v [..., S, kv_heads *
+    head_dim], heads in order, from the grouped columns ``split_qkv``
+    reads."""
+    return tuple(jnp.swapaxes(t, -2, -3).reshape(*qkv.shape[:-1], -1)
+                 for t in split_qkv(qkv, config))
 
 
 def _serve_block(h, layer, config: ModelConfig, attention_step,

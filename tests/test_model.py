@@ -153,7 +153,7 @@ def test_causal_masking():
 
 
 def test_simplified_attention_is_query_slice():
-    """Simplified mode takes the first third of QKV (reference
+    """Simplified mode takes the query projection (reference
     ``models.py:162-167``), so outputs differ from full attention."""
     params = init_params(TINY, jax.random.key(1))
     x = _batch(TINY)
@@ -245,6 +245,214 @@ def test_forward_flops_accounting():
     moe = TINY.with_(num_experts=4, moe_top_k=2)
     cap = moe.with_(moe_dispatch="capacity", moe_capacity_factor=1.0)
     assert forward_flops(cap, b, s) < forward_flops(moe, b, s)
+
+
+# ---------------------------------------------------------------------------
+# the fused qkv projection's column order
+# ---------------------------------------------------------------------------
+#
+# ``init_params`` states the order: by kv-head group, a group's query
+# heads, then its key head, then its value head.  These tests build the
+# three projections apart, pack them as that sentence says, and compare
+# with attention written out here from the three matrices — nothing
+# below calls the package's own split.
+
+# name -> (num_heads, kv_heads) at hidden 64
+LAYOUTS = {"mha": (4, 4), "gqa": (8, 2), "mqa": (4, 1)}
+
+
+def _layout_cfg(heads, attention="dense"):
+    n, kvh = heads
+    return ModelConfig(hidden_size=64, num_layers=2, num_heads=n,
+                       num_kv_heads=kvh, ffn_intermediate=128,
+                       attention=attention, dtype="float32")
+
+
+def _separate_projections(cfg, seed=5):
+    """Per layer: W_q [h, n, d], W_k, W_v [h, kvh, d] and their biases
+    (non-zero, so a bias left in another order shows)."""
+    rng = np.random.default_rng(seed)
+    L, h, d = cfg.num_layers, cfg.hidden_size, cfg.head_dim
+    out = {}
+    for name, heads in (("q", cfg.num_heads), ("k", cfg.kv_heads),
+                        ("v", cfg.kv_heads)):
+        out["w" + name] = (rng.standard_normal((L, h, heads, d))
+                           / np.sqrt(h)).astype(np.float32)
+        out["b" + name] = (0.1 * rng.standard_normal((L, heads, d))
+                           ).astype(np.float32)
+    return out
+
+
+def _packed_params(cfg, sep, seed=1):
+    """``init_params``' tree with the fused qkv kernel and bias packed
+    from the separate projections, group by group."""
+    g = cfg.num_heads // cfg.kv_heads
+    kernel, bias = [], []
+    for j in range(cfg.kv_heads):
+        for i in range(j * g, (j + 1) * g):          # the group's queries
+            kernel.append(sep["wq"][:, :, i])
+            bias.append(sep["bq"][:, i])
+        kernel.append(sep["wk"][:, :, j])             # its key head
+        bias.append(sep["bk"][:, j])
+        kernel.append(sep["wv"][:, :, j])             # its value head
+        bias.append(sep["bv"][:, j])
+    params = init_params(cfg, jax.random.key(seed))
+    qkv = params["layers"]["qkv"]
+    packed = {"kernel": jnp.asarray(np.concatenate(kernel, axis=-1)),
+              "bias": jnp.asarray(np.concatenate(bias, axis=-1))}
+    assert packed["kernel"].shape == qkv["kernel"].shape
+    assert packed["bias"].shape == qkv["bias"].shape
+    params["layers"]["qkv"] = packed
+    return params
+
+
+def _written_out_forward(params, sep, x, cfg):
+    """The model in numpy float64 from W_q, W_k, W_v: one head at a
+    time, query head i with kv head i // (n / kvh), causal."""
+    def ln(t, p, l=None):
+        scale, bias = (np.asarray(p[k], np.float64) for k in
+                       ("scale", "bias"))
+        if l is not None:
+            scale, bias = scale[l], bias[l]
+        mu = t.mean(-1, keepdims=True)
+        var = t.var(-1, keepdims=True)
+        return (t - mu) / np.sqrt(var + 1e-5) * scale + bias
+
+    def dense(t, p, l):
+        return (t @ np.asarray(p["kernel"][l], np.float64)
+                + np.asarray(p["bias"][l], np.float64))
+
+    n, d, g = cfg.num_heads, cfg.head_dim, cfg.num_heads // cfg.kv_heads
+    layers = params["layers"]
+    x = np.asarray(x, np.float64)
+    s = x.shape[1]
+    mask = np.tril(np.ones((s, s), bool))
+    for l in range(cfg.num_layers):
+        y = ln(x, layers["ln1"], l)
+        heads = []
+        for i in range(n):
+            q = y @ sep["wq"][l, :, i] + sep["bq"][l, i]
+            if cfg.attention == "simplified":
+                heads.append(q)
+                continue
+            k = y @ sep["wk"][l, :, i // g] + sep["bk"][l, i // g]
+            v = y @ sep["wv"][l, :, i // g] + sep["bv"][l, i // g]
+            scores = q @ k.transpose(0, 2, 1) / np.sqrt(d)
+            scores = np.where(mask, scores, -np.inf)
+            w = np.exp(scores - scores.max(-1, keepdims=True))
+            heads.append(w / w.sum(-1, keepdims=True) @ v)
+        x = dense(np.concatenate(heads, axis=-1), layers["out"], l) + x
+        y = np.asarray(jax.nn.gelu(jnp.asarray(
+            dense(ln(x, layers["ln2"], l), layers["ffn_up"], l),
+            jnp.float32)), np.float64)
+        x = dense(y, layers["ffn_down"], l) + x
+    return ln(x, params["ln_f"])
+
+
+@pytest.mark.parametrize("attention", ["dense", "simplified"])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_qkv_columns_by_group_forward(layout, attention):
+    """``forward`` on the packed tree is the written-out model;
+    ``simplified`` hands on ``ln1(x) @ W_q + b_q`` with the query heads
+    in order, whatever lies between them in the fused kernel."""
+    cfg = _layout_cfg(LAYOUTS[layout], attention)
+    sep = _separate_projections(cfg)
+    params = _packed_params(cfg, sep)
+    x = _batch(cfg)
+    want = _written_out_forward(params, sep, x, cfg)
+    np.testing.assert_allclose(np.asarray(forward(params, x, cfg)), want,
+                               rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layout", ["mha", "gqa"])
+def test_qkv_columns_by_group_serving(layout):
+    """The serving programs read the same parameter tree: a prompt
+    prefilled in chunks, then decoded a token at a time, gives the
+    written-out model's output at every position it produces."""
+    from dlbb_tpu.comm.mesh import build_parallelism_mesh
+    from dlbb_tpu.serve.gpt import (
+        build_decode_step,
+        build_prefill_chunk,
+        create_prefix,
+        inject_token,
+    )
+    from dlbb_tpu.serve.kvcache import create_kv_cache
+
+    cfg = _layout_cfg(LAYOUTS[layout])
+    sep = _separate_projections(cfg)
+    mesh = build_parallelism_mesh(tensor_parallel=2,
+                                  devices=jax.devices()[:2])
+    params = shard_params(_packed_params(cfg, sep), mesh)
+    seq, prompt, chunk, slot, max_batch = 24, 13, 8, 2, 4
+    x = _batch(cfg, b=1, s=seq, seed=3)
+    want = _written_out_forward(params, sep, x, cfg)[0]
+
+    cache = create_kv_cache(cfg, max_batch, 4, 8, mesh=mesh)
+    prefix = create_prefix(cfg, mesh)
+    n_chunks = -(-prompt // chunk)
+    xp = jnp.zeros((1, n_chunks * chunk, cfg.hidden_size),
+                   jnp.float32).at[:, :prompt].set(x[:, :prompt])
+    for ci in range(n_chunks):
+        cache, prefix, y_last = build_prefill_chunk(
+            cfg, mesh, chunk, ci * chunk)(
+                cache, prefix, params, xp[:, ci * chunk:(ci + 1) * chunk],
+                np.int32(slot), np.int32(prompt))
+    np.testing.assert_allclose(np.asarray(y_last), want[prompt - 1],
+                               rtol=2e-5, atol=2e-5)
+
+    decode = build_decode_step(cfg, mesh)
+    active = jnp.asarray(np.arange(max_batch) == slot)
+    carry = (cache, jnp.zeros((max_batch, 1, cfg.hidden_size), jnp.float32))
+    for i in range(prompt, seq):
+        carry = inject_token(carry, np.int32(slot), x[0, i])
+        carry, y = decode(carry, params, active)
+        np.testing.assert_allclose(np.asarray(y[slot, 0]), want[i],
+                                   rtol=2e-5, atol=2e-5)
+
+
+# name -> (num_heads, kv_heads): whole groups on a shard when tp=4
+# divides kv_heads; "gqa_split" has fewer kv heads than shards, where
+# GSPMD realigns and the answer must still be the same
+TP_LAYOUTS = {"mha": (4, 4), "gqa": (8, 4), "gqa_split": (8, 2)}
+
+
+@pytest.mark.parametrize("attention", ["dense", "flash"])
+@pytest.mark.parametrize("layout", list(TP_LAYOUTS))
+def test_tp_forward_equals_one_device_f32(mesh2x4, layout, attention):
+    cfg = _layout_cfg(TP_LAYOUTS[layout], attention)
+    params = init_params(cfg, jax.random.key(1))
+    params["layers"]["qkv"]["bias"] = 0.1 * jax.random.normal(
+        jax.random.key(2), params["layers"]["qkv"]["bias"].shape)
+    x = _batch(cfg, b=4)
+    y_one = forward(params, x, cfg)
+    y_tp = jax.jit(lambda p, a: forward(p, a, cfg, mesh=mesh2x4))(
+        shard_params(params, mesh2x4),
+        jax.device_put(x, NamedSharding(mesh2x4, batch_spec())))
+    np.testing.assert_allclose(np.asarray(y_tp), np.asarray(y_one),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("builder", [
+    "_tp_forward_target", "_decode_step_target", "_prefill_chunk_target",
+    "_prefill_target", "_decode_fused_target", "_verify_step_target",
+    "_decode_quant_target"])
+def test_tp_programs_hold_no_collective_permute(devices, builder):
+    """The audit's own tiny ``dp x tp`` programs, compiled: the two
+    row-parallel all-reduces of the layer body (as many as before the
+    columns lay by group) and not one collective-permute.  A permute
+    here means the column order and its reader fell apart, and on the
+    chip it was 14% of the 13B forward's step (``PERF.md`` §6, PR 32)."""
+    from collections import Counter
+
+    from dlbb_tpu.analysis import hlo_audit
+    from dlbb_tpu.analysis.hlo_parse import parse_collectives
+
+    fn, args = getattr(hlo_audit, builder)().build()
+    jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
+    kinds = Counter(c.kind for c in parse_collectives(
+        jitted.lower(*args).compile().as_text()))
+    assert kinds["collective-permute"] == 0, kinds
+    assert kinds["all-reduce"] == 2, kinds
 
 
 def test_tp_forward_compiles_megatron_allreduce_pattern(devices):

@@ -216,9 +216,9 @@ def test_plan_expected_kinds_decode():
 
     # dp is pure batch parallelism at inference: no collectives at all
     assert plan_expected_kinds(dp=8, decode=True) == set()
-    # tp keeps its tiny per-token set; nothing gradient-shaped sneaks in
-    assert plan_expected_kinds(dp=2, tp=4, decode=True) == {
-        "all-reduce", "collective-permute"}
+    # tp keeps its per-token row psum; nothing gradient-shaped sneaks in,
+    # and no permute realigns q, k and v (the qkv columns lie by group)
+    assert plan_expected_kinds(dp=2, tp=4, decode=True) == {"all-reduce"}
     with pytest.raises(ValueError, match="dp, tp"):
         plan_expected_kinds(sp=2, decode=True)
 

@@ -526,6 +526,20 @@ _PLANE_PLUMBING = {"parameter", "get-tuple-element", "tuple", "while",
 _PLANE_UPDATES = {"scatter", "dynamic-update-slice"}
 
 
+def _hlo_instructions(hlo: str):
+    """``(computation, line, name, result type, op)`` of every
+    instruction of an optimised HLO module."""
+    computation = ""
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
+        if head:
+            computation = head.group(1)
+            continue
+        m = _HLO_INSTRUCTION.match(line)
+        if m:
+            yield (computation, line, *m.groups())
+
+
 def _plane_producers(hlo: str, plane: tuple, dtype: str) -> dict:
     """``{instruction name: op}`` of every instruction of an optimised
     HLO module whose result holds one whole (per-device) cache plane,
@@ -540,16 +554,8 @@ def _plane_producers(hlo: str, plane: tuple, dtype: str) -> dict:
     layers = {f"[{','.join(map(str, dims))}]"
               for dims in ((b, nb, bs, kvh, d), (1, b, nb, bs, kvh, d),
                            (b, nb * bs, kvh, d), (1, b, nb * bs, kvh, d))}
-    roots, found, computation = {}, {}, ""
-    for line in hlo.splitlines():
-        head = re.match(r"^(?:ENTRY )?%([\w.\-]+) \(.*\{\s*$", line)
-        if head:
-            computation = head.group(1)
-            continue
-        m = _HLO_INSTRUCTION.match(line)
-        if not m:
-            continue
-        name, result, op = m.groups()
+    roots, found = {}, {}
+    for computation, line, name, result, op in _hlo_instructions(hlo):
         if line.lstrip().startswith("ROOT "):
             roots[computation] = op
         shape = re.sub(r"^[a-z0-9]+|\{.*$", "", result)
@@ -675,6 +681,32 @@ def test_cache_writes_are_in_place_compiled_for_the_v5e(v5e_chip, program,
     hlo = jitted.trace(*args).lower(
         lowering_platforms=("tpu",)).compile().as_text()
     _assert_writes_in_place(hlo, plane, "bf16")
+
+
+@pytest.mark.parametrize("program", CACHE_WRITERS)
+def test_qkv_kernel_is_read_where_it_lies_compiled_for_the_v5e(
+        v5e_chip, program, as_on_the_chip):
+    """The fused projection reads its layer's kernel out of the stack,
+    inside the matmul's fusion: no instruction of the layer loop writes
+    a kernel-sized buffer.  Without the barrier in ``split_qkv`` the
+    compiler folds the split's reshape into the matmul and re-lays the
+    kernel out for it, a 100 MB copy a layer in every decode step and
+    chunk: ``serve7b_backlog`` read 328 tokens/s for 474 (``PERF.md``
+    §6, PR 32)."""
+    mesh = v5e_chip
+    cfg, params, cache, x, _ = _gpt7b_decode_shapes(mesh)
+    jitted, args = _cache_writing_program(program, cfg, mesh, cache,
+                                          params, x, chunk=128)
+    hlo = jitted.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+    kernel = f"{cfg.hidden_size},{cfg.qkv_width}]"
+    written = {
+        name: f"{op} {result}"
+        for computation, _, name, result, op in _hlo_instructions(hlo)
+        if "fused_computation" not in computation
+        and op not in _PLANE_PLUMBING
+        and re.sub(r"\{.*$", "", result).endswith(kernel)}
+    assert not written, written
 
 
 def _hybrid_decode_step(mesh):
