@@ -128,8 +128,9 @@ class ModelConfig:
     linear_conv_kernel_dim: int = 0
     linear_allow_neg_eigval: bool = False
     # where a sub-layer's RMSNorm sits: "post" (OLMo 2/3: on the
-    # sub-layer's OUTPUT, ``h = x + norm(f(x))``) or "pre" (on its INPUT,
-    # ``h = x + f(norm(x))``)
+    # sub-layer's OUTPUT, ``h = x + norm(f(x))``), "pre" (on its INPUT,
+    # ``h = x + f(norm(x))``) or "sandwich" (one on each, four scales a
+    # layer: ``h = x + norm(f(norm(x)))``)
     norm_placement: str = "post"
     # the latent-attention (MLA) layers' sizes and rotary base, under
     # the published config's own keys.  ``q_lora_rank`` is not here: the
@@ -138,7 +139,19 @@ class ModelConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # rotary base of the latent-attention layers (adjacent pairs of the
+    # rotary key) and, where > 0, of the full-attention layers (the
+    # whole head dim, half-split pairs ``(i, i + d/2)``); 0 = the
+    # full-attention layers take no rotary positions (Olmo-Hybrid)
     rope_theta: float = 0.0
+    # the looped stack: the SAME ``num_layers`` layers and final norm run
+    # ``total_ut_steps`` times a token, each pass over K/V planes of its
+    # own, with a one-output exit gate after every pass; a token leaves
+    # at the first pass whose cumulative exit probability reaches
+    # ``early_exit_threshold`` (1 = every token runs every pass).  Both
+    # under the published config's own keys.  1 = a plain stack, no gate
+    total_ut_steps: int = 1
+    early_exit_threshold: float = 1.0
     # routed experts of the ``layer_types`` family (``ops/routed_experts
     # .py``): the first ``first_k_dense_replace`` layers keep the dense
     # SwiGLU of ``ffn_intermediate``; every later layer has
@@ -213,7 +226,7 @@ class ModelConfig:
     def _validate_family(self) -> None:
         gpt = (self.norm == "layernorm" and self.mlp == "gelu" and self.bias
                and not self.qk_norm and self.vocab_size == 0
-               and self.layer_types is None)
+               and self.layer_types is None and self.total_ut_steps == 1)
         if gpt:
             return
         kinds = set(self.layer_types or ())
@@ -223,24 +236,32 @@ class ModelConfig:
             raise ValueError(
                 f"layer_types must be a non-empty pattern of {LAYER_KINDS}, "
                 f"got {self.layer_types}")
-        # QK-norm belongs to the full-attention layers (OLMo 2/3); a
-        # stack of latent-attention layers norms its latent instead
+        # QK-norm belongs to the full-attention layers (OLMo 2/3), which
+        # take it, rotary positions, or both; a stack of latent-attention
+        # layers norms its latent instead
+        positioned = self.qk_norm or self.rope_theta > 0
         hybrid = (self.norm == "rmsnorm" and self.mlp == "swiglu"
                   and not self.bias and self.vocab_size > 0
                   and self.layer_types is not None
-                  and self.qk_norm == (FULL_ATTENTION in kinds))
+                  and (positioned if FULL_ATTENTION in kinds
+                       else not self.qk_norm))
         if not hybrid:
             raise ValueError(
                 "model family not implemented: the program runs the GPT "
                 "block (norm='layernorm', mlp='gelu', bias=true, "
-                "qk_norm=false, no vocab_size, no layer_types) or the "
-                "hybrid block (norm='rmsnorm', mlp='swiglu', bias=false, "
-                "qk_norm=true, vocab_size > 0, layer_types given; "
-                "qk_norm=false for a stack without full_attention "
-                "layers); got "
+                "qk_norm=false, no vocab_size, no layer_types, "
+                "total_ut_steps=1) or the layer_types block "
+                "(norm='rmsnorm', mlp='swiglu', bias=false, vocab_size > "
+                "0, layer_types given, norm_placement 'post', 'pre' or "
+                "'sandwich', total_ut_steps >= 1; its full_attention "
+                "layers take qk_norm=true, rotary positions by rope_theta "
+                "> 0, or both; qk_norm=false for a stack without "
+                "full_attention layers); got "
                 f"norm={self.norm!r}, mlp={self.mlp!r}, bias={self.bias}, "
-                f"qk_norm={self.qk_norm}, vocab_size={self.vocab_size}, "
-                f"layer_types={self.layer_types}")
+                f"qk_norm={self.qk_norm}, rope_theta={self.rope_theta}, "
+                f"vocab_size={self.vocab_size}, "
+                f"layer_types={self.layer_types}, "
+                f"total_ut_steps={self.total_ut_steps}")
         period = len(self.layer_types)
         lead = self.first_k_dense_replace
         if lead < 0 or lead % period or (self.num_layers - lead) % period \
@@ -250,10 +271,30 @@ class ModelConfig:
                 f"periods of {period} layers (layer_types="
                 f"{self.layer_types}) after first_k_dense_replace={lead} "
                 "leading layers (themselves whole periods)")
-        if self.norm_placement not in ("post", "pre"):
+        if self.norm_placement not in ("post", "pre", "sandwich"):
             raise ValueError(
                 f"unknown norm_placement {self.norm_placement!r} "
-                "(expected 'post' or 'pre')")
+                "(expected 'post', 'pre' or 'sandwich')")
+        if self.rope_theta > 0 and FULL_ATTENTION in kinds \
+                and self.head_dim % 2:
+            raise ValueError(
+                f"rotary positions need an even head_dim, got "
+                f"{self.head_dim}")
+        if self.total_ut_steps < 1 or not 0 < self.early_exit_threshold <= 1:
+            raise ValueError(
+                "the looped stack needs total_ut_steps >= 1 and 0 < "
+                f"early_exit_threshold <= 1, got {self.total_ut_steps} and "
+                f"{self.early_exit_threshold}")
+        if self.total_ut_steps > 1 and (kinds != {FULL_ATTENTION}
+                                        or self.n_routed_experts):
+            raise ValueError(
+                f"total_ut_steps={self.total_ut_steps} is implemented for "
+                "a stack of full_attention layers with a dense MLP: only "
+                "the K/V planes are laid out per (pass, layer); a "
+                "recurrent state or a latent plane per pass, and the "
+                "routing of every pass among what the probes return, are "
+                f"not (layer_types={self.layer_types}, n_routed_experts="
+                f"{self.n_routed_experts})")
         if LATENT_ATTENTION in kinds:
             sizes = (self.kv_lora_rank, self.qk_nope_head_dim,
                      self.qk_rope_head_dim, self.v_head_dim)
@@ -315,6 +356,12 @@ class ModelConfig:
             return self.num_layers if kind == FULL_ATTENTION else 0
         periods = self.num_layers // len(self.layer_types)
         return periods * self.layer_types.count(kind)
+
+    @property
+    def kv_planes(self) -> int:
+        """K (and V) planes a cache holds: one for every (pass,
+        full-attention layer), pass-major (``pass x L_full + l``)."""
+        return self.total_ut_steps * self.layers_of(FULL_ATTENTION)
 
     @property
     def has_routed_experts(self) -> bool:
@@ -547,10 +594,11 @@ def kv_cache_bytes(config: ModelConfig, max_batch: int,
                    max_seq: int, kv_quantization: str = "none",
                    block_size: Optional[int] = None, tp: int = 1) -> int:
     """Total (unsharded) KV-cache footprint of a serving config: K + V,
-    every layer, every slot, ``max_seq`` tokens at GQA ``kv_heads``
-    width, in the model dtype (or the int8 + fp32-scale layout when
-    quantized)."""
-    return kv_cache_bytes_raw(config.layers_of(FULL_ATTENTION), max_batch,
+    every layer (of a looped stack: in every pass,
+    ``ModelConfig.kv_planes``), every slot, ``max_seq`` tokens at GQA
+    ``kv_heads`` width, in the model dtype (or the int8 + fp32-scale
+    layout when quantized)."""
+    return kv_cache_bytes_raw(config.kv_planes, max_batch,
                               max_seq, cache_kv_heads(config, tp),
                               config.head_dim,
                               config.dtype,
@@ -751,7 +799,10 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             raise ValueError(
                 f"serving KV-cache footprint {per_device / 2**30:.2f} GiB "
                 f"per device (max_batch={max_batch} x max_seq={max_seq} "
-                f"x {config.layers_of(FULL_ATTENTION)} layers x kv_heads="
+                f"x {config.layers_of(FULL_ATTENTION)} layers"
+                + (f" x {config.total_ut_steps} passes"
+                   if config.total_ut_steps > 1 else "")
+                + f" x kv_heads="
                 f"{config.kv_heads} x head_dim={config.head_dim} x 2 "
                 "(K+V), "
                 + (f"int8 + fp32 scales per {block_size}-token block"

@@ -1,20 +1,30 @@
-"""The ``layer_types`` block family: Olmo-Hybrid, and (PR 31) the
-``deepseek_v3`` block of kanana-2-30b-a3b.
+"""The ``layer_types`` block family: Olmo-Hybrid, (PR 31) the
+``deepseek_v3`` block of kanana-2-30b-a3b, and (PR 33) the looped stack
+of Ouro.
 
 A stack that repeats one PERIOD of layers, each ``linear_attention``
 (the gated delta rule of ``ops/gated_delta.py`` behind a short causal
 convolution), ``full_attention`` (causal multi-head attention with
-QK-norm, no rotary embedding) or ``latent_attention`` (MLA: per token
+QK-norm, with rotary positions on half-split pairs where ``rope_theta``
+is given, or with both) or ``latent_attention`` (MLA: per token
 one normed low-rank latent and one rotary key shared by all heads, from
 which each head's keys and values are expanded, or into which its
 queries are absorbed), every one followed by an MLP: a SwiGLU, or after
 the ``first_k_dense_replace`` leading layers the routed and shared
 experts of ``ops/routed_experts.py``.  The RMSNorms sit where
 ``norm_placement`` says: on a sub-layer's OUTPUT (OLMo 2/3: ``h = x +
-norm(mixer(x))``, ``out = h + norm(mlp(h))``) or on its INPUT (``h = x +
-mixer(norm(x))``, ``out = h + mlp(norm(h))``).  No biases.  Token ids in,
-logits over ``vocab_size`` out: an embedding table, a final RMSNorm and
-an untied head.
+norm(mixer(x))``, ``out = h + norm(mlp(h))``), on its INPUT (``h = x +
+mixer(norm(x))``, ``out = h + mlp(norm(h))``) or on both (``sandwich``:
+``h = x + norm(mixer(norm(x)))``, four scales a layer).  No biases.
+Token ids in, logits over ``vocab_size`` out: an embedding table, a
+final RMSNorm and an untied head.
+
+A LOOPED stack (``total_ut_steps`` > 1, :func:`run_stack`) runs the same
+layers and the same final norm that many times a token, ``h_t =
+norm_f(stack(h_{t-1}))``, with an exit gate ``sigmoid(w . h_t + b)``
+after every pass; the K and V a layer computes in pass ``t`` lie in
+planes of their own (``pass x L_full + l``) and a later token's pass
+``t`` attends to them alone.  The head reads the last pass's ``h``.
 
 ONE definition of the block (:func:`hybrid_block`) and of the period
 (:func:`scan_periods`), used by :func:`forward` here (a whole sequence,
@@ -22,7 +32,8 @@ no cache) and by every serving program (``serve/hybrid.py``).  What
 differs between them is the *mixer*: an object with ``attention(q, k,
 v, l, state)`` and ``linear(qkv, log_alpha, beta, conv_w, l, state)``
 (and ``latent(q, c, k_rope, wkv_b, l, state)``) that owns everything
-that touches a cache or needs a position.  ``state`` is opaque to the
+that touches a cache, and says where its tokens lie (``positions()``,
+for the full-attention layers' rotary).  ``state`` is opaque to the
 block.  A mixer also says which tokens are real (``valid()``: the
 others take no expert's time) and is told what an expert layer chose
 (``routed``); ``collect()`` hands both kinds of a period's outputs on.
@@ -89,6 +100,10 @@ EMBED, LM_HEAD, LIN_PROJ, LIN_CONV, LIN_CORE, LIN_OUT = HYBRID_PHASES
 # ``ops/routed_experts.py::MOE_PHASES``.
 MLA_PHASES = ("rope", "mla_q", "mla_kv_a", "mla_kv_b")
 ROPE, MLA_Q, MLA_KV_A, MLA_KV_B = MLA_PHASES
+# ... and the looped stack's, once a PASS (not a layer): the final norm
+# between passes and the one-output exit gate
+LOOP_PHASES = ("loop_norm", "exit_gate")
+LOOP_NORM, EXIT_GATE = LOOP_PHASES
 
 # the recurrent state's precision, wherever it is kept or carried
 STATE_DTYPE = jnp.float32
@@ -100,7 +115,8 @@ STATE_DTYPE = jnp.float32
 # float32 whatever the model's dtype: ``A_log`` and ``dt_bias`` feed an
 # exponential of an exponential, ``router_bias`` decides near-ties
 _FLOAT32 = ("A_log", "dt_bias", "router_bias")
-_SCALES = ("ln1", "ln2", "q_norm", "k_norm", "o_norm", "kv_norm")
+_SCALES = ("ln1", "ln2", "ln1_out", "ln2_out", "q_norm", "k_norm",
+           "o_norm", "kv_norm")
 
 
 def _layer_shapes(config: ModelConfig, kind: str,
@@ -109,6 +125,10 @@ def _layer_shapes(config: ModelConfig, kind: str,
     whose MLP is dense, or the ``experts`` layer."""
     h, f = config.hidden_size, config.ffn_intermediate
     shapes: dict[str, tuple] = {"ln1": (h,), "ln2": (h,)}
+    if config.norm_placement == "sandwich":
+        # ``ln1``, ``ln2`` on the two sub-layers' inputs, these on their
+        # outputs
+        shapes.update(ln1_out=(h,), ln2_out=(h,))
     if experts:
         e, fe = config.n_routed_experts, config.moe_intermediate_size
         shapes.update(router=(h, e), router_bias=(e,),
@@ -123,7 +143,9 @@ def _layer_shapes(config: ModelConfig, kind: str,
     if kind == FULL_ATTENTION:
         n, d = config.num_heads, config.head_dim
         shapes.update(wq=(h, n, d), wk=(h, n, d), wv=(h, n, d),
-                      wo=(n, d, h), q_norm=(n, d), k_norm=(n, d))
+                      wo=(n, d, h))
+        if config.qk_norm:
+            shapes.update(q_norm=(n, d), k_norm=(n, d))
     elif kind == LATENT_ATTENTION:
         n, r = config.num_heads, config.kv_lora_rank
         dn, dr = config.qk_nope_head_dim, config.qk_rope_head_dim
@@ -151,7 +173,9 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     log-uniform in (0.001, 0.1), as Gated DeltaNet initialises them, so
     that random weights give decays spread over (0, 1); the router's
     selection bias uniform in +/-0.01 (a trained model's is a learned
-    buffer of that order)."""
+    buffer of that order); a looped stack's exit gate a scaled-normal
+    vector and a float32 bias uniform in +/-1 (unit-size ``h`` gives
+    ``w . h`` a deviation of 1, so the four gates spread over (0, 1))."""
     dtype = _dtype_of(config.dtype)
     lead = config.first_k_dense_replace // len(config.layer_types)
     periods = config.num_layers // len(config.layer_types) - lead
@@ -207,6 +231,11 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                                   len(config.layer_types))
         params["lead"] = tuple(layer(k, kind, lead, False) for k, kind
                                in zip(k_lead, config.layer_types))
+    if config.total_ut_steps > 1:
+        k_w, k_b = jax.random.split(jax.random.fold_in(key, 2))
+        params["exit_gate_w"] = normal(k_w, (h,), h)
+        params["exit_gate_b"] = jax.random.uniform(k_b, (), jnp.float32,
+                                                   -1.0, 1.0)
     return params
 
 
@@ -220,6 +249,7 @@ def param_specs(config: ModelConfig, mesh: Optional[Mesh],
     t = tp_axis if tp_axis in axes and mesh.shape[tp_axis] > 1 else None
     by_name = {
         "ln1": P(None, None), "ln2": P(None, None),
+        "ln1_out": P(None, None), "ln2_out": P(None, None),
         "mlp_gate": P(None, None, t), "mlp_up": P(None, None, t),
         "mlp_down": P(None, t, None),
         "wq": P(None, None, t, None), "wk": P(None, None, t, None),
@@ -255,6 +285,8 @@ def param_specs(config: ModelConfig, mesh: Optional[Mesh],
     }
     if config.first_k_dense_replace:
         specs["lead"] = stack(False)
+    if config.total_ut_steps > 1:
+        specs.update(exit_gate_w=P(None), exit_gate_b=P())
     return specs
 
 
@@ -278,8 +310,9 @@ def num_parameters(config: ModelConfig) -> int:
     periods = config.num_layers // len(config.layer_types) - lead
     layers = (lead * period(False)
               + periods * period(config.has_routed_experts))
+    gate = config.hidden_size + 1 if config.total_ut_steps > 1 else 0
     return (layers + 2 * config.vocab_size * config.hidden_size
-            + config.hidden_size)
+            + config.hidden_size + gate)
 
 
 # -- the block -----------------------------------------------------------------
@@ -330,22 +363,32 @@ def split_qkv_heads(qkv: jax.Array, config: ModelConfig
     return l2_normalise(q, dk ** -0.5), l2_normalise(k), v
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """Rotary position embedding on ADJACENT pairs ``(2i, 2i+1)`` of the
-    last axis (the published ``rope_interleave``: the source permutes
-    pairs to halves before a half-split rotation, which gives the same
-    scores): pair ``i`` of a token at position ``t`` turns by ``t x
-    theta^(-2i/d)``.  ``positions`` broadcasts against ``x.shape[:-1]``.
-    Float32 inside, ``x``'s dtype out."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         half_split: bool = False) -> jax.Array:
+    """Rotary position embedding over the last axis: pair ``i`` of a
+    token at position ``t`` turns by ``t x theta^(-2i/d)``.  The pairs
+    are ADJACENT values ``(2i, 2i+1)`` (the latent layers' published
+    ``rope_interleave``: the source permutes pairs to halves before a
+    half-split rotation, which gives the same scores), or with
+    ``half_split`` the values ``(i, i + d/2)`` (``rotate_half``: the
+    full-attention layers').  ``positions`` broadcasts against
+    ``x.shape[:-1]``.  Float32 inside, ``x``'s dtype out."""
     with jax.named_scope(ROPE):
         d = x.shape[-1]
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
         angle = positions.astype(jnp.float32)[..., None] * inv
         cos, sin = jnp.cos(angle), jnp.sin(angle)
-        pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (d // 2, 2))
-        a, b = pairs[..., 0], pairs[..., 1]
-        out = jnp.stack([a * cos - b * sin, a * sin + b * cos], axis=-1)
-        return out.reshape(x.shape).astype(x.dtype)
+        x32 = x.astype(jnp.float32)
+        if half_split:
+            a, b = x32[..., :d // 2], x32[..., d // 2:]
+            out = jnp.concatenate([a * cos - b * sin, a * sin + b * cos],
+                                  axis=-1)
+        else:
+            pairs = x32.reshape(x.shape[:-1] + (d // 2, 2))
+            a, b = pairs[..., 0], pairs[..., 1]
+            out = jnp.stack([a * cos - b * sin, a * sin + b * cos],
+                            axis=-1).reshape(x.shape)
+        return out.astype(x.dtype)
 
 
 def expand_latent(c: jax.Array, wkv_b: jax.Array, config: ModelConfig
@@ -399,18 +442,27 @@ def hybrid_block(h: jax.Array, layer: Params, kind: str,
     planes); ``experts`` says whether its MLP is the expert layer.
     Returns ``(h, state)``."""
     eps = config.rms_norm_eps
-    pre = config.norm_placement == "pre"
+    placement = config.norm_placement
+    # what a sub-layer is fed is of the weights' dtype whatever the
+    # residual stream's (a looped stack's is float32: ``run_stack``)
+    dtype = layer["ln1"].dtype
     x = h
-    if pre:
+    if placement != "post":
         with jax.named_scope(LN1):
-            x = rmsnorm(h, layer["ln1"], eps)
+            x = rmsnorm(h, layer["ln1"], eps).astype(dtype)
     if kind == FULL_ATTENTION:
         with jax.named_scope(ATTN_QKV):
-            q = _qk_norm(jnp.einsum("bsh,hnd->bsnd", x, layer["wq"]),
-                         layer["q_norm"], eps)
-            k = _qk_norm(jnp.einsum("bsh,hnd->bsnd", x, layer["wk"]),
-                         layer["k_norm"], eps)
+            q = jnp.einsum("bsh,hnd->bsnd", x, layer["wq"])
+            k = jnp.einsum("bsh,hnd->bsnd", x, layer["wk"])
             v = jnp.einsum("bsh,hnd->bsnd", x, layer["wv"])
+            if config.qk_norm:
+                q = _qk_norm(q, layer["q_norm"], eps)
+                k = _qk_norm(k, layer["k_norm"], eps)
+        if config.rope_theta > 0:
+            # before the cache sees the key: what is written is rotated
+            pos = mixer.positions()[..., None]
+            q = rope(q, pos, config.rope_theta, half_split=True)
+            k = rope(k, pos, config.rope_theta, half_split=True)
         with jax.named_scope(ATTN_CORE):
             attn, state = mixer.attention(q, k, v, l, state)
         with jax.named_scope(ATTN_OUT):
@@ -444,22 +496,30 @@ def hybrid_block(h: jax.Array, layer: Params, kind: str,
             y = jnp.einsum("bsnv,nvh->bsh", o, layer["lin_out"])
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
-    if pre:
+    if placement == "pre":
         h = h + y
         with jax.named_scope(LN2):
-            u = rmsnorm(h, layer["ln2"], eps)
+            u = rmsnorm(h, layer["ln2"], eps).astype(dtype)
         return h + _mlp(u, layer, config, mixer, experts), state
+    # a norm on each sub-layer's output; ``sandwich`` keeps the input's
+    # too, and both of a sub-layer lie under its one scope
+    out1, out2 = (("ln1_out", "ln2_out") if placement == "sandwich"
+                  else ("ln1", "ln2"))
     with jax.named_scope(LN1):
-        h = h + rmsnorm(y, layer["ln1"], eps)
-    y = _mlp(h, layer, config, mixer, experts)
+        h = h + rmsnorm(y, layer[out1], eps)
+    u = h
+    if placement == "sandwich":
+        with jax.named_scope(LN2):
+            u = rmsnorm(h, layer["ln2"], eps).astype(dtype)
+    y = _mlp(u, layer, config, mixer, experts)
     with jax.named_scope(LN2):
-        h = h + rmsnorm(y, layer["ln2"], eps)
+        h = h + rmsnorm(y, layer[out2], eps)
     return h, state
 
 
 def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
                  make_mixer: Any, state: Any, xs: Any = None,
-                 base: int = 0, experts: bool = False
+                 base: int = 0, experts: bool = False, passed: Any = None
                  ) -> tuple[jax.Array, Any, Any]:
     """``h`` through a stack of whole periods: a ``lax.scan`` over
     periods whose body runs the period's layers in order.  ``state``
@@ -468,7 +528,9 @@ def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
     of the loop's buffer (``serve/engine.py::_scan_layers`` says what
     the other way cost).  Layer ``i`` of period ``p`` is layer ``(base +
     p) * count + ordinal`` among the layers of its kind: ``base``
-    periods lie before this stack.
+    periods lie before this stack.  In pass ``passed`` of a looped stack
+    (:func:`run_stack`) its cache planes are those of that pass, ``passed
+    x L_kind`` further on.
 
     ``make_mixer(xs_p)`` builds the period's mixer from the period's
     slice of ``xs`` (further per-period inputs with a leading period
@@ -494,8 +556,10 @@ def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
             layer = layers[i]
             if whole[i]:
                 layer = {**layer, **whole[i], "stack_index": p - base}
-            h, state = hybrid_block(h, layer, kind, config, mixer,
-                                    p * count[kind] + ordinal[i], state,
+            l = p * count[kind] + ordinal[i]
+            if passed is not None:
+                l = passed * config.layers_of(kind) + l
+            h, state = hybrid_block(h, layer, kind, config, mixer, l, state,
                                     experts)
         return (h, p + 1, state), mixer.collect()
 
@@ -505,8 +569,8 @@ def scan_periods(h: jax.Array, periods: tuple, config: ModelConfig,
 
 
 def scan_stack(h: jax.Array, params: Params, config: ModelConfig,
-               make_mixer: Any, state: Any, xs: Any = None
-               ) -> tuple[jax.Array, Any, Any, Any]:
+               make_mixer: Any, state: Any, xs: Any = None,
+               passed: Any = None) -> tuple[jax.Array, Any, Any, Any]:
     """``h`` through the whole stack: the leading dense layers
     (``params["lead"]``, where there are any) and then the periods, one
     :func:`scan_periods` each.  ``xs``: per-LAYER inputs, a tuple of
@@ -515,8 +579,9 @@ def scan_stack(h: jax.Array, params: Params, config: ModelConfig,
     tuple of arrays with the period's layers of a kind leading, which
     come back as ``[L_kind, ...]`` over both scans; ``routed`` what an
     expert layer's mixer kept (None in the leading layers), which comes
-    back with the expert periods leading.  Returns ``(h, state,
-    per_layer, routed)``."""
+    back with the expert periods leading.  ``passed``: the pass of a
+    looped stack this is (:func:`run_stack`), else None.  Returns ``(h,
+    state, per_layer, routed)``."""
     n = len(config.layer_types)
     lead = config.first_k_dense_replace // n
     total = config.num_layers // n
@@ -537,17 +602,74 @@ def scan_stack(h: jax.Array, params: Params, config: ModelConfig,
     if not lead:
         h, state, (outs, routed) = scan_periods(
             h, params["periods"], config, make_mixer, state, xs,
-            experts=config.has_routed_experts)
+            experts=config.has_routed_experts, passed=passed)
         return h, state, tuple(per_layer(t) for t in outs), routed
     first = None if xs is None else tuple(t[:lead] for t in xs)
     rest = None if xs is None else tuple(t[lead:] for t in xs)
     h, state, (outs_a, _) = scan_periods(h, params["lead"], config,
-                                         make_mixer, state, first)
+                                         make_mixer, state, first,
+                                         passed=passed)
     h, state, (outs_b, routed) = scan_periods(
         h, params["periods"], config, make_mixer, state, rest, base=lead,
-        experts=config.has_routed_experts)
+        experts=config.has_routed_experts, passed=passed)
     outs = tuple(per_layer(a, b) for a, b in zip(outs_a, outs_b))
     return h, state, outs, routed
+
+
+def exit_gate(params: Params, h: jax.Array) -> jax.Array:
+    """The looped stack's exit gate of the normed ``h`` ``[..., hidden]``:
+    ``sigmoid(w . h + b)``, float32."""
+    with jax.named_scope(EXIT_GATE):
+        score = jnp.einsum("...h,h->...", h, params["exit_gate_w"],
+                           preferred_element_type=jnp.float32)
+        return jax.nn.sigmoid(score + params["exit_gate_b"])
+
+
+def run_stack(h: jax.Array, params: Params, config: ModelConfig,
+              make_mixer: Any, state: Any, xs: Any = None
+              ) -> tuple[jax.Array, Any, Any, Any, Any]:
+    """``h`` through the stack as often as the configuration says.  A
+    plain stack (``total_ut_steps`` 1) is :func:`scan_stack` and no more:
+    the final norm is :func:`logits_of`'s.  A looped one runs
+    :func:`scan_stack` in a ``lax.scan`` over the passes (ONE layer body
+    in the program, the weights read once a pass): after EACH pass the
+    final norm, then the exit gate of what it gives, so the ``h`` that
+    comes back is normed already.  Its residual stream is FLOAT32 from
+    the embedding to the head (the sub-layers are fed, and give, the
+    weights' dtype): a pass's output is the next one's input, so what
+    rounding the stream to bfloat16 at each of a pass's 96 additions
+    adds is carried into, and grown by, every later pass (on the chip the
+    logits read 0.19-0.25 from the float32 reference with a bfloat16
+    stream, ``PERF.md`` §6, PR 33).  ``state`` rides this scan's carry too;
+    ``xs`` and the mixers' per-layer outputs are pass-major ``[passes x
+    L_kind, ...]``, as the planes are.  Every pass runs whatever the
+    gates say: ``serve/hybrid.py::check_serving`` says why.
+
+    Returns ``(h, state, per_layer, routed, gates)``: ``gates`` the
+    float32 exit gate of every pass ``[passes, B, S]``, None for a plain
+    stack."""
+    passes = config.total_ut_steps
+    if passes == 1:
+        return (*scan_stack(h, params, config, make_mixer, state, xs), None)
+
+    def per_pass(t):                    # [passes x L, ...] -> [passes, L, ...]
+        return t.reshape((passes, t.shape[0] // passes) + t.shape[1:])
+
+    def one_pass(carry, xs_t):
+        h, t, state = carry
+        h, state, outs, _ = scan_stack(h, params, config, make_mixer, state,
+                                       xs_t, passed=t)
+        with jax.named_scope(LOOP_NORM):
+            h = rmsnorm(h, params["ln_f"], config.rms_norm_eps)
+        return (h, t + 1, state), (outs, exit_gate(params, h))
+
+    (h, _, state), (outs, gates) = jax.lax.scan(
+        one_pass, (h.astype(jnp.float32), jnp.int32(0), state),
+        None if xs is None else tuple(per_pass(t) for t in xs),
+        length=passes)
+    outs = tuple(t if isinstance(t, tuple)
+                 else t.reshape((-1,) + t.shape[2:]) for t in outs)
+    return h, state, outs, None, gates
 
 
 def embed_tokens(params: Params, ids: jax.Array) -> jax.Array:
@@ -557,9 +679,11 @@ def embed_tokens(params: Params, ids: jax.Array) -> jax.Array:
 
 def logits_of(params: Params, h: jax.Array,
               config: ModelConfig) -> jax.Array:
-    """Final RMSNorm and the output head; float32 logits."""
+    """Final RMSNorm and the output head; float32 logits.  A looped
+    stack's ``h`` has had its norm (:func:`run_stack`)."""
     with jax.named_scope(LM_HEAD):
-        y = rmsnorm(h, params["ln_f"], config.rms_norm_eps)
+        y = (h.astype(params["lm_head"].dtype) if config.total_ut_steps > 1
+             else rmsnorm(h, params["ln_f"], config.rms_norm_eps))
         return jnp.einsum("...h,hv->...v", y, params["lm_head"],
                           preferred_element_type=jnp.float32)
 
@@ -572,12 +696,15 @@ class SequenceMixer:
     layers in their expanded form), and the chunked delta rule from a
     zero state with zeros before the convolution's first position."""
 
-    def __init__(self, config: ModelConfig) -> None:
-        self.config = config
+    def __init__(self, config: ModelConfig, seq_len: int) -> None:
+        self.config, self.seq_len = config, seq_len
         self.chosen: list = []
 
     def valid(self):
         return None
+
+    def positions(self):
+        return jnp.arange(self.seq_len)[None, :]
 
     def routed(self, routing, counts) -> None:
         self.chosen.append(routing.experts)
@@ -620,21 +747,25 @@ class SequenceMixer:
 
 
 def forward(params: Params, ids: jax.Array, config: ModelConfig,
-            mesh: Optional[Mesh] = None, with_routing: bool = False
-            ) -> Any:
+            mesh: Optional[Mesh] = None, with_routing: bool = False,
+            with_gates: bool = False) -> Any:
     """Token ids ``[B, S]`` to float32 logits ``[B, S, vocab]``: the
     whole sequence at once, no cache.  ``mesh`` only refuses what the
     family cannot run (pipeline stages); sharding comes from the
     parameters' own placement.  ``with_routing`` also returns the
     experts every token chose in every expert layer, ``[expert layers,
-    B * S, k]``."""
+    B * S, k]``; ``with_gates`` a looped stack's exit gates ``[passes,
+    B, S]``."""
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         raise ValueError("pipeline parallelism is not implemented for "
                          "layer_types models")
     h = embed_tokens(params, ids)
-    h, _, _, chosen = scan_stack(h, params, config,
-                                 lambda _xs: SequenceMixer(config), None)
+    h, _, _, chosen, gates = run_stack(
+        h, params, config,
+        lambda _xs: SequenceMixer(config, ids.shape[1]), None)
     logits = logits_of(params, h, config)
     if with_routing:
         return logits, chosen.reshape((-1,) + chosen.shape[2:])
+    if with_gates:
+        return logits, gates
     return logits

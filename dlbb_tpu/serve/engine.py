@@ -448,16 +448,22 @@ class ServingEngine:
 
     def probe_results(self) -> dict[int, dict[str, Any]]:
         """``rid -> {"slot", "recycled", "prompt_ids", "tokens",
-        "logits", "experts", "prompt_state", "end_state"}`` of the last
+        "logits", "experts", "gates", "exit_gates", "prompt_state",
+        "end_state"}`` of the last
         ``run_trace``: ``logits[i]`` (float32 ``[vocab]``, numpy) are the
         logits token ``tokens[i]`` was the ``argmax`` of, ``experts[i]``
         and ``gates[i]`` the experts chosen at that position in every
         expert layer and the weights they got (``[expert layers, k]``;
-        None for a model without); ``recycled``
+        None for a model without), ``exit_gates[i]`` a looped stack's
+        exit gate of every pass there (``[passes]``; None for a plain
+        stack); ``recycled``
         says whether the slot had served another request before; the two
         states are the slot's recurrent state ``[L_lin, heads, d_v,
-        d_k]`` after the prompt and after the last decode step (which
-        took in ``tokens[-2]``; None if the request did not finish)."""
+        d_k]`` (of a model without one its rows of the first K plane,
+        ``[num_blocks, block_size, kvh, d]``: the family's
+        ``slot_state``) after the prompt and after the last decode step
+        (which took in ``tokens[-2]``; None if the request did not
+        finish)."""
         out = {}
         for rid, rec in self.probed.items():
             # ``seen`` is the logits, or (logits, experts, gates): a
@@ -477,8 +483,9 @@ class ServingEngine:
             out[rid] = {"slot": rec["slot"], "recycled": rec["recycled"],
                         "prompt_ids": rec["prompt_ids"], "tokens": tokens,
                         "logits": parts[0],
-                        "experts": parts[1] if len(parts) > 1 else None,
-                        "gates": parts[2] if len(parts) > 2 else None,
+                        "experts": None, "gates": None, "exit_gates": None,
+                        **dict(zip(self._family.probe_names(self.config),
+                                   parts[1:])),
                         "prompt_state": np.asarray(rec["prompt_state"]),
                         "end_state": (None if rec["end_state"] is None
                                       else np.asarray(rec["end_state"]))}
@@ -1310,7 +1317,10 @@ class ServingEngine:
                     live = int(live_tile_counts(
                         start[None, :] + trip, stepping,
                         self._kv_tile, max_tiles).sum())
-                    held = k * max_tiles * cfg.max_batch
+                    # a looped stack's step fetches them once a pass
+                    live *= self.config.total_ut_steps
+                    held = (k * max_tiles * cfg.max_batch
+                            * self.config.total_ut_steps)
                     stats.kv_tiles_live += live
                     stats.kv_tiles_held += held
                     self.registry.inc(

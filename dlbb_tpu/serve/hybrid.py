@@ -6,14 +6,16 @@ HybridCache`.
 
 - ``serve_prefill_chunk_o<offset>``: one prompt chunk of token ids.  The
   full-attention layers do what the GPT chunk does (offset-causal
-  attention over the carried prefix K/V, one block write through
-  ``write_slot_blocks``); the linear-attention layers run the chunked
+  attention over the carried prefix K/V; the chunk's K/V of every layer
+  written as whole blocks in ONE update after the layer loops,
+  ``write_slot_planes``); the linear-attention layers run the chunked
   gated delta rule from the state the previous chunk handed on and write
   the slot's state and convolution inputs; the latent-attention layers
   expand per-head keys and values from the carried prefix of latent rows
   and the chunk's own (the EXPANDED form: 2.4 x fewer operations than
   the absorbed one over a chunk) and write the chunk's rows as whole
-  blocks.  The carried ``prefix`` is ``(k, v, state, conv, latent)``; the
+  blocks.  The carried ``prefix`` is ``(k, v, state, conv, latent)``, K
+  and V of a looped stack for every (pass, layer) as its planes are; the
   one a prompt starts from is all zeros (:func:`create_prefix`), which
   is what clears a recycled slot.
 - ``serve_decode_step`` / ``serve_decode_k<K>``: embed each slot's
@@ -22,8 +24,9 @@ HybridCache`.
   ``ops/latent_attention.py``), logits, greedy ``argmax`` fed back on
   the device.
 - ``serve_inject``: a finished prefill's first token into its slot.
-- ``serve_probe_state``: a copy of one slot's recurrent state, for a
-  probed request only (twice in its life).
+- ``serve_probe_state``: a copy of one slot's recurrent state (or, where
+  a model has none, of its rows of the first K plane), for a probed
+  request only (twice in its life).
 
 The decode carry is ``(cache, tokens [max_batch] int32)``.  Every decode
 program also returns the float32 logits of the two slots named by its
@@ -36,7 +39,11 @@ three small integers a step
 (``counts``: assignments, experts that got a token, the fullest expert's
 tokens, over the expert layers), and its chunk program the same for the
 prompt's last position and the chunk's real tokens (``last = (logits,
-experts, gates, counts)``).
+experts, gates, counts)``).  A looped stack (``total_ut_steps`` > 1)
+returns beside the logits the exit gate of every pass at those
+positions (``seen = (logits, exit gates [..., passes])``, ``last =
+(logits, exit gates [passes], counts)``) and as ``counts`` the passes
+through the stack the step or chunk ran.
 
 The block and the period are ``models/hybrid.py``'s; this file holds the
 three mixers that touch the cache, and at its end what the scheduler
@@ -89,6 +96,7 @@ from dlbb_tpu.serve.kvcache import (
     hybrid_cache_shardings,
     hybrid_cache_specs,
     write_slot_blocks,
+    write_slot_planes,
     write_slot_state,
 )
 from dlbb_tpu.serve.traffic import Request
@@ -118,8 +126,8 @@ def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple:
     dtype = _dtype_of(config.dtype)
     n_lin = config.layers_of(LINEAR_ATTENTION)
     heads = config.linear_num_value_heads
-    kv = jnp.zeros((config.layers_of(FULL_ATTENTION), 0, config.kv_heads,
-                    config.head_dim), dtype)
+    kv = jnp.zeros((config.kv_planes, 0, config.kv_heads, config.head_dim),
+                   dtype)
     state = jnp.zeros((n_lin, heads, config.linear_value_head_dim,
                        config.linear_key_head_dim), hybrid.STATE_DTYPE)
     conv = jnp.zeros((n_lin, max(config.linear_conv_kernel_dim - 1, 0),
@@ -202,6 +210,9 @@ class ChunkMixer(_Mixer):
         # padding takes no expert's time
         return jnp.arange(self.chunk_len) < self.n_valid
 
+    def positions(self):
+        return (self.start + jnp.arange(self.chunk_len))[None, :]
+
     def keep(self, per_token):
         # the prompt's last position, where it lies in this chunk
         last = jnp.clip(self.n_valid - 1, 0, self.chunk_len - 1)
@@ -215,17 +226,12 @@ class ChunkMixer(_Mixer):
         v_all = jnp.concatenate([pv[j], v[0]], axis=0)
         attn = _chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
                                 self.start)
-        k_c, v_c, *rest = planes
-        blocks = (self.chunk_len // self.bs, self.bs) + k_c.shape[-2:]
-        k_c = write_slot_blocks(
-            k_c, _pad_heads(k[0], k_c.shape[-2]).reshape(blocks), l,
-            self.slot, self.start // self.bs)
-        v_c = write_slot_blocks(
-            v_c, _pad_heads(v[0], v_c.shape[-2]).reshape(blocks), l,
-            self.slot, self.start // self.bs)
+        # the K/V planes ride along untouched: the chunk program writes
+        # every layer's blocks at once from what is handed on here
+        # (``write_slot_planes``)
         self.out[0].append(k_all)
         self.out[1].append(v_all)
-        return attn.transpose(0, 2, 1, 3), (k_c, v_c, *rest)
+        return attn.transpose(0, 2, 1, 3), planes
 
     def latent(self, q, c, k_rope, wkv_b, l, planes):
         cfg = self.config
@@ -287,20 +293,33 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
     """Jitted ``prefill_chunk(cache, prefix, params, ids [1, chunk],
     slot, length) -> (cache, prefix, last)``, the signature of
     ``gpt.build_prefill_chunk`` with token ids for embeddings; ``last``
-    is the float32 logits ``[vocab]`` of the prompt's last position, and
+    is the float32 logits ``[vocab]`` of the prompt's last position,
     with routed experts ``(logits, experts chosen there [expert layers,
-    k], their gates, counts [3])``.  ``quantized`` is the seam's: this family has the
+    k], their gates, counts [3])``, of a looped stack ``(logits, exit
+    gates there [passes], passes run)``.  ``quantized`` is the seam's: this family has the
     fp layout only (``models.configs.validate_serving`` refuses int8)."""
 
     @named(f"serve_prefill_chunk_o{start}")
     def prefill_chunk(cache, prefix, params, ids, slot, length):
         n_valid = jnp.clip(length - start, 0, chunk_len)
         h = hybrid.embed_tokens(params, ids)
-        h, planes, ys, routed = hybrid.scan_stack(
+        h, planes, ys, routed, gates = hybrid.run_stack(
             h, params, config,
             lambda xs_p: ChunkMixer(config, xs_p, slot, n_valid, start,
                                     chunk_len, cache.block_size),
             cache[:-1], prefix)
+        k_c, v_c, *rest = planes
+        if k_c.shape[0]:
+            # the chunk's own K/V of every (pass, layer), off the end of
+            # what it hands the next chunk, as whole blocks of the slot
+            blocks = (k_c.shape[0], chunk_len // cache.block_size) \
+                + k_c.shape[3:]
+            k_c, v_c = (
+                write_slot_planes(
+                    plane, _pad_heads(own[:, start:], plane.shape[-2])
+                    .reshape(blocks), slot, start // cache.block_size)
+                for plane, own in ((k_c, ys[0]), (v_c, ys[1])))
+        planes = (k_c, v_c, *rest)
         local = jnp.clip(length - 1 - start, 0, chunk_len - 1)
         h_last = jax.lax.dynamic_index_in_dim(h[0], local, 0, keepdims=False)
         new_len = jnp.minimum(length, start + chunk_len)
@@ -312,6 +331,10 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
             last = (last, chosen.reshape((-1,) + chosen.shape[2:]),
                     gates.reshape((-1,) + gates.shape[2:]),
                     _sum_counts(counts))
+        elif gates is not None:
+            last = (last, jax.lax.dynamic_index_in_dim(
+                gates[:, 0], local, 1, keepdims=False),
+                jnp.int32(gates.shape[0]))
         # a kind the model has no layer of hands its empty prefix on
         ys = tuple(y if len(y) else p for y, p in zip(ys, prefix))
         return HybridCache(*planes, lengths), ys, last
@@ -338,6 +361,10 @@ class DecodeMixer(_Mixer):
     def valid(self):
         # a slot that holds no request takes no expert's time
         return self.active
+
+    def positions(self):
+        # the token a slot appends lies at the slot's length
+        return self.lengths[:, None]
 
     def keep(self, per_token):
         return jnp.take(per_token, self.probe, axis=0)
@@ -409,11 +436,13 @@ def _decode_math(carry, params, active, probe, config: ModelConfig,
     every trip of the fused scan.  Returns ``(carry, tokens, seen,
     counts)``: ``seen`` the logits of the probed slots ``[PROBES,
     vocab]``, with routed experts ``(logits, experts those slots chose
-    [PROBES, expert layers, k], their float32 gates)``; ``counts`` the
-    step's ``[3]`` then, else None."""
+    [PROBES, expert layers, k], their float32 gates)``, of a looped
+    stack ``(logits, those slots' exit gates [PROBES, passes])``;
+    ``counts`` the step's ``[3]`` with routed experts, the passes the
+    step ran of a looped stack, else None."""
     cache, tok = carry
     h = hybrid.embed_tokens(params, tok)[:, None, :]
-    h, planes, _, routed = hybrid.scan_stack(
+    h, planes, _, routed, gates = hybrid.run_stack(
         h, params, config,
         lambda _xs: DecodeMixer(config, mesh, cache.lengths, active, probe),
         cache[:-1])
@@ -429,6 +458,9 @@ def _decode_math(carry, params, active, probe, config: ModelConfig,
             t.reshape((-1,) + t.shape[2:]).transpose(1, 0, 2)
             for t in (chosen, gates))
         counts = _sum_counts(counts)
+    elif gates is not None:
+        seen = (seen, jnp.take(gates[:, :, 0].T, probe, axis=0))
+        counts = jnp.int32(gates.shape[0])
     return (HybridCache(*planes, lengths), new_tok), new_tok, seen, counts
 
 
@@ -499,16 +531,31 @@ def inject_token(carry, slot, last):
 def slot_state(cache: HybridCache, slot) -> jax.Array:
     """A copy of one slot's recurrent state ``[L_lin, heads, d_v, d_k]``:
     what ``ServingEngine.probe`` keeps of a probed request, after its
-    prompt and after its last decode step."""
+    prompt and after its last decode step.  Of a model that has no such
+    state but K/V planes, the slot's rows of the FIRST K plane
+    ``[num_blocks, block_size, kvh, d]``: what the first layer wrote of
+    every token (its norm, its projection, its rotary), before anything
+    compounds."""
+    if not cache.state.shape[0] and cache.k.shape[0]:
+        return jax.lax.dynamic_index_in_dim(cache.k[0], slot, axis=0,
+                                            keepdims=False)
     return jax.lax.dynamic_index_in_dim(cache.state, slot, axis=1,
                                         keepdims=False)
 
 
 def probe_parts(last: Any) -> tuple:
     """A chunk program's ``last`` as the decode programs' ``seen`` is
-    laid out for one slot: ``(logits,)``, or ``(logits, experts,
-    gates)``."""
+    laid out for one slot: ``(logits,)``, ``(logits, experts, gates)``
+    or ``(logits, exit gates)``."""
     return tuple(last[:-1]) if isinstance(last, tuple) else (last,)
+
+
+def probe_names(config: ModelConfig) -> tuple[str, ...]:
+    """What ``seen`` holds behind the logits, as
+    ``ServingEngine.probe_results`` names it."""
+    if config.has_routed_experts:
+        return ("experts", "gates")
+    return ("exit_gates",) if config.total_ut_steps > 1 else ()
 
 
 def fresh_carry(config: ModelConfig, serving: Any, mesh: Mesh):
@@ -543,8 +590,22 @@ def check_serving(config: ModelConfig, serving: Any) -> None:
     its mechanism (ROADMAP.md, Queue 2); int8 KV, a draft model and
     ``tp`` over latents or experts are refused in
     ``models.configs.validate_serving``, ``ep`` in
-    ``validate_expert_parallelism``."""
+    ``validate_expert_parallelism``.  What it takes: every
+    ``layer_types`` model ``ModelConfig`` builds (``norm_placement``
+    ``post``, ``pre`` or ``sandwich``; full-attention layers with
+    QK-norm, with rotary positions, or with both; a looped stack of
+    ``total_ut_steps`` passes that every token runs to the end), chunked
+    prefill, fused decode scans, the fp K/V layout."""
     latent = LATENT_ATTENTION in config.layer_types
+    if config.early_exit_threshold < 1:
+        raise ValueError(
+            f"early_exit_threshold={config.early_exit_threshold} is not "
+            "implemented: a pass through the stack is ONE program over "
+            "every slot of the batch, so a token can leave the loop early "
+            "only if the scheduler groups slots by the pass they are in "
+            "(and a later token's pass t needs K/V its predecessor never "
+            "computed); every token runs all "
+            f"{config.total_ut_steps} passes (early_exit_threshold=1)")
     if serving.speculation != "off":
         raise ValueError(
             f"serving.speculation={serving.speculation!r} is not "
@@ -612,7 +673,7 @@ def register_metrics(registry: Any, config: ModelConfig, serving: Any,
         kv_cache_bytes(config, serving.max_batch, serving.max_seq,
                        tp=tp),
         help="bytes of paged K/V the cache holds (full-attention "
-             "layers only)")
+             "layers only; of a looped stack one plane a pass and layer)")
     registry.set_gauge(
         "serve_latent_bytes",
         latent_cache_bytes(config, serving.max_batch, serving.max_seq),
@@ -625,6 +686,11 @@ def register_metrics(registry: Any, config: ModelConfig, serving: Any,
             "serve_moe_load_max", 0,
             help="the fullest expert's tokens in one expert layer of one "
                  "decode step or prompt chunk (largest seen)")
+    if config.total_ut_steps > 1:
+        registry.inc(
+            "serve_loop_passes", 0,
+            help="passes through the looped stack the decode steps and "
+                 "prompt chunks ran (each reads the stack's weights once)")
 
 
 def _moe_counted(registry: Any, config: ModelConfig,
@@ -645,19 +711,32 @@ def _moe_counted(registry: Any, config: ModelConfig,
     samples.setdefault(f"moe_{kind}_load_max", []).append(fullest)
 
 
+def _counted(registry: Any, config: ModelConfig, samples: dict[str, list],
+             kind: str, counts: Any) -> None:
+    if config.has_routed_experts:
+        _moe_counted(registry, config, samples, kind, counts)
+    else:
+        # a looped stack's: the passes each step (or the chunk) ran
+        counts = np.asarray(counts).reshape(-1)
+        registry.inc("serve_loop_passes", int(counts.sum()))
+        samples["_loop_passes"] = (samples.get("_loop_passes", 0)
+                                   + int(counts.sum()))
+        samples["_loop_runs"] = samples.get("_loop_runs", 0) + len(counts)
+
+
 def unit_counted(registry: Any, config: ModelConfig,
                  samples: dict[str, list], counts: Any) -> None:
-    """A decode unit is done and its ``counts`` (None without routed
-    experts) are on the host's side of the sync."""
+    """A decode unit is done and its ``counts`` (None for a plain dense
+    stack) are on the host's side of the sync."""
     if counts is not None:
-        _moe_counted(registry, config, samples, "unit", counts)
+        _counted(registry, config, samples, "unit", counts)
 
 
 def chunk_counted(registry: Any, config: ModelConfig,
                   samples: dict[str, list], last: Any) -> None:
     """A prompt chunk's ``last`` is ready."""
     if isinstance(last, tuple):
-        _moe_counted(registry, config, samples, "chunk", last[-1])
+        _counted(registry, config, samples, "chunk", last[-1])
 
 
 def report_shares(config: ModelConfig, samples: dict[str, list]
@@ -666,7 +745,12 @@ def report_shares(config: ModelConfig, samples: dict[str, list]
     ``experts_touched_share`` (experts that got a token over experts
     held, a layer and decode step or chunk) and
     ``expert_load_max_over_mean`` (the fullest expert's tokens over the
-    mean of the experts that got any, largest single layer)."""
+    mean of the experts that got any, largest single layer); of a
+    looped stack ``exit_pass_mean``, the passes a decode step or chunk
+    ran before its ``h`` went to the head, on average."""
+    if samples.get("_loop_runs"):
+        return {"exit_pass_mean":
+                samples["_loop_passes"] / samples["_loop_runs"]}
     if not config.has_routed_experts:
         return {}
     touched = sum(samples.get("moe_unit_touched", ())) \

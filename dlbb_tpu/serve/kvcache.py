@@ -208,6 +208,22 @@ def write_slot_blocks(plane: jax.Array, blocks: jax.Array, layer: jax.Array,
 
 
 @jax.named_scope(KV_UPDATE)
+def write_slot_planes(plane: jax.Array, blocks: jax.Array, slot: jax.Array,
+                      start_blk: int = 0) -> jax.Array:
+    """A prompt chunk's write into EVERY plane at once: ``blocks`` ``[L,
+    wb, bs, kvh, d]``, each layer's ``wb`` whole blocks, into ``plane``
+    ``[L, B, nb, ...]`` at ``(:, slot, start_blk)``: one
+    ``dynamic_update_slice`` after the chunk's layer loops, in place in
+    the donated plane.  (A plane that rode those loops' carry for a
+    write a layer was re-laid out whole, and back, by the v5e compiler
+    once the loops were nested, pass around layers: 3.75 GB of
+    temporaries, found by compiling for the chip without it, PR 33.)"""
+    start = (0, slot, start_blk) + (0,) * (plane.ndim - 3)
+    return jax.lax.dynamic_update_slice(
+        plane, blocks[:, None].astype(plane.dtype), start)
+
+
+@jax.named_scope(KV_UPDATE)
 def copy_slot_blocks(plane: jax.Array, src: jax.Array, dst: jax.Array,
                      num_blocks: int) -> tuple[jax.Array, jax.Array]:
     """The shared-prefix attach: copy slot ``src``'s first
@@ -240,7 +256,9 @@ class HybridCache(NamedTuple):
     (a block is ``block_size`` tokens of a slot, whatever a token keeps).
     The ``full_attention`` layers keep paged K/V planes exactly as
     :class:`KVCache` does, but only for themselves (``L_full`` of the
-    layers).  The ``linear_attention`` layers keep, per layer and SLOT,
+    layers), and in a looped stack (``total_ut_steps`` passes over the
+    same layers) a plane for every (pass, layer), pass-major: a token's
+    pass ``t`` attends to what pass ``t`` of the earlier tokens wrote.  The ``linear_attention`` layers keep, per layer and SLOT,
     a float32 recurrent state and the last ``conv_kernel - 1`` inputs of
     their short convolution: not paged and not growing with the slot's
     length, so the :class:`BlockLedger` never counts them (its blocks
@@ -254,7 +272,8 @@ class HybridCache(NamedTuple):
     request survives."""
 
     # kvh: ``models.configs.cache_kv_heads`` (whole tiles of 8 heads)
-    k: jax.Array        # [L_full, max_batch, num_blocks, block_size, kvh, d]
+    # planes: ``ModelConfig.kv_planes`` = passes x L_full
+    k: jax.Array        # [planes, max_batch, num_blocks, block_size, kvh, d]
     v: jax.Array        # same
     state: jax.Array    # f32 [L_lin, max_batch, heads, d_v, d_k]
     conv: jax.Array     # [L_lin, max_batch, conv_kernel - 1, heads, 2 d_k + d_v]
@@ -307,7 +326,7 @@ def create_hybrid_cache(config: ModelConfig, max_batch: int,
     n_lin = config.layers_of(LINEAR_ATTENTION)
     heads = config.linear_num_value_heads
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    kv_shape = (config.layers_of(FULL_ATTENTION), max_batch, num_blocks,
+    kv_shape = (config.kv_planes, max_batch, num_blocks,
                 block_size, cache_kv_heads(config, tp), config.head_dim)
     state_shape = (n_lin, max_batch, heads, config.linear_value_head_dim,
                    config.linear_key_head_dim)

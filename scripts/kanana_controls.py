@@ -103,6 +103,38 @@ def _bf16(x):
     return jax.lax.reduce_precision(x, exponent_bits=8, mantissa_bits=7)
 
 
+def rmsnorm_bf16(x, scale, eps):
+    """``models/hybrid.py::rmsnorm`` with its statistics and products
+    rounded to bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    x32 = x.astype(jnp.float32)
+    inv = _bf16(jax.lax.rsqrt(
+        _bf16(jnp.mean(_bf16(x32 * x32), axis=-1, keepdims=True)) + eps))
+    return _bf16(_bf16(x32 * inv)
+                 * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def chunk_attention_bf16(qh, k_all, v_all, start):
+    """``serve/attend.py::_chunk_attention`` (plain MHA) with its scores,
+    its softmax and its output rounded to bfloat16."""
+    import jax
+    import jax.numpy as jnp
+
+    _, _, c, d = qh.shape
+    q32 = qh.astype(jnp.float32)
+    k32 = k_all.transpose(1, 0, 2).astype(jnp.float32)[None]
+    v32 = v_all.transpose(1, 0, 2).astype(jnp.float32)[None]
+    mask = (jnp.arange(k_all.shape[0])[None, :]
+            <= (start + jnp.arange(c))[:, None])
+    logits = _bf16(jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / d ** 0.5)
+    probs = _bf16(jax.nn.softmax(
+        jnp.where(mask[None, None], logits, -jnp.inf), axis=-1))
+    return _bf16(jnp.einsum("bnqk,bnkd->bnqd", probs, v32)).astype(
+        k_all.dtype)
+
+
 def _router_bfloat16(setattr_, model):
     import jax
     import jax.numpy as jnp
@@ -129,19 +161,11 @@ def _norms_combine_softmax_bfloat16(setattr_, model):
     token's expert outputs, a prompt chunk's scores and softmax.  (The
     decode kernel's softmax and the SwiGLU's activation sit inside a
     kernel and a fusion, with no seam for a control.)"""
-    import jax
     import jax.numpy as jnp
 
     from dlbb_tpu.models import hybrid
     from dlbb_tpu.ops import routed_experts as moe
     from dlbb_tpu.serve import hybrid as serve_hybrid
-
-    def rmsnorm(x, scale, eps):
-        x32 = x.astype(jnp.float32)
-        inv = _bf16(jax.lax.rsqrt(
-            _bf16(jnp.mean(_bf16(x32 * x32), axis=-1, keepdims=True)) + eps))
-        return _bf16(_bf16(x32 * inv)
-                     * scale.astype(jnp.float32)).astype(x.dtype)
 
     def combine(out, d, gates):
         t, k = gates.shape
@@ -155,22 +179,9 @@ def _norms_combine_softmax_bfloat16(setattr_, model):
                                         * _bf16(gates[:, i, None])))
         return total
 
-    def chunk_attention(qh, k_all, v_all, start):
-        _, _, c, d = qh.shape
-        q32 = qh.astype(jnp.float32)
-        k32 = k_all.transpose(1, 0, 2).astype(jnp.float32)[None]
-        v32 = v_all.transpose(1, 0, 2).astype(jnp.float32)[None]
-        mask = (jnp.arange(k_all.shape[0])[None, :]
-                <= (start + jnp.arange(c))[:, None])
-        logits = _bf16(jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / d ** 0.5)
-        probs = _bf16(jax.nn.softmax(
-            jnp.where(mask[None, None], logits, -jnp.inf), axis=-1))
-        return _bf16(jnp.einsum("bnqk,bnkd->bnqd", probs, v32)).astype(
-            k_all.dtype)
-
-    setattr_(hybrid, "rmsnorm", rmsnorm)
+    setattr_(hybrid, "rmsnorm", rmsnorm_bf16)
     setattr_(moe, "combine", combine)
-    setattr_(serve_hybrid, "_chunk_attention", chunk_attention)
+    setattr_(serve_hybrid, "_chunk_attention", chunk_attention_bf16)
 
 
 def _float32_parts_bfloat16(setattr_, model):
@@ -216,13 +227,17 @@ def apply(name: str, setattr_: Callable[[Any, str, Any], None],
 
 
 def run_one(name: str, seconds: float, seed: int, rps: float,
-            chunk: int, trace: bool = False) -> dict:
+            chunk: int, trace: bool = False, cell: str = CELL,
+            controls: Any = None) -> dict:
+    """One run of ``cell`` made wrong as ``controls[name]`` says (this
+    file's :data:`CONTROLS` by default; ``scripts/ouro_controls.py``
+    hands in its own)."""
     from benchmarks.harness import device
     from benchmarks.harness.cells import resolve_cell, runner_for
     from dlbb_tpu.utils.compile_cache import configure_compile_cache
 
     t_start = time.perf_counter()
-    cell = resolve_cell(CELL)
+    cell = resolve_cell(cell)
     config = json.loads(json.dumps(cell.config))
     traffic = dict(cell.traffic)
     if rps:
@@ -235,7 +250,7 @@ def run_one(name: str, seconds: float, seed: int, rps: float,
     cell = dataclasses.replace(cell, config=config, traffic=traffic)
     configure_compile_cache()
     device.require_chips(cell.chips)
-    apply(name, setattr, config["program"]["model"])
+    (controls or CONTROLS)[name](setattr, config["program"]["model"])
     scratch = ROOT / ".bench_scratch" / f"control_{name}"
     scratch.mkdir(parents=True, exist_ok=True)
     run = runner_for(traffic["kind"])(cell, seed, seconds, trace,
@@ -257,8 +272,9 @@ def run_one(name: str, seconds: float, seed: int, rps: float,
             "faults": run.faults}
 
 
-def main() -> int:
-    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+def main(script: str = __file__, cell: str = CELL, controls: Any = None,
+         log: str = "kanana_controls.jsonl", doc: str = __doc__) -> int:
+    parser = argparse.ArgumentParser(description=doc.split("\n\n")[0])
     parser.add_argument("controls", nargs="+")
     parser.add_argument("--seconds", type=float, default=12.0)
     parser.add_argument("--seed", type=int, default=2147483659)
@@ -268,16 +284,16 @@ def main() -> int:
     args = parser.parse_args()
     if args.child:
         (name,) = args.controls
-        print("RESULT " + json.dumps(run_one(name, args.seconds, args.seed,
-                                             args.rps, args.chunk)),
-              flush=True)
+        print("RESULT " + json.dumps(run_one(
+            name, args.seconds, args.seed, args.rps, args.chunk, cell=cell,
+            controls=controls)), flush=True)
         return 0
     out = ROOT / "chiprun_out"
     out.mkdir(exist_ok=True)
     lines = []
     for i, name in enumerate(args.controls):
         done = subprocess.run(
-            [sys.executable, __file__, "--child", name, "--seconds",
+            [sys.executable, script, "--child", name, "--seconds",
              str(args.seconds), "--seed", str(args.seed + i), "--rps",
              str(args.rps), "--chunk", str(args.chunk)],
             capture_output=True, text=True)
@@ -290,7 +306,7 @@ def main() -> int:
         print(line, flush=True)
         print("\n".join(notes[-4:]), flush=True)
         lines.append(line)
-    with open(out / "kanana_controls.jsonl", "a") as f:
+    with open(out / log, "a") as f:
         f.write("\n".join(lines) + "\n")
     return 0
 

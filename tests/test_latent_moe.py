@@ -524,7 +524,7 @@ def test_tp_and_ep_are_refused_and_the_gate_prices_the_latents():
 
 @pytest.mark.parametrize("change, reason", [
     (dict(qk_norm=True), "model family not implemented"),
-    (dict(norm_placement="sandwich"), "unknown norm_placement"),
+    (dict(norm_placement="both"), "unknown norm_placement"),
     (dict(kv_lora_rank=0), "latent_attention layers need"),
     (dict(qk_rope_head_dim=7), "latent_attention layers need"),
     (dict(num_experts_per_tok=9), "routed experts need"),

@@ -32,7 +32,8 @@ CAPABILITIES = {
     # a probing family's decode programs return ``(tokens, seen,
     # counts)``: it says how a chunk's ``last`` lays out as ``seen``, and
     # books the counts of a unit and of a chunk
-    "probe": ("slot_state", "probe_parts", "unit_counted", "chunk_counted"),
+    "probe": ("slot_state", "probe_parts", "probe_names", "unit_counted",
+              "chunk_counted"),
     "monolithic_prefill": ("build_prefill", "build_prefix_attach"),
 }
 

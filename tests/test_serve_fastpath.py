@@ -801,6 +801,76 @@ def test_latent_decode_step_compiled_for_the_v5e_copies_no_plane_and_no_expert(
     assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2**20
 
 
+@pytest.mark.parametrize("program", ["serve_decode_step", "serve_decode_k16",
+                                     "serve_prefill_chunk_o128"])
+def test_looped_programs_compiled_for_the_v5e_hold_one_layer_body_and_copy_no_plane(
+        v5e_chip, as_on_the_chip, program):
+    """The Ouro cell's programs at its published widths and its 8 slots
+    of 640 tokens (2 of its 48 layers, all 4 passes: the loops' bodies
+    do not depend on the number of layers): the passes are a LOOP around
+    the layer loop, so a decode program holds ONE ``kv_attend_decode``
+    kernel and not four; every K/V plane ``[passes x layers, ...]`` is
+    written in place (the decode append's scatter, or the chunk's one
+    update of every plane after its loops) and nothing else of a whole
+    plane's or a plane's layer's shape is produced; and what a program
+    holds beside its arguments is the re-tiled q, k, v kernels and no
+    more (a plane is 168 MB here, 4.03 GB at 48 layers)."""
+    from benchmarks.harness import cells
+    from dlbb_tpu.models import hybrid
+    from dlbb_tpu.serve import hybrid as serve_hybrid
+    from dlbb_tpu.serve.kvcache import create_hybrid_cache
+
+    mesh = v5e_chip
+    cell = cells.resolve_cell("ouro_serve_reason_backlog").config["program"]
+    cfg = ModelConfig.from_dict(dict(cell["model"], num_layers=2))
+    sv = ServingConfig.from_dict(cell["serving"])
+    rep = NamedSharding(mesh, P())
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = shaped(jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.key(0))))
+    cache = shaped(jax.eval_shape(lambda: create_hybrid_cache(
+        cfg, sv.max_batch, sv.num_blocks, sv.block_size)))
+    b = sv.max_batch
+    plane = cache.k.shape
+    assert plane == (4 * 2, b, 40, 16, 16, 128)
+    carry = (cache, like((b,), jnp.int32))
+    masks = (like((b,), jnp.bool_),)
+    probe = like((serve_hybrid.PROBES,), jnp.int32)
+    if program == "serve_decode_step":
+        traced = serve_hybrid.build_decode_step(cfg, mesh).trace(
+            carry, params, *masks, probe)
+    elif program == "serve_decode_k16":
+        traced = serve_hybrid.build_decode_fused(cfg, mesh, 16).trace(
+            carry, params, *masks, like((b,), jnp.int32), probe)
+    else:
+        prefix = tuple(
+            like((t.shape[0], 128) + t.shape[2:], t.dtype) if i < 2 else t
+            for i, t in enumerate(shaped(jax.eval_shape(
+                lambda: serve_hybrid.create_prefix(cfg, mesh)))))
+        traced = serve_hybrid.build_prefill_chunk(cfg, mesh, 128, 128).trace(
+            cache, prefix, params, like((1, 128), jnp.int32),
+            like((), jnp.int32), like((), jnp.int32))
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo)
+    if program.startswith("serve_decode"):
+        assert len(calls) == 1 and calls[0].startswith("kv_attend_decode"), \
+            calls
+    else:
+        assert calls == []
+    _assert_writes_in_place(hlo, plane, "bf16")
+    # the q, k and v kernels of both layers, re-tiled once a call (3 x
+    # 16.8 MB), and the chunk's scores; never a plane
+    assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
+
+
 @pytest.mark.parametrize("family", ["gpt", "hybrid"])
 def test_decode_step_attends_through_the_kernel_compiled_for_the_v5e(
         v5e_chip, as_on_the_chip, family):
