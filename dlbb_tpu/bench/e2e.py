@@ -168,9 +168,10 @@ def run_e2e(
             "num_parameters": num_parameters(model_cfg),
             "attention": model_cfg.attention,
             "dtype": model_cfg.dtype,
-            # TP collective-matmul schedule (off = GSPMD fused; ring/bidir
-            # = overlapped decomposition, docs/overlap.md)
-            "tp_overlap": model_cfg.tp_overlap,
+            # the route the TP projections TOOK (off = GSPMD fused;
+            # ring/bidir = overlapped decomposition, docs/overlap.md),
+            # not the configuration's word, which may be "auto"
+            "tp_overlap": plan.tp_overlap,
         },
         "mesh": plan.mesh_dict(),
         "init_time_s": init_time,

@@ -140,10 +140,11 @@ def build_parser() -> argparse.ArgumentParser:
     e2.add_argument("--output", default=None)
     e2.add_argument("--tp-overlap", default=None,
                     choices=("off", "ring", "bidir"), dest="tp_overlap",
-                    help="override model.tp_overlap: off = GSPMD fused TP "
+                    help="force model.tp_overlap: off = GSPMD fused TP "
                          "collectives, ring/bidir = ring-decomposed "
-                         "collective matmuls overlapping comm with compute "
-                         "(docs/overlap.md)")
+                         "collective matmuls overlapping comm with compute; "
+                         "without it the config's word holds, by default "
+                         "'auto': the shapes decide (docs/overlap.md)")
     _add_trace(e2)
 
     rp = sub.add_parser(
@@ -562,7 +563,7 @@ def build_parser() -> argparse.ArgumentParser:
     tr.add_argument("--output", default=None)
     tr.add_argument("--tp-overlap", default=None,
                     choices=("off", "ring", "bidir"), dest="tp_overlap",
-                    help="override model.tp_overlap (see the e2e flag)")
+                    help="force model.tp_overlap (see the e2e flag)")
     tr.add_argument("--grad-compression", default=None,
                     choices=("none", "int8", "fp8"), dest="grad_compression",
                     help="override training.grad_compression: quantise "

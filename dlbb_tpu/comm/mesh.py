@@ -156,8 +156,30 @@ def build_parallelism_mesh(
         names.append("ep")
     shape.append(tensor_parallel)
     names.append("tp")
-    return build_mesh(MeshSpec.grid(tuple(shape), tuple(names)),
-                      devices=devices)
+    spec = MeshSpec.grid(tuple(shape), tuple(names))
+    return build_mesh(spec, devices=_ici_order(spec, devices))
+
+
+def _ici_order(spec: MeshSpec, devices: Optional[Sequence]) -> Sequence:
+    """The first ``spec.num_ranks`` devices in the order that makes
+    neighbours along the mesh's inner axes neighbours on the ICI, where
+    they are TPUs and jax has an assignment for the shape
+    (``mesh_utils.create_device_mesh``: the four chips of a v5e 2x2 tray
+    go round as 0, 1, 3, 2).  In their plain order 1 -> 2 and 3 -> 0 of a
+    ``tp`` = 4 ring are diagonals of the tray, two links each, and the
+    rings of ``parallel/collective_matmul.py`` hop i -> i + 1.  Any other
+    platform, one device, or a shape jax refuses keeps the plain order."""
+    devs = list(devices) if devices is not None else available_devices()
+    n = spec.num_ranks
+    if n < 2 or len(devs) < n or devs[0].platform != "tpu":
+        return devs
+    from jax.experimental import mesh_utils
+
+    try:
+        laid = mesh_utils.create_device_mesh(spec.shape, devices=devs[:n])
+    except (ValueError, NotImplementedError, AssertionError):
+        return devs
+    return list(laid.reshape(-1))
 
 
 def partition_devices(

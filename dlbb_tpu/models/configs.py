@@ -74,21 +74,28 @@ class ModelConfig:
     # scanned block) — trades ~1/3 more FLOPs for O(layers) less activation
     # HBM, the standard TPU memory/compute trade.
     remat: bool = False
-    # Overlapped tensor-parallel collective-matmul schedule
-    # (dlbb_tpu/parallel/collective_matmul.py):
-    # - "off": GSPMD Megatron layout — XLA inserts the per-layer TP
-    #   all-reduces (the default; unchanged lowering);
-    # - "ring": every TP projection becomes a ring-decomposed
-    #   all-gather-matmul / matmul-reduce-scatter — the collective is a
-    #   chain of neighbour ppermutes hidden behind per-shard partial
-    #   matmuls, and activations between blocks live sequence-sharded
-    #   over tp;
-    # - "bidir": same decomposition on a bidirectional ring (both ICI
+    # How the tensor-parallel projections meet their collectives
+    # (dlbb_tpu/parallel/collective_matmul.py, docs/overlap.md):
+    # - "auto" (the default): the shapes decide.  A dense-FFN forward or
+    #   train step on a mesh with tp > 1 and pp == 1 takes the overlapped
+    #   route when its hops are large enough not to be latency and enough
+    #   of them hide behind the partial matmuls beside them
+    #   (collective_matmul.auto_schedule: the thresholds and the chip runs
+    #   that set them); every other program is the fused one, and nothing
+    #   raises.  models/transformer.py::tp_overlap_route gives the route a
+    #   program took;
+    # - "off": forced fused.  GSPMD Megatron layout, XLA inserts the two
+    #   exposed all-reduces a layer;
+    # - "ring": forced.  Every TP projection is a ring-decomposed
+    #   all-gather-matmul / matmul-reduce-scatter, a chain of neighbour
+    #   ppermutes hidden behind per-shard partial matmuls, and activations
+    #   between blocks live sequence-sharded over tp;
+    # - "bidir": forced, the same on a bidirectional ring (both ICI
     #   directions per step; half the hops for the all-gather side).
-    # Requires tp > 1, pp == 1, a dense (non-MoE) FFN, and sequence
-    # length divisible by the sequence-shard count — validated by
+    # A forced ring requires tp > 1, pp == 1, a dense (non-MoE) FFN, and a
+    # sequence length divisible by the sequence-shard count, validated by
     # validate_tp_overlap below.
-    tp_overlap: str = "off"
+    tp_overlap: str = "auto"
     # Rematerialisation policy (effective only with remat=True):
     # - "full": save nothing per block, recompute the whole block forward
     #   in the backward pass (max memory saving, ~+1 forward of recompute);
@@ -200,10 +207,10 @@ class ModelConfig:
                 f"moe_capacity_factor must be > 0, got "
                 f"{self.moe_capacity_factor}"
             )
-        if self.tp_overlap not in ("off", "ring", "bidir"):
+        if self.tp_overlap not in ("auto", "off", "ring", "bidir"):
             raise ValueError(
                 f"unknown tp_overlap {self.tp_overlap!r} "
-                "(expected 'off', 'ring', or 'bidir')"
+                "(expected 'auto', 'off', 'ring', or 'bidir')"
             )
         if self.remat_policy not in ("full", "dots"):
             raise ValueError(
@@ -337,13 +344,19 @@ class ModelConfig:
         if self.num_kv_heads not in (None, self.num_heads):
             raise ValueError("the hybrid family's full-attention layers are "
                              "plain MHA here (num_kv_heads == num_heads)")
-        if (self.is_moe or self.tp_overlap != "off" or self.remat
+        if (self.is_moe or self.forces_tp_ring or self.remat
                 or self.attention not in ("full", "dense")
                 or not self.causal):
             raise ValueError(
                 "the hybrid family runs causal exact attention with a dense "
                 "MLP: no experts, tp_overlap, remat, or attention modes "
                 "other than 'full'/'dense'")
+
+    @property
+    def forces_tp_ring(self) -> bool:
+        """``tp_overlap`` names a ring schedule: that route is taken or
+        the plan is refused, where "auto" would quietly stay fused."""
+        return self.tp_overlap in ("ring", "bidir")
 
     @property
     def is_hybrid(self) -> bool:
@@ -473,13 +486,15 @@ def validate_attention_parallelism(config: ModelConfig, sp: int) -> None:
 
 def validate_tp_overlap(config: ModelConfig, tp: int, pp: int = 1,
                         seq_len: int = 0, sp: int = 1) -> None:
-    """Reject tp_overlap combinations the decomposed schedule cannot run.
+    """Reject a FORCED tp_overlap schedule the decomposition cannot run
+    ("auto" and "off" pass anywhere: where a ring cannot run, "auto"
+    stays fused).
 
     The ring kernels gather/scatter the *sequence* dim over tp, so the
     knob needs a real tp axis, an even sequence split, a dense FFN (the
     MoE expert dispatch keeps its GSPMD lowering), and no pipeline (the
     pipeline engine owns its own shard_map and activation layout)."""
-    if config.tp_overlap == "off":
+    if not config.forces_tp_ring:
         return
     if tp <= 1:
         raise ValueError(
@@ -740,9 +755,9 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             "(model.n_routed_experts with layer_types: "
             "ops/routed_experts.py, no capacity and no dropped token)"
         )
-    if config.tp_overlap != "off":
+    if config.forces_tp_ring:
         raise ValueError(
-            f"serving requires model.tp_overlap='off' (got "
+            f"serving requires model.tp_overlap 'auto' or 'off' (got "
             f"{config.tp_overlap!r}): the ring schedules gather the "
             "sequence dim, which decode steps of length 1 cannot shard"
         )

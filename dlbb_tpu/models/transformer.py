@@ -409,14 +409,37 @@ def _moe_ffn(y, layer: Params, config: ModelConfig):
     return out, moe_aux_loss(probs, gates, config.moe_top_k)
 
 
-def _use_tp_overlap(config: ModelConfig, mesh) -> bool:
-    """Whether this (config, mesh) pair routes TP projections through the
-    ring-decomposed collective matmuls (``parallel/collective_matmul.py``).
-    The knob is inert without a >1 tp axis, so single-device runs and
-    non-TP meshes keep the GSPMD lowering bit for bit."""
-    return (config.tp_overlap != "off" and mesh is not None
-            and "tp" in getattr(mesh, "axis_names", ())
-            and mesh.shape["tp"] > 1)
+def tp_overlap_route(config: ModelConfig, mesh, x_shape,
+                     dtype=None) -> str:
+    """The route the four TP projections of a block take for a residual
+    stream of global shape ``x_shape`` on ``mesh``: "off" (the fused
+    GSPMD matmuls and their two all-reduces), "ring" or "bidir"
+    (``parallel/collective_matmul.py``, the stream sequence-sharded over
+    tp between blocks).
+
+    Without a >1 tp axis there is nothing to overlap, whatever the knob
+    says, so single-device runs and non-TP meshes keep the GSPMD lowering
+    bit for bit.  A forced ``config.tp_overlap`` is taken at its word
+    (``validate_tp_overlap`` has refused what cannot run).  Under "auto"
+    the shapes decide (``collective_matmul.auto_schedule``), and a program
+    no ring can take (experts, a pipeline, a sequence tp does not divide,
+    hops too small to hide) is the fused one: nothing raises."""
+    if (mesh is None or "tp" not in getattr(mesh, "axis_names", ())
+            or mesh.shape["tp"] <= 1):
+        return "off"
+    if config.tp_overlap != "auto":
+        return config.tp_overlap
+    if config.is_moe or (PP_AXIS in mesh.axis_names
+                         and mesh.shape[PP_AXIS] > 1):
+        return "off"
+    from dlbb_tpu.parallel.collective_matmul import auto_schedule
+
+    itemsize = jnp.dtype(dtype or _dtype_of(config.dtype)).itemsize
+    return auto_schedule(
+        mesh, x_shape, itemsize,
+        # column-parallel outputs, then row-parallel contractions
+        (config.qkv_width, config.ffn_intermediate,
+         config.hidden_size, config.ffn_intermediate)) or "off"
 
 
 def _block(x, layer: Params, config: ModelConfig, mesh=None,
@@ -425,22 +448,22 @@ def _block(x, layer: Params, config: ModelConfig, mesh=None,
     ``models.py:147-190``); the FFN is the gated-expert mixture when
     ``config.num_experts > 0``.
 
-    With ``tp_overlap`` on, the four TP projections run as ring-decomposed
-    collective matmuls: the residual stream x enters sequence-sharded over
-    tp, each column-parallel projection gathers it behind partial matmuls
-    (``allgather_matmul``) and each row-parallel projection returns it to
-    the sequence-sharded layout behind the same ring
+    On an overlapped route (``tp_overlap_route``) the four TP projections
+    run as ring-decomposed collective matmuls: the residual stream x
+    enters sequence-sharded over tp, each column-parallel projection
+    gathers it behind partial matmuls (``allgather_matmul``) and each
+    row-parallel projection returns it to the sequence-sharded layout
+    behind the same ring
     (``matmul_reducescatter``) — no exposed TP all-reduce remains.
 
     Returns ``(x, aux)`` — aux is the layer's MoE load-balancing loss
     (0.0 for the dense FFN)."""
-    if _use_tp_overlap(config, mesh):
+    sched = tp_overlap_route(config, mesh, x.shape, x.dtype)
+    if sched != "off":
         from dlbb_tpu.parallel.collective_matmul import (
             allgather_matmul,
             matmul_reducescatter,
         )
-
-        sched = config.tp_overlap
 
         def col(y, kernel, bias):
             return allgather_matmul(y, kernel, mesh, schedule=sched) + bias
@@ -518,7 +541,7 @@ def forward(params: Params, x: jax.Array, config: ModelConfig,
             num_microbatches=num_microbatches, with_aux=with_aux,
         )
 
-    if _use_tp_overlap(config, mesh):
+    if tp_overlap_route(config, mesh, x.shape, x.dtype) != "off":
         # pin the residual stream to the sequence-sharded-over-tp layout
         # BEFORE the scan: the carry's sharding must be stable across
         # iterations (every block returns this layout), and constraining

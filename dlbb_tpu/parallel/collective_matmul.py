@@ -27,7 +27,7 @@ Two schedules:
   direction.
 - ``bidir`` — bidirectional: both ICI directions at once.  The
   all-gather ring halves the *hop count* (two chunks arrive per step);
-  the reduce-scatter ring splits the output features in half and
+  the reduce-scatter ring splits every chunk's rows in half and
   reduces each half around opposite directions (half-sized messages
   both ways).  Wins when the schedule is latency-bound (small chunks,
   long rings) or when both link directions are otherwise idle.
@@ -49,6 +49,8 @@ all-gather; see docs/overlap.md for the audit contract).
 
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
 import jax.numpy as jnp
 from jax import lax
@@ -62,6 +64,74 @@ SCHEDULES = ("ring", "bidir")
 # grads psum over the ones present (the replicated-param reduction GSPMD
 # would otherwise insert)
 _BATCH_AXES = ("dp", "sp")
+
+
+# -- when a ring pays: the rule behind ``model.tp_overlap: auto`` ------------
+#
+# A ring moves the bytes the fused all-reduce moves; what it wins is the
+# share of its hops that a partial matmul runs beside, and what it can lose
+# is a hop too small to be anything but latency.  Both are read from shapes.
+# The numbers are from ``scripts/overlap_candidates.py`` on the four chips
+# of a v5e 2x2 host (PERF.md §6, PR 34, calls 1 and 2).
+#
+# One ICI link in one direction: bare ppermute chains took 0.0233 ms more a
+# hop for every MB between 2.6 and 10.5 MB, one way and both ways alike
+# (call 1).  The MXU's rate is the chip's published bf16 peak; a float32
+# product runs the same single bf16 pass under jax's default precision, so
+# only its bytes differ.
+LINK_BYTES_PER_S = 43e9
+MXU_FLOPS_PER_S = 197e12
+# The smallest hop and the least hidden share a chip run has shown to win:
+# the source's 1B widths at tp 4, batch 8 x sequence 512 (hops of 4 MiB,
+# share 0.335: 31.05 -> 24.12 ms a forward, call 2; its 7B, 0.67: 117.68 ->
+# 90.81; its 13B, 0.78: 216.67 -> 163.83).  Nothing smaller or narrower has
+# been measured, so there the program stays the fused one.
+MIN_HOP_BYTES = 4 << 20
+MIN_HIDDEN_SHARE = 0.33
+# The schedule the rule returns.  Call 2, ms a 13B forward: fused 216.67,
+# the compiler's own windowed decomposition of the sequence-sharded layout
+# 196.01, ``ring`` 186.41 (the first hop of both reduce-scatter rings runs
+# bare: XLA fuses the add into the next partial matmul), ``bidir`` 163.83.
+AUTO_SCHEDULE = "bidir"
+
+
+def hidden_share(shard_widths: Sequence[int], itemsize: int) -> float:
+    """Mean over a layer's rings of the share of a hop that the partial
+    matmul beside it covers, each capped at 1.
+
+    A ring chunk of ``r`` rows and ``h`` columns takes ``r * h * itemsize
+    / LINK_BYTES_PER_S`` to hop; the partial product beside it is ``2 * r
+    * h * w`` operations, ``w`` being the shard's output width in an
+    all-gather ring and its contraction width in a reduce-scatter ring.
+    The ratio is ``2 * w * link / (itemsize * mxu)``: no rows, no ``h``.
+    At bf16 it is ``w / 4,581``."""
+    per_width = 2 * LINK_BYTES_PER_S / (itemsize * MXU_FLOPS_PER_S)
+    return sum(min(1.0, w * per_width) for w in shard_widths) / len(
+        shard_widths)
+
+
+def auto_schedule(mesh: Mesh, x_shape: Sequence[int], itemsize: int,
+                  widths: Sequence[int], tp_axis: str = "tp"
+                  ) -> str | None:
+    """The schedule a layer's TP projections should take on ``mesh`` for a
+    residual stream of global shape ``x_shape``, or None for the fused
+    route.  ``widths`` are the global widths the rings shard: each
+    column-parallel projection's output and each row-parallel projection's
+    contraction.  Never raises: a shape no ring can take is fused."""
+    p = mesh.shape[tp_axis]
+    b_axis, _, sp, _ = _mesh_layout(mesh, tp_axis)
+    seq_shards = p * (mesh.shape["sp"] if sp else 1)
+    dp = mesh.shape[b_axis] if b_axis else 1
+    batch, seq, hidden = x_shape
+    if (p < 2 or seq % seq_shards or batch % dp
+            or any(w % p for w in widths)):
+        return None
+    hop_bytes = (batch // dp) * (seq // seq_shards) * hidden * itemsize
+    if hop_bytes < MIN_HOP_BYTES:
+        return None
+    if hidden_share([w // p for w in widths], itemsize) < MIN_HIDDEN_SHARE:
+        return None
+    return AUTO_SCHEDULE
 
 
 def _check_schedule(schedule: str) -> bool:
@@ -129,16 +199,20 @@ def _ag_matmul_body(x, w, axis: str, p: int, bidir: bool):
     column shard).  Row block ``src`` of the output is ``x_src @ w``;
     x chunks travel the ring while the chunk in hand is multiplied."""
     b, s, h = x.shape
-    out = jnp.zeros((b, p * s, w.shape[1]), dtype=x.dtype)
+    # one row block per source rank, written on axis 1 of a 4-D buffer:
+    # XLA:TPU then folds each write into its partial matmul's own fusion,
+    # in place, as its own windowed einsum does.  Written along the rows
+    # of a [b, p * s, f] buffer the dynamic_update_slice stayed an op of
+    # its own that copied the whole buffer at every visit: 38.5 ms of the
+    # 13B forward's 209 ms step (PERF.md §6, PR 34)
+    out = jnp.zeros((b, p, s, w.shape[1]), dtype=x.dtype)
 
     def visit(chunk, src):
         nonlocal out
-        out = lax.dynamic_update_slice_in_dim(
-            out, chunk @ w, src * s, axis=1
-        )
+        out = lax.dynamic_update_index_in_dim(out, chunk @ w, src, axis=1)
 
     _ring_visit(x, axis, p, bidir, visit)
-    return out
+    return out.reshape(b, p * s, w.shape[1])
 
 
 def _matmul_rs_body(x, w, axis: str, p: int, bidir: bool):
@@ -149,8 +223,7 @@ def _matmul_rs_body(x, w, axis: str, p: int, bidir: bool):
     The accumulator travels the ring: at each step a device adds its own
     partial product for the chunk the accumulator is destined to, so the
     partial matmul for step j+1 is independent of step j's permute."""
-    b, s, f = x.shape
-    h = w.shape[1]
+    s = x.shape[1]
     if s % p != 0:
         raise ValueError(
             f"matmul_reducescatter: local sequence {s} not divisible by "
@@ -160,35 +233,38 @@ def _matmul_rs_body(x, w, axis: str, p: int, bidir: bool):
     r = lax.axis_index(axis)
     fwd, bwd = _ring_perms(p)
 
-    def partial(c, w_shard):
-        xc = lax.dynamic_slice_in_dim(x, c * s_out, s_out, axis=1)
-        return xc @ w_shard
+    def partial(c, lo=0, rows=s_out):
+        xc = lax.dynamic_slice_in_dim(x, c * s_out + lo, rows, axis=1)
+        return xc @ w
 
-    if not bidir:
+    if not bidir or s_out < 2:
         # target of the accumulator on this device at add-step j is
         # (r + p - 1 - j) mod p; after the last add it is chunk r, fully
         # reduced.  ring_hop named scopes: see _ring_visit
-        acc = partial((r + p - 1) % p, w)
+        acc = partial((r + p - 1) % p)
         for j in range(1, p):
             with jax.named_scope(f"ring_hop_fwd{j}"):
                 acc = lax.ppermute(acc, axis, fwd)
-            acc = acc + partial((r + p - 1 - j) % p, w)
+            acc = acc + partial((r + p - 1 - j) % p)
         return acc
-    # bidirectional: front half of the output features reduces clockwise,
-    # back half counter-clockwise — half-sized messages on both ICI
-    # directions every step
-    hh = h // 2
-    w_f, w_b = w[:, :hh], w[:, hh:]
-    acc_f = partial((r + p - 1) % p, w_f)
-    acc_b = partial((r + 1) % p, w_b)
+    # bidirectional: the front half of every chunk's rows reduces
+    # clockwise, the back half counter-clockwise — half-sized messages on
+    # both ICI directions every step, and two chains whose adds wait
+    # behind each other's matmuls.  Halves of the ROWS: a half of w's
+    # columns is a copy of the layer's kernel out of the stack, every
+    # layer (7.1 ms of the 13B forward's step, PERF.md §6, PR 34)
+    half = s_out // 2
+    back = s_out - half
+    acc_f = partial((r + p - 1) % p, 0, half)
+    acc_b = partial((r + 1) % p, half, back)
     for j in range(1, p):
         with jax.named_scope(f"ring_hop_fwd{j}"):
             acc_f = lax.ppermute(acc_f, axis, fwd)
-        acc_f = acc_f + partial((r + p - 1 - j) % p, w_f)
+        acc_f = acc_f + partial((r + p - 1 - j) % p, 0, half)
         with jax.named_scope(f"ring_hop_bwd{j}"):
             acc_b = lax.ppermute(acc_b, axis, bwd)
-        acc_b = acc_b + partial((r + 1 + j) % p, w_b)
-    return jnp.concatenate([acc_f, acc_b], axis=-1)
+        acc_b = acc_b + partial((r + 1 + j) % p, half, back)
+    return jnp.concatenate([acc_f, acc_b], axis=1)
 
 
 def _ag_grad_w_body(x, dy, axis: str, p: int, bidir: bool,
@@ -439,6 +515,6 @@ def matmul_reducescatter(
 def activation_spec(mesh: Mesh, tp_axis: str = "tp") -> P:
     """PartitionSpec of the overlapped residual stream: batch over dp,
     sequence over (sp?, tp) — what ``forward`` constrains the scan carry
-    to when ``tp_overlap`` is on."""
+    to on an overlapped route."""
     b, seq_sharded, _, _ = _mesh_layout(mesh, tp_axis)
     return P(b, seq_sharded, None)

@@ -33,9 +33,11 @@ class ParallelismPlan:
     tp: int
     num_microbatches: Optional[int]
     mesh: Mesh
-    # the model's TP collective-matmul schedule ("off" | "ring" | "bidir"),
-    # copied from the resolved ModelConfig so harnesses can record it next
-    # to the mesh in result JSON
+    # the route the model's TP projections take on this mesh for the
+    # input's whole batch ("off" | "ring" | "bidir": what
+    # models/transformer.py::tp_overlap_route resolves, never the
+    # configuration's "auto"), so harnesses can record it next to the
+    # mesh in result JSON
     tp_overlap: str = "off"
 
     @classmethod
@@ -80,8 +82,15 @@ class ParallelismPlan:
             )
 
         mesh = build_parallelism_mesh(dp, sp, pp, tp, ep, devices=devices)
+        from dlbb_tpu.models.transformer import tp_overlap_route
+
+        inp = config.get("input", {})
         return cls(dp, sp, pp, ep, tp, num_microbatches, mesh,
-                   tp_overlap=model_cfg.tp_overlap)
+                   tp_overlap=tp_overlap_route(
+                       model_cfg, mesh,
+                       (inp.get("batch_size", 0),
+                        inp.get("sequence_length", 0),
+                        model_cfg.hidden_size)))
 
     def mesh_dict(self) -> dict[str, int]:
         """The result-JSON ``mesh`` field."""

@@ -401,8 +401,7 @@ def prune_point(
             validate_attention_parallelism(model_pt, point.sp)
             validate_expert_parallelism(model_pt, 1)
             validate_tp_overlap(
-                model_pt if point.tp_overlap == "off"
-                else _with_overlap(model_pt, point.tp_overlap),
+                _with_overlap(model_pt, point.tp_overlap),
                 point.tp, pp=point.pp,
                 seq_len=int(input_cfg["sequence_length"]), sp=point.sp,
             )
@@ -700,8 +699,8 @@ def _measure_train(
     from dlbb_tpu.train.loop import run_train
 
     model = dict(model_dict)
-    if point.tp_overlap != "off":
-        model["tp_overlap"] = point.tp_overlap
+    # a point's route is forced, "off" too: the plan was costed for it
+    model["tp_overlap"] = point.tp_overlap
     if point.attention is not None:
         model["attention"] = point.attention
     config = {

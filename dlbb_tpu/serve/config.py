@@ -221,7 +221,7 @@ class ServingConfig:
                 f"serving.speculation={self.speculation!r} must be one "
                 f"of {SPECULATION_MODES}"
             )
-        # speculation with tp_overlap != off or non-dense attention is
+        # speculation with a forced tp_overlap ring or non-dense attention is
         # rejected inside validate_serving (those envelopes cannot serve
         # at all); the draft plane re-runs the same gate on its own
         # config below, so a draft kv plane breaking kv_heads % tp

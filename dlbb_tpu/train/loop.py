@@ -53,6 +53,7 @@ from dlbb_tpu.models.transformer import (
     forward_flops,
     init_params_sharded,
     named,
+    tp_overlap_route,
 )
 from dlbb_tpu.obs import spans
 from dlbb_tpu.ops import mosaic_call_count
@@ -823,9 +824,13 @@ def run_train(
         "pipeline_schedule": pipeline_schedule if plan.pp > 1 else None,
         "remat": model_cfg.remat,
         "remat_policy": model_cfg.remat_policy if model_cfg.remat else None,
-        # TP collective-matmul schedule (off = GSPMD fused; ring/bidir =
-        # overlapped decomposition, docs/overlap.md)
-        "tp_overlap": model_cfg.tp_overlap,
+        # the route the TP projections TOOK for a micro-batch (off = GSPMD
+        # fused; ring/bidir = overlapped decomposition, docs/overlap.md),
+        # not the configuration's word, which may be "auto"
+        "tp_overlap": tp_overlap_route(
+            model_cfg, plan.mesh,
+            (config["input"]["batch_size"] // grad_accum,
+             config["input"]["sequence_length"], model_cfg.hidden_size)),
         "compiler_options": comp_opts or None,
         "compile_time_s": compile_time,
         "mosaic_calls": mosaic_calls,

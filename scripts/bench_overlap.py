@@ -20,7 +20,9 @@ On this image the mesh is CPU-simulated: every device is a host thread
 and a ppermute is a memcpy, so wall clocks say nothing about ICI overlap
 — the committed artifact's claim is **correctness + schedule shape**
 (equivalence is pinned by tests/test_collective_matmul.py, the permute
-chain by the comm-lint HLO audit).  On the chip: not measured.
+chain by the comm-lint HLO audit).  The chip's numbers are
+``scripts/overlap_candidates.py``'s (docs/overlap.md, "Chip measurement
+status").
 
 Usage: python scripts/bench_overlap.py [--iters N] [--reps R] [--chip]
 """

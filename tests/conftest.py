@@ -244,6 +244,28 @@ def dense_attention_ref(q, k, v, causal=True):
     return np.einsum("bnqk,bnkd->bnqd", p, v)
 
 
+def abstract_forward(cfg, mesh, shape, dtype):
+    """``models.transformer.forward`` jitted as the benchmark's job runner
+    jits it (``out_shardings`` = the batch layout), and its arguments as
+    shapes sharded on ``mesh``: ``(jitted, (params, x))``.  Nothing is
+    allocated, so a program at a cell's real widths can be lowered and
+    compiled here, for the CPU mesh or for described TPU devices."""
+    from jax.sharding import NamedSharding
+
+    from dlbb_tpu.models.sharding import batch_spec, specs_for_mesh
+    from dlbb_tpu.models.transformer import forward, init_params
+
+    params = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=NamedSharding(mesh, s)),
+        jax.eval_shape(lambda: init_params(cfg, jax.random.key(0))),
+        specs_for_mesh(mesh, "tp", moe=False))
+    sh = NamedSharding(mesh, batch_spec(mesh))
+    x = jax.ShapeDtypeStruct(shape, dtype, sharding=sh)
+    return jax.jit(lambda p, a: forward(p, a, cfg, mesh=mesh),
+                   out_shardings=sh), (params, x)
+
+
 @pytest.fixture
 def compile_cache_dir(tmp_path, monkeypatch):
     """A private persistent-cache directory, set the only way the program

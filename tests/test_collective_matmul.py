@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import abstract_forward
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from dlbb_tpu.comm.mesh import build_parallelism_mesh
@@ -332,3 +333,134 @@ def test_plan_carries_tp_overlap(devices):
            "input": {"batch_size": 4, "sequence_length": 18}}
     with pytest.raises(ValueError, match="sequence_length=18"):
         ParallelismPlan.from_config(bad, cfg)
+
+
+# -- the rule behind ``tp_overlap: auto`` -------------------------------------
+
+# the benchmark cell ``fwd13b_tp4`` (benchmarks/configs/paper13b-tp4.json x
+# fwd_b8_s512): batch 8, sequence 512, the 13B's widths, bf16
+CELL = ModelConfig(hidden_size=5120, num_layers=2, num_heads=40,
+                   ffn_intermediate=20480, attention="full",
+                   dtype="bfloat16")
+CELL_SHAPE = (8, 512, 5120)
+
+# name -> (mesh degrees, config, activation shape, the route expected);
+# None stands for ``collective_matmul.AUTO_SCHEDULE``
+ROUTE_CASES = {
+    "cell": (dict(tensor_parallel=4), CELL, CELL_SHAPE, None),
+    "cell_under_dp": (dict(data_parallel=2, tensor_parallel=4), CELL,
+                      (16, 512, 5120), None),
+    "tp1": (dict(tensor_parallel=1), CELL, CELL_SHAPE, "off"),
+    "experts": (dict(tensor_parallel=4), CELL.with_(num_experts=4),
+                CELL_SHAPE, "off"),
+    "pp2": (dict(pipeline_parallel=2, tensor_parallel=4), CELL,
+            CELL_SHAPE, "off"),
+    "seq510": (dict(tensor_parallel=4), CELL, (8, 510, 5120), "off"),
+    "audit_tiny": (dict(data_parallel=2, tensor_parallel=4), TINY,
+                   (4, 8, 64), "off"),
+    "short_hops": (dict(tensor_parallel=4), CELL, (1, 8, 5120), "off"),
+    "narrow": (dict(tensor_parallel=4),
+               CELL.with_(hidden_size=512, num_heads=4,
+                          ffn_intermediate=2048), (64, 4096, 512), "off"),
+    "forced_off": (dict(tensor_parallel=4), CELL.with_(tp_overlap="off"),
+                   CELL_SHAPE, "off"),
+    "forced_ring": (dict(tensor_parallel=4), CELL.with_(tp_overlap="ring"),
+                    CELL_SHAPE, "ring"),
+    "forced_bidir": (dict(tensor_parallel=4),
+                     CELL.with_(tp_overlap="bidir"), CELL_SHAPE, "bidir"),
+    "forced_ring_tiny": (dict(data_parallel=2, tensor_parallel=4),
+                         TINY.with_(tp_overlap="ring"), (4, 8, 64), "ring"),
+    "forced_ring_tp1": (dict(tensor_parallel=1),
+                        CELL.with_(tp_overlap="ring"), CELL_SHAPE, "off"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROUTE_CASES))
+def test_route_is_decided_from_shapes(devices, case):
+    """``tp_overlap_route``: under the default "auto" the cell's shapes
+    take the schedule the chip runs kept and every program no ring can
+    take, or whose hops are too small or too narrow to hide, is fused
+    without an error; a forced value is taken at its word wherever there
+    is a tp axis."""
+    from dlbb_tpu.models.transformer import tp_overlap_route
+    from dlbb_tpu.parallel.collective_matmul import AUTO_SCHEDULE
+
+    degrees, cfg, shape, want = ROUTE_CASES[case]
+    mesh = build_parallelism_mesh(**degrees)
+    assert tp_overlap_route(cfg, mesh, shape) == (want or AUTO_SCHEDULE)
+    assert tp_overlap_route(cfg, None, shape) == "off"
+
+
+def test_cell_forward_compiles_to_rings_by_itself(devices):
+    """The cell's forward at its widths (two layers: the layer loop's
+    body does not depend on their number), lowered and compiled, never
+    run, on the CPU mesh with tp 4 and NOTHING said about ``tp_overlap``:
+    the layer body holds the permute chain of four rings, every permute
+    under a ``ring_hop_*`` scope, and no all-reduce."""
+    from dlbb_tpu.analysis.hlo_parse import parse_collectives
+    from dlbb_tpu.parallel.collective_matmul import AUTO_SCHEDULE
+
+    assert CELL.tp_overlap == "auto"
+    mesh = build_parallelism_mesh(tensor_parallel=4)
+    fn, args = abstract_forward(CELL, mesh, CELL_SHAPE, jnp.bfloat16)
+    hlo = fn.lower(*args).compile().as_text()
+    body = [c for c in parse_collectives(hlo) if c.execution_count > 1]
+    permutes = [c for c in body if c.kind == "collective-permute"]
+    hops = {"ring": 4 * 3, "bidir": 2 * 3 + 2 * 6}[AUTO_SCHEDULE]
+    assert len(permutes) == hops, [c.op_name for c in permutes]
+    assert all("ring_hop_" in (c.op_name or "") for c in permutes), \
+        [c.op_name for c in permutes]
+    assert not [c for c in body if c.kind == "all-reduce"], \
+        "an all-reduce survived in the overlapped layer body"
+
+
+@pytest.mark.parametrize("target", ["one_device", "tiny_dp_tp"])
+def test_program_the_rule_leaves_alone_is_the_fused_one(devices, target):
+    """Where there is no tp axis, or the shapes fail the rule, "auto"
+    lowers to the text the forced fused route lowers to."""
+    if target == "one_device":
+        mesh = build_parallelism_mesh(tensor_parallel=1)
+        cfg, shape = TINY, (4, 16, 64)
+    else:  # the audits' dp x tp target (analysis/hlo_audit.py)
+        mesh = build_parallelism_mesh(data_parallel=2, tensor_parallel=4)
+        cfg, shape = TINY, (4, 8, 64)
+    texts = []
+    for route in ("auto", "off"):
+        fn, args = abstract_forward(cfg.with_(tp_overlap=route), mesh,
+                                     shape, jnp.float32)
+        texts.append(fn.lower(*args).as_text())
+    assert texts[0] == texts[1]
+    assert "collective_permute" not in texts[0]
+
+
+def test_forward_the_rule_overlaps_by_itself_equals_one_device_f32(devices):
+    """The smallest shape at which "auto" takes the rings BY ITSELF in
+    float32 (the source's 7B widths at tp 4, one layer, hops of exactly
+    ``MIN_HOP_BYTES``: half the widths or half the rows and the rule
+    says no) against the one-device forward, as
+    ``test_model.py::test_tp_forward_equals_one_device_f32`` holds the
+    fused route."""
+    from dlbb_tpu.models.transformer import tp_overlap_route
+    from dlbb_tpu.parallel.collective_matmul import AUTO_SCHEDULE
+
+    cfg = ModelConfig(hidden_size=4096, num_layers=1, num_heads=32,
+                      ffn_intermediate=16384, attention="full",
+                      dtype="float32")
+    shape = (2, 512, 4096)
+    mesh = build_parallelism_mesh(tensor_parallel=4)
+    assert tp_overlap_route(cfg, mesh, shape) == AUTO_SCHEDULE
+    assert tp_overlap_route(cfg, mesh, (1, 512, 4096)) == "off"
+    assert tp_overlap_route(
+        cfg.with_(hidden_size=2048, num_heads=16, ffn_intermediate=8192),
+        mesh, (4, 512, 2048)) == "off"
+
+    params = init_params(cfg, jax.random.key(1))
+    x = jax.random.normal(jax.random.key(0), shape, jnp.float32)
+    y_one = forward(params, x, cfg)
+    sh = NamedSharding(mesh, batch_spec(mesh))
+    fn = jax.jit(lambda p, a: forward(p, a, cfg, mesh=mesh),
+                 out_shardings=sh)
+    args = (shard_params(params, mesh), jax.device_put(x, sh))
+    assert "collective-permute" in fn.lower(*args).compile().as_text()
+    np.testing.assert_allclose(np.asarray(fn(*args)), np.asarray(y_one),
+                               rtol=1e-5, atol=1e-5)

@@ -435,22 +435,38 @@ def test_tp_forward_equals_one_device_f32(mesh2x4, layout, attention):
 @pytest.mark.parametrize("builder", [
     "_tp_forward_target", "_decode_step_target", "_prefill_chunk_target",
     "_prefill_target", "_decode_fused_target", "_verify_step_target",
-    "_decode_quant_target"])
+    "_decode_quant_target", "_tp_overlap_forward_target"])
 def test_tp_programs_hold_no_collective_permute(devices, builder):
     """The audit's own tiny ``dp x tp`` programs, compiled: the two
     row-parallel all-reduces of the layer body (as many as before the
     columns lay by group) and not one collective-permute.  A permute
     here means the column order and its reader fell apart, and on the
-    chip it was 14% of the 13B forward's step (``PERF.md`` §6, PR 32)."""
+    chip it was 14% of the 13B forward's step (``PERF.md`` §6, PR 32).
+
+    The overlapped forward, the route the 13B cell takes since PR 34, is
+    held to the same: EVERY permute it has is a ring's hop (under a
+    ``ring_hop_*`` scope of ``parallel/collective_matmul.py``), none
+    realigns q, k and v, and no all-reduce is left."""
     from collections import Counter
 
     from dlbb_tpu.analysis import hlo_audit
     from dlbb_tpu.analysis.hlo_parse import parse_collectives
+    from dlbb_tpu.parallel.collective_matmul import AUTO_SCHEDULE
 
-    fn, args = getattr(hlo_audit, builder)().build()
+    overlapped = builder == "_tp_overlap_forward_target"
+    target = getattr(hlo_audit, builder)
+    fn, args = (target(AUTO_SCHEDULE) if overlapped else target()).build()
     jitted = fn if hasattr(fn, "lower") else jax.jit(fn)
-    kinds = Counter(c.kind for c in parse_collectives(
-        jitted.lower(*args).compile().as_text()))
+    collectives = parse_collectives(jitted.lower(*args).compile().as_text())
+    kinds = Counter(c.kind for c in collectives)
+    if overlapped:
+        stray = [c.op_name for c in collectives
+                 if c.kind == "collective-permute"
+                 and "ring_hop_" not in (c.op_name or "")]
+        assert not stray, stray
+        assert kinds["collective-permute"] > 0, kinds
+        assert kinds["all-reduce"] == 0, kinds
+        return
     assert kinds["collective-permute"] == 0, kinds
     assert kinds["all-reduce"] == 2, kinds
 

@@ -621,18 +621,23 @@ def test_cache_writes_are_in_place_on_the_simulated_mesh(mesh2x4, program):
 
 
 @pytest.fixture(scope="module")
-def v5e_chip():
-    """One described (not attached) v5e chip as a 1 x 1 (dp, tp) mesh."""
+def v5e_tray():
+    """The four described (not attached) chips of a v5e 2x2 host."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     os.environ.setdefault("TPU_ACCELERATOR_TYPE", "v5litepod-4")
     os.environ.setdefault("TPU_WORKER_HOSTNAMES", "localhost")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
-                                            topology_name="v5e:2x2")
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return build_parallelism_mesh(1, 1, 1, 1, 1, devices=topo.devices[:1])
+
+
+@pytest.fixture(scope="module")
+def v5e_chip(v5e_tray):
+    """One described v5e chip as a 1 x 1 (dp, tp) mesh."""
+    return build_parallelism_mesh(1, 1, 1, 1, 1, devices=v5e_tray[:1])
 
 
 @pytest.fixture()
@@ -901,6 +906,60 @@ def test_decode_step_attends_through_the_kernel_compiled_for_the_v5e(
                 and re.sub(r"^[a-z0-9]+|\{.*$", "", m.group(2)) in layer):
             left[m.group(1)] = m.group(3)
     assert not left, f"ops of a whole layer's shape: {left}"
+
+
+def test_tp4_forward_compiled_for_the_v5e_hops_beside_its_matmuls(
+        v5e_tray, as_on_the_chip):
+    """The 13B forward of the four-chip cell (``fwd13b_tp4``; two layers:
+    the layer loop's body does not depend on their number), with nothing
+    said about ``tp_overlap``, as the TPU's own compiler schedules it.
+    This file holds it because every test that describes a TPU lives in
+    one file.  The ``tp`` axis goes round the tray (0, 1, 3, 2: in the
+    plain order two hops of four are diagonals); every ring hop of the
+    layer body has a partial matmul between its start and its done; the
+    all-gather rings' block writes are folded into those matmuls (no
+    ``dynamic-update-slice`` of its own, which on the chip copied the
+    whole output at every visit: 38.5 ms of a 209 ms step, ``PERF.md``
+    §6, PR 34); no instruction writes a kernel-sized buffer; and no
+    all-reduce is left."""
+    from conftest import abstract_forward
+
+    mesh = build_parallelism_mesh(tensor_parallel=4, devices=v5e_tray)
+    assert [d.id for d in mesh.devices.reshape(-1)] == [0, 1, 3, 2]
+    cfg = ModelConfig(hidden_size=5120, num_layers=2, num_heads=40,
+                      ffn_intermediate=20480, dtype="bfloat16",
+                      attention="full")
+    fn, args = abstract_forward(cfg, mesh, (8, 512, 5120), jnp.bfloat16)
+    hlo = fn.trace(*args).lower(
+        lowering_platforms=("tpu",)).compile().as_text()
+
+    body = [(line, name, result, op)
+            for computation, line, name, result, op in _hlo_instructions(hlo)
+            if computation.startswith("wide.region_0")]
+    assert body, "no layer loop body found"
+    ops = [op for _, _, _, op in body]
+    assert "all-reduce" not in ops and "all-reduce-start" not in ops
+    assert "dynamic-update-slice" not in ops
+    kernels = {"5120,3840]", "1280,5120]", "5120,5120]"}
+    written = [f"{op} {result}" for _, _, result, op in body
+               if op not in _PLANE_PLUMBING | {"slice-start", "slice-done",
+                                               "custom-call"}
+               and re.sub(r"\{.*$", "", result)[-10:] in kernels]
+    assert not written, written
+    open_hops, bare = {}, []
+    for line, name, _result, op in body:
+        if op == "collective-permute-start":
+            assert "ring_hop_" in line, line
+            open_hops[name] = 0
+        elif op == "collective-permute-done":
+            start = re.search(r"\(%?(collective-permute-start[\w.]*)\)",
+                              line).group(1)
+            if not open_hops.pop(start):
+                bare.append(start)
+        elif op == "fusion" and "dot_general" in line:
+            for hop in open_hops:
+                open_hops[hop] += 1
+    assert not bare, f"hops with no matmul beside them: {bare}"
 
 
 def test_toy_widths_are_refused_on_the_chip_when_the_engine_is_built(
