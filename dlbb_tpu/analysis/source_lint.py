@@ -49,7 +49,7 @@ distributed benchmark repo cares about and generic linters do not:
 - ``profiler-in-timed-region``: a profiler/tracing call —
   ``jax.profiler.*`` (``trace``, ``start_trace``, ``TraceAnnotation``,
   ``StepTraceAnnotation``), the ``utils/profiling.py`` wrappers
-  (``maybe_trace`` / ``annotate`` / ``step_annotation``), or the obs
+  (``maybe_trace`` / ``annotate``), or the obs
   device capture (``obs.capture.capture_device_trace``) — inside a timed
   region.  Profiler instrumentation perturbs the region it observes
   (xplane capture serialises device work and burns host cycles), so
@@ -144,7 +144,7 @@ _WALLCLOCK_NAMES = {
 # anything reached through a `...profiler...` attribute chain
 # (jax.profiler.trace / start_trace / TraceAnnotation / ...)
 _PROFILER_CALL_NAMES = {
-    "maybe_trace", "annotate", "step_annotation", "capture_device_trace",
+    "maybe_trace", "annotate", "capture_device_trace",
 }
 # per-iteration device->host transfers the in-loop rule flags: the
 # named trio only (float()/int() scalarisation of a device scalar moves

@@ -143,6 +143,24 @@ def _with_deadline(fn, deadline: Optional[float], label: str,
     return box["value"]
 
 
+def _launch_span(name: str, launch: int, fields=None, program=None):
+    """A span that names a launch (:meth:`ServingEngine._launch`): the
+    call itself, with the ``program`` called, or a wait of the scheduler
+    thread for a device value (``serve-decode-sync`` /
+    ``serve-prefill-sync`` / ``serve-inject-sync``), with the ``launch``
+    waited for.  ``fields()`` gives the span's own arguments (a decode
+    unit's ``k``, an admission's ``rid``).  With no tracer the shared
+    null context: no argument built, no string formatted."""
+    if spans.active() is None:
+        return spans.span(name)
+    args = fields() if fields else {}
+    args["launch"] = launch
+    if program is not None:
+        # the name the profile's "XLA Modules" line prints
+        args["program"] = f"jit_{program.__name__}"
+    return spans.span(name, **args)
+
+
 # ---------------------------------------------------------------------------
 # the engine
 # ---------------------------------------------------------------------------
@@ -171,6 +189,9 @@ class _RunStats:
     generated_tokens: int = 0
     decode_steps: int = 0       # decode steps executed (fused trips count)
     decode_units: int = 0       # host dispatches (a fused scan is ONE)
+    # calls of a jitted serving program on the scheduler's path: the
+    # next launch's number (``ServingEngine._launch``)
+    launches: int = 0
     fused_scans: int = 0
     fused_steps: int = 0
     single_steps: int = 0
@@ -250,6 +271,8 @@ class ServingEngine:
         # fleet-replica control plane for the CURRENT run (run_trace's
         # ``control=``); None outside a fleet
         self._control: Any = None
+        # the CURRENT run's counts (``_launch`` numbers its calls there)
+        self._stats = _RunStats()
         self.registry = registry if registry is not None else MetricsRegistry()
         self._requests = self.registry.labeled_counter(
             "serve_requests", "outcome",
@@ -394,10 +417,6 @@ class ServingEngine:
             self._spec_commit = family.build_spec_commit(config, mesh)
             self._inject_sampled = jax.jit(family.inject_token_sampled,
                                            donate_argnums=(0,))
-            self.registry.inc(
-                "serve_sampled_tokens", 0,
-                help="tokens committed by the sampled (temperature > 0) "
-                     "residual-sampling path")
         if serving.spec_drafting:
             self._verify = {g: family.build_verify_step(config, mesh, g)
                             for g in self._spec_gammas}
@@ -430,6 +449,34 @@ class ServingEngine:
 
     def _now(self) -> float:
         return time.perf_counter() - self._t0
+
+    # -- the launch sequence -------------------------------------------------
+
+    def _launch(self, program, *args, span: str = "serve-launch",
+                fields=None, via=None):
+        """THE call of a jitted serving program on the scheduler's path:
+        ``program(*args)``, numbered.  Every one goes through here, so
+        the run's launches are ONE sequence 0..n-1 (``stats.launches``,
+        the report's ``launches``) and the n-th launch is the n-th
+        ``jit_serve_*`` event of a profile's "XLA Modules" line.
+
+        With a tracer active the call runs inside ``span`` — the span
+        that wraps exactly this call already (``serve-decode-dispatch``,
+        ``serve-prefill-chunk``, ``serve-prefix-attach``) or the child
+        ``serve-launch`` — which carries ``fields()`` (the span's own
+        arguments, built only then), ``launch`` (the number) and
+        ``program`` (the name the profile prints).  With none it costs
+        one integer add and the shared null context.  ``via(call)`` runs
+        the call under a caller's guard (the dispatch watchdog).  The
+        number never reaches a traced value: the programs and their
+        cache keys are what they were."""
+        stats = self._stats
+        number = stats.launches
+        stats.launches = number + 1
+        with _launch_span(span, number, fields, program):
+            if via is None:
+                return program(*args)
+            return via(lambda: program(*args))
 
     # -- setup -------------------------------------------------------------
 
@@ -508,7 +555,8 @@ class ServingEngine:
             "first_logits": first_logits, "units": [],
             # the slot's recurrent state as the prompt's last chunk
             # left it: a copy of one slot, dispatched and not waited for
-            "prompt_state": self._family.slot_state(cache, np.int32(slot)),
+            "prompt_state": self._launch(self._family.slot_state, cache,
+                                         np.int32(slot)),
             "end_state": None}
 
     def _prompt_input(self, req: Request, pad_to: int) -> jax.Array:
@@ -919,7 +967,7 @@ class ServingEngine:
         queue: deque[Request] = deque()
         slots: dict[int, _SlotState] = {}
         free_slots = list(range(cfg.max_batch))
-        stats = _RunStats()
+        self._stats = stats = _RunStats()
         series: dict[str, list] = {
             "t_s": [], "queue_depth": [], "active_slots": [],
             "blocks_in_use": [], "blocks_reserved": [],
@@ -1168,9 +1216,11 @@ class ServingEngine:
         def sync_one() -> None:
             unit = inflight.popleft()
             try:
-                # ``k`` is the unit WAITED FOR — under a deeper window
-                # an older one than the unit just dispatched
-                with spans.span("serve-decode-sync", k=unit["k_exec"]):
+                # ``k`` and ``launch`` are the unit's WAITED FOR — under
+                # a deeper window an older one than the unit just
+                # dispatched
+                with _launch_span("serve-decode-sync", unit["launch"],
+                                  lambda: {"k": unit["k_exec"]}):
                     _with_deadline(
                         lambda: jax.block_until_ready(unit["ys"]),
                         unit_deadline(unit["k_exec"]),
@@ -1242,12 +1292,17 @@ class ServingEngine:
             # boundary sync below — in the per-step/window=1 cadence
             # the span therefore spans the real step wall (as PR-9's
             # did); under a deeper window the synced device time
-            # belongs to an older unit and per-unit device attribution
-            # lives in decode_step_s/per_token_s instead.  Its children
-            # tell the two apart: ``serve-decode-dispatch`` (the jit
-            # call of THIS unit) and ``serve-decode-sync`` (the wait,
-            # with the ``k`` of the unit waited for); what is left is
-            # the host bookkeeping at scan exit
+            # belongs to an older unit.  A unit's own device time is
+            # its paired execution's: ``serve-decode-dispatch`` (the jit
+            # call of THIS unit) carries the unit's ``launch`` number,
+            # and the n-th launch is the n-th ``jit_serve_*`` event of
+            # the profile's "XLA Modules" line
+            # (``docs/observability.md`` §1; ``decode_step_s`` /
+            # ``per_token_s`` are host intervals that also hold
+            # whatever was queued before the unit).
+            # ``serve-decode-sync`` is the wait, with the ``k`` and the
+            # ``launch`` of the unit waited for; what is left is the
+            # host bookkeeping at scan exit
             with spans.span("serve-decode", active=len(slots), steps=k,
                             unit=stats.decode_units):
                 if inject.fire("serve-decode-fail"):
@@ -1258,29 +1313,31 @@ class ServingEngine:
                         "injected serve-decode-fail at the decode "
                         "dispatch boundary")
 
-                def dispatch(fn):
+                def guarded(call):
                     def run():
                         if inject.fire("serve-decode-hang"):
                             # a wedged dispatch: the sleep sits on the
                             # watchdog's daemon thread, never on the
                             # engine's scheduler thread
                             time.sleep(inject.param("hang_seconds"))
-                        return fn()
-                    with spans.span("serve-decode-dispatch", k=k):
-                        return _with_deadline(run, deadline,
-                                              f"decode[k={k}]",
-                                              "serve-dispatch")
+                        return call()
+                    return _with_deadline(run, deadline, f"decode[k={k}]",
+                                          "serve-dispatch")
+
+                def dispatch(program, *args):
+                    return self._launch(program, *args,
+                                        span="serve-decode-dispatch",
+                                        fields=lambda: {"k": k},
+                                        via=guarded)
 
                 if k == 1:
                     if token_mode:
                         carry, ys = dispatch(
-                            lambda: self._decode_token(
-                                carry, self.params, self._table,
-                                active_dev))
+                            self._decode_token, carry, self.params,
+                            self._table, active_dev)
                     else:
-                        carry, ys = dispatch(
-                            lambda: self._decode(carry, self.params,
-                                                 active_dev))
+                        carry, ys = dispatch(self._decode, carry,
+                                             self.params, active_dev)
                     stats.single_steps += 1
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, 1))
@@ -1292,18 +1349,19 @@ class ServingEngine:
                                              self._active_sharding)
                     if token_mode:
                         carry, ys = dispatch(
-                            lambda: self._decode_fused_token[k](
-                                carry, self.params, self._table,
-                                active_dev, rem_dev))
+                            self._decode_fused_token[k], carry,
+                            self.params, self._table, active_dev, rem_dev)
                     else:
                         carry, ys = dispatch(
-                            lambda: self._decode_fused[k](
-                                carry, self.params, active_dev, rem_dev))
+                            self._decode_fused[k], carry, self.params,
+                            active_dev, rem_dev)
                     stats.fused_scans += 1
                     stats.fused_steps += k
                     self.registry.inc("serve_fused_scan_steps", k)
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, steps[s]))
+                # this unit's launch: its wait names it (``sync_one``)
+                launched = stats.launches - 1
                 # what the unit's steps attend over: step i of a slot
                 # reads the tokens (and the tiles) under its length + i
                 trip = np.arange(k)[:, None]
@@ -1389,12 +1447,14 @@ class ServingEngine:
                         # left it, copied before the slot is given away
                         rec = self.probed.get(slots[s].req.rid)
                         if rec is not None:
-                            rec["end_state"] = self._family.slot_state(
-                                carry[0], np.int32(s))
+                            rec["end_state"] = self._launch(
+                                self._family.slot_state, carry[0],
+                                np.int32(s))
                 done_states = [release(s) for s in completions]
                 if completions:
                     refresh_active()
                 inflight.append({"t0": t0, "ys": ys, "k_exec": k,
+                                 "launch": launched,
                                  "rows": rows, "counts": counts,
                                  "tokens": ys_are_tokens,
                                  "completions": done_states})
@@ -1449,14 +1509,17 @@ class ServingEngine:
                         "injected serve-decode-fail at the verify "
                         "dispatch boundary")
 
-                def dispatch(fn):
+                def guarded(call):
                     def run():
                         if inject.fire("serve-decode-hang"):
                             time.sleep(inject.param("hang_seconds"))
-                        return fn()
+                        return call()
                     return _with_deadline(run, deadline,
                                           f"verify[gamma={g}]",
                                           "serve-dispatch")
+
+                def dispatch(program, *args):
+                    return self._launch(program, *args, via=guarded)
 
                 rem_np = np.zeros((cfg.max_batch,), np.int32)
                 for s, _ in rows:
@@ -1478,9 +1541,9 @@ class ServingEngine:
                                           self._active_sharding)
                     t_d = time.perf_counter()
                     dcache, ids = dispatch(
-                        lambda: self._draft_scan[g](
-                            draft_cache[0], self._draft_params,
-                            self._table, carry[1], dlen, active_dev))
+                        self._draft_scan[g], draft_cache[0],
+                        self._draft_params, self._table, carry[1], dlen,
+                        active_dev)
                     draft_cache[0] = dcache
                     # host dispatch wall only — the proposals stay on
                     # device and flow straight into the verify
@@ -1498,12 +1561,14 @@ class ServingEngine:
                     # deterministic drafter's one-hot), and the tiny
                     # spec_commit program applies the decided commits
                     carry, y = dispatch(
-                        lambda: self._verify_probs[g](
-                            carry, self.params, self._table, ids,
-                            active_dev))
-                    y_np = _with_deadline(
-                        lambda: np.asarray(y), deadline,
-                        f"verify[gamma={g}]", "serve-sync")
+                        self._verify_probs[g], carry, self.params,
+                        self._table, ids, active_dev)
+                    with _launch_span("serve-decode-sync",
+                                      stats.launches - 1,
+                                      lambda: {"k": g + 1}):
+                        y_np = _with_deadline(
+                            lambda: np.asarray(y), deadline,
+                            f"verify[gamma={g}]", "serve-sync")
                     ids_np = (np.asarray(ids)
                               if cfg.speculation == "draft-model"
                               else drafts_np)
@@ -1538,19 +1603,18 @@ class ServingEngine:
                     com_dev = jax.device_put(jnp.asarray(commits_np),
                                              self._active_sharding)
                     carry = dispatch(
-                        lambda: self._spec_commit(
-                            carry, self._table, next_dev, com_dev,
-                            active_dev))
-                    self.registry.inc("serve_sampled_tokens",
-                                      int(commits_np.sum()))
+                        self._spec_commit, carry, self._table, next_dev,
+                        com_dev, active_dev)
                 else:
                     carry, tok, commits = dispatch(
-                        lambda: self._verify[g](
-                            carry, self.params, self._table, ids,
-                            active_dev, rem_dev))
-                    commits_np = _with_deadline(
-                        lambda: np.asarray(commits), deadline,
-                        f"verify[gamma={g}]", "serve-sync")
+                        self._verify[g], carry, self.params, self._table,
+                        ids, active_dev, rem_dev)
+                    with _launch_span("serve-decode-sync",
+                                      stats.launches - 1,
+                                      lambda: {"k": g + 1}):
+                        commits_np = _with_deadline(
+                            lambda: np.asarray(commits), deadline,
+                            f"verify[gamma={g}]", "serve-sync")
                 t_ready = time.perf_counter()
                 dt = t_ready - max(t0, last_sync[0])
                 last_sync[0] = t_ready
@@ -1961,29 +2025,29 @@ class ServingEngine:
                         # matched chunks' prefill dispatches (the TTFT
                         # win), and its returned fp prefix carry is
                         # exactly what those chunks would have produced
-                        with spans.span("serve-prefix-attach",
-                                        rid=req.rid, slot=slot,
-                                        donor=plan["donor"],
-                                        blocks=plan["attach_blocks"]):
-                            cache, prefix = self._attach_jit(m_chunks)(
-                                cache, np.int32(plan["donor"]),
-                                np.int32(slot))
+                        cache, prefix = self._launch(
+                            self._attach_jit(m_chunks), cache,
+                            np.int32(plan["donor"]), np.int32(slot),
+                            span="serve-prefix-attach",
+                            fields=lambda: {
+                                "rid": req.rid, "slot": slot,
+                                "donor": plan["donor"],
+                                "blocks": plan["attach_blocks"]})
                         plan["attached_tokens"] = m_chunks * chunk
                     else:
                         prefix = self._create_prefix()
                     lasts = []
                     for ci in range(m_chunks, n_chunks):
-                        with spans.span("serve-prefill-chunk",
-                                        rid=req.rid, chunk=ci,
-                                        seq=stats.prefill_chunks):
-                            cache, prefix, y_last = \
-                                self._chunk_jit(ci)(
-                                    cache, prefix,
-                                    self.params,
-                                    x_prompt[:, ci * chunk:
-                                             (ci + 1) * chunk],
-                                    np.int32(slot),
-                                    np.int32(req.prompt_len))
+                        cache, prefix, y_last = self._launch(
+                            self._chunk_jit(ci), cache, prefix,
+                            self.params,
+                            x_prompt[:, ci * chunk:(ci + 1) * chunk],
+                            np.int32(slot), np.int32(req.prompt_len),
+                            span="serve-prefill-chunk",
+                            fields=lambda: {
+                                "rid": req.rid, "chunk": ci,
+                                "seq": stats.prefill_chunks})
+                        launched = stats.launches - 1
                         lasts.append(y_last)
                         stats.prefill_chunks += 1
                         self.registry.inc("serve_prefill_chunks")
@@ -2009,7 +2073,9 @@ class ServingEngine:
                                     "failed closed)")
                             cache = carry[0]
                     carry = (cache, carry[1])
-                    jax.block_until_ready(y_last)
+                    with _launch_span("serve-prefill-sync", launched,
+                                      lambda: {"rid": req.rid}):
+                        jax.block_until_ready(y_last)
                     # the interleaved units' dispatch+sync time is
                     # already billed to decode_step_s/per_token_s —
                     # keep prefill_s a PREFILL cost
@@ -2025,20 +2091,25 @@ class ServingEngine:
                 with spans.span("serve-prefill", rid=req.rid,
                                 bucket=bucket, slot=slot):
                     t0 = time.perf_counter()
-                    cache, y_last = self._prefill_jit(bucket)(
-                        carry[0], self.params, x_prompt,
-                        np.int32(slot), np.int32(req.prompt_len))
+                    cache, y_last = self._launch(
+                        self._prefill_jit(bucket), carry[0], self.params,
+                        x_prompt, np.int32(slot),
+                        np.int32(req.prompt_len))
+                    launched = stats.launches - 1
                     if self._draft_prefill is not None:
                         # the draft plane is prefilled at admission from
                         # the SAME prompt embeddings (idempotent masked
                         # writes, so the retry wrapper covers it); its
                         # cost is billed as prefill — the admission
                         # price of the draft model
-                        dcache, _dy = self._draft_prefill(
-                            draft_cache[0], self._draft_params, x_prompt,
-                            np.int32(slot), np.int32(req.prompt_len))
+                        dcache, _dy = self._launch(
+                            self._draft_prefill, draft_cache[0],
+                            self._draft_params, x_prompt, np.int32(slot),
+                            np.int32(req.prompt_len))
                         draft_cache[0] = dcache
-                    jax.block_until_ready(y_last)
+                    with _launch_span("serve-prefill-sync", launched,
+                                      lambda: {"rid": req.rid}):
+                        jax.block_until_ready(y_last)
                     dt = time.perf_counter() - t0
                 carry = (cache, carry[1])
             return bucket, y_last, dt
@@ -2266,25 +2337,33 @@ class ServingEngine:
                                 # token is drawn from their softmax, and
                                 # the device only embeds the committed id
                                 # (once per ADMISSION, not per token)
-                                # comm-lint: disable=host-transfer-in-loop
-                                p0 = softmax_np(np.asarray(y_last),
-                                                cfg.temperature)
+                                with _launch_span("serve-inject-sync",
+                                                  stats.launches - 1,
+                                                  lambda: {"rid": req.rid}):
+                                    # comm-lint: disable=host-transfer-in-loop
+                                    y_np = np.asarray(y_last)
+                                p0 = softmax_np(y_np, cfg.temperature)
                                 first_id = int(sample_rng.choice(
                                     p0.shape[-1], p=p0))
-                                carry = self._inject_sampled(
-                                    carry, np.int32(slot),
-                                    np.int32(first_id), self._table)
+                                carry = self._launch(
+                                    self._inject_sampled, carry,
+                                    np.int32(slot), np.int32(first_id),
+                                    self._table)
                             elif token_mode:
                                 # greedy token inject: argmax on device, a
                                 # 4-byte id to host — the history seed AND
                                 # the equivalence capture in one transfer
-                                carry, first_tok = self._inject_greedy(
-                                    carry, np.int32(slot), y_last,
-                                    self._table)
-                                first_id = int(first_tok)
+                                carry, first_tok = self._launch(
+                                    self._inject_greedy, carry,
+                                    np.int32(slot), y_last, self._table)
+                                with _launch_span("serve-inject-sync",
+                                                  stats.launches - 1,
+                                                  lambda: {"rid": req.rid}):
+                                    first_id = int(first_tok)
                             else:
-                                carry = self._inject(carry, np.int32(slot),
-                                                     y_last)
+                                carry = self._launch(
+                                    self._inject, carry, np.int32(slot),
+                                    y_last)
                             recycled = slot in used_slots
                             used_slots.add(slot)
                             if recycled:
@@ -2349,16 +2428,24 @@ class ServingEngine:
                             stats.generated_tokens += 1
                             scheduled = True
                             if self.capture_tokens:
-                                # device-side argmax: a 4-byte scalar comes
-                                # to host per admission, never the whole
-                                # hidden state (host-transfer-in-loop)
-                                tokens_by_rid.setdefault(req.rid, []).append(
-                                    first_id if token_mode
+                                if not token_mode:
+                                    # device-side argmax: a 4-byte scalar
+                                    # comes to host per admission, never
+                                    # the whole hidden state
+                                    # (host-transfer-in-loop); it runs
+                                    # behind the newest launch, the inject
                                     # (a probing family's ``last`` may
                                     # carry more behind the logits)
-                                    else int(jnp.argmax(
-                                        self._family.probe_parts(y_last)[0]
-                                        if self._probes else y_last)))
+                                    with _launch_span(
+                                            "serve-inject-sync",
+                                            stats.launches - 1,
+                                            lambda: {"rid": req.rid}):
+                                        first_id = int(jnp.argmax(
+                                            self._family.probe_parts(
+                                                y_last)[0]
+                                            if self._probes else y_last))
+                                tokens_by_rid.setdefault(
+                                    req.rid, []).append(first_id)
                             self._event(
                                 "request-prefill", req.rid, slot=slot,
                                 bucket=bucket,
@@ -2484,6 +2571,9 @@ class ServingEngine:
             "generated_tokens": stats.generated_tokens,
             "decode_steps": stats.decode_steps,
             "decode_units": stats.decode_units,
+            # calls of a jitted serving program (``_launch``): the span
+            # file's ``launch`` arguments run 0..launches-1
+            "launches": stats.launches,
             # share of the K/V planes' tiles the decode steps fetched
             f"{self._tiles_of}_live_share": (
                 stats.kv_tiles_live / stats.kv_tiles_held

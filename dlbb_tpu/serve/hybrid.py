@@ -790,8 +790,11 @@ def decode_programs(config: ModelConfig, mesh: Mesh, ks: tuple[int, ...],
     scheduler's signature ``(carry, params, active[, remaining]) ->
     (carry, ys)``: ``probe()`` gives the probed slots ``[PROBES]`` each
     program takes as its last argument, and ``ys`` is the triple
-    ``(tokens, seen of the probed slots, counts)``."""
+    ``(tokens, seen of the probed slots, counts)``.  Each goes by the
+    name of the jitted program it calls (``ServingEngine._launch``
+    reads it)."""
     def bound(program):
+        @named(program.__name__)
         def call(carry, params, *masks):
             carry, *ys = program(carry, params, *masks, probe())
             return carry, tuple(ys)

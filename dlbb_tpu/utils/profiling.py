@@ -19,7 +19,6 @@ Surface, mirroring the reference's env-switched design:
   (``utils/timing.py`` wraps its warmup/measure loops in these; every
   ``obs/spans.py`` span of an active tracer opens one, which is how
   ``train/loop.py`` and ``serve/engine.py`` get theirs).
-- ``step_annotation(name, step)`` — per-step annotation for training loops.
 
 This module is one of the two sanctioned profiler API homes (with
 ``dlbb_tpu/obs/capture.py``): the ``profiler-in-timed-region`` comm-lint
@@ -39,7 +38,7 @@ from typing import Iterator, Optional
 # numpy-only by design (cli.py lazy-imports per branch) and must not pay
 # the jax import just because this module is on their import path.
 
-__all__ = ["maybe_trace", "annotate", "step_annotation", "default_trace_dir"]
+__all__ = ["maybe_trace", "annotate", "default_trace_dir"]
 
 
 def default_trace_dir() -> Optional[str]:
@@ -74,11 +73,3 @@ def annotate(name: str, **args):
     import jax
 
     return jax.profiler.TraceAnnotation(name, **args)
-
-
-def step_annotation(name: str, step: int):
-    """Per-step region for training/benchmark loops (groups device ops
-    under one step in the trace viewer)."""
-    import jax
-
-    return jax.profiler.StepTraceAnnotation(name, step_num=step)

@@ -10,7 +10,6 @@ from dlbb_tpu.utils.profiling import (
     annotate,
     default_trace_dir,
     maybe_trace,
-    step_annotation,
 )
 
 
@@ -29,7 +28,7 @@ def test_maybe_trace_writes_xplane(devices, tmp_path):
         assert resolved == trace_dir
         with annotate("measure"):
             for i in range(2):
-                with step_annotation("step", i):
+                with annotate("step", step=i):
                     y = jax.jit(lambda x: x @ x)(jnp.ones((64, 64)))
                     jax.block_until_ready(y)
     assert _xplane_files(trace_dir), "no xplane trace emitted"
