@@ -372,7 +372,8 @@ def merge_reports(partial: dict[str, Any],
     merged["decode_step_time"] = summarize(raw["decode_step_s"])
 
     for key in ("completed_output_tokens", "generated_tokens",
-                "decode_steps", "decode_units", "launches",
+                "decode_steps", "decode_units", "decode_units_overlapped",
+                "launches",
                 "wall_seconds",
                 "compile_time_s"):
         merged[key] = partial.get(key, 0) + resumed.get(key, 0)

@@ -189,6 +189,9 @@ class _RunStats:
     generated_tokens: int = 0
     decode_steps: int = 0       # decode steps executed (fused trips count)
     decode_units: int = 0       # host dispatches (a fused scan is ONE)
+    # single-step units settled after a later launch went out: the
+    # device found its next program queued when the step ended
+    decode_units_overlapped: int = 0
     # calls of a jitted serving program on the scheduler's path: the
     # next launch's number (``ServingEngine._launch``)
     launches: int = 0
@@ -301,6 +304,10 @@ class ServingEngine:
             ("serve_fused_scan_steps",
              "decode steps executed inside fused lax.scan dispatches"),
             ("serve_prefill_chunks", "prefill chunks processed"),
+            ("serve_decode_units_overlapped",
+             "single-step decode units waited for only after a later "
+             "program (a prompt chunk, a prefix attach) was launched "
+             "behind them"),
             ("serve_hung_dispatches",
              "decode units abandoned by the dispatch watchdog"),
             ("serve_input_ready",
@@ -1018,7 +1025,11 @@ class ServingEngine:
         # silent skip (the serving twin of the sweep quarantine)
         failed_detail: list[dict[str, Any]] = []
         # bounded in-flight window: decode units dispatched but not yet
-        # synced (cfg.inflight_window == 1 syncs every unit);
+        # synced.  A fused scan waits there for the window's boundary
+        # (``cfg.inflight_window``); a single step waits for the next
+        # program that donates the whole carry (``settle_aliased``) or
+        # for a boundary that drains, so that what donates the cache
+        # alone (a prompt chunk, a prefix attach) goes out behind it;
         # last_sync anchors the per-unit interval so
         # back-to-back units never double-count queued device time
         inflight: deque[dict[str, Any]] = deque()
@@ -1232,6 +1243,10 @@ class ServingEngine:
             dt = t_ready - max(unit["t0"], last_sync[0])
             last_sync[0] = t_ready
             stats.decode_step_s.append(dt)
+            if unit["k_exec"] == 1 and stats.launches > unit["next_launch"]:
+                # the device found a program queued behind this step
+                stats.decode_units_overlapped += 1
+                self.registry.inc("serve_decode_units_overlapped")
             per_step = dt / unit["k_exec"]
             step_ema[0] = (per_step if step_ema[0] == 0.0
                            else 0.5 * step_ema[0] + 0.5 * per_step)
@@ -1275,11 +1290,29 @@ class ServingEngine:
             while inflight:
                 sync_one()
 
+        def settle_aliased() -> None:
+            """Before a launch that donates the WHOLE carry (a decode or
+            verify unit, an inject).  A single step's ``ys`` is the same
+            logical value as the carry's ``x`` (``decode_step`` returns
+            ``((cache, y), y)``); where donation is honoured the two
+            outputs may alias one buffer, and the launch would delete
+            the held ``ys``.  So a single step still in flight (it can
+            only be the window's newest unit) is settled first, with
+            whatever was dispatched before it.  A fused scan's stacked
+            ``ys`` is a buffer of its own and stays."""
+            if inflight and inflight[-1]["k_exec"] == 1:
+                drain()
+
         def decode_unit(k: int, steps: dict[int, int],
                         snap: dict[str, Any]) -> None:
             """One decode unit, committed: the device dispatch (under
             the watchdog when armed), torn-protected host bookkeeping,
-            and the in-flight window push + boundary sync.  Transient
+            and the in-flight window push.  A fused scan then syncs the
+            window down to its bound; a single step is left in flight
+            and settled by whoever next donates the whole carry
+            (``settle_aliased``: ``dispatch_decode``, an inject) or
+            drains (``prefill_once`` behind each chunk, a fault path,
+            the loop's exit).  Transient
             bookkeeping faults roll themselves back and replay (pure
             host recomputation — the device result is already in hand,
             so NEVER a re-dispatch); everything else raises out to
@@ -1288,11 +1321,10 @@ class ServingEngine:
             rows: list[tuple[int, int, int, int]] = []
             deadline = unit_deadline(k)
             t0 = time.perf_counter()
-            # ONE span per dispatched unit, covering dispatch AND the
-            # boundary sync below — in the per-step/window=1 cadence
-            # the span therefore spans the real step wall (as PR-9's
-            # did); under a deeper window the synced device time
-            # belongs to an older unit.  A unit's own device time is
+            # ONE span per dispatched unit, covering the dispatch and,
+            # after a fused scan, the boundary sync below, whose device
+            # time belongs to an older unit; a single step's wait comes
+            # later and outside it.  A unit's own device time is
             # its paired execution's: ``serve-decode-dispatch`` (the jit
             # call of THIS unit) carries the unit's ``launch`` number,
             # and the n-th launch is the n-th ``jit_serve_*`` event of
@@ -1455,19 +1487,23 @@ class ServingEngine:
                     refresh_active()
                 inflight.append({"t0": t0, "ys": ys, "k_exec": k,
                                  "launch": launched,
+                                 "next_launch": stats.launches,
                                  "rows": rows, "counts": counts,
                                  "tokens": ys_are_tokens,
                                  "completions": done_states})
-                # a k==1 unit's y is the SAME logical value as the
-                # carry's x (decode_step returns ((cache, y), y)); on
-                # donation-honoring backends the duplicate outputs may
-                # alias one buffer, and the next dispatch donating the
-                # carry would invalidate the held ys — so per-step
-                # units never stay in flight (a fused scan's stacked
-                # ys is its own buffer and may)
-                window = 1 if k == 1 else cfg.inflight_window
-                while len(inflight) >= window:
-                    sync_one()
+                # who donates what decides where a unit is waited for.
+                # The decode programs, the verify units and the injects
+                # donate the whole carry; a prompt chunk and the prefix
+                # attach donate the cache alone and never touch
+                # ``carry[1]``.  So a single step, whose ``ys`` may
+                # alias that ``x``, is settled before the next program
+                # of the first kind (``settle_aliased``) and not here:
+                # a chunk sliced and called meanwhile is queued behind
+                # it and starts the moment the step ends.  A fused scan
+                # rides the window
+                if k > 1:
+                    while len(inflight) >= cfg.inflight_window:
+                        sync_one()
 
         def spec_unit(g: int, drafts_np: np.ndarray,
                       snap: dict[str, Any]) -> None:
@@ -1836,6 +1872,13 @@ class ServingEngine:
             a full fused scan between chunks would re-create the
             head-of-line blocking the interleave exists to remove).
 
+            Every decode program donates the whole carry, so a single
+            step still in flight is settled first (``settle_aliased``);
+            its watchdog may fail the resident batch closed, and then
+            there is nothing to dispatch.  The unit dispatched here is
+            waited for by ``decode_unit`` (a fused scan, at the
+            window's bound) or by a later settle (a single step).
+
             Hardened (docs/resilience.md, serving faults): a
             transiently-failed dispatch rolls the host ledger/slot
             state back to the pre-dispatch snapshot and re-issues with
@@ -1846,6 +1889,9 @@ class ServingEngine:
             its daemon thread and the engine continues on a fresh
             carry."""
             nonlocal carry
+            settle_aliased()
+            if not slots:
+                return
             refresh_active()
             if (spec_on and max_k is None
                     and (control is None or control.spec_enabled)):
@@ -1993,6 +2039,18 @@ class ServingEngine:
             wrapper below; idempotent on retry:
             chunk writes are deterministic block writes of identical
             values, and interleaved decode units commit independently.
+            A chunk (and the prefix attach) donates the cache alone, so
+            it is called BEHIND whatever decode unit is in flight (the
+            loop's own step at the admission's head, the interleaved
+            step of the chunk before) and the window is drained only
+            then: the device finds the chunk queued when the step ends.
+            The next interleaved step, which donates the whole carry,
+            goes out after that drain; after the last chunk the drain
+            comes before ``serve-prefill-sync``, so the window is empty
+            when the first token is injected.  A drain whose watchdog
+            abandons the window resets the carry under a chunk already
+            sent on the old cache: the prefill restarts, as after a
+            failed interleaved dispatch.
             With a prefix-attach ``plan``, the matched chunks' prefills
             are replaced by ONE donor-block copy (``build_prefix_attach``)
             and only the suffix chunks run; a carry reset since planning
@@ -2037,6 +2095,7 @@ class ServingEngine:
                     else:
                         prefix = self._create_prefix()
                     lasts = []
+                    resets = carry_resets[0]
                     for ci in range(m_chunks, n_chunks):
                         cache, prefix, y_last = self._launch(
                             self._chunk_jit(ci), cache, prefix,
@@ -2048,31 +2107,35 @@ class ServingEngine:
                                 "rid": req.rid, "chunk": ci,
                                 "seq": stats.prefill_chunks})
                         launched = stats.launches - 1
+                        carry = (cache, carry[1])
                         lasts.append(y_last)
                         stats.prefill_chunks += 1
                         self.registry.inc("serve_prefill_chunks")
+                        # the chunk is queued: now wait for the step
+                        # dispatched before it, then interleave the next
+                        # one (the resident batch decodes between chunks
+                        # instead of head-of-line blocking); both are
+                        # decode time, not this prefill's
+                        td = time.perf_counter()
+                        if inflight:
+                            with spans.span("serve-drain",
+                                            inflight=len(inflight)):
+                                drain()
                         if ci < n_chunks - 1 and slots:
-                            # interleave: the resident batch decodes
-                            # between chunks instead of head-of-line
-                            # blocking
-                            carry = (cache, carry[1])
-                            td = time.perf_counter()
-                            resets = carry_resets[0]
                             dispatch_decode(max_k=1)
-                            decode_spent += time.perf_counter() - td
-                            if carry_resets[0] != resets:
-                                # the resident batch failed and took the
-                                # carry with it — this request's chunks
-                                # 0..ci died in the old cache; restart
-                                # the prefill on the fresh carry (chunk
-                                # writes are deterministic, so a replay
-                                # is exact) via the retry wrapper
-                                raise TransientFault(
-                                    "carry reset during the chunked-"
-                                    "prefill interleave (resident batch "
-                                    "failed closed)")
-                            cache = carry[0]
-                    carry = (cache, carry[1])
+                        decode_spent += time.perf_counter() - td
+                        if carry_resets[0] != resets:
+                            # the resident batch failed and took the
+                            # carry with it — this request's chunks
+                            # 0..ci died in the old cache; restart
+                            # the prefill on the fresh carry (chunk
+                            # writes are deterministic, so a replay
+                            # is exact) via the retry wrapper
+                            raise TransientFault(
+                                "carry reset during the chunked-"
+                                "prefill interleave (resident batch "
+                                "failed closed)")
+                        cache = carry[0]
                     with _launch_span("serve-prefill-sync", launched,
                                       lambda: {"rid": req.rid}):
                         jax.block_until_ready(y_last)
@@ -2161,6 +2224,12 @@ class ServingEngine:
             fail_requests([_SlotState(req=req, tokens_done=0)], exc,
                           "dispatch-failed")
             if dispatched and not isinstance(exc, InjectedFault):
+                # units dispatched before the failed program are sound:
+                # settle them, then fail what is resident
+                try:
+                    drain()
+                except Exception:  # noqa: BLE001
+                    inflight.clear()
                 fail_resident(exc, "dispatch-failed")
                 carry = self._fresh_carry()
                 draft_cache[0] = self._fresh_draft_cache()
@@ -2268,11 +2337,16 @@ class ServingEngine:
                             deadline_s=req.deadline_s)
             scheduled = False
             if queue and free_slots:
-                # scan boundary: settle in-flight decode before the
+                # scan boundary: in-flight decode is settled before the
                 # prefill blocks, so its sync cost lands in decode
-                # timing and TTFT stays honest
-                with spans.span("serve-drain", inflight=len(inflight)):
-                    drain()
+                # timing and TTFT stays honest.  A bucketed prefill
+                # settles it here; a chunked one behind its first chunk
+                # (``prefill_once``), so that the device has that chunk
+                # queued while the host takes the input, builds the
+                # prefix and calls it
+                if cfg.prefill_chunk is None:
+                    with spans.span("serve-drain", inflight=len(inflight)):
+                        drain()
                 # one child span per step of an admission, each with
                 # the request's ``rid`` and its ``slot``: plan, embed
                 # and prefill (inside ``prefill_once``), inject, book —
@@ -2329,6 +2403,10 @@ class ServingEngine:
                         with spans.span("serve-admit-inject", rid=req.rid,
                                         slot=slot):
                             first_id = -1
+                            # every inject donates the whole carry: no
+                            # single step may be in flight behind it
+                            # (``prefill_once`` left the window empty)
+                            settle_aliased()
                             if token_mode and self._sampled:
                                 # sampled inject: position 0 obeys the same
                                 # temperature law as every later token —
@@ -2571,6 +2649,9 @@ class ServingEngine:
             "generated_tokens": stats.generated_tokens,
             "decode_steps": stats.decode_steps,
             "decode_units": stats.decode_units,
+            # single steps waited for after a later launch went out (a
+            # prompt chunk queued behind them): of ``decode_units``
+            "decode_units_overlapped": stats.decode_units_overlapped,
             # calls of a jitted serving program (``_launch``): the span
             # file's ``launch`` arguments run 0..launches-1
             "launches": stats.launches,

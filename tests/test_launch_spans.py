@@ -2,7 +2,9 @@
 ``docs/observability.md`` §1): every call of a jitted serving program on
 the scheduler's path is a numbered span, every wait of the scheduler
 thread for a device value names the launch it waits for, and with no
-tracer the helper costs a count and the shared null context.
+tracer the helper costs a count and the shared null context.  Since
+PR 36 a single decode step is waited for only before the next program
+that donates the whole carry: a prompt chunk goes out behind it.
 """
 
 from __future__ import annotations
@@ -69,6 +71,43 @@ def _trace(n=5):
         for i in range(n)))
 
 
+def _backlog(n=14):
+    """More requests than the 8 slots, all due at once.  The first
+    eight fill the slots with one-chunk prompts, two of them one token
+    from their end: the loop's first unit is a single step that
+    completes them, and the admissions of the six that waited (prompts
+    of two to four chunks) begin with that step still in flight."""
+    return TrafficTrace(kind="test", seed=0, params={}, requests=tuple(
+        Request(rid=i, arrival_s=0.0,
+                prompt_len=6 if i < 8 else 6 + 5 * (i % 6),
+                output_len=2 + 3 * (i % 4) if i < 8 else 5 + 2 * (i % 5),
+                seed=40 + i, prompt_period=3)
+        for i in range(n)))
+
+
+TRACES = {"few": _trace, "backlog": _backlog}
+# the programs of ``_trace()`` by launch number, as the tree before
+# PR 36 launched them: ``c<offset>`` a prompt chunk, ``s`` a single
+# decode step, ``i`` the inject, ``p`` a probed slot's state copied,
+# ``k<K>`` a fused scan; a bar where an admission ends
+SEQUENCES = {
+    "gpt": "c0 i | c0 s c8 i | c0 s c8 s c16 i | c0 s c8 s c16 i | "
+           "c0 s c8 s c16 s c24 i | k16 k8 k4 k2 s",
+    "hybrid": "c0 i p | c0 s c8 i | c0 s c8 s c16 i | c0 s c8 s c16 i p | "
+              "c0 s c8 s c16 s c24 i | k16 p k8 k4 p k2 s",
+}
+# every jit built with the whole carry as its donated argument 0
+DONATES_CARRY = re.compile(r"^jit_serve_(decode|inject|spec_)")
+
+
+def _programs(sequence):
+    names = {"s": "jit_serve_decode_step", "i": "jit_serve_inject",
+             "p": "jit_serve_probe_state"}
+    return [names.get(t) or (f"jit_serve_prefill_chunk_o{t[1:]}"
+                             if t[0] == "c" else f"jit_serve_decode_{t}")
+            for t in sequence.replace("|", " ").split()]
+
+
 def _compiled(engine) -> set[str]:
     """The names, as a profile prints them, of every program the engine
     holds a jit of."""
@@ -84,12 +123,13 @@ def _compiled(engine) -> set[str]:
     return {f"jit_{p.__name__}" for p in programs if p is not None}
 
 
-def _begins(engine, path):
+def _begins(engine, path, trace=None):
+    trace = trace or _trace()
     with spans.tracing(path):
-        report = engine.run_trace(_trace())
+        report = engine.run_trace(trace)
     events = spans.load_trace(path)["traceEvents"]
     assert spans.validate_trace_events(events) == []
-    assert report["requests"]["completed"] == 5
+    assert report["requests"]["completed"] == len(trace)
     return report, [ev for ev in events if ev["ph"] == "B"]
 
 
@@ -162,6 +202,83 @@ def test_every_wait_names_a_launch_made_before_it(kind, mesh2x4, tmp_path):
         assert older_than_newest > 0
         assert all(span_of[ev["args"]["launch"]] == "serve-decode-dispatch"
                    for ev in decode_waits)
+
+
+@pytest.mark.parametrize("kind", sorted(SEQUENCES))
+def test_interleaved_step_is_waited_for_behind_the_next_chunk(
+        kind, mesh2x4, tmp_path):
+    """Chunk c, step c, chunk c + 1, step c + 1: the programs go out in
+    the order they always did, and the wait for step c begins after the
+    call of chunk c + 1 and ends before step c + 1 (or the inject) is
+    called."""
+    engine = _engine(kind, mesh2x4)
+    report, begins = _begins(engine, tmp_path / "spans.json")
+    at = {id(ev): i for i, ev in enumerate(begins)}
+    launches = [ev for ev in begins if "program" in ev.get("args", {})]
+    assert [ev["args"]["program"] for ev in launches] == \
+        _programs(SEQUENCES[kind])
+    wait_of = {ev["args"]["launch"]: at[id(ev)] for ev in begins
+               if ev["name"] == "serve-decode-sync"}
+    interleaved = 0
+    for step, chunk, after in zip(launches, launches[1:], launches[2:]):
+        if (step["name"], chunk["name"]) != (
+                "serve-decode-dispatch", "serve-prefill-chunk"):
+            continue
+        interleaved += 1
+        assert step["args"]["k"] == 1
+        assert at[id(chunk)] < wait_of[step["args"]["launch"]] < \
+            at[id(after)], step
+    # one a non-final chunk dispatched with slots resident
+    assert interleaved == SEQUENCES[kind].count("s c") == 8
+    assert report["decode_units_overlapped"] == interleaved
+
+
+@pytest.mark.parametrize("kind,traffic", [
+    ("gpt", "few"), ("hybrid", "few"), ("gpt-ngram", "few"),
+    ("gpt", "backlog"), ("hybrid", "backlog"), ("gpt-ngram", "backlog")])
+def test_no_carry_is_donated_under_a_single_step_in_flight(
+        kind, traffic, mesh2x4, tmp_path):
+    """The CPU honours no donation, so a held ``ys`` deleted by a later
+    launch would pass here unseen: the spans show instead that between
+    the call of a single step and the wait for it no program is called
+    that donates the whole carry (a decode or verify program, an
+    inject).  The units so overlapped are what the report and the
+    registry count."""
+    engine = _engine(kind, mesh2x4)
+    counted = engine.registry.get("serve_decode_units_overlapped")
+    report, begins = _begins(engine, tmp_path / "spans.json",
+                             TRACES[traffic]())
+    single = None             # launch of the single step not waited for
+    behind = 0                # launches made since it was called
+    overlapped = heads = 0
+    at_head = False           # an admission began with it in flight
+    for ev in begins:
+        args = ev.get("args", {})
+        if "program" in args:
+            if single is not None:
+                assert not DONATES_CARRY.match(args["program"]), (single, ev)
+                behind += 1
+            if ev["name"] == "serve-decode-dispatch" and args["k"] == 1:
+                single, behind, at_head = args["launch"], 0, False
+        elif ev["name"] == "serve-admission":
+            at_head = single is not None
+        elif ev["name"] == "serve-decode-sync" and \
+                args["launch"] == single:
+            overlapped += behind > 0
+            heads += at_head and behind > 0
+            single = None
+    assert single is None
+    assert report["decode_units_overlapped"] == overlapped
+    assert engine.registry.get("serve_decode_units_overlapped") == \
+        counted + overlapped
+    if engine.serving.prefill_chunk is None:
+        # a bucketed prefill drains at the admission's head
+        assert overlapped == 0
+    elif traffic == "backlog":
+        # the interleaved steps, and the loop's own step where an
+        # admission follows a completion: its first chunk goes out
+        # behind that step
+        assert 0 < heads < overlapped <= report["fast_path"]["single_steps"]
 
 
 def test_without_a_tracer_the_helper_takes_the_null_path_and_still_counts(

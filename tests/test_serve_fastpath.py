@@ -250,11 +250,34 @@ def test_k_horizon_overshoots_every_remaining_length(mesh2x4):
 # ---------------------------------------------------------------------------
 
 
-def test_chunked_prefill_matches_monolithic(mesh2x4):
+@pytest.mark.parametrize("case", ["programs", "engine-interleaved",
+                                  "engine-admission-behind-a-step"])
+def test_chunked_prefill_matches_monolithic(case, mesh2x4, request):
     """Chunk-by-chunk prefill writes the identical cache and returns
     the identical last-token output as the monolithic bucketed
     prefill (the offset-causal prefix-carry attention is the same
-    math)."""
+    math).  Through the engine: a chunked run whose interleaved steps
+    are waited for behind the next chunk completes the same tokens as
+    the run with bucketed prefills, which drains before every one."""
+    if case != "programs":
+        # the traces whose launch order ``test_launch_spans.py`` pins:
+        # prompts of one to four chunks admitted beside a resident
+        # batch, and more requests than slots, where an admission's
+        # first chunk goes out behind the loop's own step
+        import test_launch_spans
+
+        trace = (test_launch_spans._trace() if case == "engine-interleaved"
+                 else test_launch_spans._backlog())
+        mono = request.getfixturevalue("baseline_engine").run_trace(trace)
+        chunked = request.getfixturevalue("fast_engine").run_trace(trace)
+        assert mono["requests"]["completed"] == len(trace)
+        assert chunked["completed_tokens"] == mono["completed_tokens"]
+        assert mono["fast_path"]["prefill_chunks"] == 0
+        assert mono["decode_units_overlapped"] == 0
+        fast = chunked["fast_path"]
+        assert fast["prefill_chunks"] > len(trace)
+        assert 0 < chunked["decode_units_overlapped"] <= fast["single_steps"]
+        return
     from dlbb_tpu.models.transformer import init_params_sharded
     from dlbb_tpu.serve.gpt import (
         build_prefill,

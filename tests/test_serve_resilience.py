@@ -313,6 +313,66 @@ def test_carry_reset_mid_chunked_prefill_restarts_prefill(mesh2x4):
 
 
 @pytest.mark.serve_chaos_smoke
+def test_deferred_settle_past_its_deadline_restarts_the_prefill(
+        mesh2x4, monkeypatch, tmp_path):
+    """An interleaved step is waited for only after the next chunk was
+    called.  If that wait blows its deadline the window is abandoned
+    and the carry replaced UNDER a chunk already sent on the old cache:
+    the prefill restarts on the fresh carry, only the resident batch
+    fails, and the admitting request's tokens are an unfaulted run's."""
+    from dlbb_tpu.resilience.errors import DeadlineExceeded
+    from dlbb_tpu.serve import engine as engine_module
+
+    engine = ServingEngine(
+        SMOKE_MODEL, replace(SMOKE_SERVING, prefill_chunk=8),
+        mesh2x4, verbose=False, capture_tokens=True)
+    # A (1 chunk) is resident when B's 3-chunk prefill interleaves
+    trace = TrafficTrace(
+        kind="poisson", seed=0, params={},
+        requests=(
+            Request(rid=0, arrival_s=0.0, prompt_len=4, output_len=4,
+                    seed=11),
+            Request(rid=1, arrival_s=0.0, prompt_len=20, output_len=4,
+                    seed=12),
+        ),
+    )
+    baseline = engine.run_trace(trace)
+    assert baseline["requests"]["completed"] == 2
+    real = engine_module._with_deadline
+    hung = []
+
+    def first_wait_hangs(fn, deadline, label, phase):
+        if phase == "serve-sync" and not hung:
+            hung.append(label)
+            raise DeadlineExceeded(label, 0.3, phase=phase)
+        return real(fn, deadline, label, phase)
+
+    monkeypatch.setattr(engine_module, "_with_deadline", first_wait_hangs)
+    with spans.tracing(tmp_path / "spans.json"):
+        report = engine.run_trace(trace)
+    assert hung == ["decode[k=1]"]
+    outcomes = report["requests"]["outcomes"]
+    assert outcomes == {"0": "failed[hung-dispatch]", "1": "completed"}
+    assert report["resilience"]["hung_dispatches"] == 1
+    assert report["resilience"]["retries"] == 1      # the prefill restart
+    assert (report["completed_tokens"]["1"]
+            == baseline["completed_tokens"]["1"])
+    # the wait that hung began after B's second chunk had been called,
+    # and the restart sent all three chunks again
+    begins = [ev for ev in
+              spans.load_trace(tmp_path / "spans.json")["traceEvents"]
+              if ev["ph"] == "B"]
+    names = [(ev["name"], ev.get("args", {}).get("chunk")) for ev in begins
+             if ev["name"] in ("serve-prefill-chunk", "serve-decode-sync")
+             and ev.get("args", {}).get("rid", 1) == 1]
+    assert names[:6] == [
+        ("serve-prefill-chunk", 0), ("serve-prefill-chunk", 1),
+        ("serve-decode-sync", None), ("serve-prefill-chunk", 0),
+        ("serve-prefill-chunk", 1), ("serve-prefill-chunk", 2)]
+    assert report["fast_path"]["prefill_chunks"] == 4  # A's one, B's three
+
+
+@pytest.mark.serve_chaos_smoke
 def test_deadline_sheds_queue_heads_and_counts_late_completions(
         chaos_engine, tmp_path):
     """A t=0 burst with a 20ms SLO: the first grant wave is admitted
