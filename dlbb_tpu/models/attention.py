@@ -9,13 +9,15 @@ to the input dtype at the end.  Causal (decoder) masking is the default;
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 
 def dense_attention(
-    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = True,
+    scale: Optional[float] = None,
 ) -> jax.Array:
     """q: ``[B, num_heads, S, head_dim]`` -> same shape.
 
@@ -23,6 +25,7 @@ def dense_attention(
     ``[B, kv_heads, S, head_dim]`` with ``num_heads % kv_heads == 0`` —
     query-head groups then share K/V heads via einsum broadcasting, with no
     materialised repeat (K/V stay at kv_heads width in memory).
+    ``scale`` multiplies the scores in place of ``1 / sqrt(head_dim)``.
     """
     b, n, s, d = q.shape
     kvh = k.shape[1]
@@ -30,9 +33,10 @@ def dense_attention(
     grouped = kvh != n
     if grouped:
         q32 = q32.reshape(b, kvh, n // kvh, s, d)
-        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32) / math.sqrt(d)
+        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32)
     else:
-        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / math.sqrt(d)
+        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32)
+    logits = logits / math.sqrt(d) if scale is None else logits * scale
     if causal:
         mask = jnp.tril(jnp.ones((s, s), dtype=bool))
         logits = jnp.where(mask, logits, -jnp.inf)

@@ -18,7 +18,11 @@ FULL_ATTENTION = "full_attention"
 # multi-head latent attention (MLA): one low-rank latent and one rotary
 # key a token, shared by every head (``model_type: deepseek_v3``)
 LATENT_ATTENTION = "latent_attention"
-LAYER_KINDS = (LINEAR_ATTENTION, FULL_ATTENTION, LATENT_ATTENTION)
+# a Mamba-2 state-space (SSD) layer (``model_type: granitemoehybrid``):
+# a scalar decay a head over a ``[d_head, d_state]`` state, ``B`` and
+# ``C`` shared by the heads of a group (``ops/ssd.py``)
+MAMBA = "mamba"
+LAYER_KINDS = (LINEAR_ATTENTION, FULL_ATTENTION, LATENT_ATTENTION, MAMBA)
 # lanes of one cached latent row: ``kv_lora_rank + qk_rope_head_dim``
 # rounded up to whole lanes of 128 (the TPU tiles the plane's last dim
 # by 128, so the memory is spent either way, and whole lanes are what
@@ -175,6 +179,34 @@ class ModelConfig:
     moe_intermediate_size: int = 0
     first_k_dense_replace: int = 0
     routed_scaling_factor: float = 1.0
+    # the state-space (``mamba``) layers' sizes, under the published
+    # config's own keys: ``mamba_n_heads`` heads of ``mamba_d_head``
+    # (together ``mamba_expand x hidden_size``), a state of
+    # ``mamba_d_state`` a head and value, ``B`` and ``C`` of
+    # ``mamba_n_groups`` groups (one is what is implemented), a causal
+    # convolution of ``mamba_d_conv`` positions over x, B and C together
+    # (with a bias where ``mamba_conv_bias``), and the tokens of one
+    # chunk of the chunked scan
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_d_conv: int = 0
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    # Granite's four scalars of the ``layer_types`` family, each neutral
+    # by default: the embedding times ``embedding_multiplier``, each
+    # sub-layer's output times ``residual_multiplier`` before it joins
+    # the residual stream, the logits over ``logits_scaling``, and the
+    # attention scores times ``attention_multiplier`` in place of
+    # ``head_dim ** -0.5`` (None).  ``tie_word_embeddings``: the output
+    # head IS the embedding table (one tensor, counted once)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    attention_multiplier: Optional[float] = None
+    tie_word_embeddings: bool = False
 
     def __post_init__(self) -> None:
         if self.layer_types is not None:
@@ -244,14 +276,13 @@ class ModelConfig:
                 f"layer_types must be a non-empty pattern of {LAYER_KINDS}, "
                 f"got {self.layer_types}")
         # QK-norm belongs to the full-attention layers (OLMo 2/3), which
-        # take it, rotary positions, or both; a stack of latent-attention
-        # layers norms its latent instead
-        positioned = self.qk_norm or self.rope_theta > 0
+        # take it, rotary positions, both or neither (``nope``: the
+        # recurrent layers beside them carry position); a stack of
+        # latent-attention layers norms its latent instead
         hybrid = (self.norm == "rmsnorm" and self.mlp == "swiglu"
                   and not self.bias and self.vocab_size > 0
                   and self.layer_types is not None
-                  and (positioned if FULL_ATTENTION in kinds
-                       else not self.qk_norm))
+                  and (FULL_ATTENTION in kinds or not self.qk_norm))
         if not hybrid:
             raise ValueError(
                 "model family not implemented: the program runs the GPT "
@@ -262,7 +293,7 @@ class ModelConfig:
                 "0, layer_types given, norm_placement 'post', 'pre' or "
                 "'sandwich', total_ut_steps >= 1; its full_attention "
                 "layers take qk_norm=true, rotary positions by rope_theta "
-                "> 0, or both; qk_norm=false for a stack without "
+                "> 0, both or neither; qk_norm=false for a stack without "
                 "full_attention layers); got "
                 f"norm={self.norm!r}, mlp={self.mlp!r}, bias={self.bias}, "
                 f"qk_norm={self.qk_norm}, rope_theta={self.rope_theta}, "
@@ -341,9 +372,32 @@ class ModelConfig:
                     f"({self.linear_num_key_heads} != "
                     f"{self.linear_num_value_heads}): grouped value heads "
                     "in the gated delta rule are not implemented")
-        if self.num_kv_heads not in (None, self.num_heads):
-            raise ValueError("the hybrid family's full-attention layers are "
-                             "plain MHA here (num_kv_heads == num_heads)")
+        if MAMBA in kinds:
+            sizes = (self.mamba_n_heads, self.mamba_d_head,
+                     self.mamba_d_state, self.mamba_d_conv,
+                     self.mamba_chunk_size)
+            if min(sizes) < 1:
+                raise ValueError(
+                    "mamba layers need mamba_n_heads, mamba_d_head, "
+                    "mamba_d_state, mamba_d_conv and mamba_chunk_size >= 1, "
+                    f"got {sizes}")
+            if self.mamba_n_heads * self.mamba_d_head \
+                    != self.mamba_expand * self.hidden_size:
+                raise ValueError(
+                    f"mamba_n_heads x mamba_d_head = {self.mamba_n_heads} x "
+                    f"{self.mamba_d_head} is not mamba_expand x hidden_size "
+                    f"= {self.mamba_expand} x {self.hidden_size}")
+            if self.mamba_n_groups != 1:
+                raise ValueError(
+                    f"mamba_n_groups={self.mamba_n_groups} is not "
+                    "implemented: B and C are one group's, shared by every "
+                    "head (ops/ssd.py)")
+            if LINEAR_ATTENTION in kinds:
+                raise ValueError(
+                    "linear_attention and mamba layers in one stack are not "
+                    "implemented: the cache keeps ONE recurrent-state plane "
+                    "and one plane of convolution inputs, of one kind's "
+                    "shape (serve/kvcache.py::HybridCache)")
         if (self.is_moe or self.forces_tp_ring or self.remat
                 or self.attention not in ("full", "dense")
                 or not self.causal):
@@ -399,6 +453,17 @@ class ModelConfig:
         """Values of one cached latent row as HELD: ``latent_width`` in
         whole lanes (``LATENT_LANES``), the rest zeros."""
         return -(-self.latent_width // LATENT_LANES) * LATENT_LANES
+
+    @property
+    def mamba_inner(self) -> int:
+        """The state-space layers' inner width: every head's values."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """Channels of the state-space layers' convolution: x of every
+        head, then B and C of every group."""
+        return self.mamba_inner + 2 * self.mamba_n_groups * self.mamba_d_state
 
     @property
     def linear_conv_channels(self) -> int:
@@ -590,6 +655,22 @@ def kv_cache_bytes_raw(num_layers: int, max_batch: int, max_seq: int,
     return elems * head_dim * _DTYPE_BYTES.get(dtype, 2)
 
 
+def kv_rows(config: ModelConfig, tp: int = 1) -> bool:
+    """Whether a ``layer_types`` model's K/V planes hold a token's K (or
+    V) of a layer as ONE row of ``kv_heads x head_dim`` values, ``[planes,
+    slots, blocks, block, kv_heads x head_dim]``, and not head by head:
+    where a head is no whole number of 128 lanes but the row is (8 heads
+    of 64 are four lanes-rows).  Held head by head, the TPU would tile
+    ``(heads, head_dim)`` by (8, 128) and a head of 64 would take the
+    room of 128: twice the K/V.  The row is what the decode kernel then
+    copies and multiplies (``ops/decode_attention.py``).  Not under tp,
+    where the planes keep a head dim to shard, and not for a model
+    without full-attention layers, whose planes hold nothing."""
+    row = config.kv_heads * config.head_dim
+    return (config.is_hybrid and tp <= 1 and config.kv_planes > 0
+            and config.head_dim % 128 != 0 and row % 128 == 0)
+
+
 def cache_kv_heads(config: ModelConfig, tp: int = 1) -> int:
     """K/V heads a cache plane holds.  The GPT block's planes hold
     ``kv_heads``.  A ``layer_types`` model's hold them rounded up to a
@@ -599,8 +680,10 @@ def cache_kv_heads(config: ModelConfig, tp: int = 1) -> int:
     way, and with 30 heads the v5e compiler re-laid both whole planes
     out inside every decode step to get whole tiles (two 2 GB copies a
     step, compiled for the chip without it, PR 27).  Under tp the planes
-    keep ``kv_heads``, so that query and key heads split alike."""
-    if config.is_hybrid and tp <= 1:
+    keep ``kv_heads``, so that query and key heads split alike.  Planes
+    that hold whole rows (:func:`kv_rows`) hold ``kv_heads`` too: no
+    head is added and none is widened."""
+    if config.is_hybrid and tp <= 1 and not kv_rows(config, tp):
         return -(-config.kv_heads // 8) * 8
     return config.kv_heads
 
@@ -622,19 +705,28 @@ def kv_cache_bytes(config: ModelConfig, max_batch: int,
 
 
 def state_cache_bytes(config: ModelConfig, max_batch: int) -> int:
-    """Total (unsharded) footprint of the linear-attention layers'
-    slot-indexed state (``serve/kvcache.py::StateCache``): per layer and
+    """Total (unsharded) footprint of the recurrent layers' slot-indexed
+    state (``serve/kvcache.py::HybridCache``; linear-attention or
+    state-space layers, of which a model has one kind): per layer and
     slot one float32 ``[heads, d_v, d_k]`` recurrent state and the last
     ``conv_kernel - 1`` inputs of the short convolution in the model
     dtype.  It does not grow with a slot's length, so the block ledger
     never counts it; 0 for a model without such layers."""
+    itemsize = _DTYPE_BYTES.get(config.dtype, 2)
+    n_ssm = config.layers_of(MAMBA)
+    if n_ssm:
+        # a state-space layer's: ``[heads, d_head, d_state]`` float32 and
+        # the last ``d_conv - 1`` inputs of x, B and C together
+        state = config.mamba_inner * config.mamba_d_state * 4
+        conv = (config.mamba_d_conv - 1) * config.mamba_conv_channels
+        return n_ssm * max_batch * (state + conv * itemsize)
     n_lin = config.layers_of(LINEAR_ATTENTION)
     if not n_lin:
         return 0
     state = (config.linear_num_value_heads * config.linear_value_head_dim
              * config.linear_key_head_dim * 4)
     conv = ((config.linear_conv_kernel_dim - 1) * config.linear_conv_channels
-            * _DTYPE_BYTES.get(config.dtype, 2))
+            * itemsize)
     return n_lin * max_batch * (state + conv)
 
 
@@ -730,6 +822,13 @@ def validate_serving(config: ModelConfig, max_batch: int, max_seq: int,
             raise ValueError(
                 f"linear_num_value_heads={lin_heads} not divisible by "
                 f"tp={tp}: the recurrent state shards its head dim over tp")
+        if tp > 1 and MAMBA in config.layer_types:
+            raise ValueError(
+                f"tp={tp} is not implemented for mamba layers: B and C are "
+                "shared by every head, so they would be computed whole on "
+                "every shard beside a head-sharded x, and the fused "
+                "in-projection and its convolution are not split that way "
+                "(ROADMAP.md, Queue 2)")
         if tp > 1 and (LATENT_ATTENTION in config.layer_types
                        or config.has_routed_experts):
             raise ValueError(

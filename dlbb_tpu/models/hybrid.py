@@ -1,6 +1,6 @@
 """The ``layer_types`` block family: Olmo-Hybrid, (PR 31) the
-``deepseek_v3`` block of kanana-2-30b-a3b, and (PR 33) the looped stack
-of Ouro.
+``deepseek_v3`` block of kanana-2-30b-a3b, (PR 33) the looped stack
+of Ouro, and (PR 37) the ``granitemoehybrid`` block of Granite 4.0-H.
 
 A stack that repeats one PERIOD of layers, each ``linear_attention``
 (the gated delta rule of ``ops/gated_delta.py`` behind a short causal
@@ -9,15 +9,23 @@ QK-norm, with rotary positions on half-split pairs where ``rope_theta``
 is given, or with both) or ``latent_attention`` (MLA: per token
 one normed low-rank latent and one rotary key shared by all heads, from
 which each head's keys and values are expanded, or into which its
-queries are absorbed), every one followed by an MLP: a SwiGLU, or after
+queries are absorbed) or ``mamba`` (the Mamba-2 recurrence of
+``ops/ssd.py`` behind one fused projection that also yields the gate and
+the step, held as two column blocks, and a convolution over x, B and C
+together; a gated RMSNorm over the whole inner width in front of its
+out-projection), every one followed by an MLP: a SwiGLU, or after
 the ``first_k_dense_replace`` leading layers the routed and shared
 experts of ``ops/routed_experts.py``.  The RMSNorms sit where
 ``norm_placement`` says: on a sub-layer's OUTPUT (OLMo 2/3: ``h = x +
 norm(mixer(x))``, ``out = h + norm(mlp(h))``), on its INPUT (``h = x +
 mixer(norm(x))``, ``out = h + mlp(norm(h))``) or on both (``sandwich``:
-``h = x + norm(mixer(norm(x)))``, four scales a layer).  No biases.
+``h = x + norm(mixer(norm(x)))``, four scales a layer).  No biases
+but the state-space layers' convolution's.  The full-attention layers'
+K and V may have fewer heads than the queries (``num_kv_heads``).
 Token ids in, logits over ``vocab_size`` out: an embedding table, a
-final RMSNorm and an untied head.
+final RMSNorm and a head, untied or (``tie_word_embeddings``) the
+embedding table itself.  Granite's four multipliers
+(``models/configs.py``) are applied where they differ from 1.
 
 A LOOPED stack (``total_ut_steps`` > 1, :func:`run_stack`) runs the same
 layers and the same final norm that many times a token, ``h_t =
@@ -31,7 +39,8 @@ ONE definition of the block (:func:`hybrid_block`) and of the period
 no cache) and by every serving program (``serve/hybrid.py``).  What
 differs between them is the *mixer*: an object with ``attention(q, k,
 v, l, state)`` and ``linear(qkv, log_alpha, beta, conv_w, l, state)``
-(and ``latent(q, c, k_rope, wkv_b, l, state)``) that owns everything
+(and ``latent(q, c, k_rope, wkv_b, l, state)``, ``ssm(xbc, dt, layer,
+l, state)``) that owns everything
 that touches a cache, and says where its tokens lie (``positions()``,
 for the full-attention layers' rotary).  ``state`` is opaque to the
 block.  A mixer also says which tokens are real (``valid()``: the
@@ -62,6 +71,7 @@ from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
     LATENT_ATTENTION,
     LINEAR_ATTENTION,
+    MAMBA,
     ModelConfig,
 )
 from dlbb_tpu.models.transformer import (
@@ -81,6 +91,7 @@ from dlbb_tpu.ops.gated_delta import (
     l2_normalise,
 )
 from dlbb_tpu.ops.routed_experts import expert_layer
+from dlbb_tpu.ops.ssd import ssd_chunked
 
 Params = dict[str, Any]
 
@@ -100,6 +111,13 @@ EMBED, LM_HEAD, LIN_PROJ, LIN_CONV, LIN_CORE, LIN_OUT = HYBRID_PHASES
 # ``ops/routed_experts.py::MOE_PHASES``.
 MLA_PHASES = ("rope", "mla_q", "mla_kv_a", "mla_kv_b")
 ROPE, MLA_Q, MLA_KV_A, MLA_KV_B = MLA_PHASES
+# ... and the state-space layers': the fused in-projection (gate, x, B,
+# C and the step, with the step's softplus), the convolution with its
+# bias and SiLU, the recurrence (``ssm_core`` holds ``state_update`` or
+# ``state_scan`` as ``lin_core`` does), and the gate, the gated norm and
+# the out-projection
+SSM_PHASES = ("ssm_proj", "ssm_conv", "ssm_core", "ssm_out")
+SSM_PROJ, SSM_CONV, SSM_CORE, SSM_OUT = SSM_PHASES
 # ... and the looped stack's, once a PASS (not a layer): the final norm
 # between passes and the one-output exit gate
 LOOP_PHASES = ("loop_norm", "exit_gate")
@@ -114,9 +132,12 @@ STATE_DTYPE = jnp.float32
 
 # float32 whatever the model's dtype: ``A_log`` and ``dt_bias`` feed an
 # exponential of an exponential, ``router_bias`` decides near-ties
-_FLOAT32 = ("A_log", "dt_bias", "router_bias")
+_FLOAT32 = ("A_log", "dt_bias", "router_bias", "ssm_D")
 _SCALES = ("ln1", "ln2", "ln1_out", "ln2_out", "q_norm", "k_norm",
            "o_norm", "kv_norm")
+# scales drawn uniform in (0.5, 1.5): what they scale is of unit size
+# before its norm, so ones would hide whether the scale is applied
+_DRAWN_SCALES = ("kv_norm", "ssm_norm")
 
 
 def _layer_shapes(config: ModelConfig, kind: str,
@@ -141,8 +162,8 @@ def _layer_shapes(config: ModelConfig, kind: str,
     else:
         shapes.update(mlp_gate=(h, f), mlp_up=(h, f), mlp_down=(f, h))
     if kind == FULL_ATTENTION:
-        n, d = config.num_heads, config.head_dim
-        shapes.update(wq=(h, n, d), wk=(h, n, d), wv=(h, n, d),
+        n, d, kvh = config.num_heads, config.head_dim, config.kv_heads
+        shapes.update(wq=(h, n, d), wk=(h, kvh, d), wv=(h, kvh, d),
                       wo=(n, d, h))
         if config.qk_norm:
             shapes.update(q_norm=(n, d), k_norm=(n, d))
@@ -152,6 +173,23 @@ def _layer_shapes(config: ModelConfig, kind: str,
         dv = config.v_head_dim
         shapes.update(wq=(h, n, dn + dr), wkv_a=(h, r + dr), kv_norm=(r,),
                       wkv_b=(r, n, dn + dv), wo=(n, dv, h))
+    elif kind == MAMBA:
+        nh, inner = config.mamba_n_heads, config.mamba_inner
+        channels = config.mamba_conv_channels
+        # the fused in-projection [z (gate) | x, B, C | dt], held as two
+        # column blocks: ``ssm_in`` the first two parts (whole lanes at
+        # the published widths, 8448 = 66 x 128) and ``ssm_dt`` the
+        # step's.  Held whole, 8512 columns are no whole number of 128
+        # lanes, the TPU lays such a kernel out transposed, and the v5e
+        # compiler re-laid all 36 back inside every fused decode scan
+        # (1.19 GiB of temporaries, compiled for the chip, PR 37)
+        shapes.update(
+            ssm_in=(h, inner + channels), ssm_dt=(h, nh),
+            ssm_conv=(config.mamba_d_conv, channels),
+            A_log=(nh,), dt_bias=(nh,), ssm_D=(nh,),
+            ssm_norm=(inner,), ssm_out=(inner, h))
+        if config.mamba_conv_bias:
+            shapes.update(ssm_conv_b=(channels,))
     else:
         nh = config.linear_num_value_heads
         dk, dv = config.linear_key_head_dim, config.linear_value_head_dim
@@ -168,10 +206,16 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     """Seeded parameters: scaled-normal kernels (1/sqrt(fan_in)), unit
     norm scales (the latent's own, ``kv_norm``, uniform in (0.5, 1.5): a
     latent is of unit size before its norm, so ones would hide whether
-    the norm is applied), a unit-normal embedding; the decay's ``A`` uniform in
+    the norm is applied), a unit-normal embedding (of deviation ``1 /
+    embedding_multiplier``, so that what enters the stack is of unit
+    size whatever the multiplier); the decay's ``A`` uniform in
     (1, 16) and the step's bias the inverse softplus of a step
-    log-uniform in (0.001, 0.1), as Gated DeltaNet initialises them, so
-    that random weights give decays spread over (0, 1); the router's
+    log-uniform in (0.001, 0.1), as Gated DeltaNet and Mamba-2 initialise
+    them, so that random weights give decays spread over (0, 1); a
+    state-space layer's skip ``D`` ones, its convolution's bias uniform
+    in +/-0.1 (small, not zero: a dropped bias shows), its gated norm's
+    scale uniform in (0.5, 1.5) as ``kv_norm``; no ``lm_head`` where the
+    head is tied to the embedding; the router's
     selection bias uniform in +/-0.01 (a trained model's is a learned
     buffer of that order); a looped stack's exit gate a scaled-normal
     vector and a float32 bias uniform in +/-1 (unit-size ``h`` gives
@@ -204,7 +248,13 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
                     k, (periods,) + shape, jnp.float32,
                     math.log(1e-3), math.log(1e-1)))
                 out[name] = dt + jnp.log(-jnp.expm1(-dt))
-            elif name == "kv_norm":
+            elif name == "ssm_D":
+                out[name] = jnp.ones((periods,) + shape, jnp.float32)
+            elif name == "ssm_conv_b":
+                out[name] = jax.random.uniform(
+                    k, (periods,) + shape, jnp.float32, -0.1, 0.1
+                ).astype(dtype)
+            elif name in _DRAWN_SCALES:
                 out[name] = jax.random.uniform(
                     k, (periods,) + shape, jnp.float32, 0.5, 1.5
                 ).astype(dtype)
@@ -220,12 +270,16 @@ def init_params(config: ModelConfig, key: jax.Array) -> Params:
     k_embed, k_head, *k_layers = jax.random.split(
         key, 2 + len(config.layer_types))
     params = {
-        "embed": normal(k_embed, (vocab, h), 1),
+        # of unit size AFTER its multiplier: a unit table times Granite's
+        # 12 would drown what the layers add to the stream
+        "embed": normal(k_embed, (vocab, h),
+                        config.embedding_multiplier ** 2),
         "periods": tuple(layer(k, kind, periods, config.has_routed_experts)
                          for k, kind in zip(k_layers, config.layer_types)),
         "ln_f": jnp.ones((h,), dtype),
-        "lm_head": normal(k_head, (h, vocab), h),
     }
+    if not config.tie_word_embeddings:
+        params["lm_head"] = normal(k_head, (h, vocab), h)
     if lead:
         k_lead = jax.random.split(jax.random.fold_in(key, 1),
                                   len(config.layer_types))
@@ -270,6 +324,11 @@ def param_specs(config: ModelConfig, mesh: Optional[Mesh],
         "exp_down": P(None, None, None, None),
         "shared_gate": P(None, None, t), "shared_up": P(None, None, t),
         "shared_down": P(None, t, None),
+        # the state-space layers whole (serving refuses tp for them)
+        "ssm_in": P(None, None, None), "ssm_dt": P(None, None, None),
+        "ssm_conv": P(None, None, None),
+        "ssm_conv_b": P(None, None), "ssm_D": P(None, None),
+        "ssm_norm": P(None, None), "ssm_out": P(None, None, None),
     }
 
     def stack(experts):
@@ -281,8 +340,9 @@ def param_specs(config: ModelConfig, mesh: Optional[Mesh],
         "embed": P(None, None),
         "periods": stack(config.has_routed_experts),
         "ln_f": P(None),
-        "lm_head": P(None, t),
     }
+    if not config.tie_word_embeddings:
+        specs["lm_head"] = P(None, t)
     if config.first_k_dense_replace:
         specs["lead"] = stack(False)
     if config.total_ut_steps > 1:
@@ -311,7 +371,9 @@ def num_parameters(config: ModelConfig) -> int:
     layers = (lead * period(False)
               + periods * period(config.has_routed_experts))
     gate = config.hidden_size + 1 if config.total_ut_steps > 1 else 0
-    return (layers + 2 * config.vocab_size * config.hidden_size
+    # a tied head is the embedding table: counted once
+    tables = 1 if config.tie_word_embeddings else 2
+    return (layers + tables * config.vocab_size * config.hidden_size
             + config.hidden_size + gate)
 
 
@@ -361,6 +423,29 @@ def split_qkv_heads(qkv: jax.Array, config: ModelConfig
     act = jax.nn.silu(qkv.astype(jnp.float32))
     q, k, v = act[..., :dk], act[..., dk:2 * dk], act[..., 2 * dk:]
     return l2_normalise(q, dk ** -0.5), l2_normalise(k), v
+
+
+def split_xbc(conv: jax.Array, layer: Params, config: ModelConfig
+              ) -> tuple[jax.Array, jax.Array, jax.Array]:
+    """The convolved ``[..., channels]`` (float32) as the state-space
+    recurrence takes it: the convolution's bias added, the SiLU, then
+    ``x`` ``[..., heads, d_head]`` and ``B``, ``C`` ``[..., d_state]``,
+    in that order along the channels; float32."""
+    if "ssm_conv_b" in layer:
+        conv = conv + layer["ssm_conv_b"].astype(jnp.float32)
+    act = jax.nn.silu(conv)
+    inner, n = config.mamba_inner, config.mamba_d_state
+    x = act[..., :inner].reshape(
+        act.shape[:-1] + (config.mamba_n_heads, config.mamba_d_head))
+    return x, act[..., inner:inner + n], act[..., inner + n:]
+
+
+def gated_norm(y: jax.Array, z: jax.Array, scale: jax.Array,
+               eps: float) -> jax.Array:
+    """The state-space layers' output norm: the gate ``silu(z)`` applied
+    BEFORE an RMSNorm over the whole inner width (one group); float32."""
+    return rmsnorm(y.astype(jnp.float32)
+                   * jax.nn.silu(z.astype(jnp.float32)), scale, eps)
 
 
 def rope(x: jax.Array, positions: jax.Array, theta: float,
@@ -446,6 +531,12 @@ def hybrid_block(h: jax.Array, layer: Params, kind: str,
     # what a sub-layer is fed is of the weights' dtype whatever the
     # residual stream's (a looped stack's is float32: ``run_stack``)
     dtype = layer["ln1"].dtype
+    rm = config.residual_multiplier
+
+    def joins(y):
+        """A sub-layer's output as it joins the residual stream."""
+        return y if rm == 1.0 else rm * y
+
     x = h
     if placement != "post":
         with jax.named_scope(LN1):
@@ -494,26 +585,41 @@ def hybrid_block(h: jax.Array, layer: Params, kind: str,
             o = (rmsnorm(o, layer["o_norm"], eps)
                  * jax.nn.silu(gate.astype(jnp.float32))).astype(h.dtype)
             y = jnp.einsum("bsnv,nvh->bsh", o, layer["lin_out"])
+    elif kind == MAMBA:
+        inner, channels = config.mamba_inner, config.mamba_conv_channels
+        with jax.named_scope(SSM_PROJ):
+            proj = x @ layer["ssm_in"]
+            z = proj[..., :inner]
+            dt = jax.nn.softplus(
+                (x @ layer["ssm_dt"]).astype(jnp.float32)
+                + layer["dt_bias"])
+        # the mixer owns the convolution's carried inputs and the
+        # state: ``ssm_conv`` and ``ssm_core`` open inside it
+        o, state = mixer.ssm(proj[..., inner:], dt, layer, l, state)
+        with jax.named_scope(SSM_OUT):
+            y = gated_norm(o.reshape(o.shape[:2] + (inner,)), z,
+                           layer["ssm_norm"], eps).astype(dtype) \
+                @ layer["ssm_out"]
     else:
         raise ValueError(f"unknown layer kind {kind!r}")
     if placement == "pre":
-        h = h + y
+        h = h + joins(y)
         with jax.named_scope(LN2):
             u = rmsnorm(h, layer["ln2"], eps).astype(dtype)
-        return h + _mlp(u, layer, config, mixer, experts), state
+        return h + joins(_mlp(u, layer, config, mixer, experts)), state
     # a norm on each sub-layer's output; ``sandwich`` keeps the input's
     # too, and both of a sub-layer lie under its one scope
     out1, out2 = (("ln1_out", "ln2_out") if placement == "sandwich"
                   else ("ln1", "ln2"))
     with jax.named_scope(LN1):
-        h = h + rmsnorm(y, layer[out1], eps)
+        h = h + joins(rmsnorm(y, layer[out1], eps))
     u = h
     if placement == "sandwich":
         with jax.named_scope(LN2):
             u = rmsnorm(h, layer["ln2"], eps).astype(dtype)
     y = _mlp(u, layer, config, mixer, experts)
     with jax.named_scope(LN2):
-        h = h + rmsnorm(y, layer[out2], eps)
+        h = h + joins(rmsnorm(y, layer[out2], eps))
     return h, state
 
 
@@ -672,20 +778,32 @@ def run_stack(h: jax.Array, params: Params, config: ModelConfig,
     return h, state, outs, None, gates
 
 
-def embed_tokens(params: Params, ids: jax.Array) -> jax.Array:
+def embed_tokens(params: Params, ids: jax.Array,
+                 config: ModelConfig) -> jax.Array:
     with jax.named_scope(EMBED):
-        return jnp.take(params["embed"], ids, axis=0)
+        h = jnp.take(params["embed"], ids, axis=0)
+        if config.embedding_multiplier != 1.0:
+            h = h * config.embedding_multiplier
+        return h
 
 
 def logits_of(params: Params, h: jax.Array,
               config: ModelConfig) -> jax.Array:
-    """Final RMSNorm and the output head; float32 logits.  A looped
+    """Final RMSNorm and the output head (the embedding table where the
+    head is tied), over ``logits_scaling``; float32 logits.  A looped
     stack's ``h`` has had its norm (:func:`run_stack`)."""
     with jax.named_scope(LM_HEAD):
-        y = (h.astype(params["lm_head"].dtype) if config.total_ut_steps > 1
+        y = (h.astype(params["embed"].dtype) if config.total_ut_steps > 1
              else rmsnorm(h, params["ln_f"], config.rms_norm_eps))
-        return jnp.einsum("...h,hv->...v", y, params["lm_head"],
-                          preferred_element_type=jnp.float32)
+        if config.tie_word_embeddings:
+            logits = jnp.einsum("...h,vh->...v", y, params["embed"],
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = jnp.einsum("...h,hv->...v", y, params["lm_head"],
+                                preferred_element_type=jnp.float32)
+        if config.logits_scaling != 1.0:
+            logits = logits / config.logits_scaling
+        return logits
 
 
 # -- the whole-sequence forward (no cache) -------------------------------------
@@ -693,8 +811,9 @@ def logits_of(params: Params, h: jax.Array,
 
 class SequenceMixer:
     """The mixer of :func:`forward`: dense causal attention (the latent
-    layers in their expanded form), and the chunked delta rule from a
-    zero state with zeros before the convolution's first position."""
+    layers in their expanded form), and the chunked delta rule or the
+    chunked state-space scan from a zero state with zeros before the
+    convolution's first position."""
 
     def __init__(self, config: ModelConfig, seq_len: int) -> None:
         self.config, self.seq_len = config, seq_len
@@ -714,8 +833,23 @@ class SequenceMixer:
 
     def attention(self, q, k, v, l, state):
         qh, kh, vh = (t.transpose(0, 2, 1, 3) for t in (q, k, v))
-        attn = dense_attention(qh, kh, vh, causal=True)
+        attn = dense_attention(qh, kh, vh, causal=True,
+                               scale=self.config.attention_multiplier)
         return attn.transpose(0, 2, 1, 3), state
+
+    def ssm(self, xbc, dt, layer, l, state):
+        cfg = self.config
+        with jax.named_scope(SSM_CONV):
+            ext = jnp.pad(xbc, ((0, 0), (cfg.mamba_d_conv - 1, 0), (0, 0)))
+            x, b, c = split_xbc(causal_conv(ext, layer["ssm_conv"]), layer,
+                                cfg)
+        with jax.named_scope(SSM_CORE):
+            zero = jnp.zeros((xbc.shape[0], cfg.mamba_n_heads,
+                              cfg.mamba_d_head, cfg.mamba_d_state),
+                             STATE_DTYPE)
+            y, _ = ssd_chunked(x, dt, -jnp.exp(layer["A_log"]), b, c,
+                               layer["ssm_D"], zero, cfg.mamba_chunk_size)
+        return y, state
 
     def latent(self, q, c, k_rope, wkv_b, l, state):
         cfg = self.config
@@ -759,7 +893,7 @@ def forward(params: Params, ids: jax.Array, config: ModelConfig,
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         raise ValueError("pipeline parallelism is not implemented for "
                          "layer_types models")
-    h = embed_tokens(params, ids)
+    h = embed_tokens(params, ids, config)
     h, _, _, chosen, gates = run_stack(
         h, params, config,
         lambda _xs: SequenceMixer(config, ids.shape[1]), None)

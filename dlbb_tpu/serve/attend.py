@@ -15,6 +15,7 @@ Below both families (``serve/gpt.py``, ``serve/hybrid.py``) and beside
 from __future__ import annotations
 
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -79,7 +80,8 @@ def _cached_attention(q: jax.Array, k_flat: jax.Array, v_flat: jax.Array,
 
 @jax.named_scope(KV_ATTEND)
 def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
-                     start: int) -> jax.Array:
+                     start: int, scale: Optional[float] = None
+                     ) -> jax.Array:
     """Offset-causal fp32 attention for one prefill chunk.
 
     qh: ``[1, n, C, d]`` (the chunk's queries, global positions
@@ -89,7 +91,8 @@ def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
     mask replaced by the STATIC offset-causal mask ``j <= start + qi``
     — for real query positions this reaches only real keys, so pad
     positions in a final partial chunk never contaminate a real
-    output (their own rows are discarded by the caller)."""
+    output (their own rows are discarded by the caller).  ``scale``
+    multiplies the scores in place of ``1 / sqrt(d)``."""
     b, n, c, d = qh.shape
     kvh = k_all.shape[1]
     s_tot = k_all.shape[0]
@@ -100,13 +103,15 @@ def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
             <= (start + jnp.arange(c))[:, None])            # [C, S]
     if kvh != n:
         q32 = q32.reshape(b, kvh, n // kvh, c, d)
-        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32) / math.sqrt(d)
+        logits = jnp.einsum("bhgqd,bhkd->bhgqk", q32, k32)
+        logits = logits / math.sqrt(d) if scale is None else logits * scale
         logits = jnp.where(mask[None, None, None], logits, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bhgqk,bhkd->bhgqd", probs, v32)
         out = out.reshape(b, n, c, d)
     else:
-        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / math.sqrt(d)
+        logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32)
+        logits = logits / math.sqrt(d) if scale is None else logits * scale
         logits = jnp.where(mask[None, None], logits, -jnp.inf)
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bnqk,bnkd->bnqd", probs, v32)

@@ -10,7 +10,9 @@ HybridCache`.
   written as whole blocks in ONE update after the layer loops,
   ``write_slot_planes``); the linear-attention layers run the chunked
   gated delta rule from the state the previous chunk handed on and write
-  the slot's state and convolution inputs; the latent-attention layers
+  the slot's state and convolution inputs, and the state-space
+  (``mamba``) layers do the same with the chunked scan of
+  ``ops/ssd.py``; the latent-attention layers
   expand per-head keys and values from the carried prefix of latent rows
   and the chunk's own (the EXPANDED form: 2.4 x fewer operations than
   the absorbed one over a chunk) and write the chunk's rows as whole
@@ -43,7 +45,9 @@ experts, gates, counts)``).  A looped stack (``total_ut_steps`` > 1)
 returns beside the logits the exit gate of every pass at those
 positions (``seen = (logits, exit gates [..., passes])``, ``last =
 (logits, exit gates [passes], counts)``) and as ``counts`` the passes
-through the stack the step or chunk ran.
+through the stack the step or chunk ran.  A model with state-space
+layers returns from its chunk program ``last = (logits, [real tokens,
+rows])``: what the chunk's scan was asked and what it was padded to.
 
 The block and the period are ``models/hybrid.py``'s; this file holds the
 three mixers that touch the cache, and at its end what the scheduler
@@ -66,12 +70,19 @@ from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
     LATENT_ATTENTION,
     LINEAR_ATTENTION,
+    MAMBA,
     ModelConfig,
     kv_cache_bytes,
     latent_cache_bytes,
     state_cache_bytes,
 )
-from dlbb_tpu.models.hybrid import LIN_CONV, LIN_CORE, MLA_KV_B
+from dlbb_tpu.models.hybrid import (
+    LIN_CONV,
+    LIN_CORE,
+    MLA_KV_B,
+    SSM_CONV,
+    SSM_CORE,
+)
 from dlbb_tpu.models.transformer import _dtype_of, named
 from dlbb_tpu.ops import decode_attention as kv_kernel
 from dlbb_tpu.ops import latent_attention as latent_kernel
@@ -86,8 +97,9 @@ from dlbb_tpu.ops.gated_delta import (
     gated_delta_chunked,
     gated_delta_step,
 )
+from dlbb_tpu.ops.ssd import ssd_chunked, ssd_step
 from dlbb_tpu.obs import spans
-from dlbb_tpu.serve.attend import _chunk_attention, _layer_of
+from dlbb_tpu.serve.attend import KV_UPDATE, _chunk_attention, _layer_of
 from dlbb_tpu.serve.kvcache import (
     HybridCache,
     append_latent_rows,
@@ -107,15 +119,18 @@ def token_spec(mesh: Mesh) -> P:
     return P(hybrid_cache_specs(mesh).k[1])
 
 
-def prefix_specs(mesh: Mesh) -> tuple[P, P, P, P, P]:
+def prefix_specs(mesh: Mesh, config: ModelConfig
+                 ) -> tuple[P, P, P, P, P]:
     """The chunk carry ``(k, v, state, conv[, latent])``: no slot dim,
     heads over tp (``gpt.prefix_spec`` for K/V).  The carry has its
     fifth part only for a model with latent-attention layers
-    (:func:`create_prefix`)."""
+    (:func:`create_prefix`).  A state-space model's convolution inputs
+    ``[L, d_conv - 1, channels]`` have no head dim."""
     tp = hybrid_cache_specs(mesh).k[4]
     kv = P(None, None, tp, None)
-    return (kv, kv, P(None, tp, None, None), P(None, None, tp, None),
-            P(None, None, None))
+    conv = (P(None, None, None) if config.layers_of(MAMBA)
+            else P(None, None, tp, None))
+    return kv, kv, P(None, tp, None, None), conv, P(None, None, None)
 
 
 def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple:
@@ -125,20 +140,28 @@ def create_prefix(config: ModelConfig, mesh: Mesh) -> tuple:
     rows."""
     dtype = _dtype_of(config.dtype)
     n_lin = config.layers_of(LINEAR_ATTENTION)
+    n_ssm = config.layers_of(MAMBA)
     heads = config.linear_num_value_heads
     kv = jnp.zeros((config.kv_planes, 0, config.kv_heads, config.head_dim),
                    dtype)
-    state = jnp.zeros((n_lin, heads, config.linear_value_head_dim,
-                       config.linear_key_head_dim), hybrid.STATE_DTYPE)
-    conv = jnp.zeros((n_lin, max(config.linear_conv_kernel_dim - 1, 0),
-                      heads, config.linear_conv_channels // max(heads, 1)),
-                     dtype)
+    if n_ssm:
+        state = jnp.zeros((n_ssm, config.mamba_n_heads, config.mamba_d_head,
+                           config.mamba_d_state), hybrid.STATE_DTYPE)
+        conv = jnp.zeros((n_ssm, config.mamba_d_conv - 1,
+                          config.mamba_conv_channels), dtype)
+    else:
+        state = jnp.zeros((n_lin, heads, config.linear_value_head_dim,
+                           config.linear_key_head_dim), hybrid.STATE_DTYPE)
+        conv = jnp.zeros((n_lin, max(config.linear_conv_kernel_dim - 1, 0),
+                          heads,
+                          config.linear_conv_channels // max(heads, 1)),
+                         dtype)
     parts = (kv, kv, state, conv)
     if config.layers_of(LATENT_ATTENTION):
         parts += (jnp.zeros((config.layers_of(LATENT_ATTENTION), 0,
                              config.latent_row), dtype),)
     return tuple(jax.device_put(t, NamedSharding(mesh, s))
-                 for t, s in zip(parts, prefix_specs(mesh)))
+                 for t, s in zip(parts, prefix_specs(mesh, config)))
 
 
 def _pad_heads(t: jax.Array, heads: int) -> jax.Array:
@@ -148,6 +171,30 @@ def _pad_heads(t: jax.Array, heads: int) -> jax.Array:
     if not extra:
         return t
     return jnp.pad(t, [(0, 0)] * (t.ndim - 2) + [(0, extra), (0, 0)])
+
+
+def _as_blocks(own: jax.Array, plane: jax.Array) -> jax.Array:
+    """A chunk's own K (or V) of every plane ``[L, C, kvh, d]`` as the
+    whole blocks ``plane`` holds: ``[L, C / bs, bs, kvh', d]`` with zero
+    heads added, or ``[L, C / bs, bs, kvh x d]`` where it holds whole
+    rows."""
+    blocks = (own.shape[0], own.shape[1] // plane.shape[3]) + plane.shape[3:]
+    if plane.ndim == 6:
+        own = _pad_heads(own, plane.shape[-2])
+    return own.reshape(blocks)
+
+
+def _conv_flat(ext: jax.Array, weight: jax.Array) -> jax.Array:
+    """``ops.gated_delta.causal_conv`` of ONE position over inputs that
+    lie flat: ``ext`` ``[B, K x channels]``, position by position, the
+    current one last; ``weight`` ``[K, channels]``.  Returns ``[B,
+    channels]`` float32.  Flat, because that is how the cache holds a
+    state-space layer's inputs (``HybridCache``): the shift by one
+    position is then a slice of whole lanes."""
+    k, channels = weight.shape
+    w32 = weight.astype(jnp.float32)
+    return sum(ext[:, i * channels:(i + 1) * channels].astype(jnp.float32)
+               * w32[i] for i in range(k))
 
 
 def _cache_rows(c: jax.Array, k_rope: jax.Array, positions: jax.Array,
@@ -225,7 +272,7 @@ class ChunkMixer(_Mixer):
         k_all = jnp.concatenate([pk[j], k[0]], axis=0)
         v_all = jnp.concatenate([pv[j], v[0]], axis=0)
         attn = _chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
-                                self.start)
+                                self.start, self.config.attention_multiplier)
         # the K/V planes ride along untouched: the chunk program writes
         # every layer's blocks at once from what is handed on here
         # (``write_slot_planes``)
@@ -287,6 +334,31 @@ class ChunkMixer(_Mixer):
         self.out[3].append(tail)
         return o, (k_c, v_c, st, cv, lat)
 
+    def ssm(self, xbc, dt, layer, l, planes):
+        cfg = self.config
+        j = len(self.out[2])
+        real = (jnp.arange(self.chunk_len) < self.n_valid)[None, :, None]
+        with jax.named_scope(SSM_CONV):
+            ext = jnp.concatenate([self.xs[3][j][None], xbc], axis=1)
+            x, b, c = hybrid.split_xbc(
+                causal_conv(ext, layer["ssm_conv"]), layer, cfg)
+            # the last inputs of REAL positions, as in ``linear``
+            tail = jax.lax.dynamic_slice_in_dim(
+                ext, self.n_valid, cfg.mamba_d_conv - 1, axis=1)[0]
+        with jax.named_scope(SSM_CORE):
+            # padding leaves the state as it was: a step of zero
+            y, state = ssd_chunked(
+                x, jnp.where(real, dt, 0.0), -jnp.exp(layer["A_log"]), b, c,
+                layer["ssm_D"], self.xs[2][j][None].astype(jnp.float32),
+                cfg.mamba_chunk_size)
+            state = state[0].astype(hybrid.STATE_DTYPE)
+            k_c, v_c, st, cv, lat = planes
+            st = write_slot_state(st, state, l, self.slot)
+            cv = write_slot_state(cv, tail.reshape(-1), l, self.slot)
+        self.out[2].append(state)
+        self.out[3].append(tail)
+        return y, (k_c, v_c, st, cv, lat)
+
 
 def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
                         start: int, quantized: bool = False):
@@ -296,13 +368,14 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
     is the float32 logits ``[vocab]`` of the prompt's last position,
     with routed experts ``(logits, experts chosen there [expert layers,
     k], their gates, counts [3])``, of a looped stack ``(logits, exit
-    gates there [passes], passes run)``.  ``quantized`` is the seam's: this family has the
+    gates there [passes], passes run)``, with state-space layers
+    ``(logits, [the chunk's real tokens, its rows])``.  ``quantized`` is the seam's: this family has the
     fp layout only (``models.configs.validate_serving`` refuses int8)."""
 
     @named(f"serve_prefill_chunk_o{start}")
     def prefill_chunk(cache, prefix, params, ids, slot, length):
         n_valid = jnp.clip(length - start, 0, chunk_len)
-        h = hybrid.embed_tokens(params, ids)
+        h = hybrid.embed_tokens(params, ids, config)
         h, planes, ys, routed, gates = hybrid.run_stack(
             h, params, config,
             lambda xs_p: ChunkMixer(config, xs_p, slot, n_valid, start,
@@ -312,12 +385,10 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
         if k_c.shape[0]:
             # the chunk's own K/V of every (pass, layer), off the end of
             # what it hands the next chunk, as whole blocks of the slot
-            blocks = (k_c.shape[0], chunk_len // cache.block_size) \
-                + k_c.shape[3:]
             k_c, v_c = (
                 write_slot_planes(
-                    plane, _pad_heads(own[:, start:], plane.shape[-2])
-                    .reshape(blocks), slot, start // cache.block_size)
+                    plane, _as_blocks(own[:, start:], plane), slot,
+                    start // cache.block_size)
                 for plane, own in ((k_c, ys[0]), (v_c, ys[1])))
         planes = (k_c, v_c, *rest)
         local = jnp.clip(length - 1 - start, 0, chunk_len - 1)
@@ -335,16 +406,18 @@ def build_prefill_chunk(config: ModelConfig, mesh: Mesh, chunk_len: int,
             last = (last, jax.lax.dynamic_index_in_dim(
                 gates[:, 0], local, 1, keepdims=False),
                 jnp.int32(gates.shape[0]))
+        elif config.layers_of(MAMBA):
+            last = (last, jnp.stack([n_valid, jnp.int32(chunk_len)]))
         # a kind the model has no layer of hands its empty prefix on
         ys = tuple(y if len(y) else p for y, p in zip(ys, prefix))
         return HybridCache(*planes, lengths), ys, last
 
     parts = 5 if config.layers_of(LATENT_ATTENTION) else 4
     pre_sh = tuple(NamedSharding(mesh, s)
-                   for s in prefix_specs(mesh)[:parts])
+                   for s in prefix_specs(mesh, config)[:parts])
     return jax.jit(
         prefill_chunk, donate_argnums=(0,),
-        out_shardings=(hybrid_cache_shardings(mesh), pre_sh,
+        out_shardings=(hybrid_cache_shardings(mesh, config), pre_sh,
                        NamedSharding(mesh, P())))
 
 
@@ -371,15 +444,31 @@ class DecodeMixer(_Mixer):
 
     def attention(self, q, k, v, l, planes):
         k_c, v_c, *rest = planes
+        scale = self.config.attention_multiplier
+        if k_c.ndim == 5:
+            # planes of whole rows (``models.configs.kv_rows``): a
+            # token's heads are written, and read, as one row
+            slots = q.shape[0]
+            with jax.named_scope(KV_UPDATE):
+                k_c, v_c = (
+                    append_latent_rows(plane, t.reshape(slots, -1), l,
+                                       self.lengths, self.active, self.mesh)
+                    for plane, t in ((k_c, k), (v_c, v)))
+            attn = decode_attention(
+                q.transpose(0, 2, 1, 3), k_c, v_c, l, self.lengths,
+                self.active, self.mesh, scale)
+            return attn.transpose(0, 2, 1, 3), (k_c, v_c, *rest)
         heads, held = q.shape[2], k_c.shape[-2]
         k_c = append_token_rows(k_c, _pad_heads(k, held), l, self.lengths,
                                 self.active, self.mesh)
         v_c = append_token_rows(v_c, _pad_heads(v, held), l, self.lengths,
                                 self.active, self.mesh)
         # the plane's added heads are attended by zero queries and cut
+        # ... and a group of zero queries for each of them
+        group = heads // k.shape[-2]
         attn = decode_attention(
-            _pad_heads(q, held).transpose(0, 2, 1, 3), k_c, v_c, l,
-            self.lengths, self.active, self.mesh)
+            _pad_heads(q, held * group).transpose(0, 2, 1, 3), k_c, v_c, l,
+            self.lengths, self.active, self.mesh, scale)
         return (attn.transpose(0, 2, 1, 3)[:, :, :heads],
                 (k_c, v_c, *rest))
 
@@ -429,6 +518,28 @@ class DecodeMixer(_Mixer):
                 st, jnp.where(keep, new.astype(st.dtype), old), l, 0)
         return o[:, None], (k_c, v_c, st, cv, lat)
 
+    def ssm(self, xbc, dt, layer, l, planes):
+        cfg = self.config
+        k_c, v_c, st, cv, lat = planes
+        with jax.named_scope(SSM_CONV):
+            before = _layer_of(cv, l)           # [B, (K - 1) x channels]
+            ext = jnp.concatenate([before, xbc[:, 0]], axis=-1)
+            x, b, c = hybrid.split_xbc(_conv_flat(ext, layer["ssm_conv"]),
+                                       layer, cfg)
+            cv = jax.lax.dynamic_update_index_in_dim(
+                cv, jnp.where(self.active[:, None],
+                              ext[:, cfg.mamba_conv_channels:], before),
+                l, 0)
+        with jax.named_scope(SSM_CORE):
+            old = _layer_of(st, l)
+            y, new = ssd_step(x, dt[:, 0], -jnp.exp(layer["A_log"]), b, c,
+                              layer["ssm_D"], old.astype(jnp.float32))
+            # an inactive slot's state stays bit for bit as it was
+            st = jax.lax.dynamic_update_index_in_dim(
+                st, jnp.where(self.active[:, None, None, None],
+                              new.astype(st.dtype), old), l, 0)
+        return y[:, None], (k_c, v_c, st, cv, lat)
+
 
 def _decode_math(carry, params, active, probe, config: ModelConfig,
                  mesh: Mesh):
@@ -441,7 +552,7 @@ def _decode_math(carry, params, active, probe, config: ModelConfig,
     ``counts`` the step's ``[3]`` with routed experts, the passes the
     step ran of a looped stack, else None."""
     cache, tok = carry
-    h = hybrid.embed_tokens(params, tok)[:, None, :]
+    h = hybrid.embed_tokens(params, tok, config)[:, None, :]
     h, planes, _, routed, gates = hybrid.run_stack(
         h, params, config,
         lambda _xs: DecodeMixer(config, mesh, cache.lengths, active, probe),
@@ -464,9 +575,9 @@ def _decode_math(carry, params, active, probe, config: ModelConfig,
     return (HybridCache(*planes, lengths), new_tok), new_tok, seen, counts
 
 
-def _decode_shardings(mesh: Mesh):
+def _decode_shardings(mesh: Mesh, config: ModelConfig):
     tok_sh = NamedSharding(mesh, token_spec(mesh))
-    return (hybrid_cache_shardings(mesh), tok_sh), tok_sh
+    return (hybrid_cache_shardings(mesh, config), tok_sh), tok_sh
 
 
 def build_decode_step(config: ModelConfig, mesh: Mesh):
@@ -477,7 +588,7 @@ def build_decode_step(config: ModelConfig, mesh: Mesh):
     def decode_step(carry, params, active, probe):
         return _decode_math(carry, params, active, probe, config, mesh)
 
-    carry_sh, tok_sh = _decode_shardings(mesh)
+    carry_sh, tok_sh = _decode_shardings(mesh, config)
     rep = NamedSharding(mesh, P())
     return jax.jit(decode_step, donate_argnums=(0,),
                    out_shardings=(carry_sh, tok_sh, rep, rep))
@@ -509,7 +620,7 @@ def build_decode_fused(config: ModelConfig, mesh: Mesh, k: int):
         lengths_f = lengths0 + act_i32 * jnp.minimum(jnp.int32(k), remaining)
         return (HybridCache(*planes, lengths_f), tok), toks, seen, counts
 
-    carry_sh, tok_sh = _decode_shardings(mesh)
+    carry_sh, tok_sh = _decode_shardings(mesh, config)
     toks_sh = NamedSharding(mesh, P(None, *token_spec(mesh)))
     rep = NamedSharding(mesh, P())
     return jax.jit(decode_fused, donate_argnums=(0,),
@@ -593,9 +704,10 @@ def check_serving(config: ModelConfig, serving: Any) -> None:
     ``validate_expert_parallelism``.  What it takes: every
     ``layer_types`` model ``ModelConfig`` builds (``norm_placement``
     ``post``, ``pre`` or ``sandwich``; full-attention layers with
-    QK-norm, with rotary positions, or with both; a looped stack of
-    ``total_ut_steps`` passes that every token runs to the end), chunked
-    prefill, fused decode scans, the fp K/V layout."""
+    QK-norm, with rotary positions, with both or with neither, their K
+    and V of ``num_kv_heads``; state-space ``mamba`` layers; a looped
+    stack of ``total_ut_steps`` passes that every token runs to the
+    end), chunked prefill, fused decode scans, the fp K/V layout."""
     latent = LATENT_ATTENTION in config.layer_types
     if config.early_exit_threshold < 1:
         raise ValueError(
@@ -715,6 +827,13 @@ def _counted(registry: Any, config: ModelConfig, samples: dict[str, list],
              kind: str, counts: Any) -> None:
     if config.has_routed_experts:
         _moe_counted(registry, config, samples, kind, counts)
+    elif config.layers_of(MAMBA):
+        # a prompt chunk's: the real tokens its scan ran over and the
+        # rows they were padded to (a ``serve-prefill-chunk`` span's
+        # ``seq`` is the samples' index)
+        real, rows = (int(v) for v in np.asarray(counts).reshape(2))
+        samples.setdefault("chunk_real_tokens", []).append(real)
+        samples.setdefault("chunk_rows", []).append(rows)
     else:
         # a looped stack's: the passes each step (or the chunk) ran
         counts = np.asarray(counts).reshape(-1)
@@ -747,10 +866,17 @@ def report_shares(config: ModelConfig, samples: dict[str, list]
     ``expert_load_max_over_mean`` (the fullest expert's tokens over the
     mean of the experts that got any, largest single layer); of a
     looped stack ``exit_pass_mean``, the passes a decode step or chunk
-    ran before its ``h`` went to the head, on average."""
+    ran before its ``h`` went to the head, on average; of a model with
+    state-space layers ``chunk_real_token_share``."""
     if samples.get("_loop_runs"):
         return {"exit_pass_mean":
                 samples["_loop_passes"] / samples["_loop_runs"]}
+    if samples.get("chunk_rows"):
+        # of the rows the chunked scans ran, the share that were prompt
+        # tokens (the rest is a last chunk's padding)
+        return {"chunk_real_token_share":
+                sum(samples["chunk_real_tokens"])
+                / sum(samples["chunk_rows"])}
     if not config.has_routed_experts:
         return {}
     touched = sum(samples.get("moe_unit_touched", ())) \

@@ -82,8 +82,10 @@ from dlbb_tpu.models.configs import (
     FULL_ATTENTION,
     LATENT_ATTENTION,
     LINEAR_ATTENTION,
+    MAMBA,
     ModelConfig,
     cache_kv_heads,
+    kv_rows,
 )
 from dlbb_tpu.models.transformer import SERVE_PHASES, _dtype_of
 from dlbb_tpu.ops.latent_attention import latent_spec
@@ -258,12 +260,26 @@ class HybridCache(NamedTuple):
     :class:`KVCache` does, but only for themselves (``L_full`` of the
     layers), and in a looped stack (``total_ut_steps`` passes over the
     same layers) a plane for every (pass, layer), pass-major: a token's
-    pass ``t`` attends to what pass ``t`` of the earlier tokens wrote.  The ``linear_attention`` layers keep, per layer and SLOT,
+    pass ``t`` attends to what pass ``t`` of the earlier tokens wrote.
+    Where a head is narrower than the TPU's 128 lanes and a token's
+    heads together are whole lanes (``models.configs.kv_rows``) the K/V
+    planes hold that ROW, ``[planes, max_batch, num_blocks, block_size,
+    kvh x d]``: head by head each head would take a whole lane-row's
+    room.  The RECURRENT layers (``linear_attention``, or the
+    state-space ``mamba``: a model has one of the two kinds) keep, per
+    layer and SLOT,
     a float32 recurrent state and the last ``conv_kernel - 1`` inputs of
     their short convolution: not paged and not growing with the slot's
     length, so the :class:`BlockLedger` never counts them (its blocks
     are K/V blocks of the full layers) and the build-time gate prices
-    them apart (``models.configs.state_cache_bytes``).
+    them apart (``models.configs.state_cache_bytes``).  ONE pair of
+    planes serves either kind, by shape: the state is ``[heads, values,
+    keys]`` of a head for both (``[heads, d_head, d_state]`` of a
+    state-space layer); the state-space layers' convolution runs over x,
+    B and C together, which no head owns, so their inputs lie flat,
+    ``[L, max_batch, (d_conv - 1) x channels]`` (a decode step shifts
+    whole lanes; ``[.., d_conv - 1, channels]`` would pad three rows to a
+    tile of eight or sixteen).
 
     A slot's state is valid from the prefill that claimed the slot: the
     first prompt chunk starts from a ZERO state
@@ -275,8 +291,11 @@ class HybridCache(NamedTuple):
     # planes: ``ModelConfig.kv_planes`` = passes x L_full
     k: jax.Array        # [planes, max_batch, num_blocks, block_size, kvh, d]
     v: jax.Array        # same
-    state: jax.Array    # f32 [L_lin, max_batch, heads, d_v, d_k]
-    conv: jax.Array     # [L_lin, max_batch, conv_kernel - 1, heads, 2 d_k + d_v]
+    # L_rec: the linear-attention or the state-space layers
+    state: jax.Array    # f32 [L_rec, max_batch, heads, d_v, d_k]
+    # [L_lin, max_batch, conv_kernel - 1, heads, 2 d_k + d_v], or
+    # [L_ssm, max_batch, (d_conv - 1) x channels]
+    conv: jax.Array
     latent: jax.Array   # [L_lat, max_batch, num_blocks, block_size, row]
     lengths: jax.Array  # [max_batch] int32
 
@@ -297,22 +316,34 @@ class HybridCache(NamedTuple):
         return self.num_blocks * self.block_size
 
 
-def hybrid_cache_specs(mesh: Optional[Mesh]) -> HybridCache:
+def hybrid_cache_specs(mesh: Optional[Mesh],
+                       config: Optional[ModelConfig] = None) -> HybridCache:
     """PartitionSpecs for :class:`HybridCache`: K/V as
     :func:`cache_specs`; state and convolution inputs with their slots
-    over ``dp`` and their heads over ``tp``."""
+    over ``dp`` and their heads over ``tp``.  ``config`` says which
+    planes have another rank than those (K/V of whole rows; a
+    state-space model's flat convolution inputs): slots over ``dp`` and
+    nothing over ``tp``, which serving refuses for both."""
     kv = cache_specs(mesh)
     dp, tp = kv.k[1], kv.k[4]
-    return HybridCache(k=kv.k, v=kv.v,
+    k_spec, conv_spec = kv.k, P(None, dp, None, tp, None)
+    if config is not None and kv_rows(config, mesh.shape.get("tp", 1)
+                                      if mesh is not None else 1):
+        k_spec = P(None, dp, None, None, None)
+    if config is not None and config.layers_of(MAMBA):
+        conv_spec = P(None, dp, None)
+    return HybridCache(k=k_spec, v=k_spec,
                        state=P(None, dp, tp, None, None),
-                       conv=P(None, dp, None, tp, None),
+                       conv=conv_spec,
                        latent=latent_spec(mesh),
                        lengths=kv.lengths)
 
 
-def hybrid_cache_shardings(mesh: Mesh) -> HybridCache:
+def hybrid_cache_shardings(mesh: Mesh,
+                           config: Optional[ModelConfig] = None
+                           ) -> HybridCache:
     return jax.tree.map(
-        lambda s: NamedSharding(mesh, s), hybrid_cache_specs(mesh),
+        lambda s: NamedSharding(mesh, s), hybrid_cache_specs(mesh, config),
         is_leaf=lambda x: isinstance(x, P),
     )
 
@@ -326,13 +357,24 @@ def create_hybrid_cache(config: ModelConfig, max_batch: int,
     n_lin = config.layers_of(LINEAR_ATTENTION)
     heads = config.linear_num_value_heads
     tp = mesh.shape.get("tp", 1) if mesh is not None else 1
-    kv_shape = (config.kv_planes, max_batch, num_blocks,
-                block_size, cache_kv_heads(config, tp), config.head_dim)
-    state_shape = (n_lin, max_batch, heads, config.linear_value_head_dim,
-                   config.linear_key_head_dim)
-    conv_shape = (n_lin, max_batch,
-                  max(config.linear_conv_kernel_dim - 1, 0), heads,
-                  config.linear_conv_channels // max(heads, 1))
+    kv_shape = (config.kv_planes, max_batch, num_blocks, block_size)
+    if kv_rows(config, tp):
+        kv_shape += (config.kv_heads * config.head_dim,)
+    else:
+        kv_shape += (cache_kv_heads(config, tp), config.head_dim)
+    n_ssm = config.layers_of(MAMBA)
+    if n_ssm:
+        state_shape = (n_ssm, max_batch, config.mamba_n_heads,
+                       config.mamba_d_head, config.mamba_d_state)
+        conv_shape = (n_ssm, max_batch, (config.mamba_d_conv - 1)
+                      * config.mamba_conv_channels)
+    else:
+        state_shape = (n_lin, max_batch, heads,
+                       config.linear_value_head_dim,
+                       config.linear_key_head_dim)
+        conv_shape = (n_lin, max_batch,
+                      max(config.linear_conv_kernel_dim - 1, 0), heads,
+                      config.linear_conv_channels // max(heads, 1))
     latent_shape = (config.layers_of(LATENT_ATTENTION), max_batch,
                     num_blocks, block_size, config.latent_row)
 
@@ -347,7 +389,8 @@ def create_hybrid_cache(config: ModelConfig, max_batch: int,
 
     if mesh is None:
         return build()
-    return jax.jit(build, out_shardings=hybrid_cache_shardings(mesh))()
+    return jax.jit(build,
+                   out_shardings=hybrid_cache_shardings(mesh, config))()
 
 
 def append_latent_rows(plane: jax.Array, rows: jax.Array, layer: jax.Array,

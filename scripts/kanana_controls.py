@@ -6,14 +6,15 @@ drives the same patches at toy widths).  Not part of the benchmark and
 not a way to serve the model.
 
     python scripts/kanana_controls.py [--seconds S] [--seed N]
-        [--rps R] [--chunk C] <control> ...
+        [--rps R] [--chunk C] [--slots B] <control> ...
 
 runs the cell ``kanana_serve_longctx_backlog`` once per named control
 (``sound`` is the program as it is), each in a process of its own (a
 chip belongs to one process), and prints one JSON line each: the
 control, ``correct``, the comparison's numbers, ``out_tokens_per_s``.
-``--rps`` / ``--chunk`` override the traffic's ``backlog_rps`` and the
-configuration's ``prefill_chunk`` (the sweeps that set them).
+``--rps`` / ``--chunk`` / ``--slots`` override the traffic's
+``backlog_rps`` and the configuration's ``prefill_chunk`` and
+``max_batch`` (the sweeps that set them).
 """
 
 from __future__ import annotations
@@ -116,7 +117,7 @@ def rmsnorm_bf16(x, scale, eps):
                  * scale.astype(jnp.float32)).astype(x.dtype)
 
 
-def chunk_attention_bf16(qh, k_all, v_all, start):
+def chunk_attention_bf16(qh, k_all, v_all, start, scale=None):
     """``serve/attend.py::_chunk_attention`` (plain MHA) with its scores,
     its softmax and its output rounded to bfloat16."""
     import jax
@@ -128,7 +129,8 @@ def chunk_attention_bf16(qh, k_all, v_all, start):
     v32 = v_all.transpose(1, 0, 2).astype(jnp.float32)[None]
     mask = (jnp.arange(k_all.shape[0])[None, :]
             <= (start + jnp.arange(c))[:, None])
-    logits = _bf16(jnp.einsum("bnqd,bnkd->bnqk", q32, k32) / d ** 0.5)
+    logits = jnp.einsum("bnqd,bnkd->bnqk", q32, k32)
+    logits = _bf16(logits / d ** 0.5 if scale is None else logits * scale)
     probs = _bf16(jax.nn.softmax(
         jnp.where(mask[None, None], logits, -jnp.inf), axis=-1))
     return _bf16(jnp.einsum("bnqk,bnkd->bnqd", probs, v32)).astype(
@@ -228,7 +230,7 @@ def apply(name: str, setattr_: Callable[[Any, str, Any], None],
 
 def run_one(name: str, seconds: float, seed: int, rps: float,
             chunk: int, trace: bool = False, cell: str = CELL,
-            controls: Any = None) -> dict:
+            controls: Any = None, slots: int = 0) -> dict:
     """One run of ``cell`` made wrong as ``controls[name]`` says (this
     file's :data:`CONTROLS` by default; ``scripts/ouro_controls.py``
     hands in its own)."""
@@ -242,6 +244,8 @@ def run_one(name: str, seconds: float, seed: int, rps: float,
     traffic = dict(cell.traffic)
     if rps:
         traffic["backlog_rps"] = rps
+    if slots:
+        config["program"]["serving"]["max_batch"] = slots
     if chunk:
         serving = config["program"]["serving"]
         serving["prefill_chunk"] = chunk
@@ -280,13 +284,14 @@ def main(script: str = __file__, cell: str = CELL, controls: Any = None,
     parser.add_argument("--seed", type=int, default=2147483659)
     parser.add_argument("--rps", type=float, default=0.0)
     parser.add_argument("--chunk", type=int, default=0)
+    parser.add_argument("--slots", type=int, default=0)
     parser.add_argument("--child", action="store_true")
     args = parser.parse_args()
     if args.child:
         (name,) = args.controls
         print("RESULT " + json.dumps(run_one(
             name, args.seconds, args.seed, args.rps, args.chunk, cell=cell,
-            controls=controls)), flush=True)
+            controls=controls, slots=args.slots)), flush=True)
         return 0
     out = ROOT / "chiprun_out"
     out.mkdir(exist_ok=True)
@@ -295,7 +300,8 @@ def main(script: str = __file__, cell: str = CELL, controls: Any = None,
         done = subprocess.run(
             [sys.executable, script, "--child", name, "--seconds",
              str(args.seconds), "--seed", str(args.seed + i), "--rps",
-             str(args.rps), "--chunk", str(args.chunk)],
+             str(args.rps), "--chunk", str(args.chunk), "--slots",
+             str(args.slots)],
             capture_output=True, text=True)
         found = [l[7:] for l in done.stdout.splitlines()
                  if l.startswith("RESULT ")]

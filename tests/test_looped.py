@@ -244,7 +244,7 @@ def test_a_looped_stacks_residual_stream_is_float32_whatever_the_weights(
                                                     jax.random.key(0)))
 
         def run(params, ids):
-            h = hybrid.embed_tokens(params, ids)
+            h = hybrid.embed_tokens(params, ids, config)
             return hybrid.run_stack(h, params, config,
                                     lambda _xs: Recording(config, 8), None)[0]
         return jax.eval_shape(run, params,
@@ -493,7 +493,9 @@ def test_leaving_the_loop_early_is_refused_by_its_mechanism():
     (dict(total_ut_steps=0), "total_ut_steps >= 1"),
     (dict(early_exit_threshold=1.5), "early_exit_threshold <= 1"),
     (dict(norm_placement="both"), "unknown norm_placement"),
-    (dict(rope_theta=0.0), "model family not implemented"),
+    # (a full_attention layer with neither QK-norm nor rotary was
+    # refused until PR 37; it is Granite's ``nope``, and is taken: below)
+    (dict(mlp="gelu"), "model family not implemented"),
     (dict(layer_types=["full_attention", "linear_attention"],
           num_layers=4, linear_num_key_heads=4, linear_num_value_heads=4,
           linear_key_head_dim=8, linear_value_head_dim=8,
@@ -509,9 +511,18 @@ def test_model_config_refuses(change, reason):
 
 def test_the_refusal_says_what_the_family_takes():
     with pytest.raises(ValueError) as said:
-        ModelConfig.from_dict({**TOY, "rope_theta": 0.0})
-    for word in ("sandwich", "rope_theta > 0", "total_ut_steps"):
+        ModelConfig.from_dict({**TOY, "norm": "layernorm"})
+    for word in ("sandwich", "rope_theta > 0, both or neither",
+                 "total_ut_steps"):
         assert word in str(said.value)
+    # full-attention layers WITHOUT positions and without QK-norm are a
+    # model of the family (the recurrent layers beside them, or here
+    # nothing, carry position), and another function than the rotary one
+    nope = ModelConfig.from_dict({**TOY, "rope_theta": 0.0})
+    params = init_params(nope, jax.random.key(0))
+    ids = jnp.asarray(np.random.default_rng(2).integers(0, 256, (1, 9)))
+    assert float(jnp.abs(hybrid.forward(params, ids, nope)
+                         - hybrid.forward(params, ids, CONFIG)).max()) > 1e-3
     assert "sandwich" in family_for(CONFIG).check_serving.__doc__
     assert "total_ut_steps" in family_for(CONFIG).check_serving.__doc__
 

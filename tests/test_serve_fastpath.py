@@ -899,6 +899,99 @@ def test_looped_programs_compiled_for_the_v5e_hold_one_layer_body_and_copy_no_pl
     assert compiled.memory_analysis().temp_size_in_bytes < 96 * 2**20
 
 
+@pytest.mark.parametrize("program", ["serve_decode_step", "serve_decode_k16",
+                                     "serve_prefill_chunk_o256"])
+def test_state_space_programs_compiled_for_the_v5e_update_the_state_in_place(
+        v5e_chip, as_on_the_chip, program):
+    """The granite-4.0-h-micro cell's programs at its published widths
+    and its slots (one period of its four: nine state-space layers and
+    one attention layer; the loop's body does not depend on their
+    number): the float32 state plane ``[9, slots, 64, 64, 128]`` (1.2 GB
+    here, 4.8 GB at 40 layers) and the K/V planes of whole rows ``[1,
+    slots, 80, 16, 512]`` are written in place and nothing else of a
+    whole plane's, or of a plane's layer's, shape is produced outside a
+    fusion; Mosaic takes the row-layout ``kv_attend_decode`` (heads of
+    64), once in the decode loop's body; and what a program holds beside
+    its arguments is small (with the in-projection held whole, 8512
+    columns, the fused scan re-laid all its kernels out: 1.19 GiB at 40
+    layers, ``PERF.md`` §6, PR 37)."""
+    from benchmarks.harness import cells
+    from dlbb_tpu.models import hybrid
+    from dlbb_tpu.serve import hybrid as serve_hybrid
+    from dlbb_tpu.serve.kvcache import create_hybrid_cache
+
+    mesh = v5e_chip
+    cell = cells.resolve_cell("granite4h_serve_chat_backlog") \
+        .config["program"]
+    cfg = ModelConfig.from_dict(dict(cell["model"], num_layers=10))
+    sv = ServingConfig.from_dict(cell["serving"])
+    rep = NamedSharding(mesh, P())
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=rep), tree)
+
+    def like(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=rep)
+
+    params = shaped(jax.eval_shape(
+        lambda: hybrid.init_params(cfg, jax.random.key(0))))
+    cache = shaped(jax.eval_shape(lambda: create_hybrid_cache(
+        cfg, sv.max_batch, sv.num_blocks, sv.block_size)))
+    b, nb = sv.max_batch, sv.num_blocks
+    assert cache.k.shape == (1, b, nb, 16, 512)
+    assert cache.state.shape == (9, b, 64, 64, 128)
+    assert cache.conv.shape == (9, b, 3 * 4352)
+    carry = (cache, like((b,), jnp.int32))
+    masks = (like((b,), jnp.bool_),)
+    probe = like((serve_hybrid.PROBES,), jnp.int32)
+    if program == "serve_decode_step":
+        traced = serve_hybrid.build_decode_step(cfg, mesh).trace(
+            carry, params, *masks, probe)
+    elif program == "serve_decode_k16":
+        traced = serve_hybrid.build_decode_fused(cfg, mesh, 16).trace(
+            carry, params, *masks, like((b,), jnp.int32), probe)
+    else:
+        prefix = tuple(
+            like((t.shape[0], 256) + t.shape[2:], t.dtype) if i < 2 else t
+            for i, t in enumerate(shaped(jax.eval_shape(
+                lambda: serve_hybrid.create_prefix(cfg, mesh)))))
+        traced = serve_hybrid.build_prefill_chunk(cfg, mesh, 256, 256).trace(
+            cache, prefix, params, like((1, 256), jnp.int32),
+            like((), jnp.int32), like((), jnp.int32))
+    compiled = traced.lower(lowering_platforms=("tpu",)).compile()
+    hlo = compiled.as_text()
+    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo)
+    if program.startswith("serve_decode"):
+        assert len(calls) == 1 and calls[0].startswith("kv_attend_decode"), \
+            calls
+    else:
+        assert calls == []
+    whole = {f"[{','.join(map(str, dims))}]" for dims in (
+        cache.state.shape, cache.state.shape[1:], (1,) + cache.state.shape[1:],
+        cache.k.shape, cache.k.shape[1:], (b, nb * 16, 512),
+        cache.conv.shape)}
+    left = {}
+    for computation, _, name, result, op in _hlo_instructions(hlo):
+        if ("fused_computation" not in computation
+                and op not in _PLANE_PLUMBING | _PLANE_UPDATES | {"fusion"}
+                and re.sub(r"^[a-z0-9]+|\{.*$", "", result) in whole):
+            left[name] = op
+    assert not left, f"ops of a whole plane's or layer's shape: {left}"
+    # every fusion that gives a whole plane is the in-place write
+    writers = {name: line for _, line, name, result, op
+               in _hlo_instructions(hlo)
+               if op == "fusion" and "fused_computation" not in _
+               and re.sub(r"^[a-z0-9]+|\{.*$", "", result)
+               == f"[{','.join(map(str, cache.state.shape))}]"}
+    # (a chunk writes one slot's state: plain ``dynamic-update-slice``s)
+    assert writers or not program.startswith("serve_decode")
+    assert all("dynamic-update-slice" in name
+               or "dynamic_update_slice" in line
+               for name, line in writers.items()), writers
+    assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
+
+
 @pytest.mark.parametrize("family", ["gpt", "hybrid"])
 def test_decode_step_attends_through_the_kernel_compiled_for_the_v5e(
         v5e_chip, as_on_the_chip, family):
