@@ -1400,6 +1400,10 @@ class ServingEngine:
                 start = np.array([ledger.tokens(s) for s in steps])
                 stepping = trip < np.array(list(steps.values()))[None, :]
                 stats.unit_slot_steps.append(int(stepping.sum()))
+                if self._probes:
+                    self._family.unit_dispatched(
+                        self.registry, self.config, stats.family,
+                        stats.unit_slot_steps[-1], k, cfg.max_batch)
                 stats.unit_live_tokens.append(
                     int(((start[None, :] + trip + 1) * stepping).sum()))
                 if self._kv_tile:

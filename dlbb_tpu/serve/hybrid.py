@@ -86,6 +86,7 @@ from dlbb_tpu.models.hybrid import (
 from dlbb_tpu.models.transformer import _dtype_of, named
 from dlbb_tpu.ops import decode_attention as kv_kernel
 from dlbb_tpu.ops import latent_attention as latent_kernel
+from dlbb_tpu.ops import state_plane
 from dlbb_tpu.ops.decode_attention import decode_attention
 from dlbb_tpu.ops.latent_attention import (
     LATENT_ATTEND,
@@ -97,7 +98,7 @@ from dlbb_tpu.ops.gated_delta import (
     gated_delta_chunked,
     gated_delta_step,
 )
-from dlbb_tpu.ops.ssd import ssd_chunked, ssd_step
+from dlbb_tpu.ops.ssd import ssd_chunked, ssd_plane_step
 from dlbb_tpu.obs import spans
 from dlbb_tpu.serve.attend import KV_UPDATE, _chunk_attention, _layer_of
 from dlbb_tpu.serve.kvcache import (
@@ -531,13 +532,11 @@ class DecodeMixer(_Mixer):
                               ext[:, cfg.mamba_conv_channels:], before),
                 l, 0)
         with jax.named_scope(SSM_CORE):
-            old = _layer_of(st, l)
-            y, new = ssd_step(x, dt[:, 0], -jnp.exp(layer["A_log"]), b, c,
-                              layer["ssm_D"], old.astype(jnp.float32))
-            # an inactive slot's state stays bit for bit as it was
-            st = jax.lax.dynamic_update_index_in_dim(
-                st, jnp.where(self.active[:, None, None, None],
-                              new.astype(st.dtype), old), l, 0)
+            # in place in the carried plane, the active slots alone: an
+            # inactive slot's state stays bit for bit as it was
+            y, st = ssd_plane_step(x, dt[:, 0], -jnp.exp(layer["A_log"]), b,
+                                   c, layer["ssm_D"], st, l, self.active,
+                                   self.mesh)
         return y[:, None], (k_c, v_c, st, cv, lat)
 
 
@@ -748,8 +747,11 @@ def attend_tiles(config: ModelConfig, cache: HybridCache,
     """Which paged plane the decode kernel of this model fetches, and by
     tiles of how many tokens: ``("latent", T)`` or ``("kv", T)`` (what
     the scheduler's ``serve_<name>_tiles_live`` / ``_held`` count by).
-    Refuses, with the reason, planes the kernel cannot read on the
-    chip."""
+    Refuses, with the reason, planes the decode kernels cannot read on
+    the chip: the paged one, and a state-space model's state plane
+    (``ops/state_plane.py``)."""
+    if config.layers_of(MAMBA):
+        state_plane.check_kernel_takes(cache.state)
     if config.layers_of(LATENT_ATTENTION):
         latent_kernel.check_kernel_takes(cache.latent, config.kv_lora_rank)
         return "latent", latent_kernel.plane_tile_tokens(cache.latent)
@@ -803,6 +805,15 @@ def register_metrics(registry: Any, config: ModelConfig, serving: Any,
             "serve_loop_passes", 0,
             help="passes through the looped stack the decode steps and "
                  "prompt chunks ran (each reads the stack's weights once)")
+    if config.layers_of(MAMBA):
+        registry.inc(
+            "serve_state_slots_stepped", 0,
+            help="slots' states of a state-space layer the decode steps "
+                 "read and wrote (active slots x steps x layers)")
+        registry.inc(
+            "serve_state_slots_held", 0,
+            help="slots' states of a state-space layer the plane holds, "
+                 "times decode steps (max_batch x steps x layers)")
 
 
 def _moe_counted(registry: Any, config: ModelConfig,
@@ -843,6 +854,22 @@ def _counted(registry: Any, config: ModelConfig, samples: dict[str, list],
         samples["_loop_runs"] = samples.get("_loop_runs", 0) + len(counts)
 
 
+def unit_dispatched(registry: Any, config: ModelConfig,
+                    samples: dict[str, list], slot_steps: int,
+                    steps: int, slots: int) -> None:
+    """A decode unit of ``steps`` steps over a batch of ``slots`` goes
+    out, ``slot_steps`` (slot, step) pairs of it active by the
+    scheduler's ledger: what the state-space layers' kernel moves of the
+    state plane (``ops/state_plane.py``), and what the plane holds."""
+    layers = config.layers_of(MAMBA)
+    if layers:
+        for name, n in (("stepped", slot_steps * layers),
+                        ("held", slots * steps * layers)):
+            registry.inc(f"serve_state_slots_{name}", n)
+            samples[f"_state_slots_{name}"] = (
+                samples.get(f"_state_slots_{name}", 0) + n)
+
+
 def unit_counted(registry: Any, config: ModelConfig,
                  samples: dict[str, list], counts: Any) -> None:
     """A decode unit is done and its ``counts`` (None for a plain dense
@@ -867,16 +894,24 @@ def report_shares(config: ModelConfig, samples: dict[str, list]
     mean of the experts that got any, largest single layer); of a
     looped stack ``exit_pass_mean``, the passes a decode step or chunk
     ran before its ``h`` went to the head, on average; of a model with
-    state-space layers ``chunk_real_token_share``."""
+    state-space layers ``chunk_real_token_share`` and
+    ``state_live_share`` (slots' states the decode steps moved over
+    those the plane held)."""
     if samples.get("_loop_runs"):
         return {"exit_pass_mean":
                 samples["_loop_passes"] / samples["_loop_runs"]}
-    if samples.get("chunk_rows"):
-        # of the rows the chunked scans ran, the share that were prompt
-        # tokens (the rest is a last chunk's padding)
-        return {"chunk_real_token_share":
+    if config.layers_of(MAMBA):
+        out = {}
+        if samples.get("chunk_rows"):
+            # of the rows the chunked scans ran, the share that were
+            # prompt tokens (the rest is a last chunk's padding)
+            out["chunk_real_token_share"] = (
                 sum(samples["chunk_real_tokens"])
-                / sum(samples["chunk_rows"])}
+                / sum(samples["chunk_rows"]))
+        if samples.get("_state_slots_held"):
+            out["state_live_share"] = (samples["_state_slots_stepped"]
+                                       / samples["_state_slots_held"])
+        return out
     if not config.has_routed_experts:
         return {}
     touched = sum(samples.get("moe_unit_touched", ())) \

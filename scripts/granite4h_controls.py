@@ -48,24 +48,24 @@ def _state_bfloat16(setattr_, model):
 
 
 def _recurrence(change: Callable[[Any, Any], tuple]):
-    """Both forms of ``ops/ssd.py`` wherever the program reads them,
-    with ``(a, d)`` replaced by ``change(a, d)``."""
+    """The decode and the chunked form of ``ops/ssd.py`` wherever the
+    program reads them, with ``(a, d)`` replaced by ``change(a, d)``."""
     def patch(setattr_, model):
         from dlbb_tpu.models import hybrid
         from dlbb_tpu.ops import ssd
         from dlbb_tpu.serve import hybrid as serve_hybrid
 
-        step, chunked = ssd.ssd_step, ssd.ssd_chunked
+        step, chunked = ssd.ssd_plane_step, ssd.ssd_chunked
 
-        def wrong_step(x, dt, a, b, c, d, state):
+        def wrong_step(x, dt, a, b, c, d, *plane):
             a, d = change(a, d)
-            return step(x, dt, a, b, c, d, state)
+            return step(x, dt, a, b, c, d, *plane)
 
         def wrong_chunked(x, dt, a, b, c, d, state, chunk):
             a, d = change(a, d)
             return chunked(x, dt, a, b, c, d, state, chunk)
 
-        setattr_(serve_hybrid, "ssd_step", wrong_step)
+        setattr_(serve_hybrid, "ssd_plane_step", wrong_step)
         setattr_(serve_hybrid, "ssd_chunked", wrong_chunked)
         setattr_(hybrid, "ssd_chunked", wrong_chunked)
     return patch

@@ -264,3 +264,51 @@ def test_tile_counters_equal_the_share_of_the_traces_own_lengths(
     text = prom.read_text()
     assert f"dlbb_serve_kv_tiles_live_total {live}\n" in text
     assert f"dlbb_serve_kv_tiles_held_total {held}\n" in text
+    # a model without state-space layers counts no state stepped
+    assert "state_live_share" not in report
+    assert "serve_state_slots" not in text
+
+
+@pytest.mark.parametrize("horizon", [1, 4], ids=["per-step", "fused"])
+def test_state_counters_equal_what_the_ledger_says_with_a_slot_idle(
+        tmp_path, horizon):
+    """``serve_state_slots_stepped`` is the (slot, step) pairs that held
+    a request times the state-space layers, which is what the state
+    kernel moves (``ops/state_plane.py``): request ``(p, o)`` takes ``o -
+    1`` decode steps whatever the schedule; ``serve_state_slots_held`` is
+    what the plane holds, every slot every step.  Three requests on four
+    slots leave one idle throughout.  Report and ``metrics.prom`` carry
+    both; a model without such layers carries neither (the test
+    above)."""
+    from dlbb_tpu.models.configs import ModelConfig
+    from dlbb_tpu.serve.config import ServingConfig
+    from dlbb_tpu.serve.engine import ServingEngine
+    from dlbb_tpu.serve.traffic import Request, TrafficTrace
+
+    model = ModelConfig.from_dict(dict(
+        hidden_size=128, num_layers=4, num_heads=2, num_kv_heads=1,
+        ffn_intermediate=64, dtype="float32", norm="rmsnorm", mlp="swiglu",
+        bias=False, qk_norm=False, norm_placement="pre", vocab_size=64,
+        layer_types=["mamba", "mamba", "full_attention", "mamba"],
+        mamba_n_heads=4, mamba_d_head=64, mamba_d_state=16,
+        mamba_n_groups=1, mamba_expand=2, mamba_d_conv=4,
+        mamba_chunk_size=16, tie_word_embeddings=True))
+    serving = ServingConfig(max_batch=4, max_seq=64, block_size=8,
+                            prefill_chunk=16, decode_horizon=horizon)
+    engine = ServingEngine(model, serving, _mesh(), verbose=False)
+    trace = TrafficTrace(kind="test", seed=0, params={}, requests=tuple(
+        Request(rid=i, arrival_s=0.0, prompt_len=p, output_len=o,
+                seed=100 + i)
+        for i, (p, o) in enumerate([(20, 9), (5, 4), (33, 12)])))
+    report = engine.run_trace(trace)
+    assert report["requests"]["completed"] == len(trace)
+
+    layers = 3
+    stepped = layers * sum(r.output_len - 1 for r in trace)
+    held = layers * report["decode_steps"] * serving.max_batch
+    assert report["state_live_share"] == pytest.approx(stepped / held)
+    assert 0.0 < report["state_live_share"] <= 0.75
+    text = engine.registry.write_textfile(
+        tmp_path / "metrics.prom").read_text()
+    assert f"dlbb_serve_state_slots_stepped_total {stepped}\n" in text
+    assert f"dlbb_serve_state_slots_held_total {held}\n" in text

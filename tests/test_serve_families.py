@@ -31,9 +31,10 @@ SEAM_CONSTANTS = {"TOKENS_FED_BACK": bool, "PROBES": int, "LACKS": dict}
 CAPABILITIES = {
     # a probing family's decode programs return ``(tokens, seen,
     # counts)``: it says how a chunk's ``last`` lays out as ``seen``, and
-    # books the counts of a unit and of a chunk
-    "probe": ("slot_state", "probe_parts", "probe_names", "unit_counted",
-              "chunk_counted"),
+    # books the counts of a unit (what it is asked at its dispatch, what
+    # it counted once it is done) and of a chunk
+    "probe": ("slot_state", "probe_parts", "probe_names", "unit_dispatched",
+              "unit_counted", "chunk_counted"),
     "monolithic_prefill": ("build_prefill", "build_prefix_attach"),
 }
 

@@ -906,15 +906,20 @@ def test_state_space_programs_compiled_for_the_v5e_update_the_state_in_place(
     """The granite-4.0-h-micro cell's programs at its published widths
     and its slots (one period of its four: nine state-space layers and
     one attention layer; the loop's body does not depend on their
-    number): the float32 state plane ``[9, slots, 64, 64, 128]`` (1.2 GB
-    here, 4.8 GB at 40 layers) and the K/V planes of whole rows ``[1,
-    slots, 80, 16, 512]`` are written in place and nothing else of a
-    whole plane's, or of a plane's layer's, shape is produced outside a
-    fusion; Mosaic takes the row-layout ``kv_attend_decode`` (heads of
-    64), once in the decode loop's body; and what a program holds beside
-    its arguments is small (with the in-projection held whole, 8512
-    columns, the fused scan re-laid all its kernels out: 1.19 GiB at 40
-    layers, ``PERF.md`` §6, PR 37)."""
+    number).  The float32 state plane ``[9, slots, 64, 64, 128]`` (1.2 GB
+    here, 4.8 GB at 40 layers) is stepped in place by a Mosaic kernel of
+    its own in the decode programs (``ssm_state_step``, once a
+    state-space layer of the period, its result aliased to the plane it
+    is handed): no fusion, no ``copy`` and no other op of the plane's or
+    of a plane's layer's shape is left there (the nine
+    ``select_dynamic-update-slice`` writers are gone); a chunk writes one
+    slot's state by plain ``dynamic-update-slice``s.  The K/V planes of
+    whole rows ``[1, slots, 80, 16, 512]`` and the convolution inputs are
+    written in place; Mosaic takes the row-layout ``kv_attend_decode``
+    (heads of 64), once in the decode loop's body; and what a program
+    holds beside its arguments is small (with the in-projection held
+    whole, 8512 columns, the fused scan re-laid all its kernels out:
+    1.19 GiB at 40 layers, ``PERF.md`` §6, PR 37)."""
     from benchmarks.harness import cells
     from dlbb_tpu.models import hybrid
     from dlbb_tpu.serve import hybrid as serve_hybrid
@@ -961,31 +966,45 @@ def test_state_space_programs_compiled_for_the_v5e_update_the_state_in_place(
             like((), jnp.int32), like((), jnp.int32))
     compiled = traced.lower(lowering_platforms=("tpu",)).compile()
     hlo = compiled.as_text()
-    calls = re.findall(r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo)
-    if program.startswith("serve_decode"):
-        assert len(calls) == 1 and calls[0].startswith("kv_attend_decode"), \
-            calls
-    else:
-        assert calls == []
-    whole = {f"[{','.join(map(str, dims))}]" for dims in (
-        cache.state.shape, cache.state.shape[1:], (1,) + cache.state.shape[1:],
+    decode = program.startswith("serve_decode")
+    calls = [(name, line) for _, line, name, _, op in _hlo_instructions(hlo)
+             if op == "custom-call" and "tpu_custom_call" in line]
+    kernels = sorted(re.sub(r"[.\d]+$", "", name) for name, _ in calls)
+    # two kernels in the loop's body: the attention layer's, and the
+    # state step once a state-space layer of the period
+    assert kernels == (["kv_attend_decode"] + 9 * ["ssm_state_step"]
+                       if decode else []), kernels
+    plane = f"f32[{','.join(map(str, cache.state.shape))}]"
+    for name, line in calls:
+        if name.startswith("ssm_state_step"):
+            # the plane is the call's last operand and its first result
+            operands = line.split("custom-call(")[1].split(")")[0]
+            last = operands.count("%") - 1
+            assert line.split(" = ")[1].startswith(f"({plane}"), line[:200]
+            assert f"output_to_operand_aliasing={{{{0}}: ({last}, {{}})" \
+                in line, line[-300:]
+    state = {f"[{','.join(map(str, dims))}]" for dims in (
+        cache.state.shape, cache.state.shape[1:],
+        (1,) + cache.state.shape[1:])}
+    whole = state | {f"[{','.join(map(str, dims))}]" for dims in (
         cache.k.shape, cache.k.shape[1:], (b, nb * 16, 512),
         cache.conv.shape)}
     left = {}
     for computation, _, name, result, op in _hlo_instructions(hlo):
-        if ("fused_computation" not in computation
-                and op not in _PLANE_PLUMBING | _PLANE_UPDATES | {"fusion"}
-                and re.sub(r"^[a-z0-9]+|\{.*$", "", result) in whole):
+        shape = re.sub(r"^[a-z0-9]+|\{.*$", "", result)
+        if "fused_computation" in computation or op in _PLANE_PLUMBING:
+            continue
+        if (shape in whole and op not in _PLANE_UPDATES | {"fusion"}
+                or decode and shape in state):
             left[name] = op
     assert not left, f"ops of a whole plane's or layer's shape: {left}"
-    # every fusion that gives a whole plane is the in-place write
+    # every fusion that gives a whole state plane is a chunk's in-place
+    # write of one slot's state
     writers = {name: line for _, line, name, result, op
                in _hlo_instructions(hlo)
                if op == "fusion" and "fused_computation" not in _
                and re.sub(r"^[a-z0-9]+|\{.*$", "", result)
                == f"[{','.join(map(str, cache.state.shape))}]"}
-    # (a chunk writes one slot's state: plain ``dynamic-update-slice``s)
-    assert writers or not program.startswith("serve_decode")
     assert all("dynamic-update-slice" in name
                or "dynamic_update_slice" in line
                for name, line in writers.items()), writers

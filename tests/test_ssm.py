@@ -4,7 +4,10 @@ a tied head) at toy widths on the CPU, against the plain float32
 reference ``benchmarks/reference/granite4_hybrid.py`` on seeded weights.
 
 (a) ``ops/ssd.py``: the chunked scan equals its own recurrent step,
-across chunk boundaries and from a carried state; (b) prefill in chunks
+across chunk boundaries and from a carried state, and the decode kernel
+over the carried plane (``ssm_state_step``, interpreted here) equals that
+step for the active slots and touches nothing else, alone and through
+the fused scan; (b) prefill in chunks
 then decode through the cache, per-step and fused, on logits and on
 every state-space layer's state; (c) ``models.forward``; (d) every
 listed fault is read by the comparison; (e) an inactive slot's state and
@@ -38,7 +41,7 @@ from dlbb_tpu.models.configs import (
     kv_rows,
     state_cache_bytes,
 )
-from dlbb_tpu.ops import ssd
+from dlbb_tpu.ops import ssd, state_plane
 from dlbb_tpu.serve import hybrid as serve_hybrid
 from dlbb_tpu.serve.config import ServingConfig
 from dlbb_tpu.serve.engine import ServingEngine, family_for
@@ -177,6 +180,118 @@ def test_a_step_of_zero_leaves_the_state_as_it_was():
     _, state = ssd.ssd_chunked(x, jnp.zeros((1, 5, 2)), -jnp.ones(2), bc, bc,
                                jnp.ones(2), state0, 4)
     np.testing.assert_array_equal(state, state0)
+
+
+def _select_plane_step(x, dt, a, b, c, d, plane, layer, active, mesh):
+    """The step as it was before the kernel: :func:`ssd.ssd_step` over
+    the sliced layer, and a ``select`` that keeps an inactive slot's."""
+    old = jax.lax.dynamic_index_in_dim(plane, layer, 0, keepdims=False)
+    y, new = ssd.ssd_step(x, dt, a, b, c, d, old)
+    return y, jax.lax.dynamic_update_index_in_dim(
+        plane, jnp.where(active[:, None, None, None], new, old), layer, 0)
+
+
+@pytest.mark.parametrize("mask", ["full", "sparse", "single", "empty"])
+@pytest.mark.parametrize("shape, block, layer", [
+    # (slots, heads, P, N); heads a block; the layer of three
+    ((4, 4, 8, 16), 4, 0),        # one block a slot
+    ((5, 6, 8, 128), 2, 2),       # three: a slot starts in either buffer
+    ((3, 4, 16, 32), 1, 1),       # four
+], ids=["4x4x8x16", "5x6x8x128", "3x4x16x32"])
+def test_plane_kernel_is_the_plain_step_of_the_active_slots_and_touches_no_other(
+        monkeypatch, shape, block, layer, mask):
+    """``ssd_plane_step`` against ``ssd_step``: ``y`` and the state of
+    the active slots within float32's rounding (one reduction in another
+    order), every inactive slot and every other layer of the plane bit
+    for bit, whatever the mask and however a slot's heads fall into
+    blocks."""
+    slots, heads, p, n = shape
+    monkeypatch.setattr(state_plane, "BLOCK_BYTES", block * p * n * 4)
+    assert state_plane.block_heads(heads, p, n, 4) == block
+    active = {"full": np.ones(slots, bool),
+              "sparse": np.arange(slots) % 2 == 1,
+              "single": np.arange(slots) == slots - 1,
+              "empty": np.zeros(slots, bool)}[mask]
+    keys = jax.random.split(jax.random.key(slots * heads + layer), 7)
+    plane = jax.random.normal(keys[0], (3, slots, heads, p, n), jnp.float32)
+    x = jax.random.normal(keys[1], (slots, heads, p), jnp.float32)
+    dt = jax.nn.softplus(jax.random.normal(keys[2], (slots, heads)))
+    a = -jnp.exp(jax.random.normal(keys[3], (heads,)))
+    d = jax.random.normal(keys[4], (heads,))
+    b, c = (jax.random.normal(k, (slots, n), jnp.float32) for k in keys[5:])
+    y_ref, new_ref = ssd.ssd_step(x, dt, a, b, c, d, plane[layer])
+    y, after = jax.jit(ssd.ssd_plane_step, static_argnums=(9,))(
+        x, dt, a, b, c, d, plane, jnp.int32(layer), jnp.asarray(active),
+        _mesh())
+    was, now = np.asarray(plane), np.asarray(after)
+    np.testing.assert_allclose(np.asarray(y)[active],
+                               np.asarray(y_ref)[active],
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(now[layer][active],
+                               np.asarray(new_ref)[active],
+                               rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(now[layer][~active], was[layer][~active])
+    others = [i for i in range(3) if i != layer]
+    np.testing.assert_array_equal(now[others], was[others])
+
+
+def test_fused_scan_steps_through_the_kernel_the_slots_each_trip_still_has(
+        monkeypatch):
+    """``build_decode_fused``: a trip's mask is ``active & (i <
+    remaining)``, another one every trip.  Against the same program over
+    the step as it was before the kernel (``ssd_step`` and a ``select``):
+    the same tokens, the stepping slots' state within float32's
+    rounding, and the slot that has no step left and the inactive one
+    bit for bit as they were."""
+    mesh = _mesh()
+    params = hybrid.init_params(CONFIG, jax.random.key(0))
+    rng = np.random.default_rng(1)
+    cache = create_hybrid_cache(CONFIG, 4, 16, 8, mesh=mesh)
+    cache = cache._replace(
+        state=jnp.asarray(rng.standard_normal(cache.state.shape),
+                          jnp.float32),
+        conv=jnp.asarray(rng.standard_normal(cache.conv.shape), jnp.float32),
+        lengths=jnp.asarray([5, 9, 7, 3], jnp.int32))
+    before = np.asarray(cache.state)
+    args = (params, jnp.asarray([True, True, True, False]),
+            jnp.asarray([4, 2, 0, 1], jnp.int32),
+            jnp.zeros((serve_hybrid.PROBES,), jnp.int32))
+    tok = jnp.asarray([1, 2, 3, 4], jnp.int32)
+
+    def run():
+        fused = serve_hybrid.build_decode_fused(CONFIG, mesh, 4)
+        (after, _), toks, seen, _ = fused(          # the carry is donated
+            jax.tree.map(jnp.copy, (cache, tok)), *args)
+        return np.asarray(after.state), np.asarray(toks), np.asarray(seen)
+
+    state, toks, seen = run()
+    monkeypatch.setattr(serve_hybrid, "ssd_plane_step", _select_plane_step)
+    state_was, toks_was, seen_was = run()
+    np.testing.assert_array_equal(toks, toks_was)
+    np.testing.assert_allclose(seen, seen_was, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(state[:, :2], state_was[:, :2],
+                               rtol=1e-5, atol=1e-5)
+    assert not np.array_equal(state[:, :2], before[:, :2])
+    for stays in (state, state_was):
+        np.testing.assert_array_equal(stays[:, 2:], before[:, 2:])
+
+
+def test_a_state_plane_the_chip_cannot_take_is_refused_when_the_engine_is_built(
+        monkeypatch):
+    """On the chip the kernel copies a head's state as whole (8, 128)
+    tiles and no dense path stands behind it: an engine whose heads hold
+    ``[64, 16]`` says so, with the reason, when it is built; the cell's
+    ``[64, 128]`` are taken, in float32 and in the bfloat16 of the
+    control, whose tiles are (16, 128)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError,
+                       match=r"whole \(8, 128\) tiles.*heads of \[64, 16\]"):
+        _engine()
+    like = jax.ShapeDtypeStruct
+    state_plane.check_kernel_takes(like((36, 64, 64, 64, 128), jnp.float32))
+    state_plane.check_kernel_takes(like((36, 64, 64, 64, 128), jnp.bfloat16))
+    with pytest.raises(ValueError, match=r"\(16, 128\) tiles"):
+        state_plane.check_kernel_takes(like((2, 4, 4, 8, 128), jnp.bfloat16))
 
 
 # -- (c) the whole-sequence forward --------------------------------------------
