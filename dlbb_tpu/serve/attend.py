@@ -3,7 +3,9 @@ cache with: a layer of a carried plane (``_layer_of``, ``_layer_tokens``),
 the head-major view of an activation (``_heads``), and the three dense
 fp32 attentions over cached keys that are not the decode kernel of
 ``ops/decode_attention.py``: ``_chunk_attention`` (a prompt chunk over the
-carried prefix, static offset-causal mask), ``_cached_attention`` (one
+carried prefix, static offset-causal mask; past ``CHUNK_SCORES_BYTES`` of
+scores :func:`chunk_attention` runs the kernel of
+``ops/chunk_attention.py`` instead), ``_cached_attention`` (one
 token a slot over the whole layer, per-slot length mask: the int8
 layout's path) and ``_verify_attention`` (its gamma+1-position
 generalisation for a speculative verify).
@@ -19,13 +21,16 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from dlbb_tpu.models.transformer import SERVE_PHASES
+from dlbb_tpu.ops.chunk_attention import chunk_attend
 
 KV_UPDATE, KV_ATTEND = SERVE_PHASES
-# the float32 scores a prompt chunk's attention may hold at once before
-# its queries are taken in blocks (:func:`chunk_attention`): a global
-# layer's chunk at offset 15,360 of 16,384 would hold 1.9 GB a layer
+# the dense float32 scores a prompt chunk's attention may write to HBM;
+# past them it runs as the ``chunk_attend`` kernel (:func:`chunk_attention`):
+# a global layer's chunk at offset 15,360 of 16,384 would hold 1.9 GB a
+# layer
 CHUNK_SCORES_BYTES = 1 << 28
 
 
@@ -125,34 +130,39 @@ def _chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
     return out.astype(k_all.dtype)
 
 
+def chunk_on_kernel(heads: int, chunk: int, keys: int) -> bool:
+    """Whether a prompt chunk's attention runs as the ``chunk_attend``
+    kernel: where its dense float32 scores, query heads x chunk x keys x
+    4 B, would pass ``CHUNK_SCORES_BYTES``."""
+    return heads * chunk * keys * 4 > CHUNK_SCORES_BYTES
+
+
 def chunk_attention(qh: jax.Array, k_all: jax.Array, v_all: jax.Array,
                     start: int, scale: Optional[float] = None,
-                    mask: Optional[jax.Array] = None,
+                    keys_at: Optional[np.ndarray] = None, window: int = 0,
                     dense: Callable = _chunk_attention) -> jax.Array:
-    """``dense`` (:func:`_chunk_attention`), its queries taken in blocks
-    (one ``lax.map`` body) where the whole chunk's float32 scores would
-    pass ``CHUNK_SCORES_BYTES``: the same arithmetic, a block's scores at
-    a time.  Below it the very same program as ``dense``."""
-    b, n, c, _ = qh.shape
-    s_tot = k_all.shape[0]
-    block = c
-    while block > 8 and block % 2 == 0 \
-            and n * block * s_tot * 4 > CHUNK_SCORES_BYTES:
-        block //= 2
-    if block == c:
-        if mask is None:
-            return dense(qh, k_all, v_all, start, scale)
-        return dense(qh, k_all, v_all, start, scale, mask)
-    if mask is None:
-        mask = (jnp.arange(s_tot)[None, :]
-                <= (start + jnp.arange(c))[:, None])
-    blocks = c // block
-    qb = qh.reshape(b, n, blocks, block, -1).transpose(2, 0, 1, 3, 4)
-    out = jax.lax.map(
-        lambda xs: dense(xs[0], k_all, v_all, start, scale, xs[1]),
-        (qb, mask.reshape(blocks, block, s_tot)))
-    return out.transpose(1, 2, 0, 3, 4).reshape(qh.shape[:3]
-                                                + (v_all.shape[-1],))
+    """A prompt chunk's attention over keys at the static positions
+    ``keys_at`` (-1: none; the prefix ``0 .. S`` where none are given):
+    query ``p`` sees key positions ``0 <= j <= p``, and ``j > p -
+    window`` with a ``window``.  Past ``CHUNK_SCORES_BYTES`` of dense
+    scores (:func:`chunk_on_kernel`) the online-softmax kernel
+    ``ops/chunk_attention.py::chunk_attend``, its scores in VMEM; below
+    it ``dense`` (:func:`_chunk_attention`) over the same boolean mask."""
+    _, n, c, _ = qh.shape
+    if chunk_on_kernel(n, c, k_all.shape[0]):
+        with jax.named_scope(KV_ATTEND):
+            return chunk_attend(
+                qh, k_all, v_all,
+                np.arange(k_all.shape[0]) if keys_at is None else keys_at,
+                start, window, scale)
+    if keys_at is None:
+        return dense(qh, k_all, v_all, start, scale)
+    keys = jnp.asarray(keys_at)[None, :]
+    at = (start + jnp.arange(c))[:, None]
+    mask = (keys >= 0) & (keys <= at)
+    if window:
+        mask &= keys > at - window
+    return dense(qh, k_all, v_all, start, scale, mask)
 
 
 @jax.named_scope(KV_ATTEND)

@@ -199,6 +199,11 @@ class _RunStats:
     fused_steps: int = 0
     single_steps: int = 0
     prefill_chunks: int = 0
+    # layer attentions of the prompt chunks dispatched that ran as the
+    # ``chunk_attend`` kernel, and those that ran dense
+    # (``family.chunk_attn_counts``)
+    chunk_attn_kernel: int = 0
+    chunk_attn_dense: int = 0
     # K/V tiles the decode units' steps fetched (ops/decode_attention.py)
     # and tiles the planes they ran over hold, times steps
     kv_tiles_live: int = 0
@@ -304,6 +309,12 @@ class ServingEngine:
             ("serve_fused_scan_steps",
              "decode steps executed inside fused lax.scan dispatches"),
             ("serve_prefill_chunks", "prefill chunks processed"),
+            ("serve_chunk_attn_kernel",
+             "layer attentions of the prompt chunks dispatched that ran "
+             "as the chunk_attend kernel"),
+            ("serve_chunk_attn_dense",
+             "layer attentions of the prompt chunks dispatched that ran "
+             "as the dense float32 attention"),
             ("serve_decode_units_overlapped",
              "single-step decode units waited for only after a later "
              "program (a prompt chunk, a prefix attach) was launched "
@@ -2117,6 +2128,13 @@ class ServingEngine:
                         lasts.append(y_last)
                         stats.prefill_chunks += 1
                         self.registry.inc("serve_prefill_chunks")
+                        on_kernel, dense = self._family.chunk_attn_counts(
+                            self.config, ci * chunk, chunk)
+                        stats.chunk_attn_kernel += on_kernel
+                        stats.chunk_attn_dense += dense
+                        self.registry.inc("serve_chunk_attn_kernel",
+                                          on_kernel)
+                        self.registry.inc("serve_chunk_attn_dense", dense)
                         # the chunk is queued: now wait for the step
                         # dispatched before it, then interleave the next
                         # one (the resident batch decodes between chunks
@@ -2658,6 +2676,13 @@ class ServingEngine:
             # single steps waited for after a later launch went out (a
             # prompt chunk queued behind them): of ``decode_units``
             "decode_units_overlapped": stats.decode_units_overlapped,
+            # of the prompt chunks' layer attentions, the share that ran
+            # as the ``chunk_attend`` kernel
+            "chunk_attn_kernel_share": (
+                stats.chunk_attn_kernel
+                / (stats.chunk_attn_kernel + stats.chunk_attn_dense)
+                if stats.chunk_attn_kernel + stats.chunk_attn_dense
+                else 0.0),
             # calls of a jitted serving program (``_launch``): the span
             # file's ``launch`` arguments run 0..launches-1
             "launches": stats.launches,

@@ -889,6 +889,15 @@ def attend_tiles(config: ModelConfig, cache: Any,
     return "kv", plane_tile_tokens(cache.k, mesh)
 
 
+def chunk_attn_counts(config: ModelConfig, start: int,
+                      chunk_len: int) -> tuple[int, int]:
+    """The layer attentions of the chunk program at ``start`` that run as
+    the ``chunk_attend`` kernel (none: this family's chunk is
+    ``attend._chunk_attention``) and those that run dense (every
+    layer)."""
+    return 0, config.num_layers
+
+
 def report_shares(config: ModelConfig, samples: dict,
                   serving: Any = None) -> dict:
     """What the report says of this family's own layers: nothing."""

@@ -118,6 +118,7 @@ from dlbb_tpu.serve.attend import (
     _chunk_attention,
     _layer_of,
     chunk_attention,
+    chunk_on_kernel,
 )
 from dlbb_tpu.serve.kvcache import (
     HybridCache,
@@ -323,7 +324,7 @@ class ChunkMixer(_Mixer):
         cfg = self.config
         w, kvh, d = cfg.sliding_window, k.shape[2], k.shape[3]
         with jax.named_scope(WIN_ATTEND):
-            keys_at = jnp.arange(self.chunk_len) + self.start
+            keys_at = self.start + np.arange(self.chunk_len)
             if self.start:
                 # what the ring holds of this slot, token-major: ONE
                 # slice at (layer, slot) (a layer sliced first is every
@@ -335,16 +336,13 @@ class ChunkMixer(_Mixer):
                     for plane in planes[5:])
                 k_all = jnp.concatenate([ring_k.astype(k.dtype), k[0]], 0)
                 v_all = jnp.concatenate([ring_v.astype(v.dtype), v[0]], 0)
-                keys_at = jnp.concatenate(
-                    [jnp.asarray(ring_positions(self.start, w)), keys_at])
+                keys_at = np.concatenate(
+                    [ring_positions(self.start, w), keys_at])
             else:
                 k_all, v_all = k[0], v[0]
-            at = (self.start + jnp.arange(self.chunk_len))[:, None]
-            mask = ((keys_at[None, :] >= 0) & (keys_at[None, :] <= at)
-                    & (keys_at[None, :] > at - w))
             attn = chunk_attention(q.transpose(0, 2, 1, 3), k_all, v_all,
                                    self.start, cfg.attention_multiplier,
-                                   mask, dense=_chunk_attention)
+                                   keys_at, w, dense=_chunk_attention)
         self.out[5].append(k[0])
         self.out[6].append(v[0])
         attn = attn.transpose(0, 2, 1, 3)
@@ -1096,6 +1094,23 @@ def chunk_counted(registry: Any, config: ModelConfig,
     """A prompt chunk's ``last`` is ready."""
     if isinstance(last, tuple):
         _counted(registry, config, samples, "chunk", last[-1])
+
+
+def chunk_attn_counts(config: ModelConfig, start: int,
+                      chunk_len: int) -> tuple[int, int]:
+    """The layer attentions of the chunk program at ``start`` that run as
+    the ``chunk_attend`` kernel and those that run dense, by
+    ``serve/attend.py::chunk_on_kernel``: a full-attention layer's (every
+    pass of a looped stack) over the prefix and the chunk, a window
+    layer's over the ring and the chunk, a latent layer's always dense."""
+    heads = config.num_heads
+    attends = ((config.kv_planes, start + chunk_len),
+               (config.layers_of(SLIDING_ATTENTION),
+                chunk_len + (config.sliding_window if start else 0)))
+    kernel = sum(n for n, keys in attends
+                 if chunk_on_kernel(heads, chunk_len, keys))
+    return kernel, (sum(n for n, _ in attends) - kernel
+                    + config.layers_of(LATENT_ATTENTION))
 
 
 def report_shares(config: ModelConfig, samples: dict[str, list],

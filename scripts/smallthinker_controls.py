@@ -279,8 +279,9 @@ def _float32_parts_bfloat16(setattr_, model):
     states as float32 (the router and its softmax, the norms, the
     combine, a prompt chunk's scores and softmax) computed in bfloat16,
     each product rounded by ``reduce_precision``.  (The decode kernel's
-    softmax and the ReGLU's activation sit inside a kernel and a fusion,
-    with no seam for a control.)"""
+    softmax, a chunk's past ``attend.CHUNK_SCORES_BYTES`` of scores,
+    which runs as the ``chunk_attend`` kernel, and the ReGLU's activation
+    sit inside a kernel and a fusion, with no seam for a control.)"""
     from dlbb_tpu.models import hybrid
     from dlbb_tpu.serve import hybrid as serve_hybrid
 

@@ -841,8 +841,9 @@ def test_window_programs_compiled_for_the_v5e_read_rings_and_copy_no_plane(
     128 with a group of 7, once over the global plane and three times
     over the rings, and the grouped ReGLU products; the rings hold 4,096
     tokens a slot; no instruction but the in-place writes produces a
-    whole plane or ring; the chunk at the cell's last offset takes its
-    queries in blocks and holds little beside its arguments."""
+    whole plane or ring; the chunk at the cell's last offset runs its
+    four layers' attention as ``chunk_attend`` and holds little beside
+    its arguments."""
     from benchmarks.harness import cells
     from dlbb_tpu.models import hybrid
     from dlbb_tpu.serve import hybrid as serve_hybrid
@@ -886,7 +887,8 @@ def test_window_programs_compiled_for_the_v5e_read_rings_and_copy_no_plane(
     calls = sorted(c.split(".")[0] for c in re.findall(
         r"%(\S+) = \S+ custom-call\(.*tpu_custom_call", hlo))
     decode = program == "serve_decode_step"
-    assert calls == ["gmm"] * 12 + ["kv_attend_decode"] * 4 * decode
+    assert calls == sorted(["gmm"] * 12 + [
+        "kv_attend_decode" if decode else "chunk_attend"] * 4)
     whole = {f"[{','.join(map(str, dims))}]" for plane in (cache.k, cache.wk)
              for dims in (plane.shape, plane.shape[1:],
                           (1,) + plane.shape[1:])}

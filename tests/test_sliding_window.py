@@ -34,6 +34,7 @@ from dlbb_tpu.models.configs import (
 )
 from dlbb_tpu.ops import routed_experts as moe
 from dlbb_tpu.ops.decode_attention import decode_attention
+from dlbb_tpu.serve import attend
 from dlbb_tpu.serve.config import ServingConfig
 from dlbb_tpu.serve.engine import ServingEngine
 from dlbb_tpu.serve.kvcache import create_hybrid_cache, ring_positions
@@ -157,13 +158,13 @@ def _probed_against_reference(engine, results):
     return errors, window, same
 
 
-@pytest.mark.parametrize("horizon, window", [(1, 1), (4, 2)],
-                         ids=["per-step", "fused-k4"])
-@pytest.mark.parametrize("rids", [(0, 6), (2, 4)],
-                         ids=lambda r: f"rids{r[0]}-{r[1]}")
-def test_cached_logits_routing_and_window_match_the_reference(horizon, window,
-                                                              rids):
-    engine = _engine(decode_horizon=horizon, inflight_window=window)
+def _cached_against_reference(engine, horizon, rids):
+    """One trace through ``engine`` with ``rids`` probed: logits, routing
+    and the first window layer's attention against the reference, and
+    what the programs counted."""
+    reg = engine.registry
+    booked = (reg.get("serve_chunk_attn_kernel")
+              + reg.get("serve_chunk_attn_dense"))
     engine.probe(rids)
     report = engine.run_trace(_trace(), collect_raw=True)
     assert report["requests"]["completed"] == len(LENGTHS)
@@ -190,11 +191,45 @@ def test_cached_logits_routing_and_window_match_the_reference(horizon, window,
     steps = sum(raw["unit_slot_steps"])
     assert sum(raw["unit_ring_tiles"]) == 3 * steps
     assert report["window_live_share"] > 0
-    reg = engine.registry
     assert reg.get("serve_window_kv_bytes") == window_cache_bytes(
         CONFIG, 4) == 3 * 4 * 16 * 128 * 4 * 2
     assert reg.get("serve_window_tiles_live") >= 3 * steps
     assert report["kv_slot_bytes"] < report["kv_slot_bytes_flat"]
+    # every chunk's four layer attentions, each booked once
+    chunks = sum(-(-p // 8) for p, _ in LENGTHS)
+    assert (reg.get("serve_chunk_attn_kernel")
+            + reg.get("serve_chunk_attn_dense")) - booked == 4 * chunks
+    return report
+
+
+@pytest.mark.parametrize("horizon, window", [(1, 1), (4, 2)],
+                         ids=["per-step", "fused-k4"])
+@pytest.mark.parametrize("rids", [(0, 6), (2, 4)],
+                         ids=lambda r: f"rids{r[0]}-{r[1]}")
+def test_cached_logits_routing_and_window_match_the_reference(horizon, window,
+                                                              rids):
+    engine = _engine(decode_horizon=horizon, inflight_window=window)
+    report = _cached_against_reference(engine, horizon, rids)
+    # toy chunks never pass CHUNK_SCORES_BYTES: every attention dense
+    assert engine.registry.get("serve_chunk_attn_kernel") == 0
+    assert report["chunk_attn_kernel_share"] == 0.0
+
+
+def test_cached_logits_match_the_reference_with_every_chunk_on_the_kernel(
+        monkeypatch):
+    """The same engine with ``CHUNK_SCORES_BYTES`` at 0: every prompt
+    chunk's attention, global and window, runs as ``chunk_attend``
+    (interpreted), and the cached logits, routing and window attention
+    still match the float32 reference within the same limits."""
+    monkeypatch.setattr(attend, "CHUNK_SCORES_BYTES", 0)
+    engine = ServingEngine(
+        CONFIG, ServingConfig(**{**SERVING, "decode_horizon": 4,
+                                 "inflight_window": 2}),
+        _mesh(), seed=5, verbose=False, capture_tokens=True)
+    report = _cached_against_reference(engine, 4, (0, 6))
+    assert engine.registry.get("serve_chunk_attn_dense") == 0
+    assert engine.registry.get("serve_chunk_attn_kernel") > 0
+    assert report["chunk_attn_kernel_share"] == 1.0
 
 
 def test_recycled_slot_gives_a_fresh_engines_logits():
