@@ -50,11 +50,30 @@ MOE_PHASES = ("moe_router", "moe_dispatch", "moe_experts", "moe_combine",
               "moe_shared")
 MOE_ROUTER, MOE_DISPATCH, MOE_EXPERTS, MOE_COMBINE, MOE_SHARED = MOE_PHASES
 
-# gmm's (rows, contraction, columns) tile.  Few rows: a decode step has
-# about 3 rows an expert, and every visited (row tile, expert) pair
-# costs a whole row tile of arithmetic; the whole contraction and the
-# whole expert width, so that an expert's matrix is one or two tiles
-GMM_TILING = (128, 2048, 768)
+# gmm's tiling, chosen from the product's shape by :func:`_gmm_tiling`.
+# ``megablox.gmm`` walks ``(column tiles, visited (row tile, expert)
+# pairs, contraction tiles)`` with the contraction innermost, so a
+# contraction in two tiles changes the expert's block at every step and
+# fetches the expert's matrix again for every row tile that visits it;
+# in one tile, consecutive visits of one expert keep the block and the
+# fetch is skipped.  Megablox sets no ``vmem_limit_bytes``: the kernel
+# has the v5e's default scoped 16 MiB, of which the double-buffered
+# lhs, rhs and out blocks and the float32 accumulator may take
+# ``GMM_VMEM_BUDGET``; the rest is the kernel's own temporaries (the
+# dot's float32 result, the store's mask).  Rows: a decode step has
+# 3 to 5 rows an expert, and every visited (row tile, expert) pair
+# costs a whole row tile of arithmetic.  A sweep at the two expert
+# cells' shapes (TPU v5 lite, ``scripts/gmm_tiling_bench.py``, the
+# stacked form the layer scan calls, loads up to 16 x the mean; PERF.md
+# §6) chose these: SmallThinker's chunk gate/up 0.510 ms at (128, 2560,
+# 768) against 0.877 at the old fixed (128, 2048, 768), down 0.529 at
+# (128, 768, 2560) against 0.642 at (128, 768, 768); kanana's chunk down
+# 0.846 at (128, 768, 2048) against 0.968.  At every shape the widest
+# column tile that fits was the fastest, and rows of 256 were slower
+# than rows of 128 (SmallThinker's chunk gate/up 0.566, down 0.617).
+GMM_ROWS = 128
+GMM_VMEM_BUDGET = 12 * 2**20
+_LANES = 128
 
 
 class Routing(NamedTuple):
@@ -108,18 +127,60 @@ def dispatch(u: jax.Array, experts: jax.Array, num_experts: int,
         return Dispatch(rows, order, sizes[:num_experts], live)
 
 
+def gmm_vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM of one ``gmm`` grid step: the lhs ``[tm, tk]``, rhs ``[tk,
+    tn]`` and out ``[tm, tn]`` blocks double-buffered in the operands'
+    dtype, and the float32 accumulator ``[tm, tn]``."""
+    return 2 * (tm * tk + tk * tn + tm * tn) * itemsize + 4 * tm * tn
+
+
+def _lane_tiles(x: int) -> list[int]:
+    """Tiles of a dimension ``x``, largest first: the multiples of 128
+    that divide it, or ``x`` itself where none does (a width under 128
+    or off the lanes is taken whole)."""
+    tiles = [t for t in range(x - x % _LANES, 0, -_LANES) if x % t == 0]
+    return tiles or [x]
+
+
+def _gmm_tiling(m: int, k: int, n: int, itemsize: int = 2
+                ) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` for a grouped product of ``m`` rows ``[m, k]``
+    by ``[k, n]`` experts: the whole contraction (``tk = k``) with the
+    widest column tile that divides ``n`` and fits
+    :data:`GMM_VMEM_BUDGET`; only where no column tile fits beside the
+    whole contraction, the widest contraction tile that divides ``k``
+    that lets one fit.  ``m`` does not move it: :data:`GMM_ROWS`."""
+    del m
+    tm = GMM_ROWS
+    for tk in _lane_tiles(k):
+        for tn in _lane_tiles(n):
+            if gmm_vmem_bytes(tm, tk, tn, itemsize) <= GMM_VMEM_BUDGET:
+                return tm, tk, tn
+    return tm, _lane_tiles(k)[-1], _lane_tiles(n)[-1]
+
+
+def gmm_counts(tokens: int, top_k: int, hidden: int, width: int,
+               itemsize: int) -> tuple[int, int]:
+    """The grouped products of one expert layer over ``tokens`` tokens
+    (gate and up ``[hidden, width]``, down ``[width, hidden]``) whose
+    contraction is one tile, and those whose contraction is split."""
+    m = tokens * top_k
+    whole = sum(_gmm_tiling(m, k, n, itemsize)[1] == k
+                for k, n in ((hidden, width), (hidden, width),
+                             (width, hidden)))
+    return whole, 3 - whole
+
+
 def _gmm(rows: jax.Array, w: jax.Array, sizes: jax.Array) -> jax.Array:
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = rows.shape
-    n = w.shape[-1]
-    tm, tk, tn = GMM_TILING
-    pad = -m % tm
+    tiling = _gmm_tiling(m, k, w.shape[-1], rows.dtype.itemsize)
+    pad = -m % tiling[0]
     if pad:
         rows = jnp.pad(rows, ((0, pad), (0, 0)))
     out = gmm(rows, w, sizes, preferred_element_type=rows.dtype,
-              tiling=(tm, min(tk, k), min(tn, n)),
-              interpret=jax.default_backend() != "tpu")
+              tiling=tiling, interpret=jax.default_backend() != "tpu")
     return out[:m] if pad else out
 
 

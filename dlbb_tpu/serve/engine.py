@@ -204,6 +204,11 @@ class _RunStats:
     # (``family.chunk_attn_counts``)
     chunk_attn_kernel: int = 0
     chunk_attn_dense: int = 0
+    # grouped expert products of the decode steps and prompt chunks
+    # dispatched whose contraction was one tile, and those whose
+    # contraction was split (``family.gmm_counts``)
+    gmm_whole_k: int = 0
+    gmm_split_k: int = 0
     # K/V tiles the decode units' steps fetched (ops/decode_attention.py)
     # and tiles the planes they ran over hold, times steps
     kv_tiles_live: int = 0
@@ -302,6 +307,11 @@ class ServingEngine:
             "serve_deadline_exceeded", "reason",
             initial=("shed-queued", "completed-late"),
             help="per-request SLO deadline misses, by how they surfaced",
+        )
+        self._gmm_products = self.registry.labeled_counter(
+            "serve_gmm_products", "k_tiles", initial=("whole", "split"),
+            help="grouped expert products of the decode steps and prompt "
+                 "chunks dispatched, by contraction tiles",
         )
         for name, hlp in (
             ("serve_decode_steps",
@@ -469,6 +479,16 @@ class ServingEngine:
         return time.perf_counter() - self._t0
 
     # -- the launch sequence -------------------------------------------------
+
+    def _book_gmm(self, stats: _RunStats, tokens: int, steps: int = 1
+                  ) -> None:
+        """Book the grouped expert products of a program dispatched over
+        ``tokens`` tokens ``steps`` times, by contraction tiles."""
+        whole, split = self._family.gmm_counts(self.config, tokens)
+        stats.gmm_whole_k += whole * steps
+        stats.gmm_split_k += split * steps
+        self._gmm_products["whole"] += whole * steps
+        self._gmm_products["split"] += split * steps
 
     def _launch(self, program, *args, span: str = "serve-launch",
                 fields=None, via=None):
@@ -1403,6 +1423,7 @@ class ServingEngine:
                     self.registry.inc("serve_fused_scan_steps", k)
                     for s in sorted(steps):
                         rows.append((s, s, slots[s].req.rid, steps[s]))
+                self._book_gmm(stats, cfg.max_batch, k)
                 # this unit's launch: its wait names it (``sync_one``)
                 launched = stats.launches - 1
                 # what the unit's steps attend over: step i of a slot
@@ -2135,6 +2156,7 @@ class ServingEngine:
                         self.registry.inc("serve_chunk_attn_kernel",
                                           on_kernel)
                         self.registry.inc("serve_chunk_attn_dense", dense)
+                        self._book_gmm(stats, chunk)
                         # the chunk is queued: now wait for the step
                         # dispatched before it, then interleave the next
                         # one (the resident batch decodes between chunks
@@ -2683,6 +2705,11 @@ class ServingEngine:
                 / (stats.chunk_attn_kernel + stats.chunk_attn_dense)
                 if stats.chunk_attn_kernel + stats.chunk_attn_dense
                 else 0.0),
+            # of the grouped expert products dispatched, the share whose
+            # contraction was one tile (``ops/routed_experts.py``)
+            "gmm_whole_k_share": (
+                stats.gmm_whole_k / (stats.gmm_whole_k + stats.gmm_split_k)
+                if stats.gmm_whole_k + stats.gmm_split_k else 0.0),
             # calls of a jitted serving program (``_launch``): the span
             # file's ``launch`` arguments run 0..launches-1
             "launches": stats.launches,

@@ -898,6 +898,13 @@ def chunk_attn_counts(config: ModelConfig, start: int,
     return 0, config.num_layers
 
 
+def gmm_counts(config: ModelConfig, tokens: int) -> tuple[int, int]:
+    """The grouped expert products of one step or chunk by contraction
+    tiles (whole, split): none, this family's block has no routed
+    experts."""
+    return 0, 0
+
+
 def report_shares(config: ModelConfig, samples: dict,
                   serving: Any = None) -> dict:
     """What the report says of this family's own layers: nothing."""

@@ -99,7 +99,7 @@ from dlbb_tpu.models.hybrid import (
 from dlbb_tpu.models.transformer import _dtype_of, named
 from dlbb_tpu.ops import decode_attention as kv_kernel
 from dlbb_tpu.ops import latent_attention as latent_kernel
-from dlbb_tpu.ops import state_plane
+from dlbb_tpu.ops import routed_experts, state_plane
 from dlbb_tpu.ops.decode_attention import decode_attention
 from dlbb_tpu.ops.latent_attention import (
     LATENT_ATTEND,
@@ -1111,6 +1111,20 @@ def chunk_attn_counts(config: ModelConfig, start: int,
                  if chunk_on_kernel(heads, chunk_len, keys))
     return kernel, (sum(n for n, _ in attends) - kernel
                     + config.layers_of(LATENT_ATTENTION))
+
+
+def gmm_counts(config: ModelConfig, tokens: int) -> tuple[int, int]:
+    """The grouped expert products of one step or chunk over ``tokens``
+    tokens whose contraction is one tile and those whose contraction is
+    split, by ``ops/routed_experts.py::_gmm_tiling``: three an expert
+    layer (every pass of a looped stack), none without experts."""
+    if not config.has_routed_experts:
+        return 0, 0
+    whole, split = routed_experts.gmm_counts(
+        tokens, config.num_experts_per_tok, config.hidden_size,
+        config.moe_intermediate_size, jnp.dtype(config.dtype).itemsize)
+    layers = config.expert_layers * config.total_ut_steps
+    return whole * layers, split * layers
 
 
 def report_shares(config: ModelConfig, samples: dict[str, list],

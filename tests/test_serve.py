@@ -463,6 +463,23 @@ def test_engine_serves_poisson_trace_clean(smoke_engine, tmp_path):
     assert 'dlbb_serve_cache_blocks{stat="peak_blocks_in_use"}' in text
 
 
+def test_a_model_without_experts_books_no_grouped_product(smoke_engine,
+                                                         tmp_path):
+    """The GPT block has no routed experts: its steps and chunks book no
+    ``gmm`` product, whole or split, and the report's share reads 0."""
+    from dlbb_tpu.obs.export import serving_metrics
+
+    report = smoke_engine.run_trace(_smoke_trace(n=4, seed=3))
+    assert report["requests"]["completed"] == 4
+    assert report["gmm_whole_k_share"] == 0.0
+    reg = smoke_engine.registry
+    assert reg.get("serve_gmm_products", k_tiles="whole") == 0
+    assert reg.get("serve_gmm_products", k_tiles="split") == 0
+    text = serving_metrics(report, registry=reg).write_textfile(
+        tmp_path / "metrics.prom").read_text()
+    assert 'dlbb_serve_gmm_products_total{k_tiles="whole"} 0' in text
+
+
 def test_engine_bounded_queue_rejects_under_overload(smoke_engine):
     """Admission control: a queue bound of 1 under a burst MUST shed
     load — rejections counted, journaled as queue-full, and the rest of

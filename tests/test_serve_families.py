@@ -24,7 +24,7 @@ SEAM_FUNCTIONS = (
     "check_serving", "register_metrics", "slot_recycled", "fresh_carry",
     "create_prefix", "prompt_input", "build_prefill_chunk",
     "decode_programs", "inject_token", "attend_tiles", "report_shares",
-    "chunk_attn_counts",
+    "chunk_attn_counts", "gmm_counts",
 )
 SEAM_CONSTANTS = {"TOKENS_FED_BACK": bool, "PROBES": int, "LACKS": dict}
 # the scheduler's names for what a family may lack; what stands behind

@@ -165,6 +165,7 @@ def _cached_against_reference(engine, horizon, rids):
     reg = engine.registry
     booked = (reg.get("serve_chunk_attn_kernel")
               + reg.get("serve_chunk_attn_dense"))
+    products = reg.get("serve_gmm_products", k_tiles="whole")
     engine.probe(rids)
     report = engine.run_trace(_trace(), collect_raw=True)
     assert report["requests"]["completed"] == len(LENGTHS)
@@ -199,6 +200,12 @@ def _cached_against_reference(engine, horizon, rids):
     chunks = sum(-(-p // 8) for p, _ in LENGTHS)
     assert (reg.get("serve_chunk_attn_kernel")
             + reg.get("serve_chunk_attn_dense")) - booked == 4 * chunks
+    # three grouped products an expert layer, four layers, in every
+    # chunk and decode step; a toy width is one contraction tile
+    assert reg.get("serve_gmm_products", k_tiles="whole") - products == (
+        3 * 4 * (chunks + report["decode_steps"]))
+    assert reg.get("serve_gmm_products", k_tiles="split") == 0
+    assert report["gmm_whole_k_share"] == 1.0
     return report
 
 
