@@ -2,10 +2,12 @@
 mesh-sharded KV-cache, driven by synthetic traffic traces.  Three boxes,
 imports one way (scheduler -> a family's programs -> cache helpers):
 
-- ``engine.py``  — the scheduler: ``ServingEngine`` (admission control,
-  bounded queue, chunked prefill interleaved with fused decode scans, an
-  in-flight dispatch window, retries and the watchdog) and
-  ``family_for``, the one place that picks a block family;
+- ``engine.py``  — ``ServingEngine`` (a family's programs, compiled and
+  probed) and ``family_for``, the one place that picks a block family;
+  ``run.py`` — the scheduler: ``TraceRun``, one run of one trace
+  (admission control, bounded queue, chunked prefill interleaved with
+  fused decode scans, an in-flight dispatch window, retries and the
+  watchdog);
 - ``gpt.py`` / ``hybrid.py`` — a block family's jitted device programs
   (prefill chunk, decode step and fused ladder, inject) and what the
   scheduler asks of a family; ``gpt.py`` also holds the speculative

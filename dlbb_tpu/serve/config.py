@@ -310,6 +310,11 @@ class ServingConfig:
                 f"serving.dispatch_deadline_min_s must be > 0 seconds, "
                 f"got {self.dispatch_deadline_min_s}"
             )
+        self._validate_token_paths(dp)
+
+    def _validate_token_paths(self, dp: int) -> None:
+        """The speculation, prefix-cache, int8 and sampling knobs (the
+        tail of :meth:`validate`)."""
         # -- speculation ladder (same no-op-trap contract as
         #    inflight_window: a knob that would silently do nothing is a
         #    config error) --

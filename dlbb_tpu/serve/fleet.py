@@ -234,7 +234,8 @@ class ReplicaControl:
         # degradation knobs the engine reads per loop iteration
         self.spec_enabled = True
         self.horizon_cap: Optional[int] = None
-        # lifecycle sink the supervisor installs (engine._event feeds it)
+        # lifecycle sink the supervisor installs (TraceRun._event feeds
+        # it)
         self.on_event: Optional[Callable[[int, str, dict], None]] = None
         # heartbeat state (supervisor-read)
         self.started = False

@@ -1208,8 +1208,8 @@ def decode_programs(config: ModelConfig, mesh: Mesh, ks: tuple[int, ...],
     (carry, ys)``: ``probe()`` gives the probed slots ``[PROBES]`` each
     program takes as its last argument, and ``ys`` is the triple
     ``(tokens, seen of the probed slots, counts)``.  Each goes by the
-    name of the jitted program it calls (``ServingEngine._launch``
-    reads it)."""
+    name of the jitted program it calls (``TraceRun._launch`` reads
+    it)."""
     def bound(program):
         @named(program.__name__)
         def call(carry, params, *masks):

@@ -1,4 +1,4 @@
-"""The launch sequence of the serving engine (``ServingEngine._launch``,
+"""The launch sequence of the serving engine (``TraceRun._launch``,
 ``docs/observability.md`` §1): every call of a jitted serving program on
 the scheduler's path is a numbered span, every wait of the scheduler
 thread for a device value names the launch it waits for, and with no
@@ -16,9 +16,11 @@ import pytest
 import test_obs
 from dlbb_tpu.models.configs import ModelConfig
 from dlbb_tpu.obs import spans
-from dlbb_tpu.serve import engine as engine_module
+from dlbb_tpu.resilience.preempt import PreemptionGuard
+from dlbb_tpu.serve import run as run_module
 from dlbb_tpu.serve.config import ServingConfig
 from dlbb_tpu.serve.engine import ServingEngine
+from dlbb_tpu.serve.lookahead import InputLookahead
 from dlbb_tpu.serve.traffic import Request, TrafficTrace
 
 GPT = ModelConfig(hidden_size=64, num_layers=2, num_heads=4,
@@ -298,7 +300,7 @@ def test_without_a_tracer_the_helper_takes_the_null_path_and_still_counts(
         asked.append((name, args, got))
         return got
 
-    monkeypatch.setattr(engine_module.spans, "span", recording)
+    monkeypatch.setattr(run_module.spans, "span", recording)
     report = engine.run_trace(_trace())
     mine = [(name, args, got) for name, args, got in asked
             if name in LAUNCH_SPANS or SYNC.match(name)]
@@ -314,7 +316,8 @@ def test_without_a_tracer_the_helper_takes_the_null_path_and_still_counts(
 
 def test_the_helper_numbers_any_call_and_runs_it_under_a_guard(mesh2x4):
     engine = _engine("gpt", mesh2x4)
-    engine._stats = engine_module._RunStats()
+    run = run_module.TraceRun(engine, _trace(), PreemptionGuard(),
+                              InputLookahead(engine._request_input))
 
     def serve_toy(a, b):
         return a + b
@@ -325,10 +328,10 @@ def test_the_helper_numbers_any_call_and_runs_it_under_a_guard(mesh2x4):
         guarded.append(True)
         return call()
 
-    assert engine._launch(serve_toy, 1, 2) == 3
-    assert engine._launch(serve_toy, 3, 4, span="serve-decode-dispatch",
-                          fields=lambda: {"k": 1}, via=guard) == 7
-    assert engine._stats.launches == 2 and guarded == [True]
+    assert run._launch(serve_toy, 1, 2) == 3
+    assert run._launch(serve_toy, 3, 4, span="serve-decode-dispatch",
+                       fields=lambda: {"k": 1}, via=guard) == 7
+    assert run.stats.launches == 2 and guarded == [True]
 
 
 def test_traced_and_untraced_runs_launch_alike_and_compile_alike(

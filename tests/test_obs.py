@@ -154,7 +154,7 @@ def test_cell_paths_open_no_annotation_by_hand():
     there imports the profiling wrappers or ``jax.profiler`` itself."""
     import ast
 
-    for rel in ("serve/engine.py", "train/loop.py",
+    for rel in ("serve/engine.py", "serve/run.py", "train/loop.py",
                 "models/transformer.py", "ops/flash_attention.py"):
         tree = ast.parse((REPO / "dlbb_tpu" / rel).read_text())
         for node in ast.walk(tree):

@@ -1290,11 +1290,17 @@ def test_event_emits_nothing_without_a_tracer(baseline_engine):
     """No tracer: ``_event`` is a global load and the span entry point
     still hands back the one shared null context."""
     from dlbb_tpu.obs import spans
+    from dlbb_tpu.resilience.preempt import PreemptionGuard
+    from dlbb_tpu.serve.lookahead import InputLookahead
+    from dlbb_tpu.serve.run import TraceRun
 
     assert spans.active() is None
     assert spans.span("serve-admit-plan", rid=0, slot=0) is \
         spans.span("serve-decode")
-    baseline_engine._event("request-arrived", 0)     # must not raise
+    run = TraceRun(baseline_engine, _trace([Request(0, 0.0, 4, 2, seed=0)]),
+                   PreemptionGuard(),
+                   InputLookahead(baseline_engine._request_input))
+    run._event("request-arrived", 0)     # must not raise
     assert spans.active() is None
 
 

@@ -96,9 +96,9 @@ def test_decode_hot_path_static_zero_injection_pin():
                 raise AssertionError(
                     f"injection reference inside {module}, line "
                     f"{sub.lineno}")
-    # the scheduler is where the sites fire
+    # the scheduler's run of a trace is where the sites fire
     assert "inject.fire(" in (
-        REPO / "dlbb_tpu" / "serve" / "engine.py").read_text()
+        REPO / "dlbb_tpu" / "serve" / "run.py").read_text()
     for module in ("serve/kvcache.py", "ops/decode_attention.py"):
         assert "inject" not in (REPO / "dlbb_tpu" / module).read_text()
     assert "def decode_attention(" in (
@@ -321,7 +321,7 @@ def test_deferred_settle_past_its_deadline_restarts_the_prefill(
     the prefill restarts on the fresh carry, only the resident batch
     fails, and the admitting request's tokens are an unfaulted run's."""
     from dlbb_tpu.resilience.errors import DeadlineExceeded
-    from dlbb_tpu.serve import engine as engine_module
+    from dlbb_tpu.serve import run as run_module
 
     engine = ServingEngine(
         SMOKE_MODEL, replace(SMOKE_SERVING, prefill_chunk=8),
@@ -338,7 +338,7 @@ def test_deferred_settle_past_its_deadline_restarts_the_prefill(
     )
     baseline = engine.run_trace(trace)
     assert baseline["requests"]["completed"] == 2
-    real = engine_module._with_deadline
+    real = run_module._with_deadline
     hung = []
 
     def first_wait_hangs(fn, deadline, label, phase):
@@ -347,7 +347,7 @@ def test_deferred_settle_past_its_deadline_restarts_the_prefill(
             raise DeadlineExceeded(label, 0.3, phase=phase)
         return real(fn, deadline, label, phase)
 
-    monkeypatch.setattr(engine_module, "_with_deadline", first_wait_hangs)
+    monkeypatch.setattr(run_module, "_with_deadline", first_wait_hangs)
     with spans.tracing(tmp_path / "spans.json"):
         report = engine.run_trace(trace)
     assert hung == ["decode[k=1]"]
